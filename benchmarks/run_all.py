@@ -1,6 +1,11 @@
 """Run every secondary benchmark (SURVEY §5 / BASELINE configs 1-5) and
 print one JSON line each.  The headline ResNet-50 bench lives in
-../bench.py."""
+../bench.py.
+
+One process for each chip: every bench is a child of its own, run one
+after another, and this parent never imports jax.  Keep it so — a parent
+that touches jax holds the chip, and every child that needs it then
+fails or hangs."""
 import subprocess
 import sys
 import os

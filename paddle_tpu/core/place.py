@@ -22,20 +22,29 @@ class Place(object):
         return hash((type(self).__name__, self.device_id))
 
     def jax_device(self):
-        """Resolve to a concrete LOCAL jax.Device, falling back to the
-        default backend when the requested platform is absent (e.g.
-        asking for TPUPlace on a CPU-only host during tests).  Local
-        devices only: in a multi-process (distributed.launch) run,
-        jax.devices() leads with process 0's devices, which other
-        processes cannot place data on."""
-        if self._platform is not None:
-            try:
-                devs = jax.local_devices(backend=self._platform)
-            except RuntimeError:
-                devs = jax.local_devices()
-        else:
+        """Resolve to a concrete LOCAL jax.Device.  A place that names a
+        platform (CPUPlace, TPUPlace) raises when that backend is absent
+        or holds fewer than ``device_id + 1`` local devices — asking for
+        a chip and silently computing somewhere else is how a CPU run
+        gets reported as a TPU one.  XLAPlace names no platform and
+        means "whatever there is", ordinal wrapped.  Local devices only:
+        in a multi-process (distributed.launch) run, jax.devices() leads
+        with process 0's devices, which other processes cannot place
+        data on."""
+        if self._platform is None:
             devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+            return devs[self.device_id % len(devs)]
+        try:
+            devs = jax.local_devices(backend=self._platform)
+        except RuntimeError as e:
+            raise RuntimeError(
+                "%r: this process has no %r backend (jax's default "
+                "backend is %r)" % (self, self._platform,
+                                    jax.default_backend())) from e
+        if self.device_id >= len(devs):
+            raise RuntimeError("%r: only %d local %s device(s)"
+                               % (self, len(devs), self._platform))
+        return devs[self.device_id]
 
 
 class CPUPlace(Place):

@@ -1,62 +1,19 @@
-"""Version-compat shims for the SPMD lowering path.
+"""The PADDLE_TPU_MESH flag: parse it, key plans on it, build its Mesh.
 
-The container toolchain pins a jax whose public surface moved between
-releases: ``jax.shard_map`` only exists as
-``jax.experimental.shard_map.shard_map`` here, and newer mesh helpers
-(``jax.make_mesh``) are absent.  Every sharding-propagation consumer
-(transpiler/sharding.py, core/executor.py, the benches) resolves those
-APIs through this module — the PR-4 ``ops/pallas/_compat.py`` pattern —
-so the SPMD path degrades per-feature instead of failing at import on
-whichever jax the host ships.
-
-Also home of the mesh-flag plumbing: ``PADDLE_TPU_MESH`` parses once
-per lookup (cheap string work), and the constructed ``jax.sharding.Mesh``
-objects are cached per normalized spec so every plan build under one
-configuration shares one Mesh instance (Mesh identity participates in
-executor plan-cache keys).
+``PADDLE_TPU_MESH`` parses once per lookup (cheap string work), and the
+constructed ``jax.sharding.Mesh`` objects are cached per normalized spec
+so every plan build under one configuration shares one Mesh instance
+(Mesh identity participates in executor plan-cache keys).  Every
+sharding-propagation consumer (transpiler/sharding.py, core/executor.py,
+the benches) goes through this module.
 """
 import threading
 
-__all__ = ['resolve_shard_map', 'has_shard_map', 'mesh_axes_from_flag',
-           'mesh_for', 'named_sharding', 'spmd_device_count']
+__all__ = ['mesh_axes_from_flag', 'mesh_key', 'mesh_for',
+           'named_sharding', 'spmd_device_count']
 
 _lock = threading.Lock()
 _mesh_cache = {}  # canonical spec string -> Mesh
-
-
-def resolve_shard_map():
-    """The shard_map entry point of whatever jax is installed, or None.
-
-    Prefers the stable ``jax.shard_map`` (newer jax), falls back to
-    ``jax.experimental.shard_map.shard_map`` (the container's 0.4.x),
-    and returns None when neither exists — callers must gate, never
-    assume (the pjit/GSPMD lowering below needs no shard_map at all,
-    so absence only disables the explicitly-mapped code paths).
-    """
-    import jax
-    sm = getattr(jax, 'shard_map', None)
-    if sm is not None and not _is_deprecated_stub(jax, 'shard_map'):
-        return sm
-    try:
-        from jax.experimental.shard_map import shard_map as esm
-        return esm
-    except Exception:
-        return None
-
-
-def _is_deprecated_stub(mod, name):
-    """jax 0.4.x raises through a module __getattr__ deprecation shim
-    for names that LOOK present via getattr with a default — probe by
-    real attribute access."""
-    try:
-        getattr(mod, name)
-        return False
-    except AttributeError:
-        return True
-
-
-def has_shard_map():
-    return resolve_shard_map() is not None
 
 
 def mesh_axes_from_flag(value=None):
@@ -102,9 +59,8 @@ def mesh_for(axes):
         m = _mesh_cache.get(key)
     if m is not None:
         return m
-    import numpy as np
     import jax
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType
     n = spmd_device_count(axes)
     devices = jax.devices()
     if len(devices) < n:
@@ -114,8 +70,13 @@ def mesh_for(axes):
             "XLA_FLAGS=--xla_force_host_platform_device_count=%d"
             % (key, n, devices[0].platform if devices else '?',
                len(devices), n))
-    arr = np.array(devices[:n]).reshape([s for _n, s in axes])
-    m = Mesh(arr, tuple(name for name, _s in axes))
+    # make_mesh orders the first n devices along the chip interconnect
+    # (enumeration order is not ring order on a TPU); Auto axes keep the
+    # GSPMD semantics the sharding pass plans for
+    m = jax.make_mesh([s for _n, s in axes],
+                      tuple(name for name, _s in axes),
+                      axis_types=(AxisType.Auto,) * len(axes),
+                      devices=devices[:n])
     with _lock:
         _mesh_cache[key] = m
     return m

@@ -89,9 +89,9 @@ def from_mesh(program=None, pp_axis='pp', cut_vars=None,
     the 1F1B engine's per-stage branches and ppermute transfers.
     """
     from ..flags import FLAGS
-    from . import _compat
+    from . import mesh_flag
     program = program or default_main_program()
-    axes = _compat.mesh_axes_from_flag()
+    axes = mesh_flag.mesh_axes_from_flag()
     sizes = dict(axes or ())
     stages = int(sizes.get(pp_axis, 0))
     if stages < 2:
@@ -112,7 +112,7 @@ def from_mesh(program=None, pp_axis='pp', cut_vars=None,
         cut_vars = list(cuts)
     t = PipelineTranspiler()
     t.transpile(program, cut_vars=cut_vars, pp_axis=pp_axis)
-    t.mesh = _compat.mesh_for(axes)
+    t.mesh = mesh_flag.mesh_for(axes)
     t.num_microbatches = max(
         int(num_microbatches or FLAGS.pp_microbatches or 1), 1)
     return t

@@ -31,7 +31,7 @@ of that answer, the ``SpecLayout`` pattern from SNIPPETS.md [1]
 
 Specs here are plain hashable tuples (one entry per dim: an axis name,
 a tuple of axis names, or None) so they can ride op attrs through the
-verifier and the infer-cache; ``distributed/_compat.named_sharding``
+verifier and the infer-cache; ``distributed/mesh_flag.named_sharding``
 turns them into jax NamedShardings at jit time.
 """
 import re

@@ -111,29 +111,30 @@ DEFINE_int('graph_opt_level', 2,
            'numerically equivalent (folded constants are evaluated '
            'eagerly, so fused rounding in consumers can differ at ulp '
            'scale)')
-DEFINE_string('sparse_apply', 'auto',
+DEFINE_string('sparse_apply', 'xla',
               'lowering for the row-wise sparse optimizer apply '
-              '(SelectedRows grads in sgd/adagrad/adam): "pallas" runs '
-              'the O(touched-rows) Pallas table-update kernels '
+              '(SelectedRows grads in sgd/adagrad/adam): "xla" (default) '
+              'keeps the .at[rows].add scatter path; "pallas" runs the '
+              'row-walking Pallas table-update kernels '
               '(ops/pallas/table_update.py, interpret mode off-TPU), '
-              '"xla" keeps the .at[rows].add scatter path (an '
-              'O(table-height) pass per scattered table on TPU), '
-              '"auto" (default) picks pallas on TPU and xla elsewhere. '
-              'Resolved per trace and part of the executor plan cache '
-              'key, so flips take effect on the next plan build')
-DEFINE_string('dense_apply', 'auto',
+              'which compile and match on a v5e but measured slower '
+              'there (PERF.md, chip bring-up) and which jax refuses '
+              'inside a PADDLE_TPU_MESH step outside the embedding '
+              'engine\'s shard_map.  Resolved per trace and part of the '
+              'executor plan cache key, so flips take effect on the '
+              'next plan build')
+DEFINE_string('dense_apply', 'xla',
               'lowering for the dense optimizer apply (sgd/momentum/'
-              'adam dense branches): "pallas" runs the fused one-pass '
-              'flat-walk kernels (ops/pallas/dense_update.py — param + '
-              'every moment read once, written once in place; '
-              'interpret mode off-TPU), "xla" keeps the jnp expression '
-              'chains (several fusions with intermediate HBM '
-              'round-trips per parameter), "auto" (default) picks '
-              'pallas on TPU and xla elsewhere.  Resolved per trace '
-              'and part of the executor plan cache key, so flips '
+              'adam dense branches): "xla" (default) keeps the jnp '
+              'expression chains; "pallas" runs the fused one-pass '
+              'flat-walk kernels (ops/pallas/dense_update.py, interpret '
+              'mode off-TPU), which compile and match on a v5e but cost '
+              'a relayout of every parameter there (ResNet-50 step 79 '
+              'ms against 28; PERF.md, chip bring-up) and which jax '
+              'refuses inside a PADDLE_TPU_MESH step.  Resolved per '
+              'trace and part of the executor plan cache key, so flips '
               '(including after Executor.reset_cache()) take effect '
-              'on the next plan build.  Both lowerings are '
-              'bitwise-identical (tests/test_pallas_dense_update.py)')
+              'on the next plan build')
 DEFINE_bool('device_prefetch', False,
             'device-resident double-buffered feed for '
             'Executor.run_steps with per-step feeds: the K-step feed '
@@ -232,9 +233,11 @@ DEFINE_string('aot_cache_dir', '',
               'there (jax serialize_executable) so a brand-new '
               'PROCESS deploys by deserializing instead of '
               'trace+compile: zero warmup compiles on a warm cache.  '
-              'Point it at PADDLE_TPU_COMPILATION_CACHE_DIR to keep '
-              'the serialized executables next to the XLA compile '
-              'cache.  Empty (default) disables AOT serialization')
+              'Empty (default) disables AOT serialization: unlike the '
+              'tuner cache it does not fall back to the compile-cache '
+              'directory, because deserializing fails when the process '
+              'has more devices than the executable was built for '
+              '(ROADMAP D10)')
 DEFINE_string('verify_ir', 'boundary',
               'static program verifier over the pass-manager rewrite '
               'pipeline (transpiler/verify.py): "boundary" (default) '
@@ -418,9 +421,9 @@ DEFINE_string('tune', 'off',
 DEFINE_string('tune_cache_dir', '',
               'where tuner winners persist (JSON, one file per '
               '(plan key, device kind, mesh) under a paddle_tpu_tuning/ '
-              'subdir).  Empty falls back to '
-              'PADDLE_TPU_COMPILATION_CACHE_DIR; empty too means no '
-              'persistence (search results live only in-process).  A '
+              'subdir).  Empty falls back to the compile-cache '
+              'directory (compile_cache.py: JAX_COMPILATION_CACHE_DIR '
+              'when set, else <checkout>/.jax_cache).  A '
               'corrupted cache file is counted '
               '(paddle_tpu_tune_cache_corrupt_total) and ignored — '
               'defaults apply, nothing crashes')
@@ -541,15 +544,6 @@ DEFINE_int('pp_microbatches', 4,
            'shrinks the bubble but shrinks per-microbatch work.  '
            'Read by distributed/pipeline.from_mesh and the sharding '
            'pass pp plan block; a registered tunable')
-DEFINE_string('compilation_cache_dir', '',
-              'opt-in persistent XLA compilation cache directory: compiled '
-              'executables (Executor plans, serving warmup buckets) are '
-              'written here and reloaded across process restarts, turning '
-              'multi-second XLA compiles into disk reads.  Empty disables. '
-              'Caveats: entries key on jax/XLA version + topology, so a '
-              'toolchain upgrade silently recompiles; the cache grows '
-              'unboundedly (prune externally); and a shared dir must live '
-              'on a filesystem with atomic renames')
 
 
 if __name__ == '__main__':

@@ -1,6 +1,6 @@
 """AOT-serialized compiled-executable cache: zero-compile cold start.
 
-The persistent XLA compilation cache (PADDLE_TPU_COMPILATION_CACHE_DIR)
+The persistent XLA compilation cache (compile_cache.py)
 already makes a fresh process's warmup cheap — but not free: every
 bucket still pays deserialize + trace + lower before the cache can even
 be consulted.  This cache removes the whole pipeline from the serving

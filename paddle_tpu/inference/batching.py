@@ -56,7 +56,7 @@ import jax
 
 from .. import observability as _obs
 from ..analysis import lockdebug as _lkd
-from ..core.executor import _maybe_enable_compilation_cache
+from ..compile_cache import enable_compile_cache
 from ..observability import timeline as _tlm
 from .aot_cache import AotCache, artifact_digest
 from .serving import InferenceServer, export_inference
@@ -269,7 +269,7 @@ class BatchingInferenceServer(object):
             # old constructor default; explicit max_wait_ms= still wins
             from ..flags import FLAGS
             max_wait_ms = float(FLAGS.serving_max_wait_ms)
-        _maybe_enable_compilation_cache()
+        enable_compile_cache()
         if share_artifacts_with is not None:
             # a sibling server over the SAME model version: reuse its
             # deserialized artifacts and AOT-compiled executables

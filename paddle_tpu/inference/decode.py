@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from .. import observability as _obs
 from ..analysis import lockdebug as _lkd
+from ..compile_cache import enable_compile_cache
 from ..core.registry import get_op_impl
 from ..transpiler.memory_model import page_pool_bytes
 
@@ -302,6 +303,7 @@ class DecodeEngine(object):
                  prefix_cache=None, prefill_chunk_tokens=None,
                  dtype=jnp.float32):
         from ..flags import FLAGS
+        enable_compile_cache()
         self.params = {n: jnp.asarray(v) for n, v in params.items()}
         self.n_layers = int(n_layers)
         self.n_heads = int(n_heads)

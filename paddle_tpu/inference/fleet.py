@@ -26,9 +26,8 @@ servable/version manager) and load-aware replica dispatch (Clipper):
   ``export_bucketed`` artifact directory (``io.resolve_version_dir``
   understands both a bare artifact dir and a TF-Serving-style base dir
   of numbered versions), builds and **warms a full replica set in the
-  background** — the old version keeps serving; with a persistent
-  compile cache (``PADDLE_TPU_COMPILATION_CACHE_DIR``) warmup is disk
-  reads and the new replicas report zero post-warmup compiles — then
+  background** — the old version keeps serving; with a warm persistent
+  compile cache (compile_cache.py) warmup is disk reads and the new replicas report zero post-warmup compiles — then
   atomically flips routing and drains the old replicas so their queued
   and in-flight requests all complete.  Zero requests are dropped at
   the flip by construction: every request holds a Future bound to

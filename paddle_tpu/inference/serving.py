@@ -17,8 +17,9 @@ import jax.numpy as jnp
 from jax import export as jax_export
 
 from .. import observability as _obs
+from ..compile_cache import enable_compile_cache
 from ..core import datatypes
-from ..core.executor import Executor, _maybe_enable_compilation_cache
+from ..core.executor import Executor
 from ..core.place import default_place
 from ..core.program import Variable, default_main_program
 from ..core.scope import global_scope
@@ -96,7 +97,7 @@ def _open_exported(path):
     InferenceServer both build on it).  The jit cache matters: bare
     exported.call re-traces (and re-compiles) on every invocation —
     measured 4s/call vs 2ms for ResNet-50 b8."""
-    _maybe_enable_compilation_cache()
+    enable_compile_cache()
     with open(path, 'rb') as f:
         exported = jax_export.deserialize(f.read())
     if _obs.enabled():

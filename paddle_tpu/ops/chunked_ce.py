@@ -189,13 +189,18 @@ def _dense_bytes_budget():
             return int(float(mb) * 1024 * 1024)
         except ValueError:
             pass
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        hbm = int(stats.get('bytes_limit', 0))
-    except Exception:
-        hbm = 0
+    if jax.default_backend() != 'tpu':
+        # no accelerator to size against (memory_stats() is None on the
+        # CPU backend): the v5e figure keeps CPU runs on the path a v5e
+        # would take
+        return (16 << 30) // 8
+    dev = jax.devices()[0]
+    hbm = int((dev.memory_stats() or {}).get('bytes_limit', 0))
     if hbm <= 0:
-        hbm = 16 << 30  # v5e default when the backend has no stats
+        raise RuntimeError(
+            "cannot size the dense-CE budget: %s (%s) reports no "
+            "memory_stats()['bytes_limit'] (set "
+            "PADDLE_TPU_DENSE_CE_BUDGET_MB)" % (dev, dev.device_kind))
     return hbm // 8
 
 

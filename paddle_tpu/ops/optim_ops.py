@@ -14,34 +14,32 @@ on the touched rows).  Other optimizers densify via scatter-add.
 The ROW-WISE apply itself has two interchangeable lowerings, selected
 per trace by ops/pallas/table_update.sparse_apply_mode():
 
-  'xla'    — the `.at[rows].add` scatter path below, verbatim.  Exact,
-             but XLA:TPU lowers every scatter as a full pass over the
-             table operand (O(table height) per scattered table —
-             PERF.md "CTR at Criteo scale").
+  'xla'    — the `.at[rows].add` scatter path below, verbatim (the
+             default).  XLA:TPU lowers every scatter as a pass over the
+             table operand.
   'pallas' — ops/pallas/table_update.py: a grid over the touched rows
-             updates the donated table in place, O(touched rows), with
-             Adagrad's param+moment (and Adam's param+both-moments)
-             fused into ONE kernel pass.  Bitwise-identical to the XLA
-             path (tier-1 tests/test_pallas_table_update.py).
+             updates the donated table in place, with Adagrad's
+             param+moment (and Adam's param+both-moments) fused into
+             ONE kernel pass.  Bitwise-identical to the XLA path
+             (tier-1 tests/test_pallas_table_update.py, and on a v5e:
+             chip_smoke.py), and slower than it there at the CTR bench
+             shape — one grid step per touched row.
 
-PADDLE_TPU_SPARSE_APPLY=xla|pallas pins the path (default: pallas on
-TPU, xla elsewhere); the resolved mode is part of the executor's plan
-cache key, so a flip retraces.
+PADDLE_TPU_SPARSE_APPLY=pallas pins the kernel path; the resolved mode
+is part of the executor's plan cache key, so a flip retraces.
 
 The DENSE applies of sgd/momentum/adam have the same two lowerings,
 selected by ops/pallas/dense_update.dense_apply_mode()
-(PADDLE_TPU_DENSE_APPLY, same default/cache-key contract):
+(PADDLE_TPU_DENSE_APPLY, same default and cache-key contract):
 
-  'xla'    — the jnp expression chains below, verbatim: several fused
-             multiply-adds whose intermediates round-trip HBM between
-             fusions (dense Adam reads/writes each state table more
-             than once per step).
+  'xla'    — the jnp expression chains below, verbatim.
   'pallas' — ops/pallas/dense_update.py: ONE grid walk over the
-             flattened param applies the whole rule — each state table
-             is read once and written once through
-             input_output_aliases.  Bitwise-identical to the XLA path
-             (tier-1 tests/test_pallas_dense_update.py), AMP f32-master
-             grads included.
+             flattened param applies the whole rule through
+             input_output_aliases.  Matches the XLA path (tier-1
+             tests/test_pallas_dense_update.py; exactly, on a v5e),
+             AMP f32-master grads included; on the v5e the flat view
+             costs a relayout of every parameter (PERF.md, chip
+             bring-up).
 """
 import jax.numpy as jnp
 

@@ -1,13 +1,16 @@
 """Fused dense optimizer applies as Pallas TPU kernels.
 
 Reference parity: the DENSE branches of paddle/operators/{sgd,momentum,
-adam}_op — elementwise updates over whole parameters.  The XLA lowering
-of today's path (ops/optim_ops.py) is an op soup per parameter: dense
-Adam is three multiply-add chains whose intermediates (`m_new`, `v_new`,
-the step) round-trip HBM between fusions, so the optimizer apply reads
-and writes each state table several times per step.  At ResNet/VGG batch
-sizes the roofline says this — not matmul — is where the non-MFU time
-lives (PERF.md "MFU accounting", BENCH r05 ~0.15 MFU).
+adam}_op — elementwise updates over whole parameters.  Written on the
+premise that XLA's lowering of the same expressions (ops/optim_ops.py)
+round-trips `m_new` / `v_new` through HBM between fusions and that the
+optimizer apply is where a ResNet/VGG step loses its time.  The first
+device trace since (v5e, PR 21) says otherwise: with the XLA expressions
+the ResNet-50 b64 step is 27.9 ms, with these kernels 79.4 — the kernel
+calls themselves are 0.24 ms, the reshapes and copies that turn every
+tiled parameter into the [1, N] view and back are 51 ms.  The kernels
+stay, off by default, for the issue that gives them a layout-preserving
+view and a cell to win (ROADMAP S2, D4).
 
 These kernels fuse each rule into ONE grid walk over the flattened
 parameter: every block DMAs a [1, T] tile of param + each moment out of
@@ -38,18 +41,17 @@ into the kernel exactly as they are baked into the XLA branch.
 
 On non-TPU backends the kernels run with interpret=True — CPU CI
 executes the same code path.  The mode switch lives in
-`dense_apply_mode()`: PADDLE_TPU_DENSE_APPLY=pallas|xla forces a path,
-default is pallas on TPU and xla elsewhere; ops/optim_ops.py routes on
-it per trace and the resolved mode is part of the executor's plan cache
-key, so a flip retraces instead of silently serving the old lowering.
+`dense_apply_mode()`: PADDLE_TPU_DENSE_APPLY=pallas|xla forces a path;
+ops/optim_ops.py routes on it per trace and the resolved mode is part of
+the executor's plan cache key, so a flip retraces instead of silently
+serving the old lowering.
 """
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ['dense_apply_sgd', 'dense_apply_momentum', 'dense_apply_adam',
            'dense_apply_mode', 'pick_flat_tile', 'flat_tile_budget']
@@ -63,17 +65,20 @@ _TILES = (65536, 32768, 16384, 8192, 4096, 2048, 1024, 512, 256, 128)
 
 
 def dense_apply_mode():
-    """Resolved dense-apply path: 'pallas' or 'xla'.
+    """Resolved dense-apply path: 'xla' unless PADDLE_TPU_DENSE_APPLY
+    pins 'pallas'.
 
-    PADDLE_TPU_DENSE_APPLY=pallas|xla pins it; the default ('auto')
-    picks pallas on a TPU backend and xla elsewhere.  Read at trace
-    time and part of the executor's plan cache key, so a flip retraces
-    instead of silently serving the old path."""
+    The kernels compile and match on a v5e, and lose there: the [1, N]
+    view costs a relayout of every parameter on the way in and out —
+    ResNet-50 b64, device trace, 79.4 ms per step against 27.9 with the
+    XLA expressions (PERF.md, chip bring-up, PR 21) — so no platform
+    selects them on its own any more.  Pinned to 'pallas' under
+    PADDLE_TPU_MESH, jax refuses the step at lowering ("Mosaic kernels
+    cannot be automatically partitioned").  Read at trace time and part
+    of the executor's plan cache key, so a flip retraces instead of
+    silently serving the old path."""
     from ...flags import FLAGS
-    mode = FLAGS.dense_apply
-    if mode in ('pallas', 'xla'):
-        return mode
-    return 'pallas' if jax.default_backend() == 'tpu' else 'xla'
+    return 'pallas' if FLAGS.dense_apply == 'pallas' else 'xla'
 
 
 def flat_tile_budget():
@@ -155,7 +160,7 @@ def _flat_call(tables, vals, scalars, rule, interpret):
         input_output_aliases={t: t for t in range(nt)},
         # tiles are disjoint; 'arbitrary' (sequential) is always valid
         # and the walk is bandwidth-bound either way
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         interpret=interpret,
     )(*flat, *vflat, *sflat)

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 __all__ = ['flash_attention']
 
 _NEG_INF = -1e30
@@ -189,7 +187,7 @@ def _dimsem(*sems):
     The scoped-vmem limit is raised from the 16 MB default: the
     interior/masked two-branch tails hold two [bq, bk] fp32 tiles live
     (~18.4 MB at 1024x1024), and v5e has 128 MB of VMEM to spend."""
-    return _CompilerParams(dimension_semantics=sems,
+    return pltpu.CompilerParams(dimension_semantics=sems,
                            vmem_limit_bytes=64 * 1024 * 1024)
 
 

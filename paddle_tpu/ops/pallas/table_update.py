@@ -11,10 +11,13 @@ gradients are row-sparse end-to-end.
 
 These kernels make the apply O(touched rows x row width), independent of
 table height: the grid walks the touched rows; each program's BlockSpec
-index map (computed from the scalar-prefetched row ids) DMAs exactly one
-[1, D] row of each state table out of HBM, applies the optimizer rule on
-the VPU, and stores the row back through `input_output_aliases` — the
-table is donated, never copied, and untouched rows are never read.
+index map (computed from the scalar-prefetched row ids) DMAs the aligned
+8-row block around one touched row of each state table out of HBM (the
+TPU lowering takes no narrower window: a block's second-minor dim is a
+multiple of 8 or the whole array's), applies the optimizer rule to that
+row on the VPU, and stores the block back through
+`input_output_aliases` — the table is donated, never copied, and blocks
+without a touched row are never read.
 
 Three fused rules ship, matching the sparse branches in ops/optim_ops.py
 expression-for-expression (bitwise parity is tested, not hoped for):
@@ -29,11 +32,11 @@ expression-for-expression (bitwise parity is tested, not hoped for):
                                                     touched rows)
 
 Row-id contract (the whole family): ids are sorted ascending before the
-kernel sees them.  Sorting makes duplicate rows CONSECUTIVE, which is
-what lets a revisited row ride Mosaic's resident-block rule — when the
-index map output doesn't change between grid steps, the block stays in
-VMEM with no refetch and no intermediate store, so sequential
-accumulation into the out block is race-free.  Ids follow the oracle's
+kernel sees them.  Sorting makes duplicate rows, and rows of one block,
+CONSECUTIVE, which is what lets a revisited block ride Mosaic's
+resident-block rule — when the index map output doesn't change between
+grid steps, the block stays in VMEM with no refetch and no intermediate
+store, so sequential accumulation into the out block is race-free.  Ids follow the oracle's
 index semantics exactly: negatives in [-height, 0) wrap Python-style
 (like XLA scatter/gather), and anything else outside [0, height) is a
 sentinel — it sorts to the tail (clamped into range for the index map
@@ -44,10 +47,11 @@ bitwise-exact.  merge_rows_sentinel (core/selected_rows.py) produces
 exactly this layout.
 
 On non-TPU backends the kernels run with interpret=True — CPU CI
-executes the same code path (how the tier-1 parity tests work).  The
-mode switch lives in `sparse_apply_mode()`:
-PADDLE_TPU_SPARSE_APPLY=pallas|xla forces a path, default is pallas on
-TPU and xla elsewhere; ops/optim_ops.py routes on it per trace.
+executes the same code path (how the tier-1 parity tests work), and
+tests/test_tpu_lowering.py lowers them for the TPU, which interpret mode
+does not.  The mode switch lives in `sparse_apply_mode()`:
+PADDLE_TPU_SPARSE_APPLY=pallas|xla forces a path; ops/optim_ops.py
+routes on it per trace.
 """
 import functools
 
@@ -57,41 +61,54 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.selected_rows import merge_rows_sentinel
-from ._compat import CompilerParams as _CompilerParams
 
 __all__ = ['sparse_apply_sgd', 'sparse_apply_adagrad', 'sparse_apply_adam',
            'sparse_apply_mode']
 
 
 def sparse_apply_mode():
-    """Resolved sparse-apply path: 'pallas' or 'xla'.
+    """Resolved sparse-apply path: 'xla' unless PADDLE_TPU_SPARSE_APPLY
+    pins 'pallas'.
 
-    PADDLE_TPU_SPARSE_APPLY=pallas|xla pins it; the default ('auto')
-    picks pallas on a TPU backend and xla elsewhere.  Read at trace
-    time and part of the executor's plan cache key, so a flip retraces
-    instead of silently serving the old path."""
+    The kernels compile and match bit for bit on a v5e, and lose there
+    at the one shape measured: 32768 touched rows of a 1,000,003 x 16
+    table take 8.6 / 14.8 / 19.2 ms per sgd / adagrad / adam call
+    against 4.4 / 7.3 / 13.6 ms for the scatter expressions — one grid
+    step per touched row is the cost (PERF.md, chip bring-up, PR 21) —
+    so no platform selects them on its own any more.  Pinned to
+    'pallas' under PADDLE_TPU_MESH, only the embedding engine's
+    per-shard apply (inside a shard_map) can host the kernel; anywhere
+    else jax refuses the step at lowering ("Mosaic kernels cannot be
+    automatically partitioned").  Read at trace time and part of the
+    executor's plan cache key, so a flip retraces instead of silently
+    serving the old path."""
     from ...flags import FLAGS
-    mode = FLAGS.sparse_apply
-    if mode in ('pallas', 'xla'):
-        return mode
-    return 'pallas' if jax.default_backend() == 'tpu' else 'xla'
+    return 'pallas' if FLAGS.sparse_apply == 'pallas' else 'xla'
 
 
-def _rowwise_kernel(rows_ref, *refs, nt, nv, ns, height, accumulate,
-                    rule):
-    """One grid step = one touched row.  refs layout: nt table blocks,
-    nv value blocks, ns scalar blocks, then nt aliased out blocks.
+# rows per block: the f32 sublane count.  The TPU lowering refuses a
+# block whose second-minor dim is neither a multiple of 8 nor the whole
+# array's, so a one-row (1, D) window does not lower; the walk fetches
+# the aligned 8-row block around each touched row instead and updates
+# the one row inside it
+_SUBLANES = 8
 
-    Block identity is the CLAMPED row (the index map clamps sentinels
-    into range), so `fresh` — "this grid step targets a different table
-    row than the previous one" — must compare clamped ids: a sentinel
-    step immediately after a real update of row height-1 shares its
-    block and must not be treated as a first visit."""
+
+def _rowwise_kernel(rows_ref, *refs, nt, nv, ns, height, tb, vb, rule):
+    """One grid step = one touched row.  refs layout: nt table blocks
+    [tb, D], nv value blocks [vb, D], ns scalar blocks, then nt aliased
+    out blocks.
+
+    Block identity is the CLAMPED row's block (the index map clamps
+    sentinels into range), so `fresh` — "this grid step targets a
+    different table block than the previous one" — must compare clamped
+    block ids: a sentinel step right after a real update in the last
+    block shares that block and must not be treated as a first visit."""
     i = pl.program_id(0)
     row = rows_ref[i]
     h1 = height - 1
-    bi = jnp.minimum(row, h1)
-    prev_bi = jnp.minimum(rows_ref[jnp.maximum(i - 1, 0)], h1)
+    bi = jnp.minimum(row, h1) // tb
+    prev_bi = jnp.minimum(rows_ref[jnp.maximum(i - 1, 0)], h1) // tb
     fresh = jnp.logical_or(i == 0, bi != prev_bi)
     valid = jnp.logical_and(row >= 0, row < height)
     tabs = refs[:nt]
@@ -99,36 +116,30 @@ def _rowwise_kernel(rows_ref, *refs, nt, nv, ns, height, accumulate,
     scalars = tuple(r[0, 0] for r in refs[nt + nv:nt + nv + ns])
     outs = refs[nt + nv + ns:]
 
-    @pl.when(jnp.logical_and(valid, fresh))
-    def _update():
-        for o, new in zip(outs, rule(tuple(t[...] for t in tabs),
-                                     tuple(v[...] for v in vals),
-                                     scalars)):
-            o[...] = new
-
-    if accumulate:
-        # duplicate of the previous row: the block is resident (no
-        # refetch, no store happened in between) — accumulate into the
-        # out block, reproducing scatter-add's per-row slot order
-        @pl.when(jnp.logical_and(valid, jnp.logical_not(fresh)))
-        def _accum():
-            for o, new in zip(outs, rule(tuple(o[...] for o in outs),
-                                         tuple(v[...] for v in vals),
-                                         scalars)):
-                o[...] = new
-
-    # first visit of a clamped sentinel block with no real update for
-    # that row: write the fetched content back unchanged — every block a
-    # grid step maps is stored, so leaving it unwritten would store
-    # garbage over the row
-    @pl.when(jnp.logical_and(jnp.logical_not(valid), fresh))
-    def _copy_back():
+    # first visit of a block: every block a grid step maps is stored, so
+    # the out block starts as the fetched content (untouched rows, and a
+    # clamped sentinel's whole block, go back unchanged)
+    @pl.when(fresh)
+    def _copy_in():
         for o, t in zip(outs, tabs):
             o[...] = t[...]
 
+    # the block stays resident while consecutive steps map it (no
+    # refetch, no store in between), so the row is always read from the
+    # OUT block: a duplicate of the previous row sees its accumulated
+    # value — scatter-add's per-row slot order — and another row of the
+    # same block sees the copy made on the first visit
+    @pl.when(valid)
+    def _update():
+        r = pl.ds(row - bi * tb, 1)
+        v = pl.ds(i % vb, 1)
+        for o, new in zip(outs, rule(tuple(o[r, :] for o in outs),
+                                     tuple(x[v, :] for x in vals),
+                                     scalars)):
+            o[r, :] = new
 
-def _rowwise_call(rows, tables, vals, scalars, rule, accumulate,
-                  interpret):
+
+def _rowwise_call(rows, tables, vals, scalars, rule, interpret):
     """Launch the row-walking grid: rows [K] int32 (sorted, sentinels at
     the tail), tables/vals lists of [H, D] / [K, D] f32, scalars a list
     of () f32.  Returns the updated tables (input_output_aliased, so
@@ -138,23 +149,27 @@ def _rowwise_call(rows, tables, vals, scalars, rule, accumulate,
     nt, nv, ns = len(tables), len(vals), len(scalars)
     if interpret is None:
         interpret = jax.default_backend() != 'tpu'
+    # a dim shorter than one sublane tile is taken whole (the lowering
+    # accepts a block dim equal to the array's)
+    tb = min(_SUBLANES, height)
+    vb = min(_SUBLANES, k)
 
     def _tab_map(i, rows_ref):
-        return (jnp.minimum(rows_ref[i], height - 1), 0)
+        return (jnp.minimum(rows_ref[i], height - 1) // tb, 0)
 
-    row_spec = pl.BlockSpec((1, width), _tab_map)
+    tab_spec = pl.BlockSpec((tb, width), _tab_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(k,),
         in_specs=(
-            [row_spec] * nt +
-            [pl.BlockSpec((1, width), lambda i, r: (i, 0))] * nv +
+            [tab_spec] * nt +
+            [pl.BlockSpec((vb, width), lambda i, r: (i // vb, 0))] * nv +
             [pl.BlockSpec((1, 1), lambda i, r: (0, 0))] * ns),
-        out_specs=[row_spec] * nt,
+        out_specs=[tab_spec] * nt,
     )
     kernel = functools.partial(
-        _rowwise_kernel, nt=nt, nv=nv, ns=ns, height=height,
-        accumulate=accumulate, rule=rule)
+        _rowwise_kernel, nt=nt, nv=nv, ns=ns, height=height, tb=tb,
+        vb=vb, rule=rule)
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -164,7 +179,7 @@ def _rowwise_call(rows, tables, vals, scalars, rule, accumulate,
         input_output_aliases={1 + t: t for t in range(nt)},
         # the grid is sequential by construction (resident-block
         # accumulation and sentinel skips depend on visit order)
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         interpret=interpret,
     )(rows, *tables, *vals, *scalars)
@@ -207,7 +222,7 @@ def sparse_apply_sgd(param, rows, values, lr, interpret=None):
         return (p + u_blk,)
 
     return _rowwise_call(rows[order], [param], [u[order]], [], rule,
-                         accumulate=True, interpret=interpret)
+                         interpret=interpret)
 
 
 def sparse_apply_adagrad(param, moment, rows, values, lr, epsilon,
@@ -239,7 +254,7 @@ def sparse_apply_adagrad(param, moment, rows, values, lr, epsilon,
         return (p_new, mom + sq_blk)
 
     return _rowwise_call(mrows, [param, moment], [g, sq], [neg_lr], rule,
-                         accumulate=False, interpret=interpret)
+                         interpret=interpret)
 
 
 def sparse_apply_adam(param, moment1, moment2, rows, values, lr_t,
@@ -273,5 +288,4 @@ def sparse_apply_adam(param, moment1, moment2, rows, values, lr_t,
         return (p + step, m_new, v_new)
 
     return _rowwise_call(mrows, [param, moment1, moment2], [g],
-                         [neg_lrt], rule, accumulate=False,
-                         interpret=interpret)
+                         [neg_lrt], rule, interpret=interpret)

@@ -60,18 +60,23 @@ def _unreverse_and_mask(seqs, rev_idx, lengths, t):
 
 
 def _device_vmem_bytes():
-    """Per-core VMEM of the attached accelerator, from device_kind:
-    16 MB for TPU v2–v5 families, 32 MB starting with the v6
-    generation (Trillium), 16 MB when the generation is unparseable."""
-    import re
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
+    """Scoped VMEM a Pallas kernel may claim by default on the attached
+    TPU, from device_kind: 16 MiB for the v2–v5 families, 32 MiB
+    starting with v6 (Trillium).  On a TPU backend an unparseable
+    device_kind is an error — the tile chooser must not size a kernel
+    for a part it cannot identify.  Off-TPU the kernels only run
+    interpreted, where the budget merely picks a tile: the v5e figure
+    serves."""
+    if jax.default_backend() != 'tpu':
         return 16 * 1024 * 1024
-    m = re.search(r'v(\d+)', kind)
-    if m and int(m.group(1)) >= 6:
-        return 32 * 1024 * 1024
-    return 16 * 1024 * 1024
+    import re
+    kind = jax.devices()[0].device_kind
+    m = re.search(r'v(\d+)', kind.lower())
+    if not m:
+        raise RuntimeError(
+            "cannot size the RNN kernels' VMEM budget: unrecognised TPU "
+            "device_kind %r (set PADDLE_TPU_RNN_VMEM_BUDGET_MB)" % kind)
+    return (32 if int(m.group(1)) >= 6 else 16) * 1024 * 1024
 
 
 def _rnn_vmem_budget():

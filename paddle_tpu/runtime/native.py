@@ -1,16 +1,19 @@
-"""ctypes binding for native/paddle_tpu_native.cc with lazy g++ build.
+"""ctypes binding for native/paddle_tpu_native.cc, built on first use.
 
 Reference parity: N1-N3 (threaded prefetch / recordio / staging arena —
-the C++ around the reference's data path).  The .so builds on first use
-into native/build/; every class below degrades to a pure-Python
-implementation when the toolchain is unavailable, so the package never
-hard-depends on a compiler.
+the C++ around the reference's data path).  The .so builds with g++ into
+native/build/ under a name that carries a hash of the source, so a tree
+that was copied or checked out (where mtimes say nothing) rebuilds
+exactly when the source differs.  With PADDLE_TPU_USE_NATIVE_RUNTIME on
+(the default) a failed build raises with the compiler's stderr; turned
+off, every class below runs its pure-Python implementation.
 
 ctypes calls release the GIL, so a blocking `pop()` lets producer threads
 run C++ memcpy/CRC concurrently with Python — the property that makes the
 prefetch pipeline actually parallel.
 """
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,39 +21,50 @@ import threading
 _here = os.path.dirname(os.path.abspath(__file__))
 _src = os.path.join(_here, '..', '..', 'native', 'paddle_tpu_native.cc')
 _build_dir = os.path.join(_here, '..', '..', 'native', 'build')
-_so_path = os.path.join(_build_dir, 'libpaddle_tpu_native.so')
 
 _lib = None
 _lib_lock = threading.Lock()
-_build_error = None
 
 
-def _build():
+def _so_path():
+    with open(_src, 'rb') as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_build_dir,
+                        'libpaddle_tpu_native-%s.so' % digest)
+
+
+def _build(so_path):
     os.makedirs(_build_dir, exist_ok=True)
-    cmd = ['g++', '-O2', '-shared', '-fPIC', '-pthread',
-           '-o', _so_path, _src]
-    subprocess.run(cmd, check=True, capture_output=True)
+    # build beside the target and rename: launch children may all find
+    # the library missing at once, and none may load a half-written one
+    tmp = '%s.%d.tmp' % (so_path, os.getpid())
+    cmd = ['g++', '-O2', '-shared', '-fPIC', '-pthread', '-o', tmp, _src]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+    except (OSError, subprocess.CalledProcessError) as e:
+        # no g++ on this machine, or it rejected the source
+        raise RuntimeError(
+            "building the native runtime failed (set "
+            "PADDLE_TPU_USE_NATIVE_RUNTIME=0 to run the pure-Python "
+            "data path instead):\n$ %s\n%s"
+            % (' '.join(cmd), getattr(e, 'stderr', None) or e)) from e
+    os.replace(tmp, so_path)
 
 
 def _load():
-    """Build (if needed) and load the native library; None on failure."""
-    global _lib, _build_error
+    """The native library, built if this source was never built here;
+    None when PADDLE_TPU_USE_NATIVE_RUNTIME is off."""
+    global _lib
+    from ..flags import FLAGS
+    if not FLAGS.use_native_runtime:
+        return None
     with _lib_lock:
-        if _lib is not None or _build_error is not None:
+        if _lib is not None:
             return _lib
-        try:
-            if not os.path.exists(_so_path) or (
-                    os.path.getmtime(_so_path) < os.path.getmtime(_src)):
-                _build()
-            try:
-                lib = ctypes.CDLL(_so_path)
-            except OSError:
-                # a stale/foreign-arch binary on disk: rebuild once
-                _build()
-                lib = ctypes.CDLL(_so_path)
-        except (OSError, subprocess.CalledProcessError) as e:
-            _build_error = e
-            return None
+        so_path = _so_path()
+        if not os.path.exists(so_path):
+            _build(so_path)
+        lib = ctypes.CDLL(so_path)
         c = ctypes
         lib.ptq_create.restype = c.c_void_p
         lib.ptq_create.argtypes = [c.c_int]
@@ -90,10 +104,7 @@ def _load():
 
 
 def available():
-    """True when the C++ runtime built and loaded."""
-    from ..flags import FLAGS
-    if not FLAGS.use_native_runtime:
-        return False
+    """True when the C++ runtime is in use (built and loaded)."""
     return _load() is not None
 
 

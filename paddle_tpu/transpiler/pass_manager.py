@@ -25,9 +25,12 @@ into an explicit pipeline:
   transpiler/memory_model.py, nested under the cost report, dividing
   sharded residency by the plan's shard divisors).
 - ``run_pipeline`` builds the plan for the current configuration
-  (graph-opt level, AMP mode), runs each pass on an isolated copy —a
-  crashing pass is skipped with a per-pass report entry, it can no
-  longer corrupt the program mid-rewrite — and runs the static verifier
+  (graph-opt level, AMP mode), runs each pass on an isolated copy — a
+  crashing graph-opt or analysis pass is skipped with a per-pass report
+  entry, it can no longer corrupt the program mid-rewrite; a crashing
+  pass the caller asked for by flag (``_MUST_APPLY``: amp, sharding,
+  embed_shard, overlap_collectives) re-raises — and runs the static
+  verifier
   (transpiler/verify.py) after every pass (``every_pass``) or once at
   the end (``boundary``, default), attributing any failure to the
   offending pass.
@@ -58,6 +61,13 @@ PassDef = collections.namedtuple(
 # name -> PassDef.  Orders are declared, unique, and audited by
 # tools/check_pass_registry.py; the plan executes in ascending order.
 PASSES = {}
+
+# rewrite passes that decide what runs on the device.  Each is in the
+# plan only because a flag asked for it (PADDLE_TPU_AMP,
+# PADDLE_TPU_MESH), so a crash in one re-raises: skipping it would train
+# in f32, or run replicated, under a configuration that says otherwise
+_MUST_APPLY = frozenset(['amp', 'sharding', 'embed_shard',
+                         'overlap_collectives'])
 
 # test hook: {pass name -> fn(program)} applied to a pass's output
 # before verification — the mutation tests corrupt exactly one pass and
@@ -293,7 +303,7 @@ def plan_key(program=None):
     the Pallas flat-tile VMEM budget (PADDLE_TPU_FLAT_TILE_BUDGET —
     the autotuner's dense-apply hook) baked into traced kernels."""
     from .amp import plan_key_component
-    from ..distributed._compat import mesh_key
+    from ..distributed.mesh_flag import mesh_key
     from ..ops.pallas.table_update import sparse_apply_mode
     from ..ops.pallas.dense_update import dense_apply_mode, \
         flat_tile_budget
@@ -330,12 +340,13 @@ def run_pipeline(program, fetch_names=(), feed_names=(), level=None,
     their flags (PADDLE_TPU_AMP / PADDLE_TPU_VERIFY_IR /
     PADDLE_TPU_MESH); pass explicit values ('0' / 'off' / '') to pin
     them.  Raises IRVerificationError when the verifier rejects a pass
-    output (every_pass) or the final program (boundary); a pass that
-    *crashes* is skipped and reported instead — the legacy
-    fall-back-don't-die contract, now per pass.
+    output (every_pass) or the final program (boundary).  A graph-opt
+    or analysis pass that *crashes* is skipped and reported; a crash in
+    a ``_MUST_APPLY`` pass (the flag-requested rewrites that change
+    what runs on the device) propagates.
     """
     from .amp import resolve_mode as amp_resolve
-    from ..distributed._compat import mesh_axes_from_flag
+    from ..distributed.mesh_flag import mesh_axes_from_flag
     level = resolve_level(program, level)
     amp_mode = amp_resolve(None if amp_mode is _FROM_FLAG else amp_mode)
     mesh_axes = mesh_axes_from_flag(
@@ -402,6 +413,8 @@ def run_pipeline(program, fetch_names=(), feed_names=(), level=None,
         except Exception as e:
             entry['status'] = 'failed: %r' % (e,)
             entry['wall_s'] = time.perf_counter() - tp
+            if pd.name in _MUST_APPLY:
+                raise
             # the crashed pass may have died mid-mutation: rebuild the
             # working copy and replay the passes that already succeeded
             # (each is deterministic over the same input)

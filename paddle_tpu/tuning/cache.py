@@ -2,8 +2,8 @@
 
 Winners persist as one small JSON file per (plan key, device kind,
 mesh) under ``<dir>/paddle_tpu_tuning/`` where ``<dir>`` is
-PADDLE_TPU_TUNE_CACHE_DIR, falling back to
-PADDLE_TPU_COMPILATION_CACHE_DIR (the winners live next to the compiled
+PADDLE_TPU_TUNE_CACHE_DIR, falling back to the compile-cache directory
+(compile_cache.compile_cache_dir — the winners live next to the compiled
 executables they were tuned for).  Writes are atomic (tmp +
 ``os.replace``), so a shared dir behaves under concurrent benches the
 same way the XLA compilation cache does.
@@ -45,16 +45,15 @@ def _count(which):
 class TuneCache(object):
     """Load/store tuner winners keyed by (plan key, device kind, mesh).
 
-    ``root=None`` resolves the directory from the flags above; an empty
-    resolution disables persistence (``enabled()`` False, load always
-    None, store a no-op) — the tuner still works, it just re-searches
-    per process."""
+    ``root=None`` resolves the directory as above; ``root=''`` disables
+    persistence (``enabled()`` False, load always None, store a no-op)
+    — the tuner still works, it just re-searches per process."""
 
     def __init__(self, root=None):
         if root is None:
+            from ..compile_cache import compile_cache_dir
             from ..flags import FLAGS
-            root = FLAGS.tune_cache_dir or FLAGS.compilation_cache_dir \
-                or ''
+            root = FLAGS.tune_cache_dir or compile_cache_dir()
         self.root = os.path.join(root, 'paddle_tpu_tuning') if root \
             else ''
 

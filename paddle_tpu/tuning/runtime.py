@@ -77,7 +77,7 @@ def program_fingerprint(program):
 def cache_key_for(program, place=None):
     """The persistent winner-cache key for ``program`` here and now."""
     from ..transpiler import pass_manager
-    from ..distributed._compat import mesh_key
+    from ..distributed.mesh_flag import mesh_key
     with registry.base_env():
         pk = pass_manager.plan_key(program)
         mk = mesh_key()

@@ -250,3 +250,31 @@ def test_rnn_lm_fused_loss_matches_naive_build():
                         for f in feeds]
     np.testing.assert_allclose(losses[True], losses[False], rtol=1e-4,
                                atol=1e-5)
+
+
+def test_dense_budget_needs_a_device_it_can_identify(monkeypatch):
+    """The dense/chunked crossover is sized from the device's HBM.  Off
+    a TPU there is nothing to size against and the v5e figure serves; a
+    TPU that reports no memory_stats() is an error, not a v5e."""
+    import pytest
+
+    from paddle_tpu.ops import chunked_ce as cc
+
+    class FakeDev:
+        device_kind = 'TPU v5 lite'
+
+        def __init__(self, stats):
+            self._stats = stats
+
+        def memory_stats(self):
+            return self._stats
+
+    monkeypatch.delenv('PADDLE_TPU_DENSE_CE_BUDGET_MB', raising=False)
+    assert cc._dense_bytes_budget() == (16 << 30) // 8
+    monkeypatch.setattr(cc.jax, 'default_backend', lambda: 'tpu')
+    monkeypatch.setattr(cc.jax, 'devices',
+                        lambda: [FakeDev({'bytes_limit': 32 << 30})])
+    assert cc._dense_bytes_budget() == (32 << 30) // 8
+    monkeypatch.setattr(cc.jax, 'devices', lambda: [FakeDev(None)])
+    with pytest.raises(RuntimeError, match='memory_stats'):
+        cc._dense_bytes_budget()

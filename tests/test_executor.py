@@ -310,31 +310,48 @@ def test_use_program_cache_false_bypasses_insertion():
     np.testing.assert_allclose(out1, out2, rtol=1e-6)
 
 
-def test_persistent_compilation_cache_flag(tmp_path, monkeypatch):
-    """PADDLE_TPU_COMPILATION_CACHE_DIR wires jax's persistent
-    compilation cache: compiled executables land on disk and survive a
-    process restart."""
+def test_persistent_compilation_cache(compile_cache):
+    """With the cache placed from outside (JAX_COMPILATION_CACHE_DIR),
+    compiled executables land in that directory and survive a process
+    restart."""
+    import paddle_tpu as fluid
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name='x', shape=[3], dtype='float32')
+        y = fluid.layers.fc(input=x, size=2)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    exe.run(main, feed={'x': np.ones((2, 3), np.float32)},
+            fetch_list=[y])
+    assert compile_cache.exists() and any(compile_cache.iterdir())
+
+
+def test_compile_cache_resolver(monkeypatch):
+    """One resolver: with JAX_COMPILATION_CACHE_DIR set the package
+    sets no cache directory (jax reads the variable itself); unset, it
+    resolves to <checkout>/.jax_cache."""
+    import os
+
     import jax
 
     import paddle_tpu as fluid
-    from paddle_tpu.core import executor as executor_mod
+    from paddle_tpu import compile_cache as cc
 
-    cache_dir = tmp_path / 'xla_cache'
-    monkeypatch.setenv('PADDLE_TPU_COMPILATION_CACHE_DIR',
-                       str(cache_dir))
-    try:
-        main, startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(main, startup):
-            x = fluid.layers.data(name='x', shape=[3], dtype='float32')
-            y = fluid.layers.fc(input=x, size=2)
-        exe = fluid.Executor(fluid.CPUPlace())  # applies the flag
-        assert jax.config.jax_compilation_cache_dir == str(cache_dir)
-        exe.run(startup)
-        exe.run(main, feed={'x': np.ones((2, 3), np.float32)},
-                fetch_list=[y])
-        assert cache_dir.exists() and any(cache_dir.iterdir())
-    finally:
-        monkeypatch.delenv('PADDLE_TPU_COMPILATION_CACHE_DIR',
-                           raising=False)
-        executor_mod._maybe_enable_compilation_cache()  # back to off
-        assert jax.config.jax_compilation_cache_dir is None
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # an earlier Executor in this process may have resolved it already
+    jax.config.update('jax_compilation_cache_dir', None)
+    calls = []
+    monkeypatch.setattr(jax.config, 'update',
+                        lambda name, value: calls.append((name, value)))
+
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', '/somewhere/else')
+    assert cc.compile_cache_dir() == '/somewhere/else'
+    fluid.Executor(fluid.CPUPlace())
+    assert [c for c in calls if c[0] == 'jax_compilation_cache_dir'] == []
+
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR')
+    assert cc.compile_cache_dir() == os.path.join(checkout, '.jax_cache')
+    fluid.Executor(fluid.CPUPlace())
+    assert calls == [('jax_compilation_cache_dir',
+                      os.path.join(checkout, '.jax_cache'))]

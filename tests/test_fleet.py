@@ -415,13 +415,12 @@ def test_deploy_record_prev_protocol(versions, tmp_path):
 
 # -- AOT-warmed cold start --------------------------------------------
 def test_cold_replica_with_warm_cache_reports_zero_compiles(
-        versions, tmp_path, monkeypatch):
+        versions, compile_cache):
     """Acceptance: with a pre-populated persistent compile cache, a
     cold replica joining the fleet reports 0 post-warmup compiles
     before its first routed request — and its warmup is pure cache
     hits (the cache directory gains no new entries)."""
-    cache = str(tmp_path / 'xla_cache')
-    monkeypatch.setenv('PADDLE_TPU_COMPILATION_CACHE_DIR', cache)
+    cache = str(compile_cache)
     fleet = _mk_fleet(versions, replicas=1)
     try:
         assert os.path.isdir(cache) and os.listdir(cache), \

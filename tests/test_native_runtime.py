@@ -22,6 +22,31 @@ def test_native_library_builds():
     assert available(), "native runtime failed to build/load"
 
 
+def test_build_is_named_by_source_hash_and_fails_loudly(tmp_path,
+                                                        monkeypatch):
+    """A copied tree carries no meaningful mtimes: the library is named
+    by the source's hash, and a build that fails raises with the
+    compiler's stderr instead of degrading to the pure-Python path;
+    PADDLE_TPU_USE_NATIVE_RUNTIME=0 is the stated way to run without."""
+    import hashlib
+
+    from paddle_tpu.runtime import native
+    with open(native._src, 'rb') as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert digest in os.path.basename(native._so_path())
+
+    bad = tmp_path / 'broken.cc'
+    bad.write_text('this is not C++\n')
+    monkeypatch.setattr(native, '_src', str(bad))
+    monkeypatch.setattr(native, '_build_dir', str(tmp_path / 'build'))
+    monkeypatch.setattr(native, '_lib', None)
+    with pytest.raises(RuntimeError, match='(?s)g\\+\\+.*error'):
+        native._load()
+    monkeypatch.setenv('PADDLE_TPU_USE_NATIVE_RUNTIME', '0')
+    assert native._load() is None and not native.available()
+    assert not NativeQueue(capacity=2).native
+
+
 def test_queue_fifo_order_and_close():
     q = NativeQueue(capacity=4)
     assert q.native == available()

@@ -9,9 +9,7 @@ closed form; PADDLE_TPU_OVERLAP=0 and no-mesh runs bitwise-identical
 measured-compute overlap fraction in the run_steps collective phase and
 the Chrome-trace counter series; the pp plan block (1F1B bubble closed
 form, balanced cut selection, ppermute pricing); the SPMD executor's
-actionable pp refusal; and from_mesh mesh-driven 1F1B lowering
-(execution parity skip-guarded on jax.shard_map availability, like the
-rest of the shard_map family on this jax build).
+actionable pp refusal; and from_mesh mesh-driven 1F1B lowering.
 """
 import os
 
@@ -419,10 +417,6 @@ def test_from_mesh_cuts_and_microbatches(monkeypatch):
 def test_from_mesh_pp2_loss_parity(monkeypatch):
     """pp=2 1F1B run matches the no-pp executor losses to pinned
     tolerance (f32 reduction-order differences only)."""
-    import jax
-    if not hasattr(jax, 'shard_map'):
-        pytest.skip('jax.shard_map unavailable on this jax build '
-                    '(same gate as the shard_map test family)')
     from paddle_tpu.distributed import pipeline as pl
     monkeypatch.setenv('PADDLE_TPU_MESH', 'pp2')
     monkeypatch.setenv('PADDLE_TPU_PP_MICROBATCHES', '4')

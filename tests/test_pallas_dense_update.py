@@ -173,8 +173,11 @@ def test_pick_flat_tile():
 
 def test_mode_flag(monkeypatch):
     monkeypatch.delenv('PADDLE_TPU_DENSE_APPLY', raising=False)
-    on_tpu = jax.default_backend() == 'tpu'
-    assert dense_apply_mode() == ('pallas' if on_tpu else 'xla')
+    # no platform selects the kernels on its own: they lost to the XLA
+    # expressions on the v5e (PERF.md, chip bring-up)
+    assert dense_apply_mode() == 'xla'
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    assert dense_apply_mode() == 'xla'
     monkeypatch.setenv('PADDLE_TPU_DENSE_APPLY', 'pallas')
     assert dense_apply_mode() == 'pallas'
     monkeypatch.setenv('PADDLE_TPU_DENSE_APPLY', 'xla')

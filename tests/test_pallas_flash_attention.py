@@ -272,11 +272,18 @@ def test_rnn_vmem_budget_derives_from_device(monkeypatch):
             self.device_kind = kind
 
     monkeypatch.delenv('PADDLE_TPU_RNN_VMEM_BUDGET_MB', raising=False)
+    # off-TPU the kernels only run interpreted: the v5e figure serves
+    assert rnn._rnn_vmem_budget() == int(16 * 1024 * 1024 * 0.75)
+    monkeypatch.setattr(rnn.jax, 'default_backend', lambda: 'tpu')
     monkeypatch.setattr(rnn.jax, 'devices',
                         lambda: [FakeDev('TPU v5 lite')])
     assert rnn._rnn_vmem_budget() == int(16 * 1024 * 1024 * 0.75)
     monkeypatch.setattr(rnn.jax, 'devices', lambda: [FakeDev('TPU v6e')])
     assert rnn._rnn_vmem_budget() == int(32 * 1024 * 1024 * 0.75)
+    # a TPU the chooser cannot identify is an error, not a v5e
+    monkeypatch.setattr(rnn.jax, 'devices', lambda: [FakeDev('mystery')])
+    with pytest.raises(RuntimeError, match='mystery'):
+        rnn._rnn_vmem_budget()
     monkeypatch.setenv('PADDLE_TPU_RNN_VMEM_BUDGET_MB', '5')
     assert rnn._rnn_vmem_budget() == 5 * 1024 * 1024
 

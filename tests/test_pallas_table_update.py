@@ -206,8 +206,11 @@ def test_merge_rows_sentinel():
 
 def test_mode_flag(monkeypatch):
     monkeypatch.delenv('PADDLE_TPU_SPARSE_APPLY', raising=False)
-    on_tpu = jax.default_backend() == 'tpu'
-    assert sparse_apply_mode() == ('pallas' if on_tpu else 'xla')
+    # no platform selects the kernels on its own: they lost to the XLA
+    # expressions on the v5e (PERF.md, chip bring-up)
+    assert sparse_apply_mode() == 'xla'
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    assert sparse_apply_mode() == 'xla'
     monkeypatch.setenv('PADDLE_TPU_SPARSE_APPLY', 'pallas')
     assert sparse_apply_mode() == 'pallas'
     monkeypatch.setenv('PADDLE_TPU_SPARSE_APPLY', 'xla')

@@ -17,7 +17,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core.program import reset_unique_name_guard
-from paddle_tpu.distributed import _compat, spec_layout
+from paddle_tpu.distributed import mesh_flag, spec_layout
 from paddle_tpu.transpiler import pass_manager as pm
 from paddle_tpu.transpiler import sharding as sharding_mod
 from paddle_tpu.transpiler.verify import (IRVerificationError,

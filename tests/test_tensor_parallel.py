@@ -227,8 +227,6 @@ def test_accumulator_state_not_replicated_in_run(monkeypatch):
     """End-to-end: after a sharded step, the device buffers of a
     sharded param's moment are SHARDED over tp (not fully replicated)."""
     need_devices(2)
-    if not hasattr(jax, 'shard_map'):
-        pytest.skip('container jax lacks jax.shard_map')
     main, startup, loss = _lm_program()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup)

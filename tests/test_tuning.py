@@ -16,7 +16,6 @@ The tier-1 contract this file pins (ISSUE.md acceptance):
   identical.
 """
 import json
-import logging
 import os
 
 import numpy as np
@@ -252,11 +251,17 @@ def test_corrupted_cache_file_counts_and_falls_back(tmp_path):
     assert tcache.TuneCache.stats()['corrupt'] == before + 2
 
 
-def test_cache_disabled_without_dir(monkeypatch):
-    monkeypatch.delenv('PADDLE_TPU_TUNE_CACHE_DIR', raising=False)
-    monkeypatch.delenv('PADDLE_TPU_COMPILATION_CACHE_DIR',
-                       raising=False)
-    c = tcache.TuneCache()
+def test_cache_dir_resolution(monkeypatch, tmp_path):
+    """PADDLE_TPU_TUNE_CACHE_DIR wins; unset, the winners live beside
+    the compiled executables in the compile-cache directory; an explicit
+    empty root disables persistence."""
+    from paddle_tpu.compile_cache import compile_cache_dir
+    monkeypatch.setenv('PADDLE_TPU_TUNE_CACHE_DIR', str(tmp_path))
+    assert tcache.TuneCache().root == str(tmp_path / 'paddle_tpu_tuning')
+    monkeypatch.delenv('PADDLE_TPU_TUNE_CACHE_DIR')
+    assert tcache.TuneCache().root == os.path.join(
+        compile_cache_dir(), 'paddle_tpu_tuning')
+    c = tcache.TuneCache('')
     assert not c.enabled()
     assert c.load('deadbeef') is None
     assert not c.store('deadbeef', {'amp': 'bf16'})
@@ -423,42 +428,6 @@ def test_tune_off_is_bitwise_identical(tmp_path, monkeypatch):
     np.testing.assert_array_equal(a1, a2)
     np.testing.assert_array_equal(b1, b2)
     assert dict(os.environ) == env_before  # nothing applied
-
-
-def test_compilation_cache_late_set_applies_with_warning(
-        tmp_path, monkeypatch, caplog):
-    from paddle_tpu.core import executor as exmod
-    prog, startup, cost = _small_program()
-    monkeypatch.delenv('PADDLE_TPU_COMPILATION_CACHE_DIR',
-                       raising=False)
-    saved = (exmod._compilation_cache_dir,
-             exmod._compilation_cache_resolved)
-    try:
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        exe.run(prog, feed=_feed(), fetch_list=[cost])
-        # the wart this PR fixes: setting the dir AFTER first use used
-        # to be silently ignored until reset_cache()
-        monkeypatch.setenv('PADDLE_TPU_COMPILATION_CACHE_DIR',
-                           str(tmp_path / 'cc'))
-        with caplog.at_level(logging.WARNING,
-                             logger='paddle_tpu.core.executor'):
-            prog2, startup2, cost2 = _small_program()
-            exe.run(startup2)
-            exe.run(prog2, feed=_feed(), fetch_list=[cost2])
-        assert any('applied now' in r.getMessage()
-                   for r in caplog.records), caplog.records
-        assert exmod._compilation_cache_dir == str(tmp_path / 'cc')
-    finally:
-        monkeypatch.delenv('PADDLE_TPU_COMPILATION_CACHE_DIR',
-                           raising=False)
-        import jax
-        try:
-            jax.config.update('jax_compilation_cache_dir', None)
-        except Exception:
-            pass
-        (exmod._compilation_cache_dir,
-         exmod._compilation_cache_resolved) = saved
 
 
 # ---------------------------------------------------------------------------

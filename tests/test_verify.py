@@ -359,6 +359,26 @@ def test_crashing_pass_is_skipped_and_reported(monkeypatch):
     assert rep['eliminated']['dce'] >= 0
 
 
+def test_crashing_flag_requested_rewrite_reraises(monkeypatch):
+    """AMP (like sharding, embed_shard, overlap_collectives) is in the
+    plan only because a flag asked for it: a crash there must not
+    degrade to an f32 / replicated run under exit 0."""
+    def boom(program, ctx):
+        raise RuntimeError("amp exploded")
+    monkeypatch.setitem(pm.PASSES, 'amp',
+                        pm.PASSES['amp']._replace(fn=boom))
+    main, fetch = _data_program()
+    with pytest.raises(RuntimeError, match='amp exploded'):
+        pm.run_pipeline(main, fetch_names=(fetch,), feed_names=('x',),
+                        level=2, amp_mode='bf16', verify='boundary')
+    # ... and the executor no longer swallows it into an unrewritten run
+    monkeypatch.setenv('PADDLE_TPU_AMP', 'bf16')
+    exe = fluid.Executor(fluid.CPUPlace())
+    with pytest.raises(RuntimeError, match='amp exploded'):
+        exe.run(main, feed={'x': np.ones((2, 4), np.float32)},
+                fetch_list=[fetch])
+
+
 # ---------------------------------------------------------------------------
 # executor integration: composite plan key + reports + metrics
 # ---------------------------------------------------------------------------
