@@ -359,9 +359,11 @@ class DecodeEngine(object):
 
     # -- compiled function builders ------------------------------------
 
-    def _compile(self, fn, *args, donate=()):
-        compiled = jax.jit(fn, donate_argnums=donate).lower(
-            *args).compile()
+    def _compile(self, fn, *args, donate=(), bucket=None):
+        with _obs.span('decode.compile', args={'program': fn.__name__,
+                                               'bucket': bucket}):
+            compiled = jax.jit(fn, donate_argnums=donate).lower(
+                *args).compile()
         self.compiles_total += 1
         return compiled
 
@@ -392,13 +394,13 @@ class DecodeEngine(object):
             return k_pool, v_pool
 
         toks = jnp.zeros((bucket,), jnp.int32)
-        self._prefill[bucket] = self._compile(prefill, self.params,
-                                              toks, jnp.int32(0))
+        self._prefill[bucket] = self._compile(
+            prefill, self.params, toks, jnp.int32(0), bucket=bucket)
         kv = jnp.zeros((L, bucket, H, Dh), self.cache.k.dtype)
         pages = jnp.zeros((n_pages,), jnp.int32)
         self._pack[bucket] = self._compile(
             pack, self.cache.k, self.cache.v, kv, kv, pages,
-            donate=(0, 1))
+            donate=(0, 1), bucket=bucket)
 
     def _ensure_chunk(self, bucket):
         """Chunked-prefill executable for one chunk bucket: a SINGLE
@@ -462,7 +464,7 @@ class DecodeEngine(object):
             chunk, self.cache.k, self.cache.v,
             jnp.zeros((bucket,), jnp.int32),
             jnp.full((mpp,), trash, jnp.int32),
-            jnp.int32(0), jnp.int32(1), donate=(0, 1))
+            jnp.int32(0), jnp.int32(1), donate=(0, 1), bucket=bucket)
 
     def _ensure_step(self):
         if self._step is not None:
@@ -588,18 +590,20 @@ class DecodeEngine(object):
         prompt = np.asarray(prompt, dtype=np.int32)
         t = int(prompt.shape[0])
         bucket = self.bucket_for(t)
-        self._ensure_prefill(bucket)
-        toks = np.zeros((bucket,), np.int32)
-        toks[:t] = prompt
-        logits, k, v = self._prefill[bucket](
-            self.params, jnp.asarray(toks), jnp.int32(t - 1))
-        n_pages = bucket // self.page_size
-        page_ids = np.full((n_pages,), self.cache.trash, np.int32)
-        n_real = min(len(pages), n_pages)
-        page_ids[:n_real] = pages[:n_real]
-        self.cache.k, self.cache.v = self._pack[bucket](
-            self.cache.k, self.cache.v, k, v, jnp.asarray(page_ids))
-        return np.asarray(logits)
+        with _obs.span('decode.prefill_into',
+                       args={'tokens': t, 'bucket': bucket}):
+            self._ensure_prefill(bucket)
+            toks = np.zeros((bucket,), np.int32)
+            toks[:t] = prompt
+            logits, k, v = self._prefill[bucket](
+                self.params, jnp.asarray(toks), jnp.int32(t - 1))
+            n_pages = bucket // self.page_size
+            page_ids = np.full((n_pages,), self.cache.trash, np.int32)
+            n_real = min(len(pages), n_pages)
+            page_ids[:n_real] = pages[:n_real]
+            self.cache.k, self.cache.v = self._pack[bucket](
+                self.cache.k, self.cache.v, k, v, jnp.asarray(page_ids))
+            return np.asarray(logits)
 
     def chunk_spans(self, prompt_len, start=0):
         """The grid-aligned chunk decomposition of positions
@@ -629,17 +633,19 @@ class DecodeEngine(object):
         tokens = np.asarray(tokens, dtype=np.int32)
         c = int(tokens.shape[0])
         bucket = self.bucket_for(c)
-        self._ensure_chunk(bucket)
-        toks = np.zeros((bucket,), np.int32)
-        toks[:c] = tokens
-        mpp = self.pages_per_stream
-        pt = np.full((mpp,), self.cache.trash, np.int32)
-        n = min(len(pages), mpp)
-        pt[:n] = pages[:n]
-        self.cache.k, self.cache.v, logits = self._chunk[bucket](
-            self.cache.k, self.cache.v, jnp.asarray(toks),
-            jnp.asarray(pt), jnp.int32(pos0), jnp.int32(c))
-        return np.asarray(logits)
+        with _obs.span('decode.prefill_chunk',
+                       args={'tokens': c, 'bucket': bucket}):
+            self._ensure_chunk(bucket)
+            toks = np.zeros((bucket,), np.int32)
+            toks[:c] = tokens
+            mpp = self.pages_per_stream
+            pt = np.full((mpp,), self.cache.trash, np.int32)
+            n = min(len(pages), mpp)
+            pt[:n] = pages[:n]
+            self.cache.k, self.cache.v, logits = self._chunk[bucket](
+                self.cache.k, self.cache.v, jnp.asarray(toks),
+                jnp.asarray(pt), jnp.int32(pos0), jnp.int32(c))
+            return np.asarray(logits)
 
     def step(self, tokens, page_tables, ctx_lens):
         """One batched decode step over all ``max_streams`` slots.
@@ -647,12 +653,18 @@ class DecodeEngine(object):
         their writes land in the trash page and their outputs are
         ignored.  Returns (next_tokens [S], logits [S, V]) numpy."""
         self._ensure_step()
-        self.cache.k, self.cache.v, logits, nxt = self._step(
-            self.cache.k, self.cache.v,
-            jnp.asarray(tokens, dtype=jnp.int32),
-            jnp.asarray(page_tables, dtype=jnp.int32),
-            jnp.asarray(ctx_lens, dtype=jnp.int32))
-        return np.asarray(nxt), np.asarray(logits)
+        # the two halves of the host's part: everything up to the call
+        # into the executable returning, then the wait for the device
+        # and the copy back of tokens and [S, V] logits
+        with _obs.span('decode.step'):
+            with _obs.span('decode.step.dispatch'):
+                self.cache.k, self.cache.v, logits, nxt = self._step(
+                    self.cache.k, self.cache.v,
+                    jnp.asarray(tokens, dtype=jnp.int32),
+                    jnp.asarray(page_tables, dtype=jnp.int32),
+                    jnp.asarray(ctx_lens, dtype=jnp.int32))
+            with _obs.span('decode.step.fetch'):
+                return np.asarray(nxt), np.asarray(logits)
 
     def resident_bytes(self):
         return self.cache.resident_bytes()
@@ -732,6 +744,7 @@ class DecodeStream(object):
         self.tokens = []          # generated ids, worker-appended
         self.token_times = []     # perf_counter per emitted token
         self.submitted_t = time.perf_counter()
+        self.admitted_t = None    # when its batch slot was reserved
         self.first_token_t = None
         self.done_t = None
         self.error = None
@@ -914,23 +927,24 @@ class DecodeServer(object):
         the worker OUTSIDE the lock (device work); the slot itself was
         reserved under ``_cv`` by the loop."""
         eng = self.engine
-        if eng.chunked:
-            return self._admit_chunked(st)
-        pages = eng.cache.alloc(self._pages_needed(st))
-        if pages is None:
-            return False
-        st._pages = pages
-        self._m.pages_allocated.inc(len(pages))
-        logits = eng.prefill_into(st.prompt, pages)
-        first = int(np.argmax(logits))
-        now = time.perf_counter()
-        st.first_token_t = now
-        st.tokens.append(first)
-        st.token_times.append(now)
-        st._ctx_len = len(st.prompt)
-        self._m.ttft.observe(st.ttft_s)
-        self._m.tokens.inc()
-        return True
+        with _obs.span('server.admit', args={'rid': st.request_id}):
+            if eng.chunked:
+                return self._admit_chunked(st)
+            pages = eng.cache.alloc(self._pages_needed(st))
+            if pages is None:
+                return False
+            st._pages = pages
+            self._m.pages_allocated.inc(len(pages))
+            logits = eng.prefill_into(st.prompt, pages)
+            first = int(np.argmax(logits))
+            now = time.perf_counter()
+            st.first_token_t = now
+            st.tokens.append(first)
+            st.token_times.append(now)
+            st._ctx_len = len(st.prompt)
+            self._m.ttft.observe(st.ttft_s)
+            self._m.tokens.inc()
+            return True
 
     def _evict(self, want):
         """LRU-evict up to ``want`` unreferenced trie pages back to the
@@ -1043,19 +1057,20 @@ class DecodeServer(object):
         for st in pending[rr:] + pending[:rr]:
             prompt = st._prompt_eff
             t = len(prompt)
-            while st._prefill_pos is not None and \
-                    (budget is None or used < budget):
-                lo = st._prefill_pos
-                hi = min(lo + eng.chunk_grid, t)
-                logits = eng.prefill_chunk(prompt[lo:hi], st._pages,
-                                           lo)
-                self._m.prefill_chunks.inc()
-                used += hi - lo
-                if hi >= t:
-                    st._prefill_pos = None
-                    self._finish_prefill(st, logits)
-                else:
-                    st._prefill_pos = hi
+            with _obs.span('server.admit', args={'rid': st.request_id}):
+                while st._prefill_pos is not None and \
+                        (budget is None or used < budget):
+                    lo = st._prefill_pos
+                    hi = min(lo + eng.chunk_grid, t)
+                    logits = eng.prefill_chunk(prompt[lo:hi], st._pages,
+                                               lo)
+                    self._m.prefill_chunks.inc()
+                    used += hi - lo
+                    if hi >= t:
+                        st._prefill_pos = None
+                        self._finish_prefill(st, logits)
+                    else:
+                        st._prefill_pos = hi
             if budget is not None and used >= budget:
                 break
 
@@ -1116,14 +1131,19 @@ class DecodeServer(object):
             self._m.pages_freed.inc(len(st._pages))
         st._pages = None
         st.done_t = time.perf_counter()
+        # the request's three spans, from the stamps the stream kept
+        args = {'rid': st.request_id, 'prompt_tokens': len(st.prompt),
+                'new_tokens': len(st.tokens)}
+        for name, t0, t1 in (
+                ('queued', st.submitted_t, st.admitted_t),
+                ('prefill', st.admitted_t, st.first_token_t),
+                ('decode', st.first_token_t, st.done_t)):
+            _obs.record_span('server.request.' + name, t0, t1, args)
         self._completed += 1
         st._done.set()
 
     def _loop(self):
-        eng = self.engine
-        S, mpp = eng.max_streams, eng.pages_per_stream
-        trash = eng.cache.trash
-        while True:
+        for n in itertools.count():
             with self._cv:
                 while not self._stopping and not self._queue and \
                         all(s is None for s in self._slots):
@@ -1131,76 +1151,97 @@ class DecodeServer(object):
                 if self._stopping and not self._queue and \
                         all(s is None for s in self._slots):
                     return
-                # admission at step granularity: continuous mode fills
-                # any free slot; static mode only starts a fresh
-                # generation once the whole previous batch retired
-                admissible = []
-                if not self.static or \
-                        all(s is None for s in self._slots):
-                    admissible = [i for i, s in enumerate(self._slots)
-                                  if s is None]
-                pending = []
-                while self._queue and admissible:
-                    st = self._queue.popleft()
-                    slot = admissible.pop(0)
-                    # reserve the slot under the lock so drain() never
-                    # sees the stream in neither queue nor slots
-                    st._slot = slot
-                    self._slots[slot] = st
-                    pending.append(st)
-                self._m.queue_depth.set(len(self._queue))
-            requeue = [st for st in pending if not self._admit(st)]
-            with self._cv:
-                for st in requeue:
-                    self._slots[st._slot] = None
-                    st._slot = None
-                if requeue:
-                    self._queue.extendleft(reversed(requeue))
-                    self._m.queue_depth.set(len(self._queue))
-                active = [s for s in self._slots if s is not None]
-                self._m.streams_active.set(len(active))
-            if not active:
-                continue
-            if eng.chunked:
-                # interleave: up to chunk_tokens of prefill work, then
-                # one decode step for every prefill-complete stream —
-                # a long prompt dents running streams' inter-token
-                # latency by one chunk, not one monolithic bucket
-                self._run_prefill_chunks(active)
-                decoding = [st for st in active
-                            if st._prefill_pos is None]
-                decoding = [st for st in decoding
-                            if self._ensure_capacity(st)]
-                if eng.prefix is not None:
-                    self._m.cached_pages.set(eng.prefix.cached_pages)
-            else:
-                decoding = active
-            if not decoding:
-                continue
-            # build the batched step inputs from host stream state
-            tokens = np.zeros((S,), np.int32)
-            pts = np.full((S, mpp), trash, np.int32)
-            ctx = np.zeros((S,), np.int32)
-            for st in decoding:
-                i = st._slot
-                tokens[i] = st.tokens[-1]
-                pts[i, :len(st._pages)] = st._pages
-                ctx[i] = st._ctx_len
-            nxt, logits = eng.step(tokens, pts, ctx)
+            # one tick: admit what fits, then one decode step.  The idle
+            # wait above is no part of it.
+            args = {}
+            with _obs.span('server.tick', step=n, args=args):
+                self._tick(args)
+
+    def _tick(self, args):
+        """Admission, this tick's prefills and one batched decode step;
+        fills ``args`` (the tick span's) with what it did."""
+        eng = self.engine
+        S, mpp = eng.max_streams, eng.pages_per_stream
+        trash = eng.cache.trash
+        with self._cv:
+            # admission at step granularity: continuous mode fills
+            # any free slot; static mode only starts a fresh
+            # generation once the whole previous batch retired
+            admissible = []
+            if not self.static or \
+                    all(s is None for s in self._slots):
+                admissible = [i for i, s in enumerate(self._slots)
+                              if s is None]
+            pending = []
             now = time.perf_counter()
-            self._m.steps.inc()
-            finished = []
-            for st in decoding:
-                i = st._slot
-                st._ctx_len += 1
-                if len(st.tokens) < st.max_new_tokens:
-                    st.tokens.append(int(nxt[i]))
-                    st.token_times.append(now)
-                    self._m.tokens.inc()
-                if len(st.tokens) >= st.max_new_tokens:
-                    finished.append(st)
-            with self._cv:
-                for st in finished:
-                    self._retire(st)
-                if finished:
-                    self._cv.notify_all()
+            while self._queue and admissible:
+                st = self._queue.popleft()
+                slot = admissible.pop(0)
+                # reserve the slot under the lock so drain() never
+                # sees the stream in neither queue nor slots
+                st._slot = slot
+                self._slots[slot] = st
+                if st.admitted_t is None:
+                    st.admitted_t = now
+                pending.append(st)
+            self._m.queue_depth.set(len(self._queue))
+        requeue = [st for st in pending if not self._admit(st)]
+        with self._cv:
+            for st in requeue:
+                self._slots[st._slot] = None
+                st._slot = None
+                if st.first_token_t is None:
+                    st.admitted_t = None    # still waiting, for pages
+            if requeue:
+                self._queue.extendleft(reversed(requeue))
+                self._m.queue_depth.set(len(self._queue))
+            active = [s for s in self._slots if s is not None]
+            self._m.streams_active.set(len(active))
+            args.update(running=0, queued=len(self._queue),
+                        admitted=len(pending) - len(requeue))
+        if not active:
+            return
+        if eng.chunked:
+            # interleave: up to chunk_tokens of prefill work, then
+            # one decode step for every prefill-complete stream —
+            # a long prompt dents running streams' inter-token
+            # latency by one chunk, not one monolithic bucket
+            self._run_prefill_chunks(active)
+            decoding = [st for st in active
+                        if st._prefill_pos is None]
+            decoding = [st for st in decoding
+                        if self._ensure_capacity(st)]
+            if eng.prefix is not None:
+                self._m.cached_pages.set(eng.prefix.cached_pages)
+        else:
+            decoding = active
+        if not decoding:
+            return
+        args['running'] = len(decoding)
+        # build the batched step inputs from host stream state
+        tokens = np.zeros((S,), np.int32)
+        pts = np.full((S, mpp), trash, np.int32)
+        ctx = np.zeros((S,), np.int32)
+        for st in decoding:
+            i = st._slot
+            tokens[i] = st.tokens[-1]
+            pts[i, :len(st._pages)] = st._pages
+            ctx[i] = st._ctx_len
+        nxt, logits = eng.step(tokens, pts, ctx)
+        now = time.perf_counter()
+        self._m.steps.inc()
+        finished = []
+        for st in decoding:
+            i = st._slot
+            st._ctx_len += 1
+            if len(st.tokens) < st.max_new_tokens:
+                st.tokens.append(int(nxt[i]))
+                st.token_times.append(now)
+                self._m.tokens.inc()
+            if len(st.tokens) >= st.max_new_tokens:
+                finished.append(st)
+        with self._cv:
+            for st in finished:
+                self._retire(st)
+            if finished:
+                self._cv.notify_all()

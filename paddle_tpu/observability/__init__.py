@@ -9,14 +9,15 @@ Layers:
 - :mod:`metrics` — process-wide, thread-safe registry of ``Counter`` /
   ``Gauge`` / ``Histogram`` (label support, bounded buckets).
 - :mod:`tracing` — ``span("executor.run")`` context managers feeding the
-  registry *and* annotating XLA traces (jax.profiler.TraceAnnotation),
-  and — when the flight recorder is armed — the timeline ring.
+  registry, recording into the timeline ring (with the id of the span
+  around them) *and* annotating XLA traces
+  (jax.profiler.TraceAnnotation).
 - :mod:`timeline` — the step-timeline flight recorder: ONE bounded ring
-  of per-step phase events (feed/compile/dispatch/update/prefetch),
-  exported as Chrome ``trace_event`` JSON (``PADDLE_TPU_TRACE_DIR``,
-  Perfetto-loadable) with last-N-steps crash dumps
-  (``PADDLE_TPU_TRACE_DUMP_ON_ERROR``).  profiler.py's RecordEvent
-  records into the same ring.
+  of spans and, when armed, per-step phase events
+  (feed/compile/dispatch/update/prefetch), exported as Chrome
+  ``trace_event`` JSON (``PADDLE_TPU_TRACE_DIR``, Perfetto-loadable)
+  with last-N-steps crash dumps (``PADDLE_TPU_TRACE_DUMP_ON_ERROR``).
+  profiler.py's RecordEvent records into the same ring.
 - :mod:`exporters` — Prometheus text exposition + JSON snapshot.
 - :mod:`http` — opt-in stdlib ``/metrics`` + ``/healthz`` endpoint
   (``serve_metrics(port)``, gated by ``PADDLE_TPU_METRICS_PORT``).
@@ -24,7 +25,8 @@ Layers:
 Instrumented layers: core/executor.py (plan-cache hits/misses, compile
 wall time, run/run_steps latency, feed + donated-state bytes),
 inference/batching.py (queue depth, occupancy, request latency),
-inference/serving.py, reader decorators (samples, buffer depth).
+inference/serving.py, inference/decode.py (tick, request, admit, prefill
+and step spans), reader decorators (samples, buffer depth).
 
 Everything is zero-cost when disabled (``PADDLE_TPU_METRICS_ENABLED=0``):
 instrument sites guard on :func:`enabled` and spans collapse to a shared
@@ -34,7 +36,7 @@ jit trace.
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       DEFAULT_COMPILE_BUCKETS, DEFAULT_LATENCY_BUCKETS,
                       enabled, registry, reload_enabled, set_enabled)
-from .tracing import span
+from .tracing import record_span, span
 from .exporters import json_snapshot, prometheus_text
 from .http import (MetricsHTTPServer, healthz_report,
                    maybe_serve_from_env, register_healthz,
@@ -45,6 +47,7 @@ __all__ = [
     'Counter', 'Gauge', 'Histogram', 'MetricsRegistry',
     'DEFAULT_COMPILE_BUCKETS', 'DEFAULT_LATENCY_BUCKETS',
     'enabled', 'set_enabled', 'reload_enabled', 'registry', 'span',
+    'record_span',
     'prometheus_text', 'json_snapshot', 'snapshot',
     'MetricsHTTPServer', 'serve_metrics', 'maybe_serve_from_env',
     'register_healthz', 'unregister_healthz', 'healthz_report',

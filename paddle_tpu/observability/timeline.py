@@ -10,8 +10,12 @@ This module is the one buffer those events land in:
 - **Bounded ring** — a deque capped by ``PADDLE_TPU_PROFILER_EVENT_CAP``
   (the same bound the legacy profiler's ``_events`` used; profiler.py now
   records *into this ring*, so exactly one event buffer exists).  Events
-  are plain dicts ``{name, cat, ts, dur, step, tid, args}`` with ``ts``
-  seconds relative to the process clock origin.
+  are plain dicts ``{name, cat, ts, dur, step, tid, args}`` (a span from
+  tracing.py adds ``id`` and ``parent``) with ``ts`` seconds relative to
+  :data:`CLOCK_ORIGIN`, a ``time.perf_counter()`` reading: ``ts +
+  CLOCK_ORIGIN`` is the event's start on that clock.  ``Timeline.dropped``
+  counts the events the bound evicted, so a reader can tell a window
+  that was cut.
 - **Chrome trace export** — ``export_chrome_trace(path)`` renders the
   ring as ``trace_event`` JSON (``ph: "X"`` complete events) loadable in
   Perfetto / ``chrome://tracing``, alongside any ``jax.profiler``
@@ -35,11 +39,13 @@ This module is the one buffer those events land in:
   table, and each memory counter track's min/max — traces triage from
   a terminal without loading Perfetto.
 
-Zero-cost when disabled: instrument sites guard on :func:`armed` /
-:func:`ring_if_armed` — one cached-bool check, no ring allocation, no
-clock read (``PADDLE_TPU_TRACE_DIR`` unset and dump-on-error off).  The
-legacy profiler API (``RecordEvent``, ``profiler()``) records
-unconditionally, exactly as it always did — bounded by the cap.
+Two kinds of producer.  ``tracing.span()`` regions and the legacy
+profiler API (``RecordEvent``, ``profiler()``) record whenever they run,
+bounded by the cap.  The executor / prefetch / serving sites that would
+add several events to every step guard on :func:`armed` /
+:func:`ring_if_armed` — one cached-bool check, no clock read unless
+``PADDLE_TPU_TRACE_DIR`` or dump-on-error arm them; arming also turns on
+the flush and the crash dump.
 """
 import collections
 import json
@@ -47,13 +53,14 @@ import os
 import threading
 import time
 
-__all__ = ['ring', 'ring_if_armed', 'armed', 'reload_armed', 'reset',
-           'record', 'set_step', 'export_chrome_trace', 'maybe_flush',
-           'maybe_dump_on_error', 'device_memory_stats', 'Timeline']
+__all__ = ['CLOCK_ORIGIN', 'ring', 'ring_if_armed', 'armed',
+           'reload_armed', 'reset', 'record', 'set_step',
+           'export_chrome_trace', 'maybe_flush', 'maybe_dump_on_error',
+           'device_memory_stats', 'Timeline']
 
 # process clock origin: every event's ts is perf_counter-relative to
 # this, so exported traces start near t=0 instead of an opaque epoch
-_PC0 = time.perf_counter()
+CLOCK_ORIGIN = time.perf_counter()
 
 # event categories (the `cat` field; Perfetto colors/filters by it)
 CATEGORIES = ('feed', 'compute', 'compile', 'update', 'collective',
@@ -77,6 +84,7 @@ class Timeline(object):
         self._lock = threading.Lock()
         self._dq = collections.deque(maxlen=cap)
         self._step = 0
+        self.dropped = 0    # events the bound evicted since the last clear
 
     def set_step(self, step):
         """Current global step — events recorded without an explicit
@@ -87,18 +95,27 @@ class Timeline(object):
     def step(self):
         return self._step
 
+    def _append(self, e):
+        with self._lock:
+            if len(self._dq) == self._dq.maxlen:
+                self.dropped += 1
+            self._dq.append(e)
+
     def record(self, name, cat='user', t0=None, dur=0.0, step=None,
-               args=None):
+               args=None, span_id=None, parent=None):
         """Append one complete event.  ``t0`` is a time.perf_counter()
-        reading (defaults to now - dur); ``dur`` is seconds."""
+        reading (defaults to now - dur); ``dur`` is seconds.  A span
+        passes its ``span_id`` and the id of the span that was open
+        around it (``parent``, None at the top)."""
         if t0 is None:
             t0 = time.perf_counter() - dur
-        e = {'name': name, 'cat': cat, 'ts': t0 - _PC0,
+        e = {'name': name, 'cat': cat, 'ts': t0 - CLOCK_ORIGIN,
              'dur': float(dur),
              'step': self._step if step is None else int(step),
              'tid': threading.get_ident(), 'args': args}
-        with self._lock:
-            self._dq.append(e)
+        if span_id is not None:
+            e['id'], e['parent'] = span_id, parent
+        self._append(e)
 
     def counter_sample(self, name, value, cat='memory', t0=None,
                        step=None):
@@ -108,12 +125,11 @@ class Timeline(object):
         ``value`` lands in ``args['bytes']``."""
         if t0 is None:
             t0 = time.perf_counter()
-        e = {'name': name, 'cat': cat, 'ts': t0 - _PC0, 'dur': 0.0,
+        e = {'name': name, 'cat': cat, 'ts': t0 - CLOCK_ORIGIN, 'dur': 0.0,
              'step': self._step if step is None else int(step),
              'tid': threading.get_ident(), 'ph': 'C',
              'args': {'bytes': int(value)}}
-        with self._lock:
-            self._dq.append(e)
+        self._append(e)
 
     def events(self, cat=None, last_steps=0):
         """Snapshot of the ring, optionally filtered to one category
@@ -132,6 +148,7 @@ class Timeline(object):
     def clear(self):
         with self._lock:
             self._dq.clear()
+            self.dropped = 0
 
     def export_chrome_trace(self, path, last_steps=0):
         """Write the ring as Chrome ``trace_event`` JSON (Perfetto /
@@ -156,6 +173,8 @@ class Timeline(object):
                       'dur': round(e['dur'] * 1e6, 3),
                       'pid': pid, 'tid': e['tid'],
                       'args': dict(e['args'] or {}, step=e['step'])}
+                if 'id' in e:
+                    te['args'].update(id=e['id'], parent=e['parent'])
             trace_events.append(te)
         doc = {'traceEvents': trace_events, 'displayTimeUnit': 'ms'}
         d = os.path.dirname(path)

@@ -1,0 +1,229 @@
+"""The decode path's spans on the timeline ring (no arming): names,
+nesting by ``parent``, a request's three spans, compiles, the disabled
+path, and the ring's own account of what it dropped.  Tiny engine, CPU;
+no duration is judged here."""
+import json
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import observability as obs
+from paddle_tpu.inference.decode import (DecodeEngine, DecodeServer,
+                                         extract_params)
+from paddle_tpu.models import transformer
+from paddle_tpu.observability import timeline
+
+L, D, H, V, T = 2, 32, 4, 64, 64
+PAGE, STREAMS = 8, 4
+
+
+@pytest.fixture(scope='module')
+def params():
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 7
+        with fluid.program_guard(main, startup):
+            transformer.build(vocab_size=V, seq_len=T, n_layers=L,
+                              d_model=D, n_heads=H)
+        fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
+        return extract_params(scope, L)
+
+
+def make_engine(params, top=16, **kw):
+    return DecodeEngine(params, n_layers=L, n_heads=H, page_size=PAGE,
+                        max_streams=STREAMS, prefill_bucket=top,
+                        prefix_cache=False, **kw)
+
+
+@pytest.fixture(autouse=True)
+def _fresh_ring(monkeypatch):
+    # disarmed: what lands on the ring below got there without arming
+    monkeypatch.delenv('PADDLE_TPU_TRACE_DIR', raising=False)
+    monkeypatch.delenv('PADDLE_TPU_TRACE_DUMP_ON_ERROR', raising=False)
+    timeline.reset()
+    yield
+    timeline.reset()
+
+
+def spans():
+    return [e for e in timeline.ring().events(cat='span') if 'id' in e]
+
+
+def serve(engine, prompts, n_new):
+    server = DecodeServer(engine, warmup=False)
+    try:
+        streams = [server.submit(p, max_new_tokens=n_new) for p in prompts]
+        for st in streams:
+            st.result(timeout=120.0)
+    finally:
+        server.close()
+    return streams
+
+
+@pytest.mark.parametrize('chunked', [False, True])
+def test_span_names_and_nesting(params, chunked):
+    eng = make_engine(params, prefill_chunk_tokens=PAGE if chunked else 0)
+    eng.warmup()
+    assert not timeline.armed()
+    timeline.ring().clear()
+    rng = np.random.default_rng(0)
+    serve(eng, [rng.integers(1, V, n) for n in (5, 12, 9)], 4)
+    evs = spans()
+    by_id = {e['id']: e for e in evs}
+    assert len(by_id) == len(evs)
+
+    def parent_name(e):
+        return by_id[e['parent']]['name'] if e['parent'] else None
+
+    prefill = 'decode.prefill_chunk' if chunked else 'decode.prefill_into'
+    want = {'server.tick': None, 'server.admit': 'server.tick',
+            prefill: 'server.admit', 'decode.step': 'server.tick',
+            'decode.step.dispatch': 'decode.step',
+            'decode.step.fetch': 'decode.step'}
+    names = {e['name'] for e in evs}
+    assert set(want) <= names
+    assert 'decode.compile' not in names     # every shape was warmed
+    for e in evs:
+        if e['name'] in want:
+            assert parent_name(e) == want[e['name']], e
+            if e['parent']:     # a child lies inside its parent
+                p = by_id[e['parent']]
+                assert p['ts'] <= e['ts'] and \
+                    e['ts'] + e['dur'] <= p['ts'] + p['dur'] + 1e-9
+    ticks = [e for e in evs if e['name'] == 'server.tick']
+    assert [e['step'] for e in ticks] == list(range(len(ticks)))
+    assert all(set(e['args']) == {'running', 'admitted', 'queued'}
+               for e in ticks)
+    assert sum(e['args']['admitted'] for e in ticks) == 3
+    steps = [e for e in evs if e['name'] == 'decode.step']
+    assert len(steps) == sum(1 for e in ticks if e['args']['running'])
+    # self time is never negative: children do not overlap on a thread
+    for t in ticks:
+        kids = [e for e in evs if e['parent'] == t['id']]
+        assert sum(k['dur'] for k in kids) <= t['dur'] + 1e-9
+    pf = [e for e in evs if e['name'] == prefill]
+    assert all(0 < e['args']['tokens'] <= e['args']['bucket'] for e in pf)
+    if not chunked:
+        assert sorted(e['args']['tokens'] for e in pf) == [5, 9, 12]
+        assert {by_id[e['parent']]['args']['rid'] for e in pf} == \
+            {'r0', 'r1', 'r2'}
+
+
+def test_request_spans_share_rid_and_add_up_to_ttft(params):
+    eng = make_engine(params)
+    eng.warmup()
+    rng = np.random.default_rng(1)
+    # more requests than slots: the later ones wait for one
+    streams = serve(eng, [rng.integers(1, V, 6) for _ in range(7)], 5)
+    evs = spans()
+    for st in streams:
+        mine = {e['name']: e for e in evs if e['name'].startswith(
+            'server.request.') and e['args']['rid'] == st.request_id}
+        assert set(mine) == {'server.request.queued',
+                             'server.request.prefill',
+                             'server.request.decode'}
+        q, p, d = (mine['server.request.' + k]
+                   for k in ('queued', 'prefill', 'decode'))
+        assert q['dur'] >= 0 and p['dur'] > 0 and d['dur'] > 0
+        assert q['dur'] + p['dur'] == pytest.approx(st.ttft_s, abs=1e-9)
+        assert q['ts'] + timeline.CLOCK_ORIGIN == \
+            pytest.approx(st.submitted_t, abs=1e-9)
+        assert d['ts'] + d['dur'] + timeline.CLOCK_ORIGIN == \
+            pytest.approx(st.done_t, abs=1e-9)
+        assert q['args'] == {'rid': st.request_id, 'prompt_tokens': 6,
+                             'new_tokens': 5}
+        assert q['parent'] is None
+    waits = sorted(e['dur'] for e in evs
+                   if e['name'] == 'server.request.queued')
+    assert waits[-1] > waits[0]     # someone waited for a slot
+
+
+def test_a_new_bucket_compiles_once_under_its_prefill(params):
+    eng = make_engine(params, top=32)
+    eng.buckets = [8, 16]       # a deployment that never warmed 32
+    eng.warmup()
+    assert 'decode.compile' in {e['name'] for e in spans()}
+    timeline.ring().clear()
+    eng.buckets = [8, 16, 32]
+    pages = eng.cache.alloc(3)
+    prompt = np.arange(1, 21, dtype=np.int32)
+    eng.prefill_into(prompt, pages)
+    eng.prefill_into(prompt, pages)
+    evs = spans()
+    comp = [e for e in evs if e['name'] == 'decode.compile']
+    assert sorted((e['args']['program'], e['args']['bucket'])
+                  for e in comp) == [('pack', 32), ('prefill', 32)]
+    calls = [e for e in evs if e['name'] == 'decode.prefill_into']
+    assert len(calls) == 2
+    first = min(calls, key=lambda e: e['ts'])
+    assert {e['parent'] for e in comp} == {first['id']}
+    assert first['args'] == {'tokens': 20, 'bucket': 32}
+    assert eng.compiles_after_warmup == 2
+
+
+def test_metrics_disabled_leaves_no_ring_record(params):
+    eng = make_engine(params)
+    eng.warmup()
+    obs.set_enabled(False)
+    try:
+        timeline.ring().clear()
+        assert obs.span('x', args={}) is obs.tracing._NULL_SPAN
+        obs.record_span('server.request.queued', 0.0, 1.0)
+        streams = serve(eng, [np.arange(1, 8)], 3)
+        assert len(streams[0].tokens) == 3
+        assert timeline.ring().events() == []
+    finally:
+        obs.reload_enabled()
+
+
+def test_dropped_counts_evictions_and_clock_origin():
+    tl = timeline.Timeline(cap=4)
+    for i in range(3):
+        tl.record('e%d' % i)
+    assert tl.dropped == 0
+    for i in range(7):
+        tl.record('f%d' % i)
+    tl.counter_sample('bytes', 1)
+    assert tl.dropped == 7 and len(tl.events()) == 4
+    tl.clear()
+    assert tl.dropped == 0 and tl.events() == []
+    assert timeline.Timeline(cap=None).dropped == 0
+    # ts is relative to the public origin, on perf_counter's clock
+    t0 = time.perf_counter()
+    tl.record('at', t0=t0, dur=0.5)
+    assert tl.events()[0]['ts'] + timeline.CLOCK_ORIGIN == \
+        pytest.approx(t0, abs=1e-12)
+
+
+def test_span_ids_parents_and_threads(tmp_path):
+    out = {}
+
+    def other():
+        with obs.span('t.other', annotate=False):
+            pass
+        out['done'] = True
+
+    args = {}
+    with obs.span('t.outer', annotate=False, step=41, args=args):
+        with obs.span('t.inner', annotate=False):
+            th = threading.Thread(target=other)
+            th.start()
+            th.join(timeout=10.0)
+        args['filled'] = 'inside'
+    assert out.get('done')
+    evs = {e['name']: e for e in spans()}
+    assert evs['t.inner']['parent'] == evs['t.outer']['id']
+    assert evs['t.outer']['parent'] is None
+    # another thread's span is no child of this thread's open span
+    assert evs['t.other']['parent'] is None
+    assert evs['t.outer']['step'] == 41
+    assert evs['t.outer']['args'] == {'filled': 'inside'}
+    # the exported trace carries id and parent for a viewer
+    with open(timeline.export_chrome_trace(str(tmp_path / 't.json'))) as f:
+        doc_args = {e['name']: e['args']
+                    for e in json.load(f)['traceEvents'] if e['ph'] == 'X'}
+    assert doc_args['t.inner']['parent'] == evs['t.outer']['id']
