@@ -12,15 +12,19 @@ inference-throughput fix for decoder-only LMs, TPU-native:
   token (the TTFT moment).  Every later token is one batched decode
   step: embed S current tokens, append their K/V into the cache, and
   attend over pages (ops/attention.py ``paged_attention``).
-- **paged KV cache** — per-layer page pools
-  ``[num_pages, page_size, heads, head_dim]`` resident in device memory
-  with a HOST-side page table and free list.  Streams claim
+- **paged KV cache** — one K and one V page pool PER LAYER, each its
+  own device buffer ``[num_pages + 1, page_size, heads * head_dim]``
+  (lane-dense: a cached position is one contiguous row), with a
+  HOST-side page table and free list.  Streams claim
   ceil(span/page_size) pages at admission and free them the step they
   finish; a stream's pages need not be contiguous, so the pool packs
   mixed-length streams without fragmentation-driven copies.  The pools
   are **donated chunk→chunk** through every compiled prefill-pack and
-  decode step (``donate_argnums``) — the cache never round-trips to
-  host and never double-buffers.
+  decode step (``donate_argnums``), every buffer aliased to its own
+  output: a write scatters rows on the page axis of its layer's buffer
+  and a read gathers pages straight from it, so no program copies,
+  slices or re-lays-out a pool — the cache never round-trips to host
+  and never double-buffers.
 - **continuous batching** — admission happens at STEP granularity: a
   queued stream joins the running batch the moment a slot and pages
   free up, and a finished stream's slot is reusable the very next step.
@@ -126,10 +130,17 @@ def _forward(params, tokens, n_layers, n_heads):
 
 
 class PagedKVCache(object):
-    """Device page pools + host free list.  The pools are plain jax
-    arrays the engine threads through its donated compiled calls; the
-    free list / page tables are host state (the server's worker thread
-    owns them — no lock needed beyond the server's own)."""
+    """Device page pools + host free list.  ``k`` and ``v`` are lists
+    of ``n_layers`` jax arrays ``[num_pages + 1, page_size, n_heads *
+    head_dim]``, one buffer per layer, which the engine threads through
+    its donated compiled calls (each aliased to its own output, written
+    by a scatter on its page axis).  The minor dimension is the whole
+    heads x head_dim row: a ``[..., n_heads, head_dim]`` pool with
+    head_dim under 128 lanes is held page-minor by the TPU and every
+    program that touches it re-lays-out the whole pool at its edge
+    (PERF.md section 6, PR 25).  The free list / page tables are host
+    state (the server's worker thread owns them — no lock needed beyond
+    the server's own)."""
 
     def __init__(self, n_layers, num_pages, page_size, n_heads,
                  head_dim, dtype=jnp.float32):
@@ -142,10 +153,11 @@ class PagedKVCache(object):
         # entries and inactive slots direct their writes there, so the
         # compiled step needs no masking on the scatter
         self.trash = self.num_pages
-        shape = (self.n_layers, self.num_pages + 1, self.page_size,
-                 self.n_heads, self.head_dim)
-        self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        self.dtype = jnp.dtype(dtype)
+        shape = (self.num_pages + 1, self.page_size,
+                 self.n_heads * self.head_dim)
+        self.k = [jnp.zeros(shape, dtype) for _ in range(self.n_layers)]
+        self.v = [jnp.zeros(shape, dtype) for _ in range(self.n_layers)]
         self._free = list(range(self.num_pages))
 
     def free_pages(self):
@@ -168,7 +180,7 @@ class PagedKVCache(object):
         resident)."""
         return page_pool_bytes(self.num_pages + 1, self.page_size,
                                self.n_heads, self.head_dim,
-                               self.k.dtype, n_layers=self.n_layers)
+                               self.dtype, n_layers=self.n_layers)
 
 
 class _PrefixNode(object):
@@ -293,6 +305,16 @@ class PrefixCache(object):
 class DecodeEngine(object):
     """Compiled prefill/pack/decode executables over one weight set.
 
+    The three programs that write the KV pool — ``pack``, ``chunk`` and
+    ``step`` — take ``cache.k`` and ``cache.v`` (a list of per-layer
+    buffers each) as donated arguments and return them: a layer's new
+    K/V rows are scattered on the page axis of that layer's own buffer
+    and attention gathers pages from the same buffer, so the pools are
+    updated in place.  The ``decode.compile`` span of every program
+    records ``alias_bytes``, ``temp_bytes`` and ``pool_bytes``: in place
+    means the first equals the last and the scratch stays under what
+    the program gathers.
+
     Not thread-safe by design: exactly one caller (the DecodeServer
     worker) drives it, and the page pools move through donated
     arguments — concurrent calls would use donated buffers.
@@ -360,10 +382,18 @@ class DecodeEngine(object):
     # -- compiled function builders ------------------------------------
 
     def _compile(self, fn, *args, donate=(), bucket=None):
-        with _obs.span('decode.compile', args={'program': fn.__name__,
-                                               'bucket': bucket}):
+        span_args = {'program': fn.__name__, 'bucket': bucket}
+        with _obs.span('decode.compile', args=span_args):
             compiled = jax.jit(fn, donate_argnums=donate).lower(
                 *args).compile()
+            # whether the pools are updated in place, as the compiler
+            # declares it: the donated bytes it aliased to outputs and
+            # the scratch the program needs beside its arguments
+            mem = compiled.memory_analysis()
+            span_args.update(
+                temp_bytes=int(mem.temp_size_in_bytes),
+                alias_bytes=int(mem.alias_size_in_bytes),
+                pool_bytes=self.cache.resident_bytes())
         self.compiles_total += 1
         return compiled
 
@@ -385,18 +415,20 @@ class DecodeEngine(object):
 
         def pack(k_pool, v_pool, k, v, pages):
             # scatter the prefill K/V into the claimed pages: [L, T, H,
-            # Dh] -> [L, n_pages, P, H, Dh] written at ``pages`` (padded
-            # entries point at the trash page)
-            kp = k.reshape(L, n_pages, P, H, Dh)
-            vp = v.reshape(L, n_pages, P, H, Dh)
-            k_pool = k_pool.at[:, pages].set(kp)
-            v_pool = v_pool.at[:, pages].set(vp)
-            return k_pool, v_pool
+            # Dh] -> [L, n_pages, P, H * Dh], layer i written at
+            # ``pages`` of its own buffer (padded entries point at the
+            # trash page)
+            kp = k.reshape(L, n_pages, P, H * Dh)
+            vp = v.reshape(L, n_pages, P, H * Dh)
+            return ([pool.at[pages].set(kp[i])
+                     for i, pool in enumerate(k_pool)],
+                    [pool.at[pages].set(vp[i])
+                     for i, pool in enumerate(v_pool)])
 
         toks = jnp.zeros((bucket,), jnp.int32)
         self._prefill[bucket] = self._compile(
             prefill, self.params, toks, jnp.int32(0), bucket=bucket)
-        kv = jnp.zeros((L, bucket, H, Dh), self.cache.k.dtype)
+        kv = jnp.zeros((L, bucket, H, Dh), self.cache.dtype)
         pages = jnp.zeros((n_pages,), jnp.int32)
         self._pack[bucket] = self._compile(
             pack, self.cache.k, self.cache.v, kv, kv, pages,
@@ -431,6 +463,7 @@ class DecodeEngine(object):
             page_idx = pt[jnp.clip(pos // P, 0, mpp - 1)]
             page_idx = jnp.where(valid, page_idx, trash)
             offset = pos % P
+            k_pool, v_pool = list(k_pool), list(v_pool)
             for i in range(L):
                 p = 'tr_l%d_' % i
                 h = _ln(x, params[p + 'ln_attn_w'],
@@ -438,10 +471,10 @@ class DecodeEngine(object):
                 qkv = h @ params[p + 'qkv_w'] + params[p + 'qkv_b']
                 q, k, v = jnp.split(qkv, 3, axis=-1)
                 q = q.reshape(bucket, H, Dh)
-                k = k.reshape(bucket, H, Dh).astype(k_pool.dtype)
-                v = v.reshape(bucket, H, Dh).astype(v_pool.dtype)
-                k_pool = k_pool.at[i, page_idx, offset].set(k)
-                v_pool = v_pool.at[i, page_idx, offset].set(v)
+                k_pool[i] = k_pool[i].at[page_idx, offset].set(
+                    k.astype(k_pool[i].dtype))
+                v_pool[i] = v_pool[i].at[page_idx, offset].set(
+                    v.astype(v_pool[i].dtype))
                 ctx = chunk_att(None, {'Q': [q],
                                        'KPool': [k_pool[i]],
                                        'VPool': [v_pool[i]],
@@ -484,6 +517,7 @@ class DecodeEngine(object):
             page_idx = jnp.take_along_axis(
                 pt, (pos // P)[:, None], axis=1)[:, 0]
             offset = pos % P
+            k_pool, v_pool = list(k_pool), list(v_pool)
             for i in range(L):
                 p = 'tr_l%d_' % i
                 h = _ln(x, params[p + 'ln_attn_w'],
@@ -491,10 +525,12 @@ class DecodeEngine(object):
                 qkv = h @ params[p + 'qkv_w'] + params[p + 'qkv_b']
                 q, k, v = jnp.split(qkv, 3, axis=-1)
                 q = q.reshape(S, H, Dh)
-                k = k.reshape(S, H, Dh).astype(k_pool.dtype)
-                v = v.reshape(S, H, Dh).astype(v_pool.dtype)
-                k_pool = k_pool.at[i, page_idx, offset].set(k)
-                v_pool = v_pool.at[i, page_idx, offset].set(v)
+                # the slot's new row lands at (page, offset) of layer
+                # i's own buffer, which attention then gathers from
+                k_pool[i] = k_pool[i].at[page_idx, offset].set(
+                    k.astype(k_pool[i].dtype))
+                v_pool[i] = v_pool[i].at[page_idx, offset].set(
+                    v.astype(v_pool[i].dtype))
                 ctx = paged(None, {'Q': [q], 'KPool': [k_pool[i]],
                                    'VPool': [v_pool[i]], 'PT': [pt],
                                    'CtxLen': [pos + 1]},
@@ -897,7 +933,7 @@ class DecodeServer(object):
                 # trie-held subset an eviction sweep could reclaim
                 'prefix_cached_bytes': prefix_cached_bytes(
                     cached, eng.page_size, eng.n_heads, eng.head_dim,
-                    eng.cache.k.dtype, n_layers=eng.n_layers),
+                    eng.cache.dtype, n_layers=eng.n_layers),
                 'submitted': self._submitted,
                 'completed': self._completed,
                 'dropped': 0,  # admission queues, never sheds

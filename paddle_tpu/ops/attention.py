@@ -38,8 +38,10 @@ def paged_attention_math(q, k_pool, v_pool, page_table, ctx_len,
     registered op and the decode engine share.
 
     ``q`` [S, H, D] — one new token per stream slot; ``k_pool``/
-    ``v_pool`` [N, P, H, D] page pools; ``page_table`` [S, MPP] int32
-    page ids per stream (unused entries may point anywhere — typically
+    ``v_pool`` [N, P, H, D] page pools, or the same pages with the row
+    flattened, [N, P, H*D] (how the decode engine holds them: the
+    gathered span is reshaped, never the pool); ``page_table`` [S, MPP]
+    int32 page ids per stream (unused entries may point anywhere — typically
     the trash page — their keys are masked); ``ctx_len`` [S] int32
     VALID key count per stream, current token included.  Returns
     [S, H, D].  Gathers each stream's pages, masks positions >= ctx_len
@@ -73,7 +75,8 @@ def chunked_prefill_attention_math(q, k_pool, v_pool, page_table, pos0,
 
     ``q`` [C, H, D] — a prompt chunk whose query ``i`` sits at ABSOLUTE
     position ``pos0 + i``; ``k_pool``/``v_pool`` [N, P, H, D] page
-    pools; ``page_table`` [MPP] int32 page ids for the stream (entries
+    pools (or [N, P, H*D], as ``paged_attention_math`` takes them);
+    ``page_table`` [MPP] int32 page ids for the stream (entries
     past the claimed span may point anywhere — typically the trash
     page — their keys are causally masked); ``pos0`` scalar int32.
     Returns [C, H, D].  Key at absolute position ``j`` is valid for

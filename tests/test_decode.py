@@ -128,6 +128,123 @@ def test_decode_step_parity_ragged_last_page(params, engine):
     assert engine.cache.free_pages() == engine.cache.num_pages
 
 
+@pytest.fixture(scope='module')
+def chunked_engine(params):
+    eng = DecodeEngine(params, n_layers=L, n_heads=H, page_size=PAGE,
+                       max_streams=STREAMS, prefill_bucket=PREFILL_TOP,
+                       prefix_cache=False,
+                       prefill_chunk_tokens=PREFILL_TOP)
+    eng.warmup()
+    return eng
+
+
+@pytest.mark.parametrize('program,bucket', [
+    ('step', None), ('pack', 8), ('pack', 16), ('pack', 32),
+    ('chunk', 8), ('chunk', 16), ('chunk', 32)])
+def test_pool_updated_in_place(engine, chunked_engine, program, bucket):
+    """Every program that writes the KV pool aliases all of it to its
+    outputs and declares no scratch of a pool's size: a pack under one
+    layer's K buffer, a step or chunk under what its attention gathers
+    and scores.  CPU layouts are not the chip's: this guards the
+    structure (the parent's pack declared two whole pools here), the
+    chip's trace is the proof."""
+    eng = chunked_engine if program == 'chunk' else engine
+    compiled = eng._step if program == 'step' else \
+        getattr(eng, '_' + program)[bucket]
+    mem = compiled.memory_analysis()
+    pool = eng.resident_bytes()
+    assert mem.alias_size_in_bytes == pool
+    item = eng.cache.dtype.itemsize
+    span = 2 * eng.max_seq * eng.d_model * item   # one slot's K and V
+    if program == 'pack':
+        limit = pool // (2 * L)
+    elif program == 'step':
+        limit = 1.1 * eng.max_streams * span
+    else:   # a chunk also holds its rows' scores and probabilities
+        limit = 1.1 * (span + 2 * bucket * H * eng.max_seq * 4)
+    assert mem.temp_size_in_bytes < limit < pool
+
+
+def _pool_rows(cache):
+    """The pools as numpy [L, pages + 1, P, H * Dh], K and V."""
+    return (np.stack([np.asarray(x) for x in cache.k]),
+            np.stack([np.asarray(x) for x in cache.v]))
+
+
+def test_pool_placement_exact(params, engine):
+    """Where the writers put K/V: after two prefills and six steps with
+    slots 1 and 3 inactive, a prompt position holds bit-for-bit what
+    the prefill program returned, a decoded position the K/V of the
+    full-context forward, an unclaimed page what it held before, and
+    only the trash page took the inactive slots' and the padding's
+    writes; a second warm-up then changes no resident page."""
+    rng = np.random.default_rng(31)
+    cache, mpp, n_steps = engine.cache, engine.pages_per_stream, 6
+    prompts = {0: rng.integers(0, V, size=11), 2: rng.integers(0, V, size=5)}
+    for pools in (cache.k, cache.v):    # a mark the trash page can lose
+        pools[:] = [x.at[cache.trash].set(7.0) for x in pools]
+    k0, v0 = _pool_rows(cache)
+    pages, toks, want = {}, {}, {}
+    for slot, prompt in prompts.items():
+        pages[slot] = cache.alloc(-(-(len(prompt) + n_steps) // PAGE))
+        logits = engine.prefill_into(prompt, pages[slot])
+        toks[slot] = list(prompt) + [int(np.argmax(logits))]
+        bucket = engine.bucket_for(len(prompt))
+        padded = np.zeros((bucket,), np.int32)
+        padded[:len(prompt)] = prompt
+        _, k, v = engine._prefill[bucket](
+            engine.params, jnp.asarray(padded), jnp.int32(len(prompt) - 1))
+        want[slot] = [np.asarray(x).reshape(L, bucket, D) for x in (k, v)]
+    for _ in range(n_steps):
+        pt = np.full((STREAMS, mpp), cache.trash, np.int32)
+        tok = np.zeros((STREAMS,), np.int64)
+        ctx = np.zeros((STREAMS,), np.int32)
+        for slot in prompts:
+            pt[slot, :len(pages[slot])] = pages[slot]
+            tok[slot] = toks[slot][-1]
+            ctx[slot] = len(toks[slot]) - 1
+        nxt, _ = engine.step(tok, pt, ctx)
+        for slot in prompts:
+            toks[slot].append(int(nxt[slot]))
+    k1, v1 = _pool_rows(cache)
+    claimed = sorted(p for ps in pages.values() for p in ps)
+    for slot, prompt in prompts.items():
+        cached = toks[slot][:-1]     # the last token is not cached yet
+        _, k_ref, v_ref = _forward(
+            params, jnp.asarray([cached], jnp.int32), L, H)
+        refs = [np.asarray(r)[:, 0].reshape(L, len(cached), D)
+                for r in (k_ref, v_ref)]
+        for pool, before, packed, ref in zip((k1, v1), (k0, v0),
+                                             want[slot], refs):
+            for pos in range(len(cached)):
+                got = pool[:, pages[slot][pos // PAGE], pos % PAGE]
+                if pos < len(prompt):
+                    assert np.array_equal(got, packed[:, pos]), (slot, pos)
+                assert np.max(np.abs(got - ref[:, pos])) <= ULP_BAR, \
+                    (slot, pos)
+            # the claimed span past the context is as it was
+            for pos in range(len(cached), len(pages[slot]) * PAGE):
+                page, off = pages[slot][pos // PAGE], pos % PAGE
+                assert np.array_equal(pool[:, page, off],
+                                      before[:, page, off])
+    others = [p for p in range(cache.num_pages) if p not in claimed]
+    assert np.array_equal(k1[:, others], k0[:, others])
+    assert np.array_equal(v1[:, others], v0[:, others])
+    # an inactive slot writes its row at offset 0 of the trash page
+    for pool in (k1, v1):
+        assert not np.any(pool[:, cache.trash, 0] == 7.0)
+        assert np.all(pool[:, cache.trash, 1:] == 7.0)
+    engine._compiles_at_warmup = None       # make warmup() run again
+    engine.warmup()
+    k2, v2 = _pool_rows(cache)
+    resident = list(range(cache.num_pages))
+    assert np.array_equal(k2[:, resident], k1[:, resident])
+    assert np.array_equal(v2[:, resident], v1[:, resident])
+    for ps in pages.values():
+        cache.free(ps)
+    assert engine.compiles_after_warmup == 0
+
+
 def test_paged_attention_op_matches_contiguous(params):
     """The registered paged_attention op, reading KV through a
     shuffled page table, matches attention over the same KV laid out

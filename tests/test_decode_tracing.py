@@ -165,6 +165,26 @@ def test_a_new_bucket_compiles_once_under_its_prefill(params):
     assert eng.compiles_after_warmup == 2
 
 
+@pytest.mark.parametrize('chunked', [False, True])
+def test_compile_spans_say_whether_the_pool_is_in_place(params, chunked):
+    eng = make_engine(params, prefill_chunk_tokens=PAGE if chunked else 0)
+    eng.warmup()
+    comp = [e['args'] for e in spans() if e['name'] == 'decode.compile']
+    built = [('chunk', 8)] if chunked else \
+        [('pack', 8), ('pack', 16), ('prefill', 8), ('prefill', 16)]
+    assert sorted((a['program'], a['bucket']) for a in comp
+                  if a['bucket']) == built
+    assert [a['program'] for a in comp if a['bucket'] is None] == ['step']
+    pool = eng.resident_bytes()
+    for a in comp:
+        assert set(a) == {'program', 'bucket', 'temp_bytes', 'alias_bytes',
+                          'pool_bytes'}
+        assert a['pool_bytes'] == pool and a['temp_bytes'] >= 0
+        # a prefill takes no pool; every other program aliases all of it
+        assert a['alias_bytes'] == (0 if a['program'] == 'prefill'
+                                    else pool)
+
+
 def test_metrics_disabled_leaves_no_ring_record(params):
     eng = make_engine(params)
     eng.warmup()

@@ -346,6 +346,7 @@ def test_page_pool_bytes_matches_live_cache():
                          n_heads=2, head_dim=8)
     assert cache.resident_bytes() == memory_model.page_pool_bytes(
         9, 4, 2, 8, dtype='float32', n_layers=2, kv=2)
+    assert len(cache.k) == len(cache.v) == 2    # a buffer a layer
     assert cache.resident_bytes() == \
         sum(int(np.prod(pool.shape)) * pool.dtype.itemsize
-            for pool in (cache.k, cache.v))
+            for pool in cache.k + cache.v)
