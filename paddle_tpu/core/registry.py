@@ -56,6 +56,9 @@ AMP_WHITE = frozenset({
     # softmax state), so their INPUTS lower; their loss outputs are
     # always f32 (amp.py WHITE_F32_OUTPUT_OPS)
     'fused_linear_softmax_ce', 'vocab_parallel_ce',
+    # routed-expert FFN: grouped expert matmuls; its router and combine
+    # are float32 inside the op whatever its inputs' precision
+    'moe_ffn',
 })
 
 AMP_BLACK = frozenset({
@@ -67,12 +70,14 @@ AMP_BLACK = frozenset({
     'log_loss', 'margin_rank_loss', 'modified_huber_loss', 'rank_loss',
     'warpctc', 'nce', 'linear_chain_crf', 'crf_decoding',
     # normalization / statistics
-    'batch_norm', 'layer_norm', 'norm', 'lrn', 'l1_norm',
+    'batch_norm', 'layer_norm', 'rms_norm', 'norm', 'lrn', 'l1_norm',
     'squared_l2_norm', 'squared_l2_distance', 'cos_sim', 'clip_by_norm',
     # wide accumulations
     'sum', 'mean', 'reduce_sum', 'reduce_mean', 'reduce_prod',
     # range-sensitive elementwise math
     'exp', 'log', 'pow', 'square',
+    # position angles up to max_seq: sin/cos want the float32 mantissa
+    'rotary_embedding',
     # metrics
     'accuracy', 'auc', 'precision_recall', 'positive_negative_pair',
     'chunk_eval', 'edit_distance', 'detection_output',

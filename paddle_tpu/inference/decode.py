@@ -67,12 +67,17 @@ class PromptTooLongError(ValueError):
     keep working; raised in the SUBMITTING thread, never the worker."""
 
 
-def extract_params(scope, n_layers):
-    """Pull the transformer's fixed-name ``tr_*`` parameters out of a
-    scope (models/transformer.py param_names manifest) as a plain
-    {name: jax.Array} dict — the engine's weights."""
-    from ..models.transformer import param_names
-    return {n: jnp.asarray(scope.get(n)) for n in param_names(n_layers)}
+def extract_params(scope, n_layers, block=None):
+    """Pull a model's fixed-name parameters out of a scope as a plain
+    {name: jax.Array} dict — the engine's weights.  The manifest is the
+    block description's ``names`` (inference/blocks.py), or the
+    transformer's ``tr_*`` (models/transformer.py param_names) when no
+    block is given."""
+    if block is None:
+        from ..models.transformer import param_names as names
+    else:
+        names = block.names
+    return {n: jnp.asarray(scope.get(n)) for n in names(n_layers)}
 
 
 def decode_buckets(page_size, top):
@@ -315,6 +320,16 @@ class DecodeEngine(object):
     means the first equals the last and the scratch stays under what
     the program gathers.
 
+    Which decoder is served is the ``block`` handed in (a description
+    from inference/blocks.py).  Its prefill, chunk and step are ONE loop
+    over layers (``_layers``) that differs only in where K/V rows are
+    written and which attention op reads them, and the weights are an
+    OPERAND of each of its programs (``argument_bytes`` of the
+    ``decode.compile`` span holds them; no program bakes a copy).
+    Without a block the engine serves the OPT layer of
+    models/transformer.py from its three hand-written closures, with the
+    weights closed over, as before (ROADMAP D1/D2 fold them in).
+
     Not thread-safe by design: exactly one caller (the DecodeServer
     worker) drives it, and the page pools move through donated
     arguments — concurrent calls would use donated buffers.
@@ -323,16 +338,42 @@ class DecodeEngine(object):
     def __init__(self, params, n_layers, n_heads, page_size=None,
                  num_pages=None, max_streams=None, prefill_bucket=None,
                  prefix_cache=None, prefill_chunk_tokens=None,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, max_seq=None, block=None):
         from ..flags import FLAGS
         enable_compile_cache()
-        self.params = {n: jnp.asarray(v) for n, v in params.items()}
+        self.block = block
+        if block is None:
+            self.params = {n: jnp.asarray(v) for n, v in params.items()}
+        else:
+            self.params = self._place(params)
         self.n_layers = int(n_layers)
         self.n_heads = int(n_heads)
-        self.d_model = int(self.params['tr_embed'].shape[1])
+        if block is None:
+            self.d_model = int(self.params['tr_embed'].shape[1])
+            self.vocab_size = int(self.params['tr_embed'].shape[0])
+            table = int(self.params['tr_pos'].shape[0])
+            self.max_seq = int(max_seq or table)
+            if self.max_seq > table:
+                raise ValueError("max_seq %d exceeds the position "
+                                 "table's %d rows" % (self.max_seq, table))
+        else:
+            if max_seq is None:
+                raise ValueError("a block without a position table "
+                                 "needs max_seq")
+            if block.n_heads != self.n_heads:
+                raise ValueError("block has %d heads, engine %d"
+                                 % (block.n_heads, self.n_heads))
+            sizes = block.sizes(self.params)
+            self.d_model = sizes['d_model']
+            self.vocab_size = sizes['vocab_size']
+            self.max_seq = int(max_seq)
         self.head_dim = self.d_model // self.n_heads
-        self.vocab_size = int(self.params['tr_embed'].shape[0])
-        self.max_seq = int(self.params['tr_pos'].shape[0])
+        # the weights as the leading operand of a block's programs
+        self._weights = () if block is None else (self.params,)
+        # routed-expert totals over the engine's life (``_routing``);
+        # all zero for a block without experts, and without a block
+        self.routing = {'assignments': 0, 'max_load': 0, 'touched': 0.0,
+                        'steps': 0}
         self.page_size = int(page_size or FLAGS.decode_page_size)
         self.max_streams = int(max_streams or FLAGS.decode_max_streams)
         if self.max_seq % self.page_size:
@@ -393,9 +434,149 @@ class DecodeEngine(object):
             span_args.update(
                 temp_bytes=int(mem.temp_size_in_bytes),
                 alias_bytes=int(mem.alias_size_in_bytes),
+                argument_bytes=int(mem.argument_size_in_bytes),
                 pool_bytes=self.cache.resident_bytes())
         self.compiles_total += 1
         return compiled
+
+    def _layers(self, params, x, positions, active, attend):
+        """A block's layers over x [T, D]: ``attend(i, q, k, v)`` is
+        where prefill, chunk and step differ (it writes layer i's K/V
+        rows and returns the attention output [T, H, Dh]).  Returns
+        (x, extra): ``extra`` is what the program returns beside its
+        usual outputs, ``(routing counts [L, E],)`` or ``()``."""
+        blk, counts = self.block, []
+        for i in range(self.n_layers):
+            q, k, v = blk.qkv(params, x, i, positions)
+            ctx = attend(i, q, k.astype(self.cache.dtype),
+                         v.astype(self.cache.dtype))
+            x, c = blk.after_attention(params, x, ctx, i, active)
+            counts.append(c)
+        return x, (() if counts[0] is None else (jnp.stack(counts),))
+
+    @staticmethod
+    def _place(params):
+        """A block's weights onto the device, once, under a set-up span
+        that waits for them (they are operands: nothing else holds a
+        copy)."""
+        placed = {}
+        with _obs.span('decode.weights', args=placed):
+            out = jax.block_until_ready(
+                {n: jnp.asarray(v) for n, v in params.items()})
+            placed.update(
+                bytes=sum(v.nbytes for v in out.values()),
+                tensors=len(out), dtype=str(max(
+                    out.values(), key=lambda v: v.nbytes).dtype))
+        return out
+
+    def _routing(self, counts, span_args, step=False):
+        """The routing counts [L, E] a call returned beside its usual
+        outputs (``_layers``) -> the span's arguments and the engine's
+        totals: assignments (top_k x tokens x layers), experts with a
+        token (mean over layers; totalled over decode steps only), most
+        tokens on one expert."""
+        c = np.asarray(counts)
+        touched = float(np.mean(np.sum(c > 0, axis=1)))
+        span_args.update(moe_assignments=int(c.sum()), moe_touched=touched,
+                         moe_max_load=int(c.max()))
+        tot = self.routing
+        tot['assignments'] += span_args['moe_assignments']
+        tot['max_load'] = max(tot['max_load'], span_args['moe_max_load'])
+        if step:
+            tot['touched'] += touched
+            tot['steps'] += 1
+
+    # -- a block's three programs: one loop, three ways to attend -------
+
+    @staticmethod
+    def _write_then(k_pool, v_pool, page_idx, offset, read):
+        """``attend`` of chunk and step: layer i's new rows land at
+        (page, offset) of its own buffers, then ``read(i, q)`` attends
+        over what was written."""
+        def attend(i, q, k, v):
+            k_pool[i] = k_pool[i].at[page_idx, offset].set(k)
+            v_pool[i] = v_pool[i].at[page_idx, offset].set(v)
+            return read(i, q)
+        return attend
+
+    def _block_prefill(self, bucket):
+        from ..ops.attention import _dense_attention
+        blk, H, Dh = self.block, self.n_heads, self.head_dim
+
+        def prefill(params, tokens, last):
+            pos = jnp.arange(bucket)
+            ks, vs = [], []
+
+            def attend(i, q, k, v):
+                # attention reads the rows as the cache will hold them
+                # (already in the pools' dtype); pack writes them later
+                ks.append(k.reshape(bucket, H, Dh))
+                vs.append(v.reshape(bucket, H, Dh))
+                return _dense_attention(q[None], ks[-1][None],
+                                        vs[-1][None], True, None)[0]
+
+            x, extra = self._layers(
+                params, blk.embed(params, tokens, pos), pos, pos <= last,
+                attend)
+            return (blk.head(params, x[last][None])[0], jnp.stack(ks),
+                    jnp.stack(vs)) + extra
+        return prefill
+
+    def _block_chunk(self, bucket):
+        blk, P, mpp = self.block, self.page_size, self.pages_per_stream
+        trash = self.cache.trash
+        chunk_att = get_op_impl('chunked_prefill_attention').compute
+
+        def chunk(params, k_pool, v_pool, tokens, pt, pos0, n_valid):
+            # padded rows (i >= n_valid) write to the trash page, are
+            # not counted, and their outputs never leave the executable
+            pos = pos0 + jnp.arange(bucket)
+            valid = jnp.arange(bucket) < n_valid
+            page_idx = pt[jnp.clip(pos // P, 0, mpp - 1)]
+            page_idx = jnp.where(valid, page_idx, trash)
+            offset = pos % P
+            k_pool, v_pool = list(k_pool), list(v_pool)
+
+            def read(i, q):
+                return chunk_att(None, {'Q': [q], 'KPool': [k_pool[i]],
+                                        'VPool': [v_pool[i]],
+                                        'PT': [pt], 'Pos0': [pos0]},
+                                 {})['Out'][0]
+
+            x, extra = self._layers(
+                params, blk.embed(params, tokens, pos), pos, valid,
+                self._write_then(k_pool, v_pool, page_idx, offset, read))
+            x_last = x[jnp.clip(n_valid - 1, 0, bucket - 1)]
+            return (k_pool, v_pool,
+                    blk.head(params, x_last[None])[0]) + extra
+        return chunk
+
+    def _block_step(self):
+        blk, P, trash = self.block, self.page_size, self.cache.trash
+        paged = get_op_impl('paged_attention').compute
+
+        def step(params, k_pool, v_pool, tokens, pt, ctx_len):
+            pos = jnp.clip(ctx_len, 0, self.max_seq - 1)
+            page_idx = jnp.take_along_axis(
+                pt, (pos // P)[:, None], axis=1)[:, 0]
+            offset = pos % P
+            k_pool, v_pool = list(k_pool), list(v_pool)
+
+            def read(i, q):
+                return paged(None, {'Q': [q], 'KPool': [k_pool[i]],
+                                    'VPool': [v_pool[i]], 'PT': [pt],
+                                    'CtxLen': [pos + 1]}, {})['Out'][0]
+
+            # an inactive slot's page table is all trash: it runs (its
+            # rows never meet another slot's) and is not counted
+            x, extra = self._layers(
+                params, blk.embed(params, tokens, pos), pos,
+                pt[:, 0] != trash,
+                self._write_then(k_pool, v_pool, page_idx, offset, read))
+            logits = blk.head(params, x)
+            return (k_pool, v_pool, logits,
+                    jnp.argmax(logits, axis=-1)) + extra
+        return step
 
     def _ensure_prefill(self, bucket):
         if bucket in self._prefill:
@@ -412,6 +593,9 @@ class DecodeEngine(object):
             # of every bucket — invisible to compiles_total
             logits, k, v = _forward(params, tokens[None], L, H)
             return logits[0, last], k[:, 0], v[:, 0]
+
+        if self.block is not None:
+            prefill = self._block_prefill(bucket)
 
         def pack(k_pool, v_pool, k, v, pages):
             # scatter the prefill K/V into the claimed pages: [L, T, H,
@@ -493,11 +677,15 @@ class DecodeEngine(object):
             logits = x_last @ params['tr_head_w'] + params['tr_head_b']
             return k_pool, v_pool, logits
 
+        if self.block is not None:
+            chunk = self._block_chunk(bucket)
+        n_w = len(self._weights)
         self._chunk[bucket] = self._compile(
-            chunk, self.cache.k, self.cache.v,
+            chunk, *self._weights, self.cache.k, self.cache.v,
             jnp.zeros((bucket,), jnp.int32),
             jnp.full((mpp,), trash, jnp.int32),
-            jnp.int32(0), jnp.int32(1), donate=(0, 1), bucket=bucket)
+            jnp.int32(0), jnp.int32(1), donate=(n_w, n_w + 1),
+            bucket=bucket)
 
     def _ensure_step(self):
         if self._step is not None:
@@ -547,11 +735,14 @@ class DecodeEngine(object):
             logits = x @ params['tr_head_w'] + params['tr_head_b']
             return k_pool, v_pool, logits, jnp.argmax(logits, axis=-1)
 
+        if self.block is not None:
+            step = self._block_step()
+        n_w = len(self._weights)
         self._step = self._compile(
-            step, self.cache.k, self.cache.v,
+            step, *self._weights, self.cache.k, self.cache.v,
             jnp.zeros((S,), jnp.int32),
             jnp.full((S, mpp), self.cache.trash, jnp.int32),
-            jnp.zeros((S,), jnp.int32), donate=(0, 1))
+            jnp.zeros((S,), jnp.int32), donate=(n_w, n_w + 1))
 
     def warmup(self):
         """AOT-compile every prefill bucket, its pack, and the decode
@@ -576,10 +767,10 @@ class DecodeEngine(object):
             mpp = self.pages_per_stream
             for b in self.chunk_buckets:
                 self.cache.k, self.cache.v, logits = self._chunk[b](
-                    self.cache.k, self.cache.v,
+                    *self._weights, self.cache.k, self.cache.v,
                     jnp.zeros((b,), jnp.int32),
                     jnp.full((mpp,), trash, jnp.int32),
-                    jnp.int32(0), jnp.int32(b))
+                    jnp.int32(0), jnp.int32(b))[:3]
                 jax.block_until_ready(logits)
         else:
             for b in self.buckets:
@@ -588,17 +779,18 @@ class DecodeEngine(object):
             for b in self.buckets:
                 logits, k, v = self._prefill[b](
                     self.params, jnp.zeros((b,), jnp.int32),
-                    jnp.int32(0))
+                    jnp.int32(0))[:3]
                 all_trash = jnp.full((b // self.page_size,), trash,
                                      jnp.int32)
                 self.cache.k, self.cache.v = self._pack[b](
                     self.cache.k, self.cache.v, k, v, all_trash)
                 jax.block_until_ready(logits)
         S, mpp = self.max_streams, self.pages_per_stream
-        self.cache.k, self.cache.v, logits, _ = self._step(
-            self.cache.k, self.cache.v, jnp.zeros((S,), jnp.int32),
+        self.cache.k, self.cache.v, logits = self._step(
+            *self._weights, self.cache.k, self.cache.v,
+            jnp.zeros((S,), jnp.int32),
             jnp.full((S, mpp), trash, jnp.int32),
-            jnp.zeros((S,), jnp.int32))
+            jnp.zeros((S,), jnp.int32))[:3]
         jax.block_until_ready(logits)
         self._compiles_at_warmup = self.compiles_total
 
@@ -626,12 +818,12 @@ class DecodeEngine(object):
         prompt = np.asarray(prompt, dtype=np.int32)
         t = int(prompt.shape[0])
         bucket = self.bucket_for(t)
-        with _obs.span('decode.prefill_into',
-                       args={'tokens': t, 'bucket': bucket}):
+        args = {'tokens': t, 'bucket': bucket}
+        with _obs.span('decode.prefill_into', args=args):
             self._ensure_prefill(bucket)
             toks = np.zeros((bucket,), np.int32)
             toks[:t] = prompt
-            logits, k, v = self._prefill[bucket](
+            logits, k, v, *extra = self._prefill[bucket](
                 self.params, jnp.asarray(toks), jnp.int32(t - 1))
             n_pages = bucket // self.page_size
             page_ids = np.full((n_pages,), self.cache.trash, np.int32)
@@ -639,6 +831,8 @@ class DecodeEngine(object):
             page_ids[:n_real] = pages[:n_real]
             self.cache.k, self.cache.v = self._pack[bucket](
                 self.cache.k, self.cache.v, k, v, jnp.asarray(page_ids))
+            if extra:
+                self._routing(extra[0], args)
             return np.asarray(logits)
 
     def chunk_spans(self, prompt_len, start=0):
@@ -669,8 +863,8 @@ class DecodeEngine(object):
         tokens = np.asarray(tokens, dtype=np.int32)
         c = int(tokens.shape[0])
         bucket = self.bucket_for(c)
-        with _obs.span('decode.prefill_chunk',
-                       args={'tokens': c, 'bucket': bucket}):
+        args = {'tokens': c, 'bucket': bucket}
+        with _obs.span('decode.prefill_chunk', args=args):
             self._ensure_chunk(bucket)
             toks = np.zeros((bucket,), np.int32)
             toks[:c] = tokens
@@ -678,9 +872,13 @@ class DecodeEngine(object):
             pt = np.full((mpp,), self.cache.trash, np.int32)
             n = min(len(pages), mpp)
             pt[:n] = pages[:n]
-            self.cache.k, self.cache.v, logits = self._chunk[bucket](
-                self.cache.k, self.cache.v, jnp.asarray(toks),
-                jnp.asarray(pt), jnp.int32(pos0), jnp.int32(c))
+            self.cache.k, self.cache.v, logits, *extra = \
+                self._chunk[bucket](
+                    *self._weights, self.cache.k, self.cache.v,
+                    jnp.asarray(toks), jnp.asarray(pt), jnp.int32(pos0),
+                    jnp.int32(c))
+            if extra:
+                self._routing(extra[0], args)
             return np.asarray(logits)
 
     def step(self, tokens, page_tables, ctx_lens):
@@ -692,14 +890,18 @@ class DecodeEngine(object):
         # the two halves of the host's part: everything up to the call
         # into the executable returning, then the wait for the device
         # and the copy back of tokens and [S, V] logits
-        with _obs.span('decode.step'):
+        args = {} if self._weights else None    # a block's routing counts
+        with _obs.span('decode.step', args=args):
             with _obs.span('decode.step.dispatch'):
-                self.cache.k, self.cache.v, logits, nxt = self._step(
-                    self.cache.k, self.cache.v,
-                    jnp.asarray(tokens, dtype=jnp.int32),
-                    jnp.asarray(page_tables, dtype=jnp.int32),
-                    jnp.asarray(ctx_lens, dtype=jnp.int32))
+                self.cache.k, self.cache.v, logits, nxt, *extra = \
+                    self._step(
+                        *self._weights, self.cache.k, self.cache.v,
+                        jnp.asarray(tokens, dtype=jnp.int32),
+                        jnp.asarray(page_tables, dtype=jnp.int32),
+                        jnp.asarray(ctx_lens, dtype=jnp.int32))
             with _obs.span('decode.step.fetch'):
+                if extra:
+                    self._routing(extra[0], args, step=True)
                 return np.asarray(nxt), np.asarray(logits)
 
     def resident_bytes(self):
@@ -947,6 +1149,14 @@ class DecodeServer(object):
                     self.engine.compiles_after_warmup,
                 'resident_bytes': self.engine.resident_bytes(),
                 'static_batching': self.static,
+                # routed experts, over the engine's life: assignments
+                # of every call, the most tokens one expert got in a
+                # call, and the experts a decode step touched (mean over
+                # layers and steps)
+                'moe_assignments': self.engine.routing['assignments'],
+                'moe_max_load': self.engine.routing['max_load'],
+                'moe_touched_mean': self.engine.routing['touched']
+                / max(self.engine.routing['steps'], 1),
             }
 
     # -- worker side ---------------------------------------------------

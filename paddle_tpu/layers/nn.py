@@ -11,7 +11,8 @@ from .layer_helper import LayerHelper
 
 __all__ = [
     'fc', 'embedding', 'conv2d', 'conv3d', 'pool2d', 'pool3d', 'batch_norm',
-    'layer_norm', 'dropout', 'cross_entropy', 'square_error_cost',
+    'layer_norm', 'rms_norm', 'rotary_embedding', 'moe_ffn', 'dropout',
+    'cross_entropy', 'square_error_cost',
     'accuracy', 'softmax_with_cross_entropy', 'fused_linear_softmax_ce',
     'conv2d_transpose',
     'reduce_sum', 'reduce_mean', 'reduce_max', 'reduce_min', 'reduce_prod',
@@ -335,6 +336,63 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
         outputs={'Y': [out], 'Mean': [mean_out], 'Variance': [var_out]},
         attrs={'epsilon': epsilon, 'begin_norm_axis': begin_norm_axis})
     return helper.append_activation(out)
+
+
+def rms_norm(input, epsilon=1e-05, param_attr=None, name=None, **kwargs):
+    """RMSNorm over the last axis with a learned scale (init 1),
+    computed in float32 (ops/moe.py ``rms_norm``)."""
+    helper = LayerHelper('rms_norm', **locals())
+    scale = helper.create_parameter(
+        attr=helper.param_attr, shape=[input.shape[-1]], dtype='float32',
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_tmp_variable(helper.input_dtype())
+    helper.append_op(type='rms_norm',
+                     inputs={'X': [input], 'Scale': [scale]},
+                     outputs={'Out': [out]}, attrs={'epsilon': epsilon})
+    return out
+
+
+def rotary_embedding(x, pos=None, theta=10000.0, **kwargs):
+    """Rotary position embedding of ``x`` [..., T, H, Dh]; ``pos``
+    [..., T] int positions (0..T-1 when None)."""
+    helper = LayerHelper('rotary_embedding', **locals())
+    out = helper.create_tmp_variable(x.dtype)
+    inputs = {'X': [x]}
+    if pos is not None:
+        inputs['Pos'] = [pos]
+    helper.append_op(type='rotary_embedding', inputs=inputs,
+                     outputs={'Out': [out]}, attrs={'theta': float(theta)})
+    return out
+
+
+def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
+            router_attr=None, gate_attr=None, up_attr=None, down_attr=None,
+            dtype='float32', name=None, **kwargs):
+    """Routed-expert FFN (ops/moe.py ``moe_ffn``): every token of
+    ``input`` [..., D] reaches its ``top_k`` of ``num_experts`` SiLU-gated
+    experts of width ``expert_size``; no capacity, nothing dropped.  The
+    experts are three stacked parameters of ``dtype`` ([E, D, F], [E, D,
+    F], [E, F, D]); the router [D, E] is float32.  Returns (out, counts)
+    — counts [E] int32, tokens routed to each expert."""
+    helper = LayerHelper('moe_ffn', **locals())
+    d, e, f = int(input.shape[-1]), int(num_experts), int(expert_size)
+    to_attr = helper.param_attr.to_attr
+    params = {}
+    for slot, attr, shape, dt in (
+            ('RouterW', router_attr, [d, e], 'float32'),
+            ('GateW', gate_attr, [e, d, f], dtype),
+            ('UpW', up_attr, [e, d, f], dtype),
+            ('DownW', down_attr, [e, f, d], dtype)):
+        params[slot] = [helper.create_parameter(
+            attr=to_attr(attr), shape=shape, dtype=dt)]
+    out = helper.create_tmp_variable(helper.input_dtype())
+    counts = helper.create_tmp_variable('int32', stop_gradient=True)
+    helper.append_op(
+        type='moe_ffn', inputs=dict(params, X=[input]),
+        outputs={'Out': [out], 'Counts': [counts]},
+        attrs={'top_k': int(top_k),
+               'norm_topk_prob': bool(norm_topk_prob)})
+    return out, counts
 
 
 def dropout(x, dropout_prob, is_test=False, seed=0, **kwargs):

@@ -1,4 +1,13 @@
-"""Expert parallelism over an 'ep' mesh axis.
+"""Expert parallelism over an 'ep' mesh axis: the collective routine
+only.
+
+This module is the ``ep`` all-to-all dispatch, with a top-1 argmax gate
+and a fixed capacity that DROPS over-capacity tokens; no ``Program`` and
+no engine reaches it.  The mixture-of-experts layer the registry and the
+decode engine run is ``ops/moe.py`` ``moe_ffn`` (float32 softmax router,
+top-k, no capacity, no dropped token, on one chip); sharding that op's
+experts over ``ep`` with this module's all-to-all, and giving this gate
+the top-k it lacks (``ops.moe.moe_route``), is ROADMAP R1's second half.
 
 The reference predates mixture-of-experts, but the mesh design
 (SURVEY §6.5) names 'ep' among the first-class axes: each mesh member

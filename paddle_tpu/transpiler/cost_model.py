@@ -315,6 +315,19 @@ def _macs_chunked_prefill_attention(ins, outs, attrs, unknown):
     return 2 * int(c) * int(h) * int(mpp) * int(p) * int(d)
 
 
+def _macs_moe_ffn(ins, outs, attrs, unknown):
+    # per token: the router's D*E, then gate + up (2*D*F) and down (F*D)
+    # of ALL E experts — the op computes every expert and masks by the
+    # routing weight (ops/moe.py), so what executes is E/top_k times the
+    # routed count, whatever the skew.
+    x = _first(ins, 'X')
+    g = _first(ins, 'GateW')
+    if x is None or g is None or len(g[0]) != 3:
+        return None
+    e, d, f = (int(v) for v in g[0])
+    return _prod(x[0][:-1], unknown) * (d * e + e * 3 * d * f)
+
+
 MAC_FORMULAS = {
     'mul': _macs_mul,
     'matmul': _macs_matmul,
@@ -335,6 +348,7 @@ MAC_FORMULAS = {
     'chunked_prefill_attention': _macs_chunked_prefill_attention,
     'fused_linear_softmax_ce': _macs_vocab_ce,
     'vocab_parallel_ce': _macs_vocab_ce,
+    'moe_ffn': _macs_moe_ffn,
 }
 
 

@@ -178,7 +178,7 @@ def test_compile_spans_say_whether_the_pool_is_in_place(params, chunked):
     pool = eng.resident_bytes()
     for a in comp:
         assert set(a) == {'program', 'bucket', 'temp_bytes', 'alias_bytes',
-                          'pool_bytes'}
+                          'argument_bytes', 'pool_bytes'}
         assert a['pool_bytes'] == pool and a['temp_bytes'] >= 0
         # a prefill takes no pool; every other program aliases all of it
         assert a['alias_bytes'] == (0 if a['program'] == 'prefill'
