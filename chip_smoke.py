@@ -55,6 +55,8 @@ CHIP = dict(
     serve=dict(L=6, D=512, H=8, V=30000, T=512, page=16, streams=16,
                bucket=256, prompt=(4, 200), new=(8, 32)),
     flash=dict(B=2, T=8192, H=8, D=64),         # bench_attention.py
+    # one layer of the olmoe-1b-7b_serve_chat32_chunked decode step
+    paged=dict(S=32, H=16, D=128, P=16, MPP=64, N=2049),
     lstm=dict(T=128, B=256, H=256),             # bench_lstm_lm.py
     gru=dict(T=64, B=512, H=512),               # bench_seq2seq.py
     dense=[(2048, 1000), (7, 7, 3, 64), (64,)],  # fc head, stem, a bias
@@ -66,6 +68,7 @@ TOY = dict(
     serve=dict(L=2, D=64, H=4, V=200, T=64, page=8, streams=4,
                bucket=32, prompt=(4, 20), new=(3, 6)),
     flash=dict(B=1, T=256, H=2, D=64),
+    paged=dict(S=4, H=2, D=128, P=16, MPP=10, N=25),
     lstm=dict(T=6, B=8, H=128),
     gru=dict(T=6, B=8, H=128),
     dense=[(40, 30), (3, 3, 3, 8), (64,)],
@@ -617,6 +620,36 @@ def kernel_cases(cfg):
             lambda q, k, v: fa.flash_attention(
                 q, k, v, causal=True, interpret=interpret))(q, k, v, cot),
         with_grads(attn_ref), *TOL_BF16))
+
+    # -- paged decode attention over live pages ---------------------------
+    from paddle_tpu.ops.attention import paged_attention_math
+    from paddle_tpu.ops.pallas import paged_attention
+    pg = cfg['paged']
+    pool = ((pg['N'], pg['P'], pg['H'] * pg['D']), bf16)
+
+    def paged_make(rng):
+        # a third of the slots run, contexts anywhere up to max_seq, on
+        # pages scattered over the pool; the others idle on the trash page
+        s, mpp, n = pg['S'], pg['MPP'], pg['N']
+        pt = np.full((s, mpp), n - 1, np.int32)
+        ctx = np.ones((s,), np.int32)
+        free = rng.permutation(n - 1)
+        for slot in rng.permutation(s)[:max(1, s // 3)]:
+            ctx[slot] = rng.integers(1, mpp * pg['P'] + 1)
+            pages = -(-int(ctx[slot]) // pg['P'])
+            pt[slot, :pages], free = free[:pages], free[pages:]
+        kv = [(rng.standard_normal(pool[0]) * 0.5).astype(np.float32)
+              .astype(bf16) for _ in range(2)]
+        return [rng.standard_normal((s, pg['H'], pg['D']))
+                .astype(np.float32)] + kv + [pt, ctx]
+
+    cases.append(KernelCase(
+        'paged_attention_live_pages',
+        [((pg['S'], pg['H'], pg['D']), f32), pool, pool,
+         ((pg['S'], pg['MPP']), jnp.int32), ((pg['S'],), jnp.int32)],
+        lambda q, k, v, pt, ctx, interpret: paged_attention(
+            q, k, v, pt, ctx, interpret=interpret),
+        paged_attention_math, *TOL_BF16, make=paged_make, timed=True))
 
     # -- fused recurrences, forward and backward -------------------------
     def scan_case(name, c, gates, kernel, reference):

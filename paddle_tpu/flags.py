@@ -94,7 +94,7 @@ DEFINE_string('metrics_host', '127.0.0.1',
               'loopback — the listener is unauthenticated, so binding '
               'wider (0.0.0.0 for a scrape sidecar/k8s probe) is a '
               'deliberate choice, not the default')
-DEFINE_int('profiler_event_cap', 10000,
+DEFINE_int('profiler_event_cap', 40000,
            'max RecordEvent/profile-region entries the profiler retains '
            '(deque maxlen; oldest drop first) so long-lived serving '
            'processes using RecordEvent do not leak memory.  <=0 means '

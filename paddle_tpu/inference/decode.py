@@ -374,6 +374,9 @@ class DecodeEngine(object):
         # all zero for a block without experts, and without a block
         self.routing = {'assignments': 0, 'max_load': 0, 'touched': 0.0,
                         'steps': 0}
+        # KV pages over the engine's decode steps (``_kv_pages``): the
+        # running slots' live ones, and the page tables' entries
+        self.kv_pages = {'live': 0, 'table': 0}
         self.page_size = int(page_size or FLAGS.decode_page_size)
         self.max_streams = int(max_streams or FLAGS.decode_max_streams)
         if self.max_seq % self.page_size:
@@ -422,8 +425,8 @@ class DecodeEngine(object):
 
     # -- compiled function builders ------------------------------------
 
-    def _compile(self, fn, *args, donate=(), bucket=None):
-        span_args = {'program': fn.__name__, 'bucket': bucket}
+    def _compile(self, fn, *args, donate=(), bucket=None, **span_args):
+        span_args.update(program=fn.__name__, bucket=bucket)
         with _obs.span('decode.compile', args=span_args):
             compiled = jax.jit(fn, donate_argnums=donate).lower(
                 *args).compile()
@@ -485,6 +488,20 @@ class DecodeEngine(object):
         if step:
             tot['touched'] += touched
             tot['steps'] += 1
+
+    def _kv_pages(self, page_tables, ctx_lens, span_args):
+        """A decode step's KV pages, from the host's arrays alone ->
+        the span's arguments and the engine's totals: ``kv_live_pages``,
+        what attention has to read (each running slot's pages up to and
+        with the position written this step), and ``kv_table_pages``,
+        what a gather of whole page tables reads (S x MPP)."""
+        pts = np.asarray(page_tables)
+        running = pts[:, 0] != self.cache.trash
+        live = int(np.sum(
+            np.asarray(ctx_lens)[running] // self.page_size + 1))
+        span_args.update(kv_live_pages=live, kv_table_pages=pts.size)
+        self.kv_pages['live'] += live
+        self.kv_pages['table'] += pts.size
 
     # -- a block's three programs: one loop, three ways to attend -------
 
@@ -690,6 +707,7 @@ class DecodeEngine(object):
     def _ensure_step(self):
         if self._step is not None:
             return
+        from ..ops.attention import paged_attention_path
         L, H, Dh, D = (self.n_layers, self.n_heads, self.head_dim,
                        self.d_model)
         P, S = self.page_size, self.max_streams
@@ -742,7 +760,11 @@ class DecodeEngine(object):
             step, *self._weights, self.cache.k, self.cache.v,
             jnp.zeros((S,), jnp.int32),
             jnp.full((S, mpp), self.cache.trash, jnp.int32),
-            jnp.zeros((S,), jnp.int32), donate=(n_w, n_w + 1))
+            jnp.zeros((S,), jnp.int32), donate=(n_w, n_w + 1),
+            # what the op's dispatch takes for these shapes (the step
+            # calls it with no context: the default backend)
+            attention=paged_attention_path(
+                jax.default_backend(), Dh, P, self.cache.dtype))
 
     def warmup(self):
         """AOT-compile every prefill bucket, its pack, and the decode
@@ -890,7 +912,8 @@ class DecodeEngine(object):
         # the two halves of the host's part: everything up to the call
         # into the executable returning, then the wait for the device
         # and the copy back of tokens and [S, V] logits
-        args = {} if self._weights else None    # a block's routing counts
+        # a block's routing counts and KV pages
+        args = {} if self._weights else None
         with _obs.span('decode.step', args=args):
             with _obs.span('decode.step.dispatch'):
                 self.cache.k, self.cache.v, logits, nxt, *extra = \
@@ -900,6 +923,8 @@ class DecodeEngine(object):
                         jnp.asarray(page_tables, dtype=jnp.int32),
                         jnp.asarray(ctx_lens, dtype=jnp.int32))
             with _obs.span('decode.step.fetch'):
+                if args is not None:
+                    self._kv_pages(page_tables, ctx_lens, args)
                 if extra:
                     self._routing(extra[0], args, step=True)
                 return np.asarray(nxt), np.asarray(logits)
@@ -1157,6 +1182,10 @@ class DecodeServer(object):
                 'moe_max_load': self.engine.routing['max_load'],
                 'moe_touched_mean': self.engine.routing['touched']
                 / max(self.engine.routing['steps'], 1),
+                # KV pages over a block engine's decode steps: live
+                # ones of the running slots, page-table entries
+                'kv_live_pages': self.engine.kv_pages['live'],
+                'kv_table_pages': self.engine.kv_pages['table'],
             }
 
     # -- worker side ---------------------------------------------------

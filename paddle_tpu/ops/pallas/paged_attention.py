@@ -1,0 +1,200 @@
+"""Paged decode attention as a Pallas TPU kernel: live pages only.
+
+``ops/attention.py`` ``paged_attention_math`` gathers ``MPP * P``
+positions for every slot whatever its context is, widens them to f32
+and re-lays them out for XLA's multiply-reduce: three passes over
+``S * max_seq`` rows of HBM a layer.  This kernel reads each slot's
+``ceil(ctx_len / P)`` live pages straight from the pool as the decode
+engine holds it, ``[N, P, H*D]``, and nothing else: the pools stay in
+HBM, the page table and the context lengths are scalar-prefetched, and
+the kernel copies blocks of pages to VMEM itself (one DMA a page, the
+next block in flight while this one is multiplied).
+
+One grid step is one slot.  A block of pages is ``[T, H*D]`` in VMEM
+with every head's keys side by side on the lanes, so all heads go
+through the MXU at once against a block-diagonal query ``[H, H*D]``
+(row h holds q_h on head h's lanes, zeros elsewhere): scores
+``[H, T]``, f32 online softmax over blocks, and ``p @ V`` gives
+``[H, H*D]`` whose h-th row is head h's output on head h's lanes.  The
+diagonal is picked once, at the end.  That needs each head to be a
+whole number of 128-lane registers (``head_dim % 128 == 0``) and a page
+to be a whole number of the pool dtype's sublane tiles, which is what
+``supported`` tests; the op falls back to the math otherwise.
+
+Same mathematics as ``paged_attention_math``: every live position of
+every head, f32 scores, softmax and accumulation, K and V as stored;
+positions ``>= ctx_len`` have probability ``exp(-1e30 - m) == 0`` there
+and are left out here.  The products take the pool's dtype as input
+(bf16 pools: bf16 inputs, f32 accumulation; f32 pools: f32 at
+``highest``).  A slot with ``ctx_len == 0`` reads nothing and returns
+zeros (the math averages whatever its table points at).
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ['paged_attention', 'supported']
+
+_NEG_INF = -1e30
+# positions a block: 8 pages of 16.  Measured on a v5e, one layer of 32
+# slots x 16 heads of 128, bf16, ten slots running at contexts 32-1024:
+# 0.099 ms at 128, 0.112 at 256, 0.140 at 512 (the first block of a slot
+# is not overlapped, so a larger one waits longer); every slot at 1024:
+# 0.40 ms, 670 GB/s over the live bytes (PERF.md section 6, PR 28)
+_BLOCK_POSITIONS = 128
+
+
+def _sublane_rows(dtype):
+    """Rows of one (sublane, 128-lane) tile: 8 for f32, 16 for bf16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def supported(head_dim, page_size, dtype):
+    """Whether the kernel takes these shapes: heads that are whole
+    128-lane registers of the ``H*D`` row, pages that are whole sublane
+    tiles of the pool's dtype (a page is one DMA into a tile-aligned
+    slice of the block)."""
+    return head_dim % 128 == 0 and \
+        page_size % _sublane_rows(dtype) == 0
+
+
+def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+            sem, m_scr, l_scr, acc_scr, *, scale, page, ppb, mpp,
+            head_dim, precision):
+    s = pl.program_id(0)
+    ctx = len_ref[s]
+    n_pages = pl.cdiv(ctx, page)          # live pages of this slot
+    n_blocks = pl.cdiv(n_pages, ppb)
+    hp, hd = acc_scr.shape
+    t = ppb * page
+
+    def on_live_pages(blk, slot, act):
+        # one DMA a page, a page past the live ones none at all
+        for j in range(ppb):
+            @pl.when(blk * ppb + j < n_pages)
+            def _():
+                pid = pt_ref[s * mpp + blk * ppb + j]
+                rows = pl.ds(j * page, page)
+                act(pltpu.make_async_copy(
+                    k_hbm.at[pid], k_buf.at[slot, rows], sem.at[0, slot]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[pid], v_buf.at[slot, rows], sem.at[1, slot]))
+
+    def start(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        on_live_pages(0, 0, start)
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    # row h of the block-diagonal query: q_h on head h's lanes
+    row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+    diag = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
+    qbd = jnp.where(diag, q_ref[0], 0.0).astype(k_buf.dtype)
+
+    def block(blk, carry):
+        slot = jax.lax.rem(blk, 2)
+
+        @pl.when(blk + 1 < n_blocks)
+        def _prefetch():
+            on_live_pages(blk + 1, 1 - slot, start)
+
+        on_live_pages(blk, slot, wait)
+
+        @pl.when(ctx < (blk + 1) * t)
+        def _zero_dead_rows():
+            # the last block's rows past ctx_len (a last page's tail,
+            # pages not copied) hold whatever was there: their p is 0,
+            # and 0 * NaN is not
+            rows = blk * t + jax.lax.broadcasted_iota(
+                jnp.int32, (t, 1), 0)
+            v_buf[slot] = jnp.where(rows < ctx, v_buf[slot],
+                                    jnp.zeros((), v_buf.dtype))
+
+        sc = jax.lax.dot_general(
+            qbd, k_buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=precision) * scale               # [hp, t]
+        pos = blk * t + jax.lax.broadcasted_iota(jnp.int32, (hp, t), 1)
+        sc = jnp.where(pos < ctx, sc, _NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)        # a live block has a live column
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1,
+                                                  keepdims=True)
+        m_scr[...] = m_new
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    l = l_scr[...]
+    out = acc_scr[...] / jnp.where(l > 0, l, 1.0)
+    o_ref[0] = jnp.sum(jnp.where(diag, out, 0.0), axis=0,
+                       keepdims=True).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=('scale', 'interpret'))
+def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
+                    interpret=False):
+    """``paged_attention_math``'s signature and result: ``q`` [S, H, D],
+    pools [N, P, H*D] (a 4-D [N, P, H, D] pool is viewed flat; on the
+    TPU that view is a copy unless the pool is held flat, as the decode
+    engine holds it), ``page_table`` [S, MPP], ``ctx_len`` [S].  Page
+    ids are clipped to the pool as the math clips them.  The caller
+    tests ``supported`` first."""
+    s, h, d = q.shape
+    n, page = k_pool.shape[0], k_pool.shape[1]
+    mpp = page_table.shape[1]
+    hd = h * d
+    if scale is None:
+        scale = float(d) ** -0.5
+    dtype = k_pool.dtype
+    ppb = max(1, min(mpp, _BLOCK_POSITIONS // page))
+    hp = -(-h // 16) * 16         # query rows, a whole tile in any dtype
+    kernel = functools.partial(
+        _kernel, scale=scale, page=page, ppb=ppb, mpp=mpp, head_dim=d,
+        precision=(jax.lax.Precision.HIGHEST if dtype == jnp.float32
+                   else None))
+    row = pl.BlockSpec((1, 1, hd), lambda i, pt, ln: (i, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((s, 1, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s,),
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb * page, hd), dtype),
+                pltpu.VMEM((2, ppb * page, hd), dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, hd), jnp.float32)]),
+        # the scoped-vmem default (16 MB) holds the double-buffered
+        # blocks of a 2048-wide row; wider rows and f32 pools need more
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name='paged_attention_live_pages',
+        interpret=interpret,
+    )(jnp.clip(page_table.astype(jnp.int32), 0, n - 1).reshape(-1),
+      jnp.clip(ctx_len.astype(jnp.int32), 0, mpp * page),
+      q.astype(jnp.float32).reshape(s, 1, hd),
+      k_pool.reshape(n, page, hd), v_pool.reshape(n, page, hd))
+    return out.reshape(s, h, d)

@@ -177,8 +177,13 @@ def test_compile_spans_say_whether_the_pool_is_in_place(params, chunked):
     assert [a['program'] for a in comp if a['bucket'] is None] == ['step']
     pool = eng.resident_bytes()
     for a in comp:
+        # the step also says which attention the op's dispatch took:
+        # on the CPU, heads of any size, the gathered span
         assert set(a) == {'program', 'bucket', 'temp_bytes', 'alias_bytes',
-                          'argument_bytes', 'pool_bytes'}
+                          'argument_bytes', 'pool_bytes'} | (
+            {'attention'} if a['program'] == 'step' else set())
+        if a['program'] == 'step':
+            assert a['attention'] == 'xla_gather'
         assert a['pool_bytes'] == pool and a['temp_bytes'] >= 0
         # a prefill takes no pool; every other program aliases all of it
         assert a['alias_bytes'] == (0 if a['program'] == 'prefill'

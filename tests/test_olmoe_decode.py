@@ -482,11 +482,60 @@ def test_spans_and_server_stats(params, ring, chunked):
     assert 8.0 <= stats['moe_touched_mean'] <= E
 
 
+@pytest.mark.parametrize('chunked', [False, True])
+def test_step_spans_count_kv_pages_from_the_hosts_ctx_lens(
+        params, ring, chunked):
+    """``kv_live_pages`` is what attention has to read (each running
+    slot's pages up to and with the row written this step),
+    ``kv_table_pages`` what a gather of whole page tables reads: both
+    follow from the arrays the server hands the engine."""
+    ring.clear()
+    eng = make_engine(params, top=16,
+                      prefill_chunk_tokens=PAGE if chunked else 0)
+    handed, step = [], eng.step
+
+    def spy(tokens, page_tables, ctx_lens):
+        handed.append((np.array(page_tables), np.array(ctx_lens)))
+        return step(tokens, page_tables, ctx_lens)
+    eng.step = spy
+    server = DecodeServer(eng)
+    rng = np.random.default_rng(13)
+    try:
+        streams = [server.submit(rng.integers(1, V, n), max_new_tokens=m)
+                   for n, m in ((5, 6), (15, 4), (9, 9))]
+        for st in streams:
+            st.result(timeout=120.0)
+        stats = server.stats()
+    finally:
+        server.close()
+    steps = spans(ring, 'decode.step')
+    assert len(steps) == len(handed) >= 8
+    live = []
+    for e, (pts, ctx) in zip(steps, handed):
+        running = pts[:, 0] != eng.cache.trash
+        assert running.any()
+        live.append(sum(-(-(int(c) + 1) // PAGE) for c in ctx[running]))
+        assert e['args']['kv_live_pages'] == live[-1]
+        assert e['args']['kv_table_pages'] == pts.size \
+            == STREAMS * MAX_SEQ // PAGE
+        assert 0 < live[-1] <= pts.size
+    # the 15-token prompt crosses into its third page while it decodes
+    assert len(set(live)) > 1
+    assert stats['kv_live_pages'] == sum(live)
+    assert stats['kv_table_pages'] == len(steps) * STREAMS * MAX_SEQ // PAGE
+    # the step's compile span names the attention its shapes take: off
+    # the TPU the gathered span, whatever the head size
+    comp = [e['args'] for e in spans(ring, 'decode.compile')]
+    assert [a['attention'] for a in comp if a['program'] == 'step'] \
+        == ['xla_gather']
+    assert not any('attention' in a for a in comp if a['program'] != 'step')
+
+
 def test_opt_engine_reports_no_routing(ring):
     """An engine without a block gains nothing per step or per tick: no
     ``decode.weights`` span, no argument on its ``decode.step`` spans,
     and the server's counters stay zero; only ``decode.compile`` (set-up)
-    carries the new ``argument_bytes``."""
+    carries the new ``argument_bytes`` and, for the step, ``attention``."""
     from paddle_tpu.models import transformer
     scope = fluid.Scope()
     main_p, startup = fluid.Program(), fluid.Program()
@@ -517,3 +566,6 @@ def test_opt_engine_reports_no_routing(ring):
                for e in spans(ring, 'decode.prefill_into'))
     assert (stats['moe_assignments'], stats['moe_max_load'],
             stats['moe_touched_mean']) == (0, 0, 0.0)
+    assert (stats['kv_live_pages'], stats['kv_table_pages']) == (0, 0)
+    assert [e['args']['attention'] for e in spans(ring, 'decode.compile')
+            if e['args']['program'] == 'step'] == ['xla_gather']

@@ -1,0 +1,188 @@
+"""Pallas paged-attention kernel vs ``paged_attention_math``, and the
+op's dispatch between the two.
+
+Runs interpret=True on the CPU backend — same kernel code that compiles
+to Mosaic on TPU (tests/test_tpu_lowering.py compiles it at the decode
+engine's shapes).
+"""
+from types import SimpleNamespace
+
+import numpy as np
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.core.registry import get_op_impl
+from paddle_tpu.ops import pallas as pallas_kernels
+from paddle_tpu.ops.attention import (paged_attention_math,
+                                      paged_attention_path)
+from paddle_tpu.ops.pallas import paged_attention
+from paddle_tpu.ops.pallas.paged_attention import supported
+
+P, MPP, N = 16, 10, 24          # max_seq 160: two blocks of 8 pages
+MAX_SEQ = P * MPP
+CTX_LENS = [1, P - 1, P, P + 1, 77, MAX_SEQ]
+# bf16 pools: q and the probabilities are rounded to bf16 for the MXU
+# (f32 accumulation), the math multiplies in f32: a few bf16 ulps of an
+# output of scale ~0.3.  f32 pools: the order of the sums only.
+TOL = {'bfloat16': 1e-2, 'float32': 2e-5}
+
+
+def make(seed, h, d, dtype, ndim=3, page=P, n=N, s=len(CTX_LENS)):
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(s, h, d), jnp.float32)
+    shape = (n, page, h * d) if ndim == 3 else (n, page, h, d)
+    k = jnp.asarray(rng.randn(*shape), dtype)
+    v = jnp.asarray(rng.randn(*shape), dtype)
+    # a slot's pages distinct and scattered over the pool
+    pt = jnp.asarray(np.stack([rng.permutation(n)[:MPP]
+                               for _ in range(s)]), jnp.int32)
+    return q, k, v, pt
+
+
+def both(q, k, v, pt, ctx, **kw):
+    ctx = jnp.asarray(ctx, jnp.int32)
+    got = paged_attention(q, k, v, pt, ctx, interpret=True, **kw)
+    want = paged_attention_math(q, k, v, pt, ctx, **kw)
+    assert got.shape == want.shape == q.shape and got.dtype == q.dtype
+    return np.asarray(got), np.asarray(want)
+
+
+def close(got, want, dtype):
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=TOL[jnp.dtype(dtype).name])
+
+
+@pytest.mark.parametrize('ndim', [3, 4])
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize('head_dim', [128, 256])
+def test_kernel_matches_math(head_dim, dtype, ndim):
+    q, k, v, pt = make(1, 2, head_dim, dtype, ndim)
+    close(*both(q, k, v, pt, CTX_LENS), dtype)
+
+
+@pytest.mark.parametrize('ctx_len', CTX_LENS)
+def test_every_context_length_alone(ctx_len):
+    # one slot at a time: nothing of a longer neighbour's blocks is left
+    # in the buffers or the running sums
+    q, k, v, pt = make(2, 2, 128, jnp.float32, s=3)
+    close(*both(q, k, v, pt, [ctx_len, 1, ctx_len]), jnp.float32)
+
+
+def test_f32_pages_of_eight_rows_and_a_scale():
+    # a page of one f32 sublane tile, 16 pages a block, three blocks
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(4, 3, 128), jnp.float32)
+    k = jnp.asarray(rng.randn(50, 8, 3 * 128), jnp.float32)
+    v = jnp.asarray(rng.randn(50, 8, 3 * 128), jnp.float32)
+    pt = jnp.asarray(rng.randint(0, 50, (4, 40)), jnp.int32)
+    close(*both(q, k, v, pt, [320, 129, 128, 7], scale=0.05), jnp.float32)
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+def test_repeated_trash_and_out_of_range_page_ids(dtype):
+    q, k, v, pt = make(4, 2, 128, dtype, s=4)
+    pt = np.array(pt)
+    pt[0, :] = 5                          # one page, ten times
+    pt[1, :] = N - 1                      # an idle slot: all trash
+    pt[2, ::2] = [-3, N, N + 40, -1, 10 ** 6]     # clipped as the math
+    pt[3, 3:] = N - 1                     # trash past the live pages
+    got, want = both(q, k, v, jnp.asarray(pt), [MAX_SEQ, 1, 150, 40])
+    close(got, want, dtype)
+    # the idle slot attends over one row: that row of V, head by head
+    np.testing.assert_allclose(
+        got[1].reshape(-1), np.asarray(v[N - 1, 0], np.float32),
+        rtol=0, atol=TOL[jnp.dtype(dtype).name])
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+def test_nothing_past_the_live_pages_is_read_or_multiplied(dtype):
+    """Every page no slot may read, and every row past ``ctx_len`` in a
+    last page, set to NaN: the result is finite and the clean pool's."""
+    q, k, v, _ = make(5, 2, 128, dtype)
+    ctx = np.array(CTX_LENS)
+    pages = -(-ctx // P)
+    # live pages distinct over all slots; a table's other entries point
+    # at pages nobody may read
+    order = np.random.RandomState(5).permutation(N)
+    live, dead = order[:pages.sum()], order[pages.sum():]
+    assert len(dead) >= 2
+    pt = np.resize(dead, (len(ctx), MPP))
+    for s, (lo, n) in enumerate(zip(np.cumsum(pages) - pages, pages)):
+        pt[s, :n] = live[lo:lo + n]
+    pt = jnp.asarray(pt, jnp.int32)
+    clean, _ = both(q, k, v, pt, ctx)
+    kp, vp = (np.array(x.astype(jnp.float32)) for x in (k, v))
+    for pool in (kp, vp):
+        pool[dead] = np.nan
+        for s, (c, n) in enumerate(zip(ctx, pages)):
+            pool[int(pt[s, n - 1]), c - (n - 1) * P:] = np.nan
+    poisoned = paged_attention(
+        q, jnp.asarray(kp, dtype), jnp.asarray(vp, dtype), pt,
+        jnp.asarray(ctx, jnp.int32), interpret=True)
+    assert np.all(np.isfinite(np.asarray(poisoned)))
+    assert np.array_equal(np.asarray(poisoned), clean)
+    # the math masks the same rows after multiplying them: it reads them
+    assert not np.all(np.isfinite(np.asarray(paged_attention_math(
+        q, jnp.asarray(kp, dtype), jnp.asarray(vp, dtype), pt,
+        jnp.asarray(ctx, jnp.int32)))))
+
+
+def test_a_slot_with_no_context_reads_nothing():
+    q, k, v, pt = make(6, 2, 128, jnp.float32, s=2)
+    k = k.at[:].set(jnp.nan)
+    got = paged_attention(q, k, v, pt, jnp.asarray([0, 0], jnp.int32),
+                          interpret=True)
+    assert np.array_equal(np.asarray(got), np.zeros(q.shape, np.float32))
+
+
+# -- the op's dispatch ------------------------------------------------------
+
+def run_op(backend, q, k, v, pt, ctx):
+    return np.asarray(get_op_impl('paged_attention').compute(
+        SimpleNamespace(backend=backend),
+        {'Q': [q], 'KPool': [k], 'VPool': [v], 'PT': [pt],
+         'CtxLen': [jnp.asarray(ctx, jnp.int32)]}, {})['Out'][0])
+
+
+@pytest.mark.parametrize('backend,head_dim,dtype,page', [
+    ('tpu', 64, jnp.bfloat16, 16),      # two heads share a 128-lane vreg
+    ('tpu', 64, jnp.float32, 16),
+    ('cpu', 128, jnp.bfloat16, 16),     # not a TPU
+    ('gpu', 128, jnp.float32, 16),
+    ('tpu', 128, jnp.bfloat16, 8),      # half a bf16 sublane tile a page
+    ('tpu', 256, jnp.float32, 4),
+], ids=lambda x: getattr(x, '__name__', str(x)))
+def test_dispatch_falls_back_to_the_math_bit_for_bit(
+        backend, head_dim, dtype, page):
+    assert paged_attention_path(backend, head_dim, page, dtype) \
+        == 'xla_gather'
+    q, k, v, pt = make(7, 2, head_dim, dtype, page=page, s=3)
+    ctx = [1, 3 * page + 1, MPP * page]
+    want = np.asarray(paged_attention_math(
+        q, k, v, pt, jnp.asarray(ctx, jnp.int32)))
+    assert np.array_equal(run_op(backend, q, k, v, pt, ctx), want)
+
+
+@pytest.mark.parametrize('head_dim,dtype,page', [
+    (128, jnp.bfloat16, 16), (128, jnp.bfloat16, 32), (256, jnp.bfloat16, 16),
+    (128, jnp.float32, 8), (128, jnp.float32, 16), (256, jnp.float32, 16),
+], ids=lambda x: getattr(x, '__name__', str(x)))
+def test_dispatch_takes_the_kernel_on_a_tpu(monkeypatch, head_dim, dtype,
+                                            page):
+    assert supported(head_dim, page, dtype)
+    assert paged_attention_path('tpu', head_dim, page, dtype) \
+        == 'pallas_paged'
+    # the op calls the package's entry point: here, interpreted
+    calls = []
+
+    def kernel(*args, **kw):
+        calls.append(kw)
+        return paged_attention(*args, interpret=True, **kw)
+    monkeypatch.setattr(pallas_kernels, 'paged_attention', kernel)
+    q, k, v, pt = make(8, 2, head_dim, dtype, page=page, s=2)
+    ctx = [page + 1, MPP * page]
+    got = run_op('tpu', q, k, v, pt, ctx)
+    assert calls == [{'scale': None}]
+    close(got, np.asarray(paged_attention_math(
+        q, k, v, pt, jnp.asarray(ctx, jnp.int32))), dtype)
