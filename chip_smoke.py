@@ -429,8 +429,12 @@ def phase_serve(smoke):
     import jax.numpy as jnp
     import paddle_tpu as fluid
     from paddle_tpu.inference.decode import (DecodeEngine, DecodeServer,
-                                             _forward, extract_params)
+                                             extract_params)
     from paddle_tpu.models import transformer
+    # the plain full-context forward lives with the tests
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), 'tests'))
+    from reference_opt import forward as _forward
     c = smoke.cfg['serve']
     L, H, V = c['L'], c['H'], c['V']
     scope = fluid.Scope()

@@ -1,6 +1,7 @@
 from .serving import export_inference, load_exported, InferenceServer
 from .batching import (BatchingInferenceServer, bucket_sizes,
                        export_bucketed)
+from .blocks import OlmoeBlock, OptBlock
 from .decode import (DecodeEngine, DecodeServer, DecodeStream,
                      decode_buckets, extract_params)
 from .fleet import ServingFleet
@@ -10,6 +11,6 @@ from .tenancy import AdmissionError, TenantRegistry, SLO_CLASSES
 __all__ = ['export_inference', 'load_exported', 'InferenceServer',
            'BatchingInferenceServer', 'export_bucketed', 'bucket_sizes',
            'DecodeEngine', 'DecodeServer', 'DecodeStream',
-           'decode_buckets', 'extract_params',
+           'decode_buckets', 'extract_params', 'OptBlock', 'OlmoeBlock',
            'ServingFleet', 'AotCache', 'AdmissionError',
            'TenantRegistry', 'SLO_CLASSES']

@@ -5,12 +5,13 @@ The engine owns the loop over layers, the page pools, where K/V rows
 are written and which attention op reads them (dense for a whole-prompt
 prefill, ``chunked_prefill_attention`` for a chunk, ``paged_attention``
 for a decode step).  A block description supplies the rest, once, as
-pure functions of the weights (a ``{name: array}`` dict the engine
-hands in as an operand of every compiled program, never a constant):
+pure functions of the weights (a ``{name: array}`` dict):
 
 - ``names(n_layers)``      the weights it reads, by their fixed names;
-- ``sizes(params)``        ``d_model`` and ``vocab_size`` from shapes;
-- ``embed(p, tokens, positions)`` -> x [T, D] float32;
+- ``sizes(params)``        ``d_model`` and ``vocab_size`` from shapes,
+  and ``positions`` (a learned position table's rows) where it has one;
+- ``embed(p, tokens, positions)`` -> x [T, D] float32 (the positions
+  it is handed lie inside the engine's ``max_seq``);
 - ``qkv(p, x, i, positions)`` -> q [T, H, Dh], and k, v [T, H * Dh] as
   the cache holds a position (the engine casts them to the pools'
   dtype, writes them, and attends over what it wrote);
@@ -18,19 +19,25 @@ hands in as an operand of every compiled program, never a constant):
   between attention and the next layer; ``counts`` is [n_experts] int32
   (tokens routed to each expert among the rows where ``active``) or
   None for a block without experts;
-- ``head(p, x)`` -> logits [T, V] float32.
+- ``head(p, x)`` -> logits [T, V] float32;
+- ``constant_weights``     how the weights enter the engine's ``step``
+  and ``chunk`` programs: False, as an operand (no program holds a
+  copy); True, bound as constants of each executable (XLA folds them:
+  float32 weights whose matmuls run as one bf16 pass are held and read
+  as bf16, at the price of a copy a program and a compile no cache can
+  keep).  ``prefill`` takes them as an operand under either.
 
 ``positions`` are absolute token positions [T]; a block with a learned
-position table would index it in ``embed``, a rotary block turns q and k
-in ``qkv`` — the engine's ``max_seq`` is then a setting, not a table's
-row count.
+position table indexes it in ``embed`` and the engine's ``max_seq``
+defaults to its rows, a rotary block turns q and k in ``qkv`` and the
+engine's ``max_seq`` is a setting.
 """
 import jax.numpy as jnp
 
 from ..ops.moe import (moe_counts, moe_experts, moe_route, rms_norm_math,
                        rotary_math)
 
-__all__ = ['OlmoeBlock']
+__all__ = ['OptBlock', 'OlmoeBlock']
 
 
 def _mm(x, w):
@@ -38,6 +45,62 @@ def _mm(x, w):
     weight's dtype, the accumulator is float32."""
     return jnp.dot(x.astype(w.dtype), w,
                    preferred_element_type=jnp.float32)
+
+
+class OptBlock(object):
+    """The OPT layer (models/transformer.py builds the same block as a
+    ``Program``; chipbench/reference/opt.py is its plain reference):
+    learned positions, pre-LayerNorm, one fused q/k/v projection, full
+    multi-head attention, a ReLU FFN, biases everywhere, a final
+    LayerNorm and an untied head with a bias."""
+
+    # ROADMAP D2: as operands the chip's step reads float32 weights
+    # (about +1.7 ms of 61), and setup_s loses the ~100 s compile
+    constant_weights = True
+
+    def __init__(self, n_heads, eps=1e-5):
+        self.n_heads = int(n_heads)
+        self.eps = float(eps)
+
+    @staticmethod
+    def names(n_layers):
+        from ..models.transformer import param_names
+        return param_names(n_layers)
+
+    def sizes(self, params):
+        v, d = params['tr_embed'].shape
+        return {'d_model': int(d), 'vocab_size': int(v),
+                'positions': int(params['tr_pos'].shape[0])}
+
+    def norm(self, x, w, b):
+        xf = x.astype(jnp.float32)
+        mean = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.var(xf, axis=-1, keepdims=True)
+        return (xf - mean) / jnp.sqrt(var + self.eps) * w + b
+
+    def embed(self, p, tokens, positions):
+        return p['tr_embed'][tokens] + p['tr_pos'][positions]
+
+    def qkv(self, p, x, i, positions):
+        n = 'tr_l%d_' % i
+        h = self.norm(x, p[n + 'ln_attn_w'], p[n + 'ln_attn_b'])
+        q, k, v = jnp.split(h @ p[n + 'qkv_w'] + p[n + 'qkv_b'], 3,
+                            axis=-1)
+        return q.reshape(x.shape[0], self.n_heads, -1), k, v
+
+    def activation(self, h):
+        return jnp.maximum(h, 0.0)
+
+    def after_attention(self, p, x, ctx, i, active):
+        n = 'tr_l%d_' % i
+        x = x + ctx.reshape(x.shape) @ p[n + 'proj_w'] + p[n + 'proj_b']
+        h = self.norm(x, p[n + 'ln_ffn_w'], p[n + 'ln_ffn_b'])
+        h = self.activation(h @ p[n + 'ffn_up_w'] + p[n + 'ffn_up_b'])
+        return x + h @ p[n + 'ffn_down_w'] + p[n + 'ffn_down_b'], None
+
+    def head(self, p, x):
+        x = self.norm(x, p['tr_ln_f_w'], p['tr_ln_f_b'])
+        return x @ p['tr_head_w'] + p['tr_head_b']
 
 
 class OlmoeBlock(object):
@@ -50,6 +113,8 @@ class OlmoeBlock(object):
     (``renormalize`` False, the published ``norm_topk_prob``), every
     token reaching every one of its experts.  Keys are cached AFTER
     QK-norm and rotation, values as they are."""
+
+    constant_weights = False
 
     def __init__(self, n_heads, top_k=8, eps=1e-5, theta=10000.0,
                  renormalize=False):

@@ -32,11 +32,18 @@ inference-throughput fix for decoder-only LMs, TPU-native:
   ``static_batching=True`` on the server reproduces the barriered
   baseline for the A/B the decode bench reports.
 
+There is ONE definition of a decoder's serving path: which decoder is
+served is a block description (inference/blocks.py: ``OptBlock``, the
+default, and ``OlmoeBlock``) that supplies the layer's equations, and
+the engine's ``prefill``, ``chunk`` and ``step`` are one loop over
+layers around them.  No code here names a model or a parameter.
+
 Everything device-facing is AOT-compiled at ``warmup()`` via
 ``jit(...).lower(...).compile()`` — the serving loop only ever calls
 precompiled executables, and ``stats()['compiles_after_warmup']``
 counts any miss instead of hiding a multi-second stall.
 """
+import functools
 import itertools
 import threading
 import time
@@ -52,6 +59,7 @@ from ..analysis import lockdebug as _lkd
 from ..compile_cache import enable_compile_cache
 from ..core.registry import get_op_impl
 from ..transpiler.memory_model import page_pool_bytes
+from .blocks import OptBlock
 
 __all__ = ['DecodeEngine', 'DecodeServer', 'DecodeStream',
            'extract_params', 'decode_buckets', 'PrefixCache',
@@ -70,14 +78,10 @@ class PromptTooLongError(ValueError):
 def extract_params(scope, n_layers, block=None):
     """Pull a model's fixed-name parameters out of a scope as a plain
     {name: jax.Array} dict — the engine's weights.  The manifest is the
-    block description's ``names`` (inference/blocks.py), or the
-    transformer's ``tr_*`` (models/transformer.py param_names) when no
-    block is given."""
-    if block is None:
-        from ..models.transformer import param_names as names
-    else:
-        names = block.names
-    return {n: jnp.asarray(scope.get(n)) for n in names(n_layers)}
+    block description's ``names`` (inference/blocks.py; ``OptBlock``'s
+    when none is given)."""
+    names = (block or OptBlock).names(n_layers)
+    return {n: jnp.asarray(scope.get(n)) for n in names}
 
 
 def decode_buckets(page_size, top):
@@ -93,45 +97,6 @@ def decode_buckets(page_size, top):
     while sizes[-1] < top:
         sizes.append(min(sizes[-1] * 2, top))
     return sizes
-
-
-def _ln(x, w, b, eps=1e-5):
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    return (xf - mean) / jnp.sqrt(var + eps) * w + b
-
-
-def _forward(params, tokens, n_layers, n_heads):
-    """Full-context forward over [B, T] int32 tokens: the prefill path
-    and the parity reference (same ops/attention.py dense math the
-    program's flash_attention op runs off-TPU).  Returns
-    (logits [B, T, V], k_all [L, B, T, H, Dh], v_all)."""
-    from ..ops.attention import _dense_attention
-    b, t = tokens.shape
-    x = params['tr_embed'][tokens] + params['tr_pos'][:t][None]
-    d = x.shape[-1]
-    dh = d // n_heads
-    ks, vs = [], []
-    for i in range(n_layers):
-        p = 'tr_l%d_' % i
-        h = _ln(x, params[p + 'ln_attn_w'], params[p + 'ln_attn_b'])
-        qkv = h @ params[p + 'qkv_w'] + params[p + 'qkv_b']
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, t, n_heads, dh)
-        k = k.reshape(b, t, n_heads, dh)
-        v = v.reshape(b, t, n_heads, dh)
-        ks.append(k)
-        vs.append(v)
-        ctx = _dense_attention(q, k, v, True, None).reshape(b, t, d)
-        x = x + ctx @ params[p + 'proj_w'] + params[p + 'proj_b']
-        h = _ln(x, params[p + 'ln_ffn_w'], params[p + 'ln_ffn_b'])
-        h = jnp.maximum(h @ params[p + 'ffn_up_w']
-                        + params[p + 'ffn_up_b'], 0.0)
-        x = x + h @ params[p + 'ffn_down_w'] + params[p + 'ffn_down_b']
-    x = _ln(x, params['tr_ln_f_w'], params['tr_ln_f_b'])
-    logits = x @ params['tr_head_w'] + params['tr_head_b']
-    return logits, jnp.stack(ks), jnp.stack(vs)
 
 
 class PagedKVCache(object):
@@ -321,14 +286,14 @@ class DecodeEngine(object):
     the program gathers.
 
     Which decoder is served is the ``block`` handed in (a description
-    from inference/blocks.py).  Its prefill, chunk and step are ONE loop
-    over layers (``_layers``) that differs only in where K/V rows are
-    written and which attention op reads them, and the weights are an
-    OPERAND of each of its programs (``argument_bytes`` of the
-    ``decode.compile`` span holds them; no program bakes a copy).
-    Without a block the engine serves the OPT layer of
-    models/transformer.py from its three hand-written closures, with the
-    weights closed over, as before (ROADMAP D1/D2 fold them in).
+    from inference/blocks.py; ``OptBlock``, the layer of
+    models/transformer.py, when none is).  Prefill, chunk and step are
+    ONE loop over layers (``_layers``) that differs only in where K/V
+    rows are written and which attention op reads them.  Every program
+    takes the weights as its first parameter; whether ``step`` and
+    ``chunk`` read them as that operand or as constants of the
+    executable is the block's to say (``_bound``), and the
+    ``argument_bytes`` of the ``decode.compile`` span shows which.
 
     Not thread-safe by design: exactly one caller (the DecodeServer
     worker) drives it, and the page pools move through donated
@@ -341,37 +306,27 @@ class DecodeEngine(object):
                  dtype=jnp.float32, max_seq=None, block=None):
         from ..flags import FLAGS
         enable_compile_cache()
-        self.block = block
-        if block is None:
-            self.params = {n: jnp.asarray(v) for n, v in params.items()}
-        else:
-            self.params = self._place(params)
         self.n_layers = int(n_layers)
         self.n_heads = int(n_heads)
-        if block is None:
-            self.d_model = int(self.params['tr_embed'].shape[1])
-            self.vocab_size = int(self.params['tr_embed'].shape[0])
-            table = int(self.params['tr_pos'].shape[0])
-            self.max_seq = int(max_seq or table)
-            if self.max_seq > table:
-                raise ValueError("max_seq %d exceeds the position "
-                                 "table's %d rows" % (self.max_seq, table))
-        else:
-            if max_seq is None:
-                raise ValueError("a block without a position table "
-                                 "needs max_seq")
-            if block.n_heads != self.n_heads:
-                raise ValueError("block has %d heads, engine %d"
-                                 % (block.n_heads, self.n_heads))
-            sizes = block.sizes(self.params)
-            self.d_model = sizes['d_model']
-            self.vocab_size = sizes['vocab_size']
-            self.max_seq = int(max_seq)
+        self.block = block = block or OptBlock(self.n_heads)
+        if block.n_heads != self.n_heads:
+            raise ValueError("block has %d heads, engine %d"
+                             % (block.n_heads, self.n_heads))
+        self.params = self._place(params)
+        sizes = block.sizes(self.params)
+        self.d_model = sizes['d_model']
+        self.vocab_size = sizes['vocab_size']
+        table = sizes.get('positions')
+        if not (max_seq or table):
+            raise ValueError("a block without a position table "
+                             "needs max_seq")
+        self.max_seq = int(max_seq or table)
+        if table and self.max_seq > table:
+            raise ValueError("max_seq %d exceeds the position "
+                             "table's %d rows" % (self.max_seq, table))
         self.head_dim = self.d_model // self.n_heads
-        # the weights as the leading operand of a block's programs
-        self._weights = () if block is None else (self.params,)
         # routed-expert totals over the engine's life (``_routing``);
-        # all zero for a block without experts, and without a block
+        # all zero for a block without experts
         self.routing = {'assignments': 0, 'max_load': 0, 'touched': 0.0,
                         'steps': 0}
         # KV pages over the engine's decode steps (``_kv_pages``): the
@@ -418,7 +373,7 @@ class DecodeEngine(object):
             if self.prefix_enabled else None
         self.compiles_total = 0
         self._compiles_at_warmup = None
-        self._prefill = {}   # bucket -> compiled (params, tokens)
+        self._prefill = {}   # bucket -> compiled (params, tokens, last)
         self._pack = {}      # bucket -> compiled (k, v, pools, pages)
         self._chunk = {}     # bucket -> compiled chunked-prefill fn
         self._step = None
@@ -442,6 +397,23 @@ class DecodeEngine(object):
         self.compiles_total += 1
         return compiled
 
+    def _bound(self, fn):
+        """``fn(params, ...)`` as ``step`` and ``chunk`` are compiled:
+        how the weights enter them is the block's to say
+        (``constant_weights``), and this is where it is read.  As
+        constants, the traced program reads the engine's own weights
+        and leaves its first parameter unused, so jit drops that from
+        the executable: callers hand every program the weights all the
+        same, and only ``argument_bytes`` tells."""
+        if not self.block.constant_weights:
+            return fn
+        params = self.params
+
+        @functools.wraps(fn)    # the executable is still jit_<name>
+        def bound(_, *args):
+            return fn(params, *args)
+        return bound
+
     def _layers(self, params, x, positions, active, attend):
         """A block's layers over x [T, D]: ``attend(i, q, k, v)`` is
         where prefill, chunk and step differ (it writes layer i's K/V
@@ -459,9 +431,8 @@ class DecodeEngine(object):
 
     @staticmethod
     def _place(params):
-        """A block's weights onto the device, once, under a set-up span
-        that waits for them (they are operands: nothing else holds a
-        copy)."""
+        """The weights onto the device, once, under a set-up span that
+        waits for them."""
         placed = {}
         with _obs.span('decode.weights', args=placed):
             out = jax.block_until_ready(
@@ -503,7 +474,7 @@ class DecodeEngine(object):
         self.kv_pages['live'] += live
         self.kv_pages['table'] += pts.size
 
-    # -- a block's three programs: one loop, three ways to attend -------
+    # -- the three programs: one loop, three ways to attend -------------
 
     @staticmethod
     def _write_then(k_pool, v_pool, page_idx, offset, read):
@@ -516,11 +487,16 @@ class DecodeEngine(object):
             return read(i, q)
         return attend
 
-    def _block_prefill(self, bucket):
+    def _prefill_fn(self, bucket):
         from ..ops.attention import _dense_attention
         blk, H, Dh = self.block, self.n_heads, self.head_dim
 
         def prefill(params, tokens, last):
+            # ``last`` (the prompt's final position) is a traced
+            # operand, NOT python int: slicing the returned logits on
+            # the host would dispatch an op-by-op gather whose hidden
+            # per-shape compile (~25-40ms) lands on the first stream
+            # of every bucket — invisible to compiles_total
             pos = jnp.arange(bucket)
             ks, vs = [], []
 
@@ -539,14 +515,16 @@ class DecodeEngine(object):
                     jnp.stack(vs)) + extra
         return prefill
 
-    def _block_chunk(self, bucket):
+    def _chunk_fn(self, bucket):
         blk, P, mpp = self.block, self.page_size, self.pages_per_stream
         trash = self.cache.trash
         chunk_att = get_op_impl('chunked_prefill_attention').compute
 
         def chunk(params, k_pool, v_pool, tokens, pt, pos0, n_valid):
-            # padded rows (i >= n_valid) write to the trash page, are
-            # not counted, and their outputs never leave the executable
+            # pos0 and n_valid are traced (host slicing would hide
+            # per-shape gather compiles, the prefill lesson); padded
+            # rows (i >= n_valid) write to the trash page, are not
+            # counted, and their outputs never leave the executable
             pos = pos0 + jnp.arange(bucket)
             valid = jnp.arange(bucket) < n_valid
             page_idx = pt[jnp.clip(pos // P, 0, mpp - 1)]
@@ -560,19 +538,25 @@ class DecodeEngine(object):
                                         'PT': [pt], 'Pos0': [pos0]},
                                  {})['Out'][0]
 
+            # a last chunk's padded rows point past the prompt: ``embed``
+            # (which may index a position table) gets them inside max_seq
             x, extra = self._layers(
-                params, blk.embed(params, tokens, pos), pos, valid,
+                params, blk.embed(params, tokens,
+                                  jnp.clip(pos, 0, self.max_seq - 1)),
+                pos, valid,
                 self._write_then(k_pool, v_pool, page_idx, offset, read))
             x_last = x[jnp.clip(n_valid - 1, 0, bucket - 1)]
             return (k_pool, v_pool,
                     blk.head(params, x_last[None])[0]) + extra
-        return chunk
+        return self._bound(chunk)
 
-    def _block_step(self):
+    def _step_fn(self):
         blk, P, trash = self.block, self.page_size, self.cache.trash
         paged = get_op_impl('paged_attention').compute
 
         def step(params, k_pool, v_pool, tokens, pt, ctx_len):
+            # ctx_len counts CACHED positions per slot; the incoming
+            # token sits at position ctx_len and is cached this step
             pos = jnp.clip(ctx_len, 0, self.max_seq - 1)
             page_idx = jnp.take_along_axis(
                 pt, (pos // P)[:, None], axis=1)[:, 0]
@@ -593,7 +577,7 @@ class DecodeEngine(object):
             logits = blk.head(params, x)
             return (k_pool, v_pool, logits,
                     jnp.argmax(logits, axis=-1)) + extra
-        return step
+        return self._bound(step)
 
     def _ensure_prefill(self, bucket):
         if bucket in self._prefill:
@@ -601,18 +585,6 @@ class DecodeEngine(object):
         L, H, Dh, P = (self.n_layers, self.n_heads, self.head_dim,
                        self.page_size)
         n_pages = bucket // P
-
-        def prefill(params, tokens, last):
-            # ``last`` (the prompt's final position) is a traced
-            # operand, NOT python int: slicing the returned logits on
-            # the host would dispatch an op-by-op gather whose hidden
-            # per-shape compile (~25-40ms) lands on the first stream
-            # of every bucket — invisible to compiles_total
-            logits, k, v = _forward(params, tokens[None], L, H)
-            return logits[0, last], k[:, 0], v[:, 0]
-
-        if self.block is not None:
-            prefill = self._block_prefill(bucket)
 
         def pack(k_pool, v_pool, k, v, pages):
             # scatter the prefill K/V into the claimed pages: [L, T, H,
@@ -628,7 +600,8 @@ class DecodeEngine(object):
 
         toks = jnp.zeros((bucket,), jnp.int32)
         self._prefill[bucket] = self._compile(
-            prefill, self.params, toks, jnp.int32(0), bucket=bucket)
+            self._prefill_fn(bucket), self.params, toks, jnp.int32(0),
+            bucket=bucket)
         kv = jnp.zeros((L, bucket, H, Dh), self.cache.dtype)
         pages = jnp.zeros((n_pages,), jnp.int32)
         self._pack[bucket] = self._compile(
@@ -645,126 +618,28 @@ class DecodeEngine(object):
         intermediate chunks pay one [D]x[D,V] row, not a [C,V] head."""
         if bucket in self._chunk:
             return
-        L, H, Dh, D = (self.n_layers, self.n_heads, self.head_dim,
-                       self.d_model)
-        P, mpp = self.page_size, self.pages_per_stream
-        params = self.params
-        trash = self.cache.trash
-        chunk_att = get_op_impl('chunked_prefill_attention').compute
-
-        def chunk(k_pool, v_pool, tokens, pt, pos0, n_valid):
-            # pos0 and n_valid are traced (host slicing would hide
-            # per-shape gather compiles, the _ensure_prefill lesson);
-            # padded rows (i >= n_valid) write to the trash page and
-            # their outputs never leave the executable
-            pos = pos0 + jnp.arange(bucket)
-            valid = jnp.arange(bucket) < n_valid
-            posc = jnp.clip(pos, 0, self.max_seq - 1)
-            x = params['tr_embed'][tokens] + params['tr_pos'][posc]
-            page_idx = pt[jnp.clip(pos // P, 0, mpp - 1)]
-            page_idx = jnp.where(valid, page_idx, trash)
-            offset = pos % P
-            k_pool, v_pool = list(k_pool), list(v_pool)
-            for i in range(L):
-                p = 'tr_l%d_' % i
-                h = _ln(x, params[p + 'ln_attn_w'],
-                        params[p + 'ln_attn_b'])
-                qkv = h @ params[p + 'qkv_w'] + params[p + 'qkv_b']
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                q = q.reshape(bucket, H, Dh)
-                k_pool[i] = k_pool[i].at[page_idx, offset].set(
-                    k.astype(k_pool[i].dtype))
-                v_pool[i] = v_pool[i].at[page_idx, offset].set(
-                    v.astype(v_pool[i].dtype))
-                ctx = chunk_att(None, {'Q': [q],
-                                       'KPool': [k_pool[i]],
-                                       'VPool': [v_pool[i]],
-                                       'PT': [pt], 'Pos0': [pos0]},
-                                {})['Out'][0]
-                x = x + ctx.reshape(bucket, D) @ params[p + 'proj_w'] \
-                    + params[p + 'proj_b']
-                h = _ln(x, params[p + 'ln_ffn_w'],
-                        params[p + 'ln_ffn_b'])
-                h = jnp.maximum(h @ params[p + 'ffn_up_w']
-                                + params[p + 'ffn_up_b'], 0.0)
-                x = x + h @ params[p + 'ffn_down_w'] \
-                    + params[p + 'ffn_down_b']
-            x = _ln(x, params['tr_ln_f_w'], params['tr_ln_f_b'])
-            x_last = x[jnp.clip(n_valid - 1, 0, bucket - 1)]
-            logits = x_last @ params['tr_head_w'] + params['tr_head_b']
-            return k_pool, v_pool, logits
-
-        if self.block is not None:
-            chunk = self._block_chunk(bucket)
-        n_w = len(self._weights)
         self._chunk[bucket] = self._compile(
-            chunk, *self._weights, self.cache.k, self.cache.v,
-            jnp.zeros((bucket,), jnp.int32),
-            jnp.full((mpp,), trash, jnp.int32),
-            jnp.int32(0), jnp.int32(1), donate=(n_w, n_w + 1),
-            bucket=bucket)
+            self._chunk_fn(bucket), self.params, self.cache.k,
+            self.cache.v, jnp.zeros((bucket,), jnp.int32),
+            jnp.full((self.pages_per_stream,), self.cache.trash,
+                     jnp.int32),
+            jnp.int32(0), jnp.int32(1), donate=(1, 2), bucket=bucket)
 
     def _ensure_step(self):
         if self._step is not None:
             return
         from ..ops.attention import paged_attention_path
-        L, H, Dh, D = (self.n_layers, self.n_heads, self.head_dim,
-                       self.d_model)
-        P, S = self.page_size, self.max_streams
-        mpp = self.pages_per_stream
-        params = self.params
-        paged = get_op_impl('paged_attention').compute
-
-        def step(k_pool, v_pool, tokens, pt, ctx_len):
-            # ctx_len counts CACHED positions per slot; the incoming
-            # token sits at position ctx_len and is cached this step.
-            pos = jnp.clip(ctx_len, 0, self.max_seq - 1)
-            x = params['tr_embed'][tokens] + params['tr_pos'][pos]
-            page_idx = jnp.take_along_axis(
-                pt, (pos // P)[:, None], axis=1)[:, 0]
-            offset = pos % P
-            k_pool, v_pool = list(k_pool), list(v_pool)
-            for i in range(L):
-                p = 'tr_l%d_' % i
-                h = _ln(x, params[p + 'ln_attn_w'],
-                        params[p + 'ln_attn_b'])
-                qkv = h @ params[p + 'qkv_w'] + params[p + 'qkv_b']
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-                q = q.reshape(S, H, Dh)
-                # the slot's new row lands at (page, offset) of layer
-                # i's own buffer, which attention then gathers from
-                k_pool[i] = k_pool[i].at[page_idx, offset].set(
-                    k.astype(k_pool[i].dtype))
-                v_pool[i] = v_pool[i].at[page_idx, offset].set(
-                    v.astype(v_pool[i].dtype))
-                ctx = paged(None, {'Q': [q], 'KPool': [k_pool[i]],
-                                   'VPool': [v_pool[i]], 'PT': [pt],
-                                   'CtxLen': [pos + 1]},
-                            {})['Out'][0]
-                x = x + ctx.reshape(S, D) @ params[p + 'proj_w'] \
-                    + params[p + 'proj_b']
-                h = _ln(x, params[p + 'ln_ffn_w'],
-                        params[p + 'ln_ffn_b'])
-                h = jnp.maximum(h @ params[p + 'ffn_up_w']
-                                + params[p + 'ffn_up_b'], 0.0)
-                x = x + h @ params[p + 'ffn_down_w'] \
-                    + params[p + 'ffn_down_b']
-            x = _ln(x, params['tr_ln_f_w'], params['tr_ln_f_b'])
-            logits = x @ params['tr_head_w'] + params['tr_head_b']
-            return k_pool, v_pool, logits, jnp.argmax(logits, axis=-1)
-
-        if self.block is not None:
-            step = self._block_step()
-        n_w = len(self._weights)
+        S, mpp = self.max_streams, self.pages_per_stream
         self._step = self._compile(
-            step, *self._weights, self.cache.k, self.cache.v,
+            self._step_fn(), self.params, self.cache.k, self.cache.v,
             jnp.zeros((S,), jnp.int32),
             jnp.full((S, mpp), self.cache.trash, jnp.int32),
-            jnp.zeros((S,), jnp.int32), donate=(n_w, n_w + 1),
+            jnp.zeros((S,), jnp.int32), donate=(1, 2),
             # what the op's dispatch takes for these shapes (the step
             # calls it with no context: the default backend)
             attention=paged_attention_path(
-                jax.default_backend(), Dh, P, self.cache.dtype))
+                jax.default_backend(), self.head_dim, self.page_size,
+                self.cache.dtype))
 
     def warmup(self):
         """AOT-compile every prefill bucket, its pack, and the decode
@@ -789,7 +664,7 @@ class DecodeEngine(object):
             mpp = self.pages_per_stream
             for b in self.chunk_buckets:
                 self.cache.k, self.cache.v, logits = self._chunk[b](
-                    *self._weights, self.cache.k, self.cache.v,
+                    self.params, self.cache.k, self.cache.v,
                     jnp.zeros((b,), jnp.int32),
                     jnp.full((mpp,), trash, jnp.int32),
                     jnp.int32(0), jnp.int32(b))[:3]
@@ -809,7 +684,7 @@ class DecodeEngine(object):
                 jax.block_until_ready(logits)
         S, mpp = self.max_streams, self.pages_per_stream
         self.cache.k, self.cache.v, logits = self._step(
-            *self._weights, self.cache.k, self.cache.v,
+            self.params, self.cache.k, self.cache.v,
             jnp.zeros((S,), jnp.int32),
             jnp.full((S, mpp), trash, jnp.int32),
             jnp.zeros((S,), jnp.int32))[:3]
@@ -896,7 +771,7 @@ class DecodeEngine(object):
             pt[:n] = pages[:n]
             self.cache.k, self.cache.v, logits, *extra = \
                 self._chunk[bucket](
-                    *self._weights, self.cache.k, self.cache.v,
+                    self.params, self.cache.k, self.cache.v,
                     jnp.asarray(toks), jnp.asarray(pt), jnp.int32(pos0),
                     jnp.int32(c))
             if extra:
@@ -912,19 +787,17 @@ class DecodeEngine(object):
         # the two halves of the host's part: everything up to the call
         # into the executable returning, then the wait for the device
         # and the copy back of tokens and [S, V] logits
-        # a block's routing counts and KV pages
-        args = {} if self._weights else None
+        args = {}   # the step's KV pages and routing counts
         with _obs.span('decode.step', args=args):
             with _obs.span('decode.step.dispatch'):
                 self.cache.k, self.cache.v, logits, nxt, *extra = \
                     self._step(
-                        *self._weights, self.cache.k, self.cache.v,
+                        self.params, self.cache.k, self.cache.v,
                         jnp.asarray(tokens, dtype=jnp.int32),
                         jnp.asarray(page_tables, dtype=jnp.int32),
                         jnp.asarray(ctx_lens, dtype=jnp.int32))
             with _obs.span('decode.step.fetch'):
-                if args is not None:
-                    self._kv_pages(page_tables, ctx_lens, args)
+                self._kv_pages(page_tables, ctx_lens, args)
                 if extra:
                     self._routing(extra[0], args, step=True)
                 return np.asarray(nxt), np.asarray(logits)
@@ -1182,8 +1055,8 @@ class DecodeServer(object):
                 'moe_max_load': self.engine.routing['max_load'],
                 'moe_touched_mean': self.engine.routing['touched']
                 / max(self.engine.routing['steps'], 1),
-                # KV pages over a block engine's decode steps: live
-                # ones of the running slots, page-table entries
+                # KV pages over the engine's decode steps: live ones
+                # of the running slots, page-table entries
                 'kv_live_pages': self.engine.kv_pages['live'],
                 'kv_table_pages': self.engine.kv_pages['table'],
             }

@@ -21,8 +21,10 @@ import paddle_tpu as fluid
 from paddle_tpu.core import registry
 from paddle_tpu.inference.decode import (DecodeEngine, DecodeServer,
                                          PagedKVCache, decode_buckets,
-                                         extract_params, _forward)
+                                         extract_params)
 from paddle_tpu.models import transformer
+
+from reference_opt import forward as _forward
 
 L, D, H, V, T = 2, 32, 4, 64, 64
 PAGE, STREAMS, PREFILL_TOP = 8, 4, 32
@@ -82,8 +84,13 @@ def test_warmup_compiles_all_buckets_once(engine):
 
 def test_prefill_parity_bucket_exact(params, engine):
     """A prompt that exactly fills its bucket takes the padding-free
-    path: the compiled prefill and a jit of the reference forward are
-    the same trace, so the logits agree bitwise."""
+    path.  The compiled prefill and a jit of the reference forward
+    (tests/reference_opt.py) are two traces of the same equations: the
+    engine's goes through the block description and computes the head
+    for the last row only, the reference's computes [T, V] logits and
+    takes the row, so XLA may associate the sums differently.  They
+    agree to ULP_BAR; bitwise equality is asserted only between two
+    of the engine's own paths."""
     rng = np.random.default_rng(3)
     prompt = rng.integers(0, V, size=16).tolist()   # == bucket 16
     pages = engine.cache.alloc(-(-len(prompt) // PAGE))
@@ -92,8 +99,8 @@ def test_prefill_parity_bucket_exact(params, engine):
         ref_fn = jax.jit(lambda p, t: _forward(p, t, L, H)[0])
         ref = np.asarray(ref_fn(params,
                                 jnp.asarray([prompt], jnp.int32)))[0, -1]
-        assert np.array_equal(got, ref), \
-            "bucket-exact prefill is not bitwise vs jitted recompute"
+        assert np.max(np.abs(got - ref)) <= ULP_BAR, \
+            "bucket-exact prefill is outside ULP_BAR of the reference"
     finally:
         engine.cache.free(pages)
     assert engine.compiles_after_warmup == 0

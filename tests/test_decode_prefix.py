@@ -24,8 +24,10 @@ import paddle_tpu as fluid
 from paddle_tpu.inference.decode import (DecodeEngine, DecodeServer,
                                          PrefixCache,
                                          PromptTooLongError,
-                                         extract_params, _forward)
+                                         extract_params)
 from paddle_tpu.models import transformer
+
+from reference_opt import forward as _forward
 
 L, D, H, V, T = 2, 32, 4, 64, 64
 PAGE, STREAMS, PREFILL_TOP = 8, 4, 32

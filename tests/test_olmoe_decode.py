@@ -12,6 +12,12 @@ the bar is 2e-5, as loose as OPT's 5e-7 measured / 1e-4 asserted allows.
 This is where the mathematics is proven; the chip comparisons (B), (C)
 carry the looser bars bf16 needs (chipbench/reference/olmoe.py,
 chipbench/tests/test_olmoe_chip.py).
+
+The engine serves every decoder through a block description, so what
+holds of one description and not of the other is tested here side by
+side: ``OptBlock`` (the engine's default) against tests/reference_opt.py
+in the wrong-block cases, in how the weights enter each program, and in
+what its spans carry.
 """
 import re
 
@@ -22,14 +28,15 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
-from paddle_tpu.inference.blocks import OlmoeBlock
+from paddle_tpu.inference.blocks import OlmoeBlock, OptBlock
 from paddle_tpu.inference.decode import (DecodeEngine, DecodeServer,
                                          extract_params)
-from paddle_tpu.models import olmoe
+from paddle_tpu.models import olmoe, transformer
 from paddle_tpu.observability import timeline
 from paddle_tpu.ops.moe import rms_norm_math
 
 import reference_olmoe as ref
+import reference_opt
 
 TOL_A = 2e-5
 V, L, D, H, E, F = 211, 2, 64, 4, 16, 32
@@ -57,6 +64,26 @@ def make_params(seed=0, dtype=jnp.float32):
     return p
 
 
+def make_opt_params(seed=0):
+    """Seeded OPT weights at the same toy widths (a position table of
+    MAX_SEQ rows, FFN of 4 D): matrices at std 0.12, biases at 0.1 and
+    LayerNorm weights around 1, so that a dropped bias or a norm in the
+    wrong place shows (the startup program's biases are zero)."""
+    rng, p = np.random.default_rng(seed), {}
+    shapes = {'tr_embed': (V, D), 'tr_pos': (MAX_SEQ, D),
+              'tr_head_w': (D, V), 'tr_head_b': (V,), 'qkv_w': (D, 3 * D),
+              'qkv_b': (3 * D,), 'proj_w': (D, D), 'ffn_up_w': (D, 4 * D),
+              'ffn_up_b': (4 * D,), 'ffn_down_w': (4 * D, D)}
+    for n in transformer.param_names(L):
+        shape = shapes.get(n) or shapes.get(n.split('_', 2)[2], (D,))
+        if '_ln_' in n and n.endswith('_w'):
+            w = 1 + 0.1 * rng.normal(size=shape)
+        else:
+            w = rng.normal(size=shape) * (0.12 if len(shape) == 2 else 0.1)
+        p[n] = jnp.asarray(w, jnp.float32)
+    return p
+
+
 def make_engine(p, block=None, top=32, dtype=jnp.float32, **kw):
     kw.setdefault('prefix_cache', False)
     kw.setdefault('prefill_chunk_tokens', 0)
@@ -69,6 +96,11 @@ def make_engine(p, block=None, top=32, dtype=jnp.float32, **kw):
 def ref_logits(p, seq, top_k=8):
     return np.asarray(ref.logits(p, jnp.asarray(seq, jnp.int32), L, H,
                                  top_k=top_k))
+
+
+def ref_opt_logits(p, seq):
+    return np.asarray(reference_opt.forward(
+        p, jnp.asarray([seq], jnp.int32), L, H)[0])[0]
 
 
 def rel(got, want):
@@ -295,6 +327,41 @@ class _NoRotation(OlmoeBlock):
         return u.astype(jnp.float32)
 
 
+class _NoPositionRow(OptBlock):
+    def embed(self, p, tokens, positions):
+        return p['tr_embed'][tokens]
+
+
+class _PostLN(OptBlock):
+    """Each LayerNorm after its residual add in place of before its
+    branch."""
+
+    def qkv(self, p, x, i, positions):
+        n = 'tr_l%d_' % i
+        q, k, v = jnp.split(x @ p[n + 'qkv_w'] + p[n + 'qkv_b'], 3, axis=-1)
+        return q.reshape(x.shape[0], self.n_heads, -1), k, v
+
+    def after_attention(self, p, x, ctx, i, active):
+        n = 'tr_l%d_' % i
+        x = self.norm(
+            x + ctx.reshape(x.shape) @ p[n + 'proj_w'] + p[n + 'proj_b'],
+            p[n + 'ln_attn_w'], p[n + 'ln_attn_b'])
+        h = self.activation(x @ p[n + 'ffn_up_w'] + p[n + 'ffn_up_b'])
+        return self.norm(
+            x + h @ p[n + 'ffn_down_w'] + p[n + 'ffn_down_b'],
+            p[n + 'ln_ffn_w'], p[n + 'ln_ffn_b']), None
+
+
+class _NoHeadBias(OptBlock):
+    def head(self, p, x):
+        return self.norm(x, p['tr_ln_f_w'], p['tr_ln_f_b']) @ p['tr_head_w']
+
+
+class _Gelu(OptBlock):
+    def activation(self, h):
+        return jax.nn.gelu(h)
+
+
 VARIANTS = {
     'seven_experts': lambda: OlmoeBlock(H, top_k=7),
     'renormalised': lambda: OlmoeBlock(H, renormalize=True),
@@ -302,12 +369,32 @@ VARIANTS = {
     'qk_norm_per_head': lambda: _PerHeadQKNorm(H),
     'no_rotation': lambda: _NoRotation(H),
 }
+OPT_VARIANTS = {
+    'opt_no_position_row': lambda: _NoPositionRow(H),
+    'opt_post_ln': lambda: _PostLN(H),
+    'opt_no_head_bias': lambda: _NoHeadBias(H),
+    'opt_gelu': lambda: _Gelu(H),
+}
 
 
-@pytest.mark.parametrize('variant', sorted(VARIANTS))
+@pytest.mark.parametrize('variant', sorted(VARIANTS) + sorted(OPT_VARIANTS))
 def test_a_wrong_block_moves_the_logits(params, variant):
     """Each departure from the equations is far outside (A)'s bar and
     outside the chip's (B): the checks are not blind to it."""
+    if variant in OPT_VARIANTS:
+        # the right description first, so that the bar means something:
+        # OptBlock is inside it on the very weights each wrong one fails
+        p, prompt = make_opt_params(7), \
+            np.random.default_rng(7).integers(1, V, 14)
+        got, seq = decode(make_engine(p, OptBlock(H), top=16), prompt, 3)
+        assert rel(got, ref_opt_logits(p, seq)[len(prompt) - 1:]) < TOL_A
+        got, seq = decode(make_engine(p, OPT_VARIANTS[variant](), top=16),
+                          prompt, 3)
+        err = rel(got, ref_opt_logits(p, seq)[len(prompt) - 1:])
+        # measured 0.09 (the head's bias) to 0.98 (post-LN): all above
+        # the chip's bar as well (chipbench/reference/opt.py LOGITS_TOL)
+        assert err > 100 * TOL_A and err > 2e-2
+        return
     if variant == 'renormalised':
         # what renormalising does depends on how far the 8 weights are
         # from summing to 1: a flat router here (they sum to ~0.5; with
@@ -403,46 +490,82 @@ def test_build_logits_through_the_executor(engine):
 
 # 9 -------------------------------------------------------------------------
 
-def test_weights_are_operands(params, ring):
+def _weight_shaped_constants(compiled, params):
+    """The constants of a weight matrix's shape in a program's text."""
+    shapes = {tuple(v.shape) for v in params.values() if v.ndim > 1}
+    found = []
+    for line in compiled.as_text().splitlines():
+        if 'constant(' in line:
+            dims = re.search(r'= \w+\[([\d,]*)\]', line)
+            shape = tuple(int(d) for d in dims.group(1).split(',')
+                          if d) if dims else ()
+            if shape in shapes:
+                found.append(line)
+    return found
+
+
+@pytest.mark.parametrize('model', ['olmoe', 'opt'])
+def test_how_the_weights_enter_each_program(params, ring, model):
+    """The one thing a description says about how its programs are
+    built (``constant_weights``).  OLMoE: an operand of step, chunk and
+    prefill, no program holds a copy, and a compiled step serves
+    whatever weights it is handed.  OPT: constants of step and chunk
+    (``argument_bytes`` holds the pools and the host's arrays only, and
+    the handed weights are not read), an operand of prefill all the
+    same.  ``pack`` reads none under either."""
+    if model == 'opt':
+        params, other, block = make_opt_params(0), make_opt_params(21), \
+            OptBlock(H)
+        reference = ref_opt_logits
+    else:
+        other, block, reference = make_params(21), OlmoeBlock(H), ref_logits
+    constants = model == 'opt'
+    assert block.constant_weights is constants
     ring.clear()
-    eng = make_engine(params, top=16)
+    eng = make_engine(params, block, top=16)
     eng.warmup()
-    chunked = make_engine(params, top=16, prefill_chunk_tokens=PAGE)
+    chunked = make_engine(params, block, top=16, prefill_chunk_tokens=PAGE)
     chunked.warmup()
     weight_bytes = sum(v.nbytes for v in params.values())
     seen = {}
     for e in spans(ring, 'decode.compile'):
         seen.setdefault(e['args']['program'], []).append(e['args'])
-    assert {'step', 'prefill', 'chunk', 'pack'} <= set(seen)
-    for program in ('step', 'prefill', 'chunk'):
+    assert {'step', 'prefill', 'chunk', 'pack'} == set(seen)
+    for program in ('step', 'chunk'):
         for a in seen[program]:
-            assert a['argument_bytes'] >= weight_bytes, (program, a)
+            assert (a['argument_bytes'] < weight_bytes) is constants, \
+                (program, a)
+    for a in seen['prefill']:
+        assert a['argument_bytes'] >= weight_bytes
     for a in seen['pack']:
         assert a['argument_bytes'] < weight_bytes
-    # no constant of a weight's size in any program's text
-    big = re.compile(r'constant\(')
-    shapes = {tuple(v.shape) for v in params.values() if v.ndim > 1}
+    # a constant of a weight's size in a program's text: in OPT's step
+    # and chunk, and nowhere else
     for compiled in [eng._step, chunked._step] \
-            + list(eng._prefill.values()) + list(chunked._chunk.values()):
-        for line in compiled.as_text().splitlines():
-            if big.search(line):
-                dims = re.search(r'= \w+\[([\d,]*)\]', line)
-                shape = tuple(int(d) for d in dims.group(1).split(',')
-                              if d) if dims else ()
-                assert shape not in shapes, line
-    # the same compiled step serves another engine's weights
-    other = make_params(21)
-    eng_b = make_engine(other, top=16)
+            + list(chunked._chunk.values()):
+        assert bool(_weight_shaped_constants(compiled, params)) \
+            is constants
+    for compiled in eng._prefill.values():
+        assert not _weight_shaped_constants(compiled, params)
+    # one engine's compiled step, handed another engine's weights:
+    # as an operand they are what it computes with, as constants they
+    # are not read
+    eng_b = make_engine(other, block, top=16)
     prompt = np.random.default_rng(10).integers(1, V, 12)
     pages = eng_b.cache.alloc(2)
     tok = int(np.argmax(eng_b.prefill_into(prompt, pages)))
     t, pt, c = one_slot(eng_b, 1, tok, pages, len(prompt))
+    seq = list(prompt) + [tok]
+    assert rel(eng_b.step(t, pt, c)[1][1], reference(other, seq)[-1]) \
+        < TOL_A
+    # (the pools are donated: eng_b is spent after this call)
     out = eng._step(eng_b.params, eng_b.cache.k, eng_b.cache.v,
                     jnp.asarray(t), jnp.asarray(pt), jnp.asarray(c))
-    want = ref_logits(other, list(prompt) + [tok])[-1]
-    assert rel(np.asarray(out[2])[1], want) < TOL_A
-    assert rel(np.asarray(out[2])[1],
-               ref_logits(params, list(prompt) + [tok])[-1]) > 0.1
+    got = np.asarray(out[2])[1]
+    # as constants: eng's weights over eng_b's cached rows, which is
+    # neither engine's answer
+    assert (rel(got, reference(other, seq)[-1]) < TOL_A) is not constants
+    assert rel(got, reference(params, seq)[-1]) > 0.1
 
 
 # 10 ------------------------------------------------------------------------
@@ -532,11 +655,14 @@ def test_step_spans_count_kv_pages_from_the_hosts_ctx_lens(
 
 
 def test_opt_engine_reports_no_routing(ring):
-    """An engine without a block gains nothing per step or per tick: no
-    ``decode.weights`` span, no argument on its ``decode.step`` spans,
-    and the server's counters stay zero; only ``decode.compile`` (set-up)
-    carries the new ``argument_bytes`` and, for the step, ``attention``."""
-    from paddle_tpu.models import transformer
+    """The engine's default description, ``OptBlock``, has no experts:
+    its spans carry no ``moe_*`` argument and the server's routing
+    totals stay zero.  Everything else it reports as any block does:
+    the weights' set-up span, ``argument_bytes`` and the step's
+    ``attention`` on ``decode.compile``, and the KV pages of every
+    step, counted from the arrays the server hands the engine.  Its
+    ``max_seq`` defaults to the position table's rows and may not
+    exceed them."""
     scope = fluid.Scope()
     main_p, startup = fluid.Program(), fluid.Program()
     main_p.random_seed = startup.random_seed = 7
@@ -545,27 +671,46 @@ def test_opt_engine_reports_no_routing(ring):
                           d_model=32, n_heads=4)
     fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
     ring.clear()
-    eng = DecodeEngine(extract_params(scope, 2), n_layers=2, n_heads=4,
-                       page_size=8, max_streams=2, prefill_bucket=16,
+    p = extract_params(scope, 2)
+    assert sorted(p) == sorted(transformer.param_names(2))
+    eng = DecodeEngine(p, n_layers=2, n_heads=4, page_size=8,
+                       max_streams=2, prefill_bucket=16,
                        prefix_cache=False, prefill_chunk_tokens=0)
-    assert eng.max_seq == 64 and eng.block is None
-    with pytest.raises(ValueError):
+    assert eng.max_seq == 64 and isinstance(eng.block, OptBlock)
+    with pytest.raises(ValueError, match='position table'):
         DecodeEngine(eng.params, n_layers=2, n_heads=4, max_seq=128)
+    with pytest.raises(ValueError, match='heads'):
+        DecodeEngine(eng.params, n_layers=2, n_heads=4, block=OptBlock(2))
+    assert spans(ring, 'decode.weights')[-1]['args'] == {
+        'bytes': sum(v.nbytes for v in p.values()), 'tensors': len(p),
+        'dtype': 'float32'}
+    handed, step = [], eng.step
+
+    def spy(tokens, page_tables, ctx_lens):
+        handed.append((np.array(page_tables), np.array(ctx_lens)))
+        return step(tokens, page_tables, ctx_lens)
+    eng.step = spy
     server = DecodeServer(eng)
     try:
         server.submit(np.arange(1, 8), max_new_tokens=4).result(timeout=60)
         stats = server.stats()
     finally:
         server.close()
-    assert not spans(ring, 'decode.weights')
     assert all('argument_bytes' in e['args']
                for e in spans(ring, 'decode.compile'))
     steps = spans(ring, 'decode.step')
-    assert steps and not any(e.get('args') for e in steps)
+    assert len(steps) == len(handed) == 3
+    live = [sum(-(-(int(c) + 1) // 8)
+                for c in ctx[pts[:, 0] != eng.cache.trash])
+            for pts, ctx in handed]
+    assert live == [1, 2, 2]    # position 7 is page 0's last row
+    for e, n in zip(steps, live):
+        assert e['args'] == {'kv_live_pages': n, 'kv_table_pages': 2 * 8}
     assert all(set(e['args']) == {'tokens', 'bucket'}
                for e in spans(ring, 'decode.prefill_into'))
     assert (stats['moe_assignments'], stats['moe_max_load'],
             stats['moe_touched_mean']) == (0, 0, 0.0)
-    assert (stats['kv_live_pages'], stats['kv_table_pages']) == (0, 0)
+    assert (stats['kv_live_pages'], stats['kv_table_pages']) \
+        == (sum(live), 3 * 2 * 8)
     assert [e['args']['attention'] for e in spans(ring, 'decode.compile')
             if e['args']['program'] == 'step'] == ['xla_gather']
