@@ -488,9 +488,10 @@ DEFINE_bool('decode_prefix_cache', False,
 DEFINE_int('decode_prefill_chunk_tokens', 0,
            'per-tick prefill token budget for chunked prefill in the '
            'decode worker loop: prompts prefill in page-aligned chunks '
-           'of up to this many tokens between decode steps, so a long '
-           'prompt no longer stalls running streams for one monolithic '
-           'bucket dispatch.  0 = no per-tick budget (a stream\'s '
+           'of up to this many tokens a tick, the last chunk carrying '
+           'the tick\'s decode step, so a long prompt no longer stalls '
+           'running streams for one monolithic bucket dispatch.  '
+           '0 = no per-tick budget (a stream\'s '
            'whole prefill runs at admission; chunked executables are '
            'still used when the prefix cache is on).  A registered '
            'tunable')

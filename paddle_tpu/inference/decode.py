@@ -31,6 +31,14 @@ inference-throughput fix for decoder-only LMs, TPU-native:
   Throughput is work-conserving instead of generation-batch-barriered;
   ``static_batching=True`` on the server reproduces the barriered
   baseline for the A/B the decode bench reports.
+- **chunked prefill** (on with ``prefill_chunk_tokens`` or the prefix
+  cache) — a prompt runs as chunks on a grid anchored at position 0,
+  at most ``chunk_tokens`` of them a tick, and the tick's last chunk
+  carries the running streams' decode rows through the same pass: a
+  tick with a prompt pending is ONE program that reads the weights
+  once (``chunk``), a tick without one is ``step``.  Rows never mix in
+  a layer, so each stream gets what the two programs run one after the
+  other would give it.
 
 There is ONE definition of a decoder's serving path: which decoder is
 served is a block description (inference/blocks.py: ``OptBlock``, the
@@ -377,6 +385,15 @@ class DecodeEngine(object):
         self._pack = {}      # bucket -> compiled (k, v, pools, pages)
         self._chunk = {}     # bucket -> compiled chunked-prefill fn
         self._step = None
+        # a decode step's operands with every slot idle (all-trash page
+        # tables): what warm-up runs, and what a chunk carries when it
+        # carries no decode rows
+        S = self.max_streams
+        self._idle_step = (
+            jnp.zeros((S,), jnp.int32),
+            jnp.full((S, self.pages_per_stream), self.cache.trash,
+                     jnp.int32),
+            jnp.zeros((S,), jnp.int32))
 
     # -- compiled function builders ------------------------------------
 
@@ -465,7 +482,8 @@ class DecodeEngine(object):
         the span's arguments and the engine's totals: ``kv_live_pages``,
         what attention has to read (each running slot's pages up to and
         with the position written this step), and ``kv_table_pages``,
-        what a gather of whole page tables reads (S x MPP)."""
+        what a gather of whole page tables reads (S x MPP).  Returns
+        the number of running slots."""
         pts = np.asarray(page_tables)
         running = pts[:, 0] != self.cache.trash
         live = int(np.sum(
@@ -473,6 +491,7 @@ class DecodeEngine(object):
         span_args.update(kv_live_pages=live, kv_table_pages=pts.size)
         self.kv_pages['live'] += live
         self.kv_pages['table'] += pts.size
+        return int(np.sum(running))
 
     # -- the three programs: one loop, three ways to attend -------------
 
@@ -515,65 +534,105 @@ class DecodeEngine(object):
                     jnp.stack(vs)) + extra
         return prefill
 
-    def _chunk_fn(self, bucket):
-        blk, P, mpp = self.block, self.page_size, self.pages_per_stream
-        trash = self.cache.trash
+    def _chunk_rows(self, bucket, pt, pos0, n_valid):
+        """A chunk's rows: positions, which of them hold a prompt token,
+        and where their K/V rows land."""
+        P, mpp = self.page_size, self.pages_per_stream
+        # pos0 and n_valid are traced (host slicing would hide
+        # per-shape gather compiles, the prefill lesson); padded
+        # rows (i >= n_valid) write to the trash page, are not
+        # counted, and their outputs never leave the executable
+        pos = pos0 + jnp.arange(bucket)
+        valid = jnp.arange(bucket) < n_valid
+        page_idx = pt[jnp.clip(pos // P, 0, mpp - 1)]
+        page_idx = jnp.where(valid, page_idx, self.cache.trash)
+        return pos, valid, page_idx, pos % P
+
+    def _step_rows(self, pt, ctx_len):
+        """A decode step's rows, one a slot: positions and where their
+        K/V rows land."""
+        P = self.page_size
+        # ctx_len counts CACHED positions per slot; the incoming
+        # token sits at position ctx_len and is cached this step
+        pos = jnp.clip(ctx_len, 0, self.max_seq - 1)
+        page_idx = jnp.take_along_axis(
+            pt, (pos // P)[:, None], axis=1)[:, 0]
+        return pos, page_idx, pos % P
+
+    @staticmethod
+    def _chunk_read(k_pool, v_pool, pt, pos0):
         chunk_att = get_op_impl('chunked_prefill_attention').compute
 
-        def chunk(params, k_pool, v_pool, tokens, pt, pos0, n_valid):
-            # pos0 and n_valid are traced (host slicing would hide
-            # per-shape gather compiles, the prefill lesson); padded
-            # rows (i >= n_valid) write to the trash page, are not
-            # counted, and their outputs never leave the executable
-            pos = pos0 + jnp.arange(bucket)
-            valid = jnp.arange(bucket) < n_valid
-            page_idx = pt[jnp.clip(pos // P, 0, mpp - 1)]
-            page_idx = jnp.where(valid, page_idx, trash)
-            offset = pos % P
+        def read(i, q):
+            return chunk_att(None, {'Q': [q], 'KPool': [k_pool[i]],
+                                    'VPool': [v_pool[i]],
+                                    'PT': [pt], 'Pos0': [pos0]},
+                             {})['Out'][0]
+        return read
+
+    @staticmethod
+    def _step_read(k_pool, v_pool, pt, pos):
+        paged = get_op_impl('paged_attention').compute
+
+        def read(i, q):
+            return paged(None, {'Q': [q], 'KPool': [k_pool[i]],
+                                'VPool': [v_pool[i]], 'PT': [pt],
+                                'CtxLen': [pos + 1]}, {})['Out'][0]
+        return read
+
+    def _chunk_fn(self, bucket):
+        blk, S, trash = self.block, self.max_streams, self.cache.trash
+
+        def chunk(params, k_pool, v_pool, tokens, pt, pos0, n_valid,
+                  step_tokens, step_pt, ctx_len):
+            # one pass over S + bucket rows: the tick's decode rows (as
+            # ``step`` takes them; all-trash page tables carry none),
+            # then the chunk's.  Rows never mix in a layer, so each
+            # group comes out as its own program would give it
+            spos, spage, soffset = self._step_rows(step_pt, ctx_len)
+            pos, valid, page_idx, offset = self._chunk_rows(
+                bucket, pt, pos0, n_valid)
             k_pool, v_pool = list(k_pool), list(v_pool)
+            read_step = self._step_read(k_pool, v_pool, step_pt, spos)
+            read_chunk = self._chunk_read(k_pool, v_pool, pt, pos0)
 
             def read(i, q):
-                return chunk_att(None, {'Q': [q], 'KPool': [k_pool[i]],
-                                        'VPool': [v_pool[i]],
-                                        'PT': [pt], 'Pos0': [pos0]},
-                                 {})['Out'][0]
+                return jnp.concatenate([read_step(i, q[:S]),
+                                        read_chunk(i, q[S:])])
 
             # a last chunk's padded rows point past the prompt: ``embed``
             # (which may index a position table) gets them inside max_seq
             x, extra = self._layers(
-                params, blk.embed(params, tokens,
-                                  jnp.clip(pos, 0, self.max_seq - 1)),
-                pos, valid,
-                self._write_then(k_pool, v_pool, page_idx, offset, read))
-            x_last = x[jnp.clip(n_valid - 1, 0, bucket - 1)]
-            return (k_pool, v_pool,
-                    blk.head(params, x_last[None])[0]) + extra
+                params,
+                blk.embed(params, jnp.concatenate([step_tokens, tokens]),
+                          jnp.concatenate(
+                              [spos, jnp.clip(pos, 0, self.max_seq - 1)])),
+                jnp.concatenate([spos, pos]),
+                jnp.concatenate([step_pt[:, 0] != trash, valid]),
+                self._write_then(k_pool, v_pool,
+                                 jnp.concatenate([spage, page_idx]),
+                                 jnp.concatenate([soffset, offset]), read))
+            # the head on the decode rows and the chunk's last valid row
+            last = S + jnp.clip(n_valid - 1, 0, bucket - 1)
+            logits = blk.head(params,
+                              jnp.concatenate([x[:S], x[last][None]]))
+            return (k_pool, v_pool, logits[S],
+                    jnp.argmax(logits[:S], axis=-1), logits[:S]) + extra
         return self._bound(chunk)
 
     def _step_fn(self):
-        blk, P, trash = self.block, self.page_size, self.cache.trash
-        paged = get_op_impl('paged_attention').compute
+        blk, trash = self.block, self.cache.trash
 
         def step(params, k_pool, v_pool, tokens, pt, ctx_len):
-            # ctx_len counts CACHED positions per slot; the incoming
-            # token sits at position ctx_len and is cached this step
-            pos = jnp.clip(ctx_len, 0, self.max_seq - 1)
-            page_idx = jnp.take_along_axis(
-                pt, (pos // P)[:, None], axis=1)[:, 0]
-            offset = pos % P
+            pos, page_idx, offset = self._step_rows(pt, ctx_len)
             k_pool, v_pool = list(k_pool), list(v_pool)
-
-            def read(i, q):
-                return paged(None, {'Q': [q], 'KPool': [k_pool[i]],
-                                    'VPool': [v_pool[i]], 'PT': [pt],
-                                    'CtxLen': [pos + 1]}, {})['Out'][0]
-
             # an inactive slot's page table is all trash: it runs (its
             # rows never meet another slot's) and is not counted
             x, extra = self._layers(
                 params, blk.embed(params, tokens, pos), pos,
                 pt[:, 0] != trash,
-                self._write_then(k_pool, v_pool, page_idx, offset, read))
+                self._write_then(k_pool, v_pool, page_idx, offset,
+                                 self._step_read(k_pool, v_pool, pt, pos)))
             logits = blk.head(params, x)
             return (k_pool, v_pool, logits,
                     jnp.argmax(logits, axis=-1)) + extra
@@ -614,8 +673,13 @@ class DecodeEngine(object):
         positions pos0.., scattered into the stream's pages and
         attending over chunks 0..N via the page table (the KV-carry is
         the donated pool itself — the run_steps carry pattern at pool
-        granularity).  Returns the last VALID row's logits only, so
-        intermediate chunks pay one [D]x[D,V] row, not a [C,V] head."""
+        granularity), and in the same pass the decode rows of one
+        ``step`` (tokens [S], page tables [S, MPP], context lengths
+        [S]; all-trash page tables carry none), each attending over its
+        own pages as in ``step``: one read of the weights for both.
+        Returns the chunk's last VALID row's logits, so intermediate
+        chunks pay one row of the head, not [C, V], then the decode
+        rows' next tokens [S] and logits [S, V]."""
         if bucket in self._chunk:
             return
         self._chunk[bucket] = self._compile(
@@ -623,18 +687,16 @@ class DecodeEngine(object):
             self.cache.v, jnp.zeros((bucket,), jnp.int32),
             jnp.full((self.pages_per_stream,), self.cache.trash,
                      jnp.int32),
-            jnp.int32(0), jnp.int32(1), donate=(1, 2), bucket=bucket)
+            jnp.int32(0), jnp.int32(1), *self._idle_step,
+            donate=(1, 2), bucket=bucket)
 
     def _ensure_step(self):
         if self._step is not None:
             return
         from ..ops.attention import paged_attention_path
-        S, mpp = self.max_streams, self.pages_per_stream
         self._step = self._compile(
             self._step_fn(), self.params, self.cache.k, self.cache.v,
-            jnp.zeros((S,), jnp.int32),
-            jnp.full((S, mpp), self.cache.trash, jnp.int32),
-            jnp.zeros((S,), jnp.int32), donate=(1, 2),
+            *self._idle_step, donate=(1, 2),
             # what the op's dispatch takes for these shapes (the step
             # calls it with no context: the default backend)
             attention=paged_attention_path(
@@ -667,7 +729,7 @@ class DecodeEngine(object):
                     self.params, self.cache.k, self.cache.v,
                     jnp.zeros((b,), jnp.int32),
                     jnp.full((mpp,), trash, jnp.int32),
-                    jnp.int32(0), jnp.int32(b))[:3]
+                    jnp.int32(0), jnp.int32(b), *self._idle_step)[:3]
                 jax.block_until_ready(logits)
         else:
             for b in self.buckets:
@@ -682,12 +744,9 @@ class DecodeEngine(object):
                 self.cache.k, self.cache.v = self._pack[b](
                     self.cache.k, self.cache.v, k, v, all_trash)
                 jax.block_until_ready(logits)
-        S, mpp = self.max_streams, self.pages_per_stream
         self.cache.k, self.cache.v, logits = self._step(
             self.params, self.cache.k, self.cache.v,
-            jnp.zeros((S,), jnp.int32),
-            jnp.full((S, mpp), trash, jnp.int32),
-            jnp.zeros((S,), jnp.int32))[:3]
+            *self._idle_step)[:3]
         jax.block_until_ready(logits)
         self._compiles_at_warmup = self.compiles_total
 
@@ -750,17 +809,26 @@ class DecodeEngine(object):
             lo = hi
         return spans
 
-    def prefill_chunk(self, tokens, pages, pos0):
+    def prefill_chunk(self, tokens, pages, pos0, step_tokens=None,
+                      page_tables=None, ctx_lens=None):
         """Run ONE prefill chunk for a single stream: ``tokens`` [c]
         (c <= chunk_grid) land at absolute positions pos0..pos0+c-1 in
         the pages named by ``pages`` (the stream's page table; entries
         past it route to trash).  Returns the chunk's last-row logits
         as numpy [V] — only the final chunk's matter (the TTFT
-        payload), earlier chunks' are a one-row head by-product."""
+        payload), earlier chunks' are a one-row head by-product.
+
+        Handed a decode step's operands as well (``step``'s three, of
+        streams other than the chunk's), the same call runs that step's
+        rows beside the chunk's, in place of a ``step`` after it: it
+        then returns (last-row logits, next tokens [S] as numpy, the
+        decode rows' logits [S, V] left on the device for whoever asks).
+        The span's ``tokens`` and ``bucket`` stay the chunk's;
+        ``step_rows`` counts the running slots carried."""
         tokens = np.asarray(tokens, dtype=np.int32)
         c = int(tokens.shape[0])
         bucket = self.bucket_for(c)
-        args = {'tokens': c, 'bucket': bucket}
+        args = {'tokens': c, 'bucket': bucket, 'step_rows': 0}
         with _obs.span('decode.prefill_chunk', args=args):
             self._ensure_chunk(bucket)
             toks = np.zeros((bucket,), np.int32)
@@ -769,14 +837,22 @@ class DecodeEngine(object):
             pt = np.full((mpp,), self.cache.trash, np.int32)
             n = min(len(pages), mpp)
             pt[:n] = pages[:n]
-            self.cache.k, self.cache.v, logits, *extra = \
-                self._chunk[bucket](
-                    self.params, self.cache.k, self.cache.v,
-                    jnp.asarray(toks), jnp.asarray(pt), jnp.int32(pos0),
-                    jnp.int32(c))
+            # the host's arrays go in as they are (the executable puts
+            # them on the device in one batch; a ``jnp`` scalar would be
+            # a device program of its own)
+            carried = self._idle_step if step_tokens is None else tuple(
+                np.asarray(a, dtype=np.int32)
+                for a in (step_tokens, page_tables, ctx_lens))
+            self.cache.k, self.cache.v, logits, nxt, step_logits, \
+                *extra = self._chunk[bucket](
+                    self.params, self.cache.k, self.cache.v, toks, pt,
+                    np.int32(pos0), np.int32(c), *carried)
             if extra:
                 self._routing(extra[0], args)
-            return np.asarray(logits)
+            if step_tokens is None:
+                return np.asarray(logits)
+            args['step_rows'] = self._kv_pages(page_tables, ctx_lens, args)
+            return np.asarray(logits), np.asarray(nxt), step_logits
 
     def step(self, tokens, page_tables, ctx_lens):
         """One batched decode step over all ``max_streams`` slots.
@@ -855,8 +931,15 @@ class _DecodeMetrics(object):
             'pool pressure', L))
         self.prefill_chunks = child(reg.counter(
             'paddle_tpu_decode_prefill_chunks_total',
-            'chunked-prefill dispatches scheduled between decode '
-            'steps', L))
+            'chunked-prefill dispatches', L))
+        self.prefill_chunks_carrying = child(reg.counter(
+            'paddle_tpu_decode_prefill_chunks_carrying_total',
+            'chunked-prefill dispatches that carried a decode step of '
+            'at least one running stream', L))
+        self.carried_rows = child(reg.counter(
+            'paddle_tpu_decode_carried_rows_total',
+            'decode rows of running streams that a prefill chunk '
+            'carried', L))
         self.preempted = child(reg.counter(
             'paddle_tpu_decode_preempted_streams_total',
             'streams requeued on page-pool exhaustion mid-decode '
@@ -1026,6 +1109,11 @@ class DecodeServer(object):
                 'prefix_evicted_tokens':
                     int(self._m.prefix_evicted.value),
                 'prefill_chunks': int(self._m.prefill_chunks.value),
+                # chunks that carried the tick's decode step (>= 1
+                # running row), and the rows they carried
+                'prefill_chunks_carrying':
+                    int(self._m.prefill_chunks_carrying.value),
+                'carried_rows': int(self._m.carried_rows.value),
                 'preempted': self._preempted,
                 'cached_pages': cached,
                 # shared pages are counted ONCE: they live inside the
@@ -1190,37 +1278,53 @@ class DecodeServer(object):
         if eng.prefix is not None:
             self._trie_insert(st, st._ctx_len, acquire=True)
 
-    def _run_prefill_chunks(self, active):
-        """Schedule prefill chunks under the per-tick token budget,
-        round-robin across streams so one long prompt cannot starve
-        another's TTFT.  Budget 0 = unlimited (whole prefill now)."""
+    def _plan_prefill_chunks(self, active):
+        """This tick's prefill chunks, [(stream, lo, hi)] in the order
+        they run: the per-tick token budget, round-robin across streams
+        so one long prompt cannot starve another's TTFT.  Budget 0 =
+        unlimited (whole prefill now)."""
         eng = self.engine
         budget = eng.chunk_tokens if eng.chunk_tokens > 0 else None
         pending = [st for st in active if st._prefill_pos is not None]
         if not pending:
-            return
+            return []
         rr = self._chunk_rr % len(pending)
         self._chunk_rr += 1
-        used = 0
+        plan, used = [], 0
         for st in pending[rr:] + pending[:rr]:
-            prompt = st._prompt_eff
-            t = len(prompt)
-            with _obs.span('server.admit', args={'rid': st.request_id}):
-                while st._prefill_pos is not None and \
-                        (budget is None or used < budget):
-                    lo = st._prefill_pos
-                    hi = min(lo + eng.chunk_grid, t)
-                    logits = eng.prefill_chunk(prompt[lo:hi], st._pages,
-                                               lo)
-                    self._m.prefill_chunks.inc()
-                    used += hi - lo
-                    if hi >= t:
-                        st._prefill_pos = None
-                        self._finish_prefill(st, logits)
-                    else:
-                        st._prefill_pos = hi
+            lo, t = st._prefill_pos, len(st._prompt_eff)
+            while lo < t and (budget is None or used < budget):
+                hi = min(lo + eng.chunk_grid, t)
+                plan.append((st, lo, hi))
+                used += hi - lo
+                lo = hi
             if budget is not None and used >= budget:
                 break
+        return plan
+
+    def _run_prefill_chunks(self, plan, step_operands, rows):
+        """Run the planned chunks.  The LAST carries the tick's decode
+        step (``step_operands``: ``step``'s three arrays, with ``rows``
+        running slots), so the tick reads the weights once for both;
+        returns those rows' next tokens (None when ``rows`` is 0: the
+        chunk then runs alone)."""
+        eng, nxt = self.engine, None
+        for n, (st, lo, hi) in enumerate(plan):
+            carry = step_operands if rows and n == len(plan) - 1 else ()
+            with _obs.span('server.admit', args={'rid': st.request_id}):
+                out = eng.prefill_chunk(st._prompt_eff[lo:hi], st._pages,
+                                        lo, *carry)
+                logits, nxt = out[:2] if carry else (out, None)
+                self._m.prefill_chunks.inc()
+                if carry:
+                    self._m.prefill_chunks_carrying.inc()
+                    self._m.carried_rows.inc(rows)
+                if hi >= len(st._prompt_eff):
+                    st._prefill_pos = None
+                    self._finish_prefill(st, logits)
+                else:
+                    st._prefill_pos = hi
+        return nxt
 
     def _ensure_capacity(self, st):
         """Claim-as-context-grows: the next step writes position
@@ -1299,15 +1403,22 @@ class DecodeServer(object):
                 if self._stopping and not self._queue and \
                         all(s is None for s in self._slots):
                     return
-            # one tick: admit what fits, then one decode step.  The idle
-            # wait above is no part of it.
+            # one tick: admit what fits, then one decode step (riding
+            # a prefill chunk when a prompt is pending).  The idle wait
+            # above is no part of it.
             args = {}
             with _obs.span('server.tick', step=n, args=args):
                 self._tick(args)
 
     def _tick(self, args):
-        """Admission, this tick's prefills and one batched decode step;
-        fills ``args`` (the tick span's) with what it did."""
+        """Admission, this tick's prefills and one batched decode step,
+        in as few engine calls as they take: a monolithic prefill is a
+        call a prompt at admission; with chunked prefill the tick's
+        last chunk carries the decode step (one call for both, and a
+        stream whose prompt ends in it decodes from the next tick); a
+        tick with no prompt pending is one ``step``.  The token
+        accounting after the call is the same whichever ran.  Fills
+        ``args`` (the tick span's) with what the tick did."""
         eng = self.engine
         S, mpp = eng.max_streams, eng.pages_per_stream
         trash = eng.cache.trash
@@ -1350,20 +1461,20 @@ class DecodeServer(object):
         if not active:
             return
         if eng.chunked:
-            # interleave: up to chunk_tokens of prefill work, then
-            # one decode step for every prefill-complete stream —
-            # a long prompt dents running streams' inter-token
-            # latency by one chunk, not one monolithic bucket
-            self._run_prefill_chunks(active)
+            # interleave: up to chunk_tokens of prefill work a tick,
+            # its last chunk carrying the decode step of every stream
+            # that was decoding before it — a long prompt dents running
+            # streams' inter-token latency by the chunk's own rows in
+            # one pass over the weights, not by a second program.  A
+            # stream whose prompt ends here decodes from the next tick
             decoding = [st for st in active
                         if st._prefill_pos is None]
             decoding = [st for st in decoding
                         if self._ensure_capacity(st)]
-            if eng.prefix is not None:
-                self._m.cached_pages.set(eng.prefix.cached_pages)
+            chunks = self._plan_prefill_chunks(active)
         else:
-            decoding = active
-        if not decoding:
+            decoding, chunks = active, []
+        if not (decoding or chunks):
             return
         args['running'] = len(decoding)
         # build the batched step inputs from host stream state
@@ -1375,7 +1486,15 @@ class DecodeServer(object):
             tokens[i] = st.tokens[-1]
             pts[i, :len(st._pages)] = st._pages
             ctx[i] = st._ctx_len
-        nxt, logits = eng.step(tokens, pts, ctx)
+        if chunks:
+            nxt = self._run_prefill_chunks(chunks, (tokens, pts, ctx),
+                                           len(decoding))
+        else:
+            nxt, _ = eng.step(tokens, pts, ctx)
+        if eng.prefix is not None:
+            self._m.cached_pages.set(eng.prefix.cached_pages)
+        if not decoding:
+            return
         now = time.perf_counter()
         self._m.steps.inc()
         finished = []

@@ -34,6 +34,26 @@ def pytest_configure(config):
 
 
 @pytest.fixture
+def hold_steps(monkeypatch):
+    """``release = hold_steps(engine)``: the DecodeEngine's ``step`` waits
+    until ``release()`` is called, so a test can submit more prompts while
+    the first stream stands just before its first decode step (they then
+    find it decoding, whatever the machine's load).  The engine's own
+    ``step`` is back after the test."""
+    import threading
+
+    def hold(engine):
+        released, step = threading.Event(), engine.step
+
+        def held(*args):
+            assert released.wait(60.0), 'the test never released the steps'
+            return step(*args)
+        monkeypatch.setattr(engine, 'step', held)
+        return released.set
+    return hold
+
+
+@pytest.fixture
 def compile_cache(tmp_path, monkeypatch):
     """A per-test persistent compile cache, placed the way a deployment
     places one (JAX_COMPILATION_CACHE_DIR), with jax's size and

@@ -137,8 +137,11 @@ def test_decode_step_parity_ragged_last_page(params, engine):
 
 @pytest.fixture(scope='module')
 def chunked_engine(params):
+    # (twice the pages the slots can hold: a chunk of 32 rows that also
+    # carries a decode step gathers more than the smaller pool holds)
     eng = DecodeEngine(params, n_layers=L, n_heads=H, page_size=PAGE,
                        max_streams=STREAMS, prefill_bucket=PREFILL_TOP,
+                       num_pages=2 * STREAMS * T // PAGE,
                        prefix_cache=False,
                        prefill_chunk_tokens=PREFILL_TOP)
     eng.warmup()
@@ -151,8 +154,9 @@ def chunked_engine(params):
 def test_pool_updated_in_place(engine, chunked_engine, program, bucket):
     """Every program that writes the KV pool aliases all of it to its
     outputs and declares no scratch of a pool's size: a pack under one
-    layer's K buffer, a step or chunk under what its attention gathers
-    and scores.  CPU layouts are not the chip's: this guards the
+    layer's K buffer, a step under what its attention gathers and
+    scores, a chunk (which carries a step's rows) under both its own and
+    the step's.  CPU layouts are not the chip's: this guards the
     structure (the parent's pack declared two whole pools here), the
     chip's trace is the proof."""
     eng = chunked_engine if program == 'chunk' else engine
@@ -167,8 +171,10 @@ def test_pool_updated_in_place(engine, chunked_engine, program, bucket):
         limit = pool // (2 * L)
     elif program == 'step':
         limit = 1.1 * eng.max_streams * span
-    else:   # a chunk also holds its rows' scores and probabilities
-        limit = 1.1 * (span + 2 * bucket * H * eng.max_seq * 4)
+    else:   # a chunk also holds its rows' scores and probabilities,
+        # and what the decode rows it carries gather
+        limit = 1.1 * ((1 + eng.max_streams) * span
+                       + 2 * bucket * H * eng.max_seq * 4)
     assert mem.temp_size_in_bytes < limit < pool
 
 
@@ -349,6 +355,92 @@ def test_server_static_batching_baseline(params, engine):
         assert stats['static_batching'] is True
         assert stats['dropped'] == 0
         assert stats['compiles_after_warmup'] == 0
+    finally:
+        srv.close()
+
+
+@pytest.fixture(scope='module')
+def page_chunks_engine(params):
+    """Chunked prefill, a page of prompt a tick."""
+    eng = DecodeEngine(params, n_layers=L, n_heads=H, page_size=PAGE,
+                       max_streams=STREAMS, prefill_bucket=PREFILL_TOP,
+                       prefix_cache=False, prefill_chunk_tokens=PAGE)
+    eng.warmup()
+    return eng
+
+
+def test_chunk_carrying_decode_rows_matches_full_context(
+        params, page_chunks_engine):
+    """A prefill chunk handed a decode step's operands runs that step's
+    rows in the same pass: the running stream's logits are the
+    full-context recompute's at every one of the prompt's chunks, and
+    the prompt's last-row logits are its own recompute's."""
+    eng = page_chunks_engine
+    rng = np.random.default_rng(37)
+    running = rng.integers(0, V, size=11).tolist()
+    prompt = rng.integers(0, V, size=29)
+    pages, mine = eng.cache.alloc(3), eng.cache.alloc(4)
+    for lo, hi in eng.chunk_spans(len(running)):
+        first = eng.prefill_chunk(running[lo:hi], pages, lo)
+    assert np.max(np.abs(first - _ref_logits(params, running)[-1])) \
+        <= ULP_BAR
+    toks = running + [int(np.argmax(first))]
+    pt = np.full((STREAMS, eng.pages_per_stream), eng.cache.trash, np.int32)
+    pt[3, :len(pages)] = pages
+    for lo, hi in eng.chunk_spans(len(prompt)):
+        tok, ctx = np.zeros(STREAMS, np.int32), np.zeros(STREAMS, np.int32)
+        tok[3], ctx[3] = toks[-1], len(toks) - 1
+        last, nxt, logits = eng.prefill_chunk(prompt[lo:hi], mine, lo,
+                                              tok, pt, ctx)
+        ref = _ref_logits(params, toks)[-1]
+        assert np.max(np.abs(np.asarray(logits)[3] - ref)) <= ULP_BAR
+        assert int(nxt[3]) == int(np.argmax(ref))
+        toks.append(int(nxt[3]))
+    assert np.max(np.abs(last - _ref_logits(params, prompt.tolist())[-1])) \
+        <= ULP_BAR
+    eng.cache.free(pages + mine)
+    assert eng.compiles_after_warmup == 0
+    assert eng.cache.free_pages() == eng.cache.num_pages
+
+
+def test_server_chunked_prefill_rides_with_decode(params, hold_steps,
+                                                  page_chunks_engine):
+    """Prompts that arrive while others decode are prefilled a chunk a
+    tick, each chunk carrying the running streams' decode step: every
+    stream still generates its own full-context recompute's tokens, the
+    counters add up, nothing compiles."""
+    eng = page_chunks_engine
+    rng = np.random.default_rng(39)
+    prompts = [rng.integers(0, V, size=n).tolist()
+               for n in (9, 21, 5, 30, 14)]
+    n_new = (10, 3, 4, 2, 3)
+    others_sent = hold_steps(eng)
+    srv = DecodeServer(eng)
+    try:
+        streams = [srv.submit(np.asarray(prompts[0], np.int64),
+                              max_new_tokens=n_new[0])]
+        while not streams[0].tokens:
+            streams[0]._done.wait(0.001)
+        for p, n in zip(prompts[1:], n_new[1:]):
+            streams.append(srv.submit(np.asarray(p, np.int64),
+                                      max_new_tokens=n))
+        others_sent()
+        assert srv.drain(timeout=120.0)
+        for p, n, st in zip(prompts, n_new, streams):
+            assert list(st.result(timeout=5.0)) == _ref_greedy(params, p, n)
+            assert len(st.per_token_s()) == n - 1
+        stats = srv.stats()
+        assert stats['prefill_chunks'] == 2 + 3 + 1 + 4 + 2
+        # the three prompts admitted beside the first (four slots) find
+        # it decoding: at least their first chunks carry its row
+        assert 3 <= stats['prefill_chunks_carrying'] \
+            <= stats['prefill_chunks']
+        assert stats['carried_rows'] >= stats['prefill_chunks_carrying']
+        assert stats['carried_rows'] <= STREAMS * stats['prefill_chunks']
+        assert stats['generated_tokens'] == sum(n_new)
+        assert stats['completed'] == 5 and stats['dropped'] == 0
+        assert stats['compiles_after_warmup'] == 0
+        assert stats['free_pages'] == eng.cache.num_pages
     finally:
         srv.close()
 

@@ -212,6 +212,58 @@ def test_mid_decode_join_on_shared_prefix(params, prefix_engine):
         srv.close()
 
 
+def test_only_a_ticks_last_chunk_carries_the_decode_rows(params, hold_steps):
+    """With no per-tick budget (the prefix cache's default) a tick runs
+    every pending chunk; the decode step of the streams already running
+    rides the LAST of them, and a hit submitted mid-decode still gives
+    the cold stream's tokens."""
+    from paddle_tpu.observability import timeline
+    eng = DecodeEngine(params, n_layers=L, n_heads=H, page_size=PAGE,
+                       max_streams=STREAMS,
+                       prefill_bucket=PREFILL_TOP, prefix_cache=True)
+    assert eng.chunk_tokens == 0 and eng.chunk_grid == PAGE
+    others_sent = hold_steps(eng)    # the donor's first step waits
+    srv = DecodeServer(eng)
+    rng = np.random.default_rng(49)
+    prompt = rng.integers(0, V, size=27).tolist()
+    other = rng.integers(0, V, size=22).tolist()
+    timeline.reset()
+    try:
+        donor = srv.submit(np.asarray(prompt, np.int64), max_new_tokens=8)
+        while not donor.tokens:
+            donor._done.wait(0.001)
+        hit = srv.submit(np.asarray(prompt, np.int64), max_new_tokens=3)
+        cold = srv.submit(np.asarray(other, np.int64), max_new_tokens=3)
+        others_sent()
+        ref = _ref_greedy(params, prompt, 8)
+        assert donor.result(timeout=60.0) == ref
+        assert hit.result(timeout=60.0) == ref[:3]
+        assert cold.result(timeout=60.0) == _ref_greedy(params, other, 3)
+        stats = srv.stats()
+    finally:
+        srv.close()
+    evs = [e for e in timeline.ring().events(cat='span') if 'id' in e]
+    timeline.reset()
+    by_id = {e['id']: e for e in evs}
+    per_tick = {}
+    for e in evs:
+        if e['name'] == 'decode.prefill_chunk':
+            tick = by_id[by_id[e['parent']]['parent']]
+            per_tick.setdefault(tick['id'], []).append(e)
+    # the donor's four chunks in one tick, none carrying (nothing ran);
+    # then the hit's tail and the cold prompt's three in one tick
+    counts = sorted(len(v) for v in per_tick.values())
+    assert counts[-1] >= 3 and sum(counts) == stats['prefill_chunks']
+    for chunks in per_tick.values():
+        chunks.sort(key=lambda e: e['ts'])
+        assert all(e['args']['step_rows'] == 0 for e in chunks[:-1])
+    assert stats['prefill_chunks_carrying'] == sum(
+        1 for v in per_tick.values() if v[-1]['args']['step_rows'])
+    assert stats['prefill_chunks_carrying'] >= 1
+    assert stats['compiles_after_warmup'] == 0
+    assert all(r == 0 for r in _trie_refs(eng.prefix))
+
+
 def test_eviction_never_frees_referenced_pages():
     """PrefixCache unit contract: LRU eviction only touches
     unreferenced leaves; releasing refs makes pages reclaimable

@@ -95,17 +95,26 @@ def test_span_names_and_nesting(params, chunked):
                 assert p['ts'] <= e['ts'] and \
                     e['ts'] + e['dur'] <= p['ts'] + p['dur'] + 1e-9
     ticks = [e for e in evs if e['name'] == 'server.tick']
+    pf = [e for e in evs if e['name'] == prefill]
     assert [e['step'] for e in ticks] == list(range(len(ticks)))
     assert all(set(e['args']) == {'running', 'admitted', 'queued'}
                for e in ticks)
     assert sum(e['args']['admitted'] for e in ticks) == 3
+    # a tick's decode step is a ``step``, or rides the tick's last
+    # prefill chunk (``step_rows`` running slots): never both
     steps = [e for e in evs if e['name'] == 'decode.step']
-    assert len(steps) == sum(1 for e in ticks if e['args']['running'])
+    carrying = [e for e in evs if e['name'] == 'decode.prefill_chunk'
+                and e['args']['step_rows']]
+    assert len(steps) + len(carrying) \
+        == sum(1 for e in ticks if e['args']['running'])
+    assert bool(carrying) is chunked
+    if chunked:     # a chunk's tick is its ``server.admit``'s parent
+        assert not {e['parent'] for e in steps} \
+            & {by_id[e['parent']]['parent'] for e in pf}
     # self time is never negative: children do not overlap on a thread
     for t in ticks:
         kids = [e for e in evs if e['parent'] == t['id']]
         assert sum(k['dur'] for k in kids) <= t['dur'] + 1e-9
-    pf = [e for e in evs if e['name'] == prefill]
     assert all(0 < e['args']['tokens'] <= e['args']['bucket'] for e in pf)
     if not chunked:
         assert sorted(e['args']['tokens'] for e in pf) == [5, 9, 12]

@@ -592,8 +592,12 @@ def test_spans_and_server_stats(params, ring, chunked):
     pre = spans(ring, name)
     steps = [e for e in spans(ring, 'decode.step')
              if e['args'].get('moe_assignments')]
+    # a chunk counts its prompt tokens and the decode rows it carries
+    carried = sum(e['args'].get('step_rows', 0) for e in pre)
+    assert (carried > 0) is chunked
+    assert carried == stats['carried_rows']
     assert sum(e['args']['moe_assignments'] for e in pre) \
-        == 8 * L * sum(len(p) for p in prompts)
+        == 8 * L * (sum(len(p) for p in prompts) + carried)
     assert all(1 <= e['args']['moe_touched'] <= E
                and e['args']['moe_max_load'] >= 1 for e in pre + steps)
     total = sum(e['args']['moe_assignments'] for e in pre + steps)
@@ -615,12 +619,19 @@ def test_step_spans_count_kv_pages_from_the_hosts_ctx_lens(
     ring.clear()
     eng = make_engine(params, top=16,
                       prefill_chunk_tokens=PAGE if chunked else 0)
-    handed, step = [], eng.step
+    handed, step, prefill_chunk = [], eng.step, eng.prefill_chunk
 
     def spy(tokens, page_tables, ctx_lens):
         handed.append((np.array(page_tables), np.array(ctx_lens)))
         return step(tokens, page_tables, ctx_lens)
-    eng.step = spy
+
+    def spy_chunk(*args):
+        # a chunk that carries the tick's decode step is handed the
+        # step's three arrays after its own
+        if len(args) > 3:
+            handed.append((np.array(args[4]), np.array(args[5])))
+        return prefill_chunk(*args)
+    eng.step, eng.prefill_chunk = spy, spy_chunk
     server = DecodeServer(eng)
     rng = np.random.default_rng(13)
     try:
@@ -631,8 +642,13 @@ def test_step_spans_count_kv_pages_from_the_hosts_ctx_lens(
         stats = server.stats()
     finally:
         server.close()
-    steps = spans(ring, 'decode.step')
+    steps = sorted(
+        spans(ring, 'decode.step')
+        + [e for e in spans(ring, 'decode.prefill_chunk')
+           if e['args']['step_rows']], key=lambda e: e['ts'])
     assert len(steps) == len(handed) >= 8
+    assert ('decode.prefill_chunk' in {e['name'] for e in steps}) \
+        is chunked
     live = []
     for e, (pts, ctx) in zip(steps, handed):
         running = pts[:, 0] != eng.cache.trash
@@ -714,3 +730,210 @@ def test_opt_engine_reports_no_routing(ring):
         == (sum(live), 3 * 2 * 8)
     assert [e['args']['attention'] for e in spans(ring, 'decode.compile')
             if e['args']['program'] == 'step'] == ['xla_gather']
+
+
+# 11 ------------------------------------------------------------------------
+# A prefill chunk carries the tick's decode rows: one program for both,
+# for either block description.
+
+MODELS = {'olmoe': (make_params, OlmoeBlock, ref_logits),
+          'opt': (make_opt_params, OptBlock, ref_opt_logits)}
+CARRY_TOL = 1e-5
+
+
+def chunked_engine(model, seed=0, **kw):
+    """(weights, a warmed engine that prefills in chunks of a page, the
+    reference) for one of the two block descriptions."""
+    make, block, reference = MODELS[model]
+    p = make(seed)
+    eng = make_engine(p, block(H), prefill_chunk_tokens=PAGE, **kw)
+    eng.warmup()
+    return p, eng, reference
+
+
+def pool_rows(eng, pages, n):
+    """K and V of positions 0..n-1 behind ``pages``: [2, L, n, D]."""
+    at = ([pages[i // PAGE] for i in range(n)], [i % PAGE for i in range(n)])
+    return np.stack([np.stack([np.asarray(x)[at] for x in pool])
+                     for pool in (eng.cache.k, eng.cache.v)])
+
+
+def decoding_state(eng, prompts):
+    """Prefill ``prompts`` in chunks into slots 0, 2, ...: the operands
+    of the step that decodes them all next, and their pages."""
+    pt = np.full((STREAMS, eng.pages_per_stream), eng.cache.trash, np.int32)
+    toks, ctx = np.zeros(STREAMS, np.int32), np.zeros(STREAMS, np.int32)
+    pages = []
+    for slot, prompt in zip(range(0, STREAMS, 2), prompts):
+        pages.append(eng.cache.alloc(-(-(len(prompt) + 8) // PAGE)))
+        for lo, hi in eng.chunk_spans(len(prompt)):
+            logits = eng.prefill_chunk(prompt[lo:hi], pages[-1], lo)
+        pt[slot, :len(pages[-1])] = pages[-1]
+        toks[slot], ctx[slot] = int(np.argmax(logits)), len(prompt)
+    return (toks, pt, ctx), pages
+
+
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_a_chunk_alone_returns_what_it_did(model):
+    """(a) ``prefill_chunk`` with its three arguments carries no decode
+    row: last-row logits of the reference, and in the pools the rows a
+    monolithic prefill packs."""
+    p, eng, reference = chunked_engine(model)
+    mono = make_engine(p, MODELS[model][1](H))
+    prompt = np.random.default_rng(40).integers(1, V, 27)
+    pages, mono_pages = eng.cache.alloc(4), mono.cache.alloc(4)
+    for lo, hi in eng.chunk_spans(len(prompt)):
+        logits = eng.prefill_chunk(prompt[lo:hi], pages, lo)
+    assert isinstance(logits, np.ndarray) and logits.shape == (V,)
+    assert rel(logits, reference(p, prompt)[-1]) < CARRY_TOL
+    assert rel(logits, mono.prefill_into(prompt, mono_pages)) < CARRY_TOL
+    assert rel(pool_rows(eng, pages, 27),
+               pool_rows(mono, mono_pages, 27)) < CARRY_TOL
+    assert eng.compiles_after_warmup == 0
+
+
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_carried_rows_equal_a_step_after_the_chunk(model, ring):
+    """(b) On the same state, a chunk handed a step's operands gives the
+    running slots the tokens and logits ``step`` gives them after the
+    chunk, the chunk its own last-row logits, and the pools the same
+    rows: over every chunk of a prompt, two streams decoding beside it."""
+    rng = np.random.default_rng(41)
+    running = [rng.integers(1, V, 7), rng.integers(1, V, 19)]
+    prompt = rng.integers(1, V, 27)
+    sides = []
+    for fused in (False, True):
+        p, eng, _ = chunked_engine(model)
+        (toks, pt, ctx), pages = decoding_state(eng, running)
+        mine = eng.cache.alloc(4)
+        ring.clear()
+        out = []
+        for lo, hi in eng.chunk_spans(len(prompt)):
+            if fused:
+                last, nxt, logits = eng.prefill_chunk(
+                    prompt[lo:hi], mine, lo, toks, pt, ctx)
+                assert isinstance(nxt, np.ndarray)
+            else:
+                last = eng.prefill_chunk(prompt[lo:hi], mine, lo)
+                nxt, logits = eng.step(toks, pt, ctx)
+            out.append((last, nxt, np.asarray(logits)))
+            live = sum(-(-(int(c) + 1) // PAGE) for c in ctx[::2])
+            toks, ctx = np.where(ctx, nxt, 0).astype(np.int32), \
+                ctx + (ctx > 0)
+            if fused:
+                args = spans(ring, 'decode.prefill_chunk')[-1]['args']
+                assert args['tokens'] == hi - lo and args['bucket'] == PAGE
+                assert args['step_rows'] == 2
+                assert args['kv_live_pages'] == live
+                if model == 'olmoe':
+                    assert args['moe_assignments'] == 8 * L * (hi - lo + 2)
+        assert bool(spans(ring, 'decode.step')) is not fused
+        sides.append((out, [pool_rows(eng, pg, n) for pg, n in
+                            zip(pages + [mine], (7 + 4, 19 + 4, 27))]))
+        assert eng.compiles_after_warmup == 0
+    (alone, rows_alone), (carried, rows_carried) = sides
+    for (last_a, nxt_a, lg_a), (last_c, nxt_c, lg_c) in zip(alone, carried):
+        assert rel(last_c, last_a) < CARRY_TOL
+        assert np.array_equal(nxt_c[::2], nxt_a[::2])
+        assert rel(lg_c[::2], lg_a[::2]) < CARRY_TOL
+    for a, c in zip(rows_alone, rows_carried):
+        assert rel(c, a) < CARRY_TOL
+
+
+def served(model, lengths, n_new, hold_steps):
+    """Serve prompts of ``lengths`` through a chunking server, the later
+    ones submitted while the first decodes.  Returns what a replay by
+    hand gives each alone (chunks, then steps), the streams, the stats,
+    and the engine calls the server made."""
+    p, eng, _ = chunked_engine(model, seed=3)
+    rng = np.random.default_rng(42)
+    prompts = [rng.integers(1, V, n) for n in lengths]
+
+    def chunks(prompt, pages):
+        for lo, hi in eng.chunk_spans(len(prompt)):
+            logits = eng.prefill_chunk(prompt[lo:hi], pages, lo)
+        return logits
+
+    want = [[int(np.argmax(r)) for r in decode(eng, pr, n, prefill=chunks)[0]]
+            for pr, n in zip(prompts, n_new)]
+    # the first stream's first step waits for the other prompts
+    calls, others_sent = [], hold_steps(eng)
+    for name in ('prefill_chunk', 'step'):
+        def spy(*args, _call=getattr(eng, name), _name=name):
+            calls.append(_name)
+            return _call(*args)
+        setattr(eng, name, spy)
+    timeline.ring().clear()
+    server = DecodeServer(eng, warmup=False)
+    try:
+        streams = [server.submit(pr, max_new_tokens=n)
+                   for pr, n in zip(prompts[:1], n_new[:1])]
+        while not streams[0].tokens:     # its prompt is in the pages
+            streams[0]._done.wait(0.001)
+        streams += [server.submit(pr, max_new_tokens=n)
+                    for pr, n in zip(prompts[1:], n_new[1:])]
+        others_sent()
+        for st in streams:
+            st.result(timeout=120.0)
+        stats = server.stats()
+    finally:
+        server.close()
+    return want, streams, stats, calls
+
+
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_server_tokens_equal_a_replay_by_hand(model, ring, hold_steps):
+    """(c) Prompts that arrive while another stream decodes ride with its
+    decode rows; every request still gets the tokens its own chunks and
+    steps give it alone."""
+    n_new = (14, 5, 9)
+    want, streams, stats, _ = served(model, (11, 13, 21), n_new, hold_steps)
+    assert [st.tokens for st in streams] == want
+    assert 1 <= stats['prefill_chunks_carrying'] <= stats['prefill_chunks']
+    assert stats['carried_rows'] >= stats['prefill_chunks_carrying']
+    assert stats['prefill_chunks'] == 2 + 2 + 3
+    assert stats['generated_tokens'] == sum(n_new)
+    assert stats['completed'] == 3 and stats['compiles_after_warmup'] == 0
+    assert stats['free_pages'] == 40
+
+
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_a_tick_with_a_prompt_pending_is_one_engine_call(model, ring,
+                                                         hold_steps):
+    """(d) With one chunk a tick (prompts of whole chunks), every tick
+    that has work makes ONE call into the engine: a chunk that carries
+    the running slots, under one ``decode.prefill_chunk`` span and no
+    ``decode.step``, or a plain step."""
+    n_new = (12, 4, 6)
+    want, streams, stats, calls = served(model, (8, 16, 24), n_new,
+                                         hold_steps)
+    assert [st.tokens for st in streams] == want
+    evs = [e for e in ring.events(cat='span') if 'id' in e]
+    by_id = {e['id']: e for e in evs}
+
+    def tick_of(e):
+        while e['name'] != 'server.tick':
+            e = by_id[e['parent']]
+        return e['id']
+
+    made = {}
+    for e in evs:
+        if e['name'] in ('decode.prefill_chunk', 'decode.step'):
+            made.setdefault(tick_of(e), []).append(e)
+    ticks = [e for e in evs if e['name'] == 'server.tick']
+    assert all(len(v) == 1 for v in made.values())
+    assert len(calls) == len(made) == sum(
+        1 for t in ticks if t['args']['running'] or t['id'] in made)
+    chunk_ticks = [(by_id[t], v[0]) for t, v in made.items()
+                   if v[0]['name'] == 'decode.prefill_chunk']
+    assert len(chunk_ticks) == stats['prefill_chunks'] == 1 + 2 + 3
+    for tick, call in chunk_ticks:
+        assert call['args']['step_rows'] == tick['args']['running']
+    carrying = [c for _, c in chunk_ticks if c['args']['step_rows']]
+    # the first prompt ran alone; every later chunk found it decoding
+    assert len(carrying) == stats['prefill_chunks_carrying'] == 5
+    assert stats['carried_rows'] == sum(c['args']['step_rows']
+                                        for c in carrying)
+    assert calls.count('prefill_chunk') == 6
+    assert stats['decode_steps'] == calls.count('step') + 5
+    assert stats['generated_tokens'] == sum(n_new)
