@@ -57,6 +57,7 @@ CHIP = dict(
     flash=dict(B=2, T=8192, H=8, D=64),         # bench_attention.py
     # one layer of the olmoe-1b-7b_serve_chat32_chunked decode step
     paged=dict(S=32, H=16, D=128, P=16, MPP=64, N=2049),
+    latent=dict(S=64, H=128, W=640, C=512, P=16, MPP=256, N=4097),
     lstm=dict(T=128, B=256, H=256),             # bench_lstm_lm.py
     gru=dict(T=64, B=512, H=512),               # bench_seq2seq.py
     dense=[(2048, 1000), (7, 7, 3, 64), (64,)],  # fc head, stem, a bias
@@ -69,6 +70,7 @@ TOY = dict(
                bucket=32, prompt=(4, 20), new=(3, 6)),
     flash=dict(B=1, T=256, H=2, D=64),
     paged=dict(S=4, H=2, D=128, P=16, MPP=10, N=25),
+    latent=dict(S=4, H=4, W=128, C=96, P=16, MPP=10, N=25),
     lstm=dict(T=6, B=8, H=128),
     gru=dict(T=6, B=8, H=128),
     dense=[(40, 30), (3, 3, 3, 8), (64,)],
@@ -631,21 +633,24 @@ def kernel_cases(cfg):
     pg = cfg['paged']
     pool = ((pg['N'], pg['P'], pg['H'] * pg['D']), bf16)
 
-    def paged_make(rng):
+    def running_slots(rng, g):
         # a third of the slots run, contexts anywhere up to max_seq, on
         # pages scattered over the pool; the others idle on the trash page
-        s, mpp, n = pg['S'], pg['MPP'], pg['N']
+        s, mpp, n = g['S'], g['MPP'], g['N']
         pt = np.full((s, mpp), n - 1, np.int32)
         ctx = np.ones((s,), np.int32)
         free = rng.permutation(n - 1)
         for slot in rng.permutation(s)[:max(1, s // 3)]:
-            ctx[slot] = rng.integers(1, mpp * pg['P'] + 1)
-            pages = -(-int(ctx[slot]) // pg['P'])
+            ctx[slot] = rng.integers(1, mpp * g['P'] + 1)
+            pages = -(-int(ctx[slot]) // g['P'])
             pt[slot, :pages], free = free[:pages], free[pages:]
+        return [pt, ctx]
+
+    def paged_make(rng):
         kv = [(rng.standard_normal(pool[0]) * 0.5).astype(np.float32)
               .astype(bf16) for _ in range(2)]
-        return [rng.standard_normal((s, pg['H'], pg['D']))
-                .astype(np.float32)] + kv + [pt, ctx]
+        return [rng.standard_normal((pg['S'], pg['H'], pg['D']))
+                .astype(np.float32)] + kv + running_slots(rng, pg)
 
     cases.append(KernelCase(
         'paged_attention_live_pages',
@@ -654,6 +659,32 @@ def kernel_cases(cfg):
         lambda q, k, v, pt, ctx, interpret: paged_attention(
             q, k, v, pt, ctx, interpret=interpret),
         paged_attention_math, *TOL_BF16, make=paged_make, timed=True))
+
+    # -- the same over ONE latent row a position (MLA, absorbed form) ------
+    from paddle_tpu.ops.attention import latent_paged_attention_math
+    from paddle_tpu.ops.pallas.paged_attention import latent_paged_attention
+    lt = cfg['latent']
+    scale = lt['W'] ** -0.5
+
+    def latent_make(rng):
+        # the queries as the kernel rounds them, so that the math (which
+        # keeps float32 queries) sees the same numbers
+        rows = (rng.standard_normal((lt['N'], lt['P'], lt['W'])) * 0.5
+                ).astype(np.float32).astype(bf16)
+        q = rng.standard_normal((lt['S'], lt['H'], lt['W'])).astype(
+            np.float32).astype(bf16).astype(np.float32)
+        return [q, rows] + running_slots(rng, lt)
+
+    cases.append(KernelCase(
+        'latent_paged_attention_live_pages',
+        [((lt['S'], lt['H'], lt['W']), f32),
+         ((lt['N'], lt['P'], lt['W']), bf16),
+         ((lt['S'], lt['MPP']), jnp.int32), ((lt['S'],), jnp.int32)],
+        lambda q, pool_, pt, ctx, interpret: latent_paged_attention(
+            q, pool_, pt, ctx, scale, lt['C'], interpret=interpret),
+        lambda q, pool_, pt, ctx: latent_paged_attention_math(
+            q, pool_, pt, ctx, scale, lt['C']),
+        *TOL_BF16, make=latent_make, timed=True))
 
     # -- fused recurrences, forward and backward -------------------------
     def scan_case(name, c, gates, kernel, reference):

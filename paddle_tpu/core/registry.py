@@ -50,6 +50,8 @@ AMP_WHITE = frozenset({
     'sequence_conv', 'conv_shift', 'row_conv',
     'bilinear_tensor_product', 'flash_attention', 'paged_attention',
     'chunked_prefill_attention',
+    # the same two over ONE latent row a position, shared by all heads
+    'latent_paged_attention', 'latent_chunked_prefill_attention',
     'lstm', 'lstm_unit', 'gru', 'gru_unit',
     # fused vocab-head CE ops: dominated by the [N,D]x[D,V] matmul and
     # internally f32-safe (preferred_element_type accumulation + f32

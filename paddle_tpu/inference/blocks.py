@@ -1,24 +1,48 @@
 """Block descriptions: what ``DecodeEngine`` needs to know about a
 decoder's layer to serve it.
 
-The engine owns the loop over layers, the page pools, where K/V rows
-are written and which attention op reads them (dense for a whole-prompt
-prefill, ``chunked_prefill_attention`` for a chunk, ``paged_attention``
-for a decode step).  A block description supplies the rest, once, as
-pure functions of the weights (a ``{name: array}`` dict):
+The engine owns the loop over layers, the page pools, where a
+position's cached rows are written, and the three programs (a
+whole-prompt ``prefill``, a ``chunk``, a decode ``step``).  A block
+description supplies the rest, once, as pure functions of the weights (a
+``{name: array}`` dict):
 
 - ``names(n_layers)``      the weights it reads, by their fixed names;
 - ``sizes(params)``        ``d_model`` and ``vocab_size`` from shapes,
   and ``positions`` (a learned position table's rows) where it has one;
+- ``cache_rows(sizes)``    what a position caches in every layer, as
+  ``((name, width), ...)``: ``(('k', H * Dh), ('v', H * Dh))`` for full
+  multi-head attention, ``(('latent', W),)`` for a latent cache.  The
+  engine holds one page pool ``[pages, page_size, width]`` a row a layer
+  and nothing else about the cache;
 - ``embed(p, tokens, positions)`` -> x [T, D] float32 (the positions
   it is handed lie inside the engine's ``max_seq``);
-- ``qkv(p, x, i, positions)`` -> q [T, H, Dh], and k, v [T, H * Dh] as
-  the cache holds a position (the engine casts them to the pools'
-  dtype, writes them, and attends over what it wrote);
+- ``qkv(p, x, i, positions)`` -> (q, *rows): the queries as the block's
+  own attention takes them, then one [T, width] array a cache row, in
+  ``cache_rows`` order, as the cache holds a position (the engine casts
+  them to the pools' dtype, writes them, and attends over what it
+  wrote);
+- the three ways to attend, over rows already in the pools' dtype:
+  ``attend_prefill(p, i, q, rows)`` -> (ctx, kept): causal attention of
+  a whole prompt over its own rows; ``kept`` is what ``pack`` writes
+  into the pages later, a [T, ...] array a cache row;
+  ``attend_chunk(p, i, q, pools, pt, pos0)`` -> ctx: one stream's
+  prompt chunk (query j at position ``pos0 + j``) over the stream's
+  pages ``pt`` [MPP] of layer i's ``pools`` (one buffer a cache row);
+  ``attend_step(p, i, q, pools, pt, ctx_len)`` -> ctx: one token a slot
+  over the slot's pages ``pt`` [S, MPP], ``ctx_len`` [S] positions each;
+- ``describe(program, sizes, backend, page_size, dtype)`` -> what the
+  program's ``decode.compile`` span says of the block's part in it
+  (which attention its shapes take);
 - ``after_attention(p, x, ctx, i, active)`` -> (x, counts): everything
-  between attention and the next layer; ``counts`` is [n_experts] int32
-  (tokens routed to each expert among the rows where ``active``) or
-  None for a block without experts;
+  between attention and the next layer; ``counts`` is int32 [n] (tokens
+  routed to each expert among the rows where ``active``) or None for a
+  layer without routed experts.  A block that holds a SHARE of the
+  experts (``experts_share``) ends ``counts`` with the assignments to
+  experts held elsewhere;
+- ``live_positions_arg``   the name under which a ``decode.step`` span
+  reports the cached positions the step's attention reads (a block
+  whose kernel reads one row a position says so); None: pages only;
 - ``head(p, x)`` -> logits [T, V] float32;
 - ``constant_weights``     how the weights enter the engine's ``step``
   and ``chunk`` programs: False, as an operand (no program holds a
@@ -32,12 +56,17 @@ position table indexes it in ``embed`` and the engine's ``max_seq``
 defaults to its rows, a rotary block turns q and k in ``qkv`` and the
 engine's ``max_seq`` is a setting.
 """
+import jax
 import jax.numpy as jnp
 
-from ..ops.moe import (moe_counts, moe_experts, moe_route, rms_norm_math,
-                       rotary_math)
+from ..core.registry import get_op_impl
+from ..ops.attention import (_dense_attention, latent_attention_path,
+                             paged_attention_path)
+from ..ops.moe import (moe_counts, moe_experts, moe_route,
+                       moe_route_grouped, rms_norm_math, rotary_math,
+                       swiglu_math, yarn_mscale)
 
-__all__ = ['OptBlock', 'OlmoeBlock']
+__all__ = ['KVBlock', 'OptBlock', 'OlmoeBlock', 'DotsVlmBlock']
 
 
 def _mm(x, w):
@@ -47,7 +76,44 @@ def _mm(x, w):
                    preferred_element_type=jnp.float32)
 
 
-class OptBlock(object):
+class KVBlock(object):
+    """The cache and the attention of full multi-head attention: a
+    position caches one K and one V row of ``n_heads * head_dim``,
+    ``qkv`` returns q [T, H, Dh] and k, v [T, H * Dh], and the three
+    attends are the dense causal one, ``chunked_prefill_attention`` and
+    ``paged_attention`` (ops/attention.py)."""
+
+    experts_share = False
+    live_positions_arg = None
+
+    def cache_rows(self, sizes):
+        return (('k', sizes['d_model']), ('v', sizes['d_model']))
+
+    def describe(self, program, sizes, backend, page_size, dtype):
+        # what the op's dispatch takes for these shapes (the step calls
+        # it with no context: the default backend)
+        return {'attention': paged_attention_path(
+            backend, sizes['d_model'] // self.n_heads, page_size, dtype)} \
+            if program == 'step' else {}
+
+    def attend_prefill(self, p, i, q, rows):
+        t, h, dh = q.shape
+        k, v = (r.reshape(t, h, dh) for r in rows)
+        return _dense_attention(q[None], k[None], v[None], True,
+                                None)[0], (k, v)
+
+    def attend_chunk(self, p, i, q, pools, pt, pos0):
+        return get_op_impl('chunked_prefill_attention').compute(
+            None, {'Q': [q], 'KPool': [pools[0]], 'VPool': [pools[1]],
+                   'PT': [pt], 'Pos0': [pos0]}, {})['Out'][0]
+
+    def attend_step(self, p, i, q, pools, pt, ctx_len):
+        return get_op_impl('paged_attention').compute(
+            None, {'Q': [q], 'KPool': [pools[0]], 'VPool': [pools[1]],
+                   'PT': [pt], 'CtxLen': [ctx_len]}, {})['Out'][0]
+
+
+class OptBlock(KVBlock):
     """The OPT layer (models/transformer.py builds the same block as a
     ``Program``; chipbench/reference/opt.py is its plain reference):
     learned positions, pre-LayerNorm, one fused q/k/v projection, full
@@ -103,7 +169,7 @@ class OptBlock(object):
         return x @ p['tr_head_w'] + p['tr_head_b']
 
 
-class OlmoeBlock(object):
+class OlmoeBlock(KVBlock):
     """The OLMoE layer (models/olmoe.py builds the same block as a
     ``Program``; chipbench/reference/olmoe.py is its plain reference):
     pre-RMSNorm, QK-norm over the whole projected row before the split
@@ -169,3 +235,196 @@ class OlmoeBlock(object):
 
     def head(self, p, x):
         return _mm(self.norm(x, p['olmoe_norm_f_w']), p['olmoe_head_w'])
+
+
+class DotsVlmBlock(object):
+    """The layer of dots.vlm1's language model, a DeepSeek-V3-shaped
+    decoder (models/dots_vlm.py builds the same block as a ``Program``;
+    chipbench/reference/dots_vlm.py is its plain reference): pre-RMSNorm,
+    multi-head LATENT attention (low-rank queries; one compressed
+    key/value latent and one rotary key a position, shared by all
+    heads; YaRN rotary frequencies, interleaved pairing, softmax scale
+    ``(nope + rope)^-1/2 * mscale^2``), then ``first_dense`` leading
+    layers with a dense SwiGLU FFN and after them routed experts under
+    the grouped sigmoid router plus a shared expert.
+
+    The cache holds, per position and layer, ONE row: [the latent after
+    its norm | the rotary key after rotation | zeros up to a whole
+    number of 128-lane registers], and nothing per head.  A whole-prompt
+    prefill attends in the expanded form (keys and values of every head
+    rebuilt from the rows); a decode step in the absorbed form (the key
+    up-projection folded into the query, the value up-projection
+    applied to the attended latent), which reads the rows as they are;
+    so does a chunk (on the chip 0.64-2.45 ms a layer for 256 rows over
+    0-3.6k cached positions, against 5.22 ms expanded: PERF.md).  The
+    two forms are the same numbers (tests/test_dots_vlm_decode.py).
+
+    This chip may hold a SHARE of each layer's routed experts, experts
+    ``first_expert ..`` as many as the stacked weights hold: the router
+    keeps its published width, the held experts' part is computed, what
+    the others would add is left out (ops/moe.py ``moe_experts``)."""
+
+    constant_weights = False
+    experts_share = True
+    live_positions_arg = 'kv_latent_live_positions'
+    LANES = 128
+
+    def __init__(self, n_heads, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                 v_head_dim=128, top_k=8, n_group=8, topk_group=4,
+                 routed_scaling_factor=2.5, renormalize=True,
+                 first_expert=0, first_dense=1, eps=1e-6, theta=10000.0,
+                 yarn=None, mscale_all_dim=1.0):
+        self.n_heads = int(n_heads)
+        self.nope, self.rope = int(qk_nope_head_dim), int(qk_rope_head_dim)
+        self.v_dim = int(v_head_dim)
+        self.top_k, self.n_group = int(top_k), int(n_group)
+        self.topk_group = int(topk_group)
+        self.routed_scale = float(routed_scaling_factor)
+        self.renormalize = bool(renormalize)
+        self.first_expert, self.first_dense = int(first_expert), \
+            int(first_dense)
+        self.eps, self.theta = float(eps), float(theta)
+        self.yarn = dict(yarn) if yarn else None
+        m = yarn_mscale(self.yarn['factor'], mscale_all_dim) \
+            if self.yarn else 1.0
+        self.softmax_scale = (self.nope + self.rope) ** -0.5 * m * m
+
+    def names(self, n_layers):
+        from ..models.dots_vlm import param_names
+        return param_names(n_layers, self.first_dense)
+
+    def sizes(self, params):
+        v, d = params['dots_embed'].shape
+        return {'d_model': int(d), 'vocab_size': int(v),
+                'kv_lora_rank': int(params['dots_l0_kv_norm_w'].shape[0])}
+
+    def cache_rows(self, sizes):
+        w = sizes['kv_lora_rank'] + self.rope
+        return (('latent', -(-w // self.LANES) * self.LANES),)
+
+    def describe(self, program, sizes, backend, page_size, dtype):
+        paged = latent_attention_path(backend, page_size, dtype)
+        (name, width), = self.cache_rows(sizes)
+        return {'attention_path': {'prefill': 'dense_expanded',
+                                   'pack': None, 'step': paged,
+                                   'chunk': '%s+%s' % (paged, paged)
+                                   }[program],
+                'cache_rows': {name: width},
+                'cache_bytes_per_position': width * jnp.dtype(dtype).itemsize}
+
+    def norm(self, x, w):
+        return rms_norm_math(x, w, self.eps)
+
+    def rotate(self, u, positions):
+        return rotary_math(u, positions, self.theta, self.yarn,
+                           interleaved=True)
+
+    def latent_row(self, c_raw, r_raw, w, positions):
+        """A position's cached row (unpadded): the latent AFTER its
+        norm, the rotary key AFTER rotation."""
+        return jnp.concatenate(
+            [self.norm(c_raw, w),
+             self.rotate(r_raw[:, None, :], positions)[:, 0]], axis=-1)
+
+    def route(self, h, router_w, bias):
+        return moe_route_grouped(h, router_w, bias, self.top_k,
+                                 self.n_group, self.topk_group,
+                                 self.routed_scale, self.renormalize)
+
+    def embed(self, p, tokens, positions):
+        return p['dots_embed'][tokens].astype(jnp.float32)
+
+    def qkv(self, p, x, i, positions):
+        n = 'dots_l%d_' % i
+        t = x.shape[0]
+        h = self.norm(x, p[n + 'in_norm_w'])
+        cq = self.norm(_mm(h, p[n + 'qa_w']), p[n + 'q_norm_w'])
+        q = _mm(cq, p[n + 'qb_w']).reshape(t, self.n_heads, -1)
+        q = jnp.concatenate(
+            [q[..., :self.nope], self.rotate(q[..., self.nope:], positions)],
+            axis=-1)
+        rank = p[n + 'kv_norm_w'].shape[0]
+        kva = _mm(h, p[n + 'kva_w'])
+        row = self.latent_row(kva[:, :rank], kva[:, rank:],
+                              p[n + 'kv_norm_w'], positions)
+        return q, self._lanes(row)
+
+    def _lanes(self, u):
+        """``u`` [..., w] with zeros up to the cached row's width."""
+        pad = -u.shape[-1] % self.LANES
+        return jnp.pad(u, [(0, 0)] * (u.ndim - 1) + [(0, pad)])
+
+    def _kvb(self, p, i):
+        """W_kvb [rank, H, nope + v]: per head the key and the value
+        up-projection of the latent."""
+        w = p['dots_l%d_kvb_w' % i]
+        return w.reshape(w.shape[0], self.n_heads, self.nope + self.v_dim)
+
+    def _attend_expanded(self, p, i, q, rows, valid):
+        """q [Tq, H, nope + rope] over cached ``rows`` [Tk, W] where
+        ``valid`` [Tq, Tk]: every head's keys and values rebuilt."""
+        f32 = jnp.float32
+        w = self._kvb(p, i)
+        rank = w.shape[0]
+        kv = jnp.einsum('kc,chd->khd', rows[:, :rank].astype(w.dtype), w,
+                        preferred_element_type=f32)
+        s = jnp.einsum('qhn,khn->hqk', q[..., :self.nope],
+                       kv[..., :self.nope]) \
+            + jnp.einsum('qhr,kr->hqk', q[..., self.nope:],
+                         rows[:, rank:rank + self.rope].astype(f32))
+        s = jnp.where(valid[None], s * self.softmax_scale, -1e30)
+        return jnp.einsum('hqk,khv->qhv', jax.nn.softmax(s, axis=-1),
+                          kv[..., self.nope:])
+
+    def _absorb(self, p, i, q):
+        """The key up-projection folded into the query: [T, H, W]
+        against the cached rows as they are."""
+        w = self._kvb(p, i)
+        qt = jnp.einsum('thn,chn->thc', q[..., :self.nope].astype(w.dtype),
+                        w[..., :self.nope],
+                        preferred_element_type=jnp.float32)
+        return self._lanes(jnp.concatenate([qt, q[..., self.nope:]],
+                                           axis=-1))
+
+    def _attend_absorbed(self, op, p, i, q, pool, **ins):
+        w = self._kvb(p, i)
+        ctx = get_op_impl(op).compute(
+            None, dict({'Q': [self._absorb(p, i, q)], 'Pool': [pool]},
+                       **{k: [v] for k, v in ins.items()}),
+            {'scale': self.softmax_scale, 'value_dim': w.shape[0]}
+        )['Out'][0]
+        return jnp.einsum('thc,chv->thv', ctx.astype(w.dtype),
+                          w[..., self.nope:],
+                          preferred_element_type=jnp.float32)
+
+    def attend_prefill(self, p, i, q, rows):
+        t = q.shape[0]
+        causal = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+        return self._attend_expanded(p, i, q, rows[0], causal), rows
+
+    def attend_chunk(self, p, i, q, pools, pt, pos0):
+        return self._attend_absorbed(
+            'latent_chunked_prefill_attention', p, i, q, pools[0],
+            PT=pt, Pos0=pos0)
+
+    def attend_step(self, p, i, q, pools, pt, ctx_len):
+        return self._attend_absorbed('latent_paged_attention', p, i, q,
+                                     pools[0], PT=pt, CtxLen=ctx_len)
+
+    def after_attention(self, p, x, ctx, i, active):
+        n = 'dots_l%d_' % i
+        x = x + _mm(ctx.reshape(x.shape[0], -1), p[n + 'o_w'])
+        h = self.norm(x, p[n + 'post_norm_w'])
+        ffn = p[n + 'gate_w'], p[n + 'up_w'], p[n + 'down_w']
+        if i < self.first_dense:       # a leading dense layer
+            return x + swiglu_math(h, *ffn), None
+        w, idx = self.route(h, p[n + 'router_w'], p[n + 'router_bias'])
+        y = moe_experts(h, w, idx, *ffn, first=self.first_expert,
+                        shared=(p[n + 'shared_gate_w'],
+                                p[n + 'shared_up_w'],
+                                p[n + 'shared_down_w']))
+        return x + y, moe_counts(idx, ffn[0].shape[0], active,
+                                 first=self.first_expert)
+
+    def head(self, p, x):
+        return _mm(self.norm(x, p['dots_norm_f_w']), p['dots_head_w'])

@@ -7,15 +7,19 @@ inference-throughput fix for decoder-only LMs, TPU-native:
 
 - **prefill/decode split** — a prompt runs ONCE through a full-context
   forward (per-bucket AOT-compiled, page-size-multiple bucket ladder so
-  only ~log2 prefill shapes ever compile), its per-layer K/V land in
+  only ~log2 prefill shapes ever compile), its per-layer rows land in
   claimed cache pages, and its last-position logits yield the first
   token (the TTFT moment).  Every later token is one batched decode
-  step: embed S current tokens, append their K/V into the cache, and
-  attend over pages (ops/attention.py ``paged_attention``).
-- **paged KV cache** — one K and one V page pool PER LAYER, each its
-  own device buffer ``[num_pages + 1, page_size, heads * head_dim]``
-  (lane-dense: a cached position is one contiguous row), with a
-  HOST-side page table and free list.  Streams claim
+  step: embed S current tokens, append their rows to the cache, and
+  attend over pages (ops/attention.py ``paged_attention``, or the
+  block's own).
+- **paged cache** — what a position caches is the block's to describe
+  (``cache_rows``: a K and a V row of heads x head_dim for full
+  multi-head attention, one latent row shared by all heads for latent
+  attention).  One page pool PER ROW PER LAYER, each its own device
+  buffer ``[num_pages + 1, page_size, width]`` (lane-dense: a cached
+  position is one contiguous row), with a HOST-side page table and
+  free list.  Streams claim
   ceil(span/page_size) pages at admission and free them the step they
   finish; a stream's pages need not be contiguous, so the pool packs
   mixed-length streams without fragmentation-driven copies.  The pools
@@ -42,9 +46,10 @@ inference-throughput fix for decoder-only LMs, TPU-native:
 
 There is ONE definition of a decoder's serving path: which decoder is
 served is a block description (inference/blocks.py: ``OptBlock``, the
-default, and ``OlmoeBlock``) that supplies the layer's equations, and
-the engine's ``prefill``, ``chunk`` and ``step`` are one loop over
-layers around them.  No code here names a model or a parameter.
+default, ``OlmoeBlock``, ``DotsVlmBlock``) that supplies the layer's
+equations, what a position caches and how to attend over it, and the
+engine's ``prefill``, ``chunk`` and ``step`` are one loop over layers
+around them.  No code here names a model or a parameter.
 
 Everything device-facing is AOT-compiled at ``warmup()`` via
 ``jit(...).lower(...).compile()`` — the serving loop only ever calls
@@ -65,7 +70,6 @@ import jax.numpy as jnp
 from .. import observability as _obs
 from ..analysis import lockdebug as _lkd
 from ..compile_cache import enable_compile_cache
-from ..core.registry import get_op_impl
 from ..transpiler.memory_model import page_pool_bytes
 from .blocks import OptBlock
 
@@ -108,35 +112,47 @@ def decode_buckets(page_size, top):
 
 
 class PagedKVCache(object):
-    """Device page pools + host free list.  ``k`` and ``v`` are lists
-    of ``n_layers`` jax arrays ``[num_pages + 1, page_size, n_heads *
-    head_dim]``, one buffer per layer, which the engine threads through
+    """Device page pools + host free list.  ``rows`` is what a position
+    caches in every layer, ``((name, width), ...)`` as the block
+    describes it (two rows ``k`` and ``v`` of ``n_heads * head_dim``
+    when none is given); ``pools`` holds, in that order, one list a row
+    of ``n_layers`` jax arrays ``[num_pages + 1, page_size, width]``,
+    one buffer per layer, also reachable by the row's name
+    (``cache.k``, ``cache.latent``).  The engine threads them through
     its donated compiled calls (each aliased to its own output, written
     by a scatter on its page axis).  The minor dimension is the whole
-    heads x head_dim row: a ``[..., n_heads, head_dim]`` pool with
-    head_dim under 128 lanes is held page-minor by the TPU and every
-    program that touches it re-lays-out the whole pool at its edge
-    (PERF.md section 6, PR 25).  The free list / page tables are host
-    state (the server's worker thread owns them — no lock needed beyond
-    the server's own)."""
+    row: a ``[..., n_heads, head_dim]`` pool with head_dim under 128
+    lanes is held page-minor by the TPU and every program that touches
+    it re-lays-out the whole pool at its edge (PERF.md section 6,
+    PR 25).  The free list / page tables are host state (the server's
+    worker thread owns them — no lock needed beyond the server's
+    own)."""
 
-    def __init__(self, n_layers, num_pages, page_size, n_heads,
-                 head_dim, dtype=jnp.float32):
+    def __init__(self, n_layers, num_pages, page_size, n_heads=None,
+                 head_dim=None, dtype=jnp.float32, rows=None):
         self.n_layers = int(n_layers)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        self.n_heads = int(n_heads)
-        self.head_dim = int(head_dim)
+        if rows is None:
+            rows = (('k', n_heads * head_dim), ('v', n_heads * head_dim))
+        self.rows = tuple((str(n), int(w)) for n, w in rows)
         # one extra TRASH page (index num_pages): padded page-table
         # entries and inactive slots direct their writes there, so the
         # compiled step needs no masking on the scatter
         self.trash = self.num_pages
         self.dtype = jnp.dtype(dtype)
-        shape = (self.num_pages + 1, self.page_size,
-                 self.n_heads * self.head_dim)
-        self.k = [jnp.zeros(shape, dtype) for _ in range(self.n_layers)]
-        self.v = [jnp.zeros(shape, dtype) for _ in range(self.n_layers)]
+        self.pools = [
+            [jnp.zeros((self.num_pages + 1, self.page_size, w), dtype)
+             for _ in range(self.n_layers)] for _n, w in self.rows]
         self._free = list(range(self.num_pages))
+
+    def __getattr__(self, name):
+        # a row's buffers by the row's name (only reached for names the
+        # instance does not have)
+        for j, (row, _w) in enumerate(self.__dict__.get('rows', ())):
+            if row == name:
+                return self.pools[j]
+        raise AttributeError(name)
 
     def free_pages(self):
         return len(self._free)
@@ -152,13 +168,15 @@ class PagedKVCache(object):
     def free(self, pages):
         self._free.extend(pages)
 
+    def row_widths(self):
+        return [w for _n, w in self.rows]
+
     def resident_bytes(self):
-        """Golden closed form: layers x {K,V} x pages x page_size x
-        heads x head_dim x dtype (trash page included — it is
-        resident)."""
+        """Golden closed form: layers x pages x page_size x the rows'
+        widths x dtype (trash page included — it is resident)."""
         return page_pool_bytes(self.num_pages + 1, self.page_size,
-                               self.n_heads, self.head_dim,
-                               self.dtype, n_layers=self.n_layers)
+                               dtype=self.dtype, n_layers=self.n_layers,
+                               row_widths=self.row_widths())
 
 
 class _PrefixNode(object):
@@ -283,21 +301,23 @@ class PrefixCache(object):
 class DecodeEngine(object):
     """Compiled prefill/pack/decode executables over one weight set.
 
-    The three programs that write the KV pool — ``pack``, ``chunk`` and
-    ``step`` — take ``cache.k`` and ``cache.v`` (a list of per-layer
-    buffers each) as donated arguments and return them: a layer's new
-    K/V rows are scattered on the page axis of that layer's own buffer
-    and attention gathers pages from the same buffer, so the pools are
-    updated in place.  The ``decode.compile`` span of every program
-    records ``alias_bytes``, ``temp_bytes`` and ``pool_bytes``: in place
-    means the first equals the last and the scratch stays under what
-    the program gathers.
+    The three programs that write the page pools — ``pack``, ``chunk``
+    and ``step`` — take ``cache.pools`` (a list of per-layer buffers for
+    each row the block caches: ``cache.k`` and ``cache.v``, or
+    ``cache.latent``) as donated arguments, one argument a row, and
+    return them: a layer's new rows are scattered on the page axis of
+    that layer's own buffers and attention reads pages from the same
+    buffers, so the pools are updated in place.  The ``decode.compile``
+    span of every program records ``alias_bytes``, ``temp_bytes`` and
+    ``pool_bytes``: in place means the first equals the last and the
+    scratch stays under what the program gathers.
 
     Which decoder is served is the ``block`` handed in (a description
     from inference/blocks.py; ``OptBlock``, the layer of
     models/transformer.py, when none is).  Prefill, chunk and step are
-    ONE loop over layers (``_layers``) that differs only in where K/V
-    rows are written and which attention op reads them.  Every program
+    ONE loop over layers (``_layers``) that differs only in where a
+    position's rows are written and which of the block's three attends
+    reads them.  Every program
     takes the weights as its first parameter; whether ``step`` and
     ``chunk`` read them as that operand or as constants of the
     executable is the block's to say (``_bound``), and the
@@ -321,7 +341,7 @@ class DecodeEngine(object):
             raise ValueError("block has %d heads, engine %d"
                              % (block.n_heads, self.n_heads))
         self.params = self._place(params)
-        sizes = block.sizes(self.params)
+        self.sizes = sizes = block.sizes(self.params)
         self.d_model = sizes['d_model']
         self.vocab_size = sizes['vocab_size']
         table = sizes.get('positions')
@@ -332,11 +352,10 @@ class DecodeEngine(object):
         if table and self.max_seq > table:
             raise ValueError("max_seq %d exceeds the position "
                              "table's %d rows" % (self.max_seq, table))
-        self.head_dim = self.d_model // self.n_heads
         # routed-expert totals over the engine's life (``_routing``);
         # all zero for a block without experts
-        self.routing = {'assignments': 0, 'max_load': 0, 'touched': 0.0,
-                        'steps': 0}
+        self.routing = {'assignments': 0, 'all_assignments': 0,
+                        'max_load': 0, 'touched': 0.0, 'steps': 0}
         # KV pages over the engine's decode steps (``_kv_pages``): the
         # running slots' live ones, and the page tables' entries
         self.kv_pages = {'live': 0, 'table': 0}
@@ -352,8 +371,8 @@ class DecodeEngine(object):
         self.buckets = decode_buckets(self.page_size,
                                       min(top, self.max_seq))
         self.cache = PagedKVCache(self.n_layers, num_pages,
-                                  self.page_size, self.n_heads,
-                                  self.head_dim, dtype)
+                                  self.page_size, dtype=dtype,
+                                  rows=block.cache_rows(sizes))
         self.prefix_enabled = bool(FLAGS.decode_prefix_cache
                                    if prefix_cache is None
                                    else prefix_cache)
@@ -382,7 +401,7 @@ class DecodeEngine(object):
         self.compiles_total = 0
         self._compiles_at_warmup = None
         self._prefill = {}   # bucket -> compiled (params, tokens, last)
-        self._pack = {}      # bucket -> compiled (k, v, pools, pages)
+        self._pack = {}      # bucket -> compiled (*pools, *rows, pages)
         self._chunk = {}     # bucket -> compiled chunked-prefill fn
         self._step = None
         # a decode step's operands with every slot idle (all-trash page
@@ -399,6 +418,10 @@ class DecodeEngine(object):
 
     def _compile(self, fn, *args, donate=(), bucket=None, **span_args):
         span_args.update(program=fn.__name__, bucket=bucket)
+        # the block's part in the program: which attention it takes
+        span_args.update(self.block.describe(
+            fn.__name__, self.sizes, jax.default_backend(),
+            self.page_size, self.cache.dtype))
         with _obs.span('decode.compile', args=span_args):
             compiled = jax.jit(fn, donate_argnums=donate).lower(
                 *args).compile()
@@ -432,19 +455,20 @@ class DecodeEngine(object):
         return bound
 
     def _layers(self, params, x, positions, active, attend):
-        """A block's layers over x [T, D]: ``attend(i, q, k, v)`` is
-        where prefill, chunk and step differ (it writes layer i's K/V
-        rows and returns the attention output [T, H, Dh]).  Returns
-        (x, extra): ``extra`` is what the program returns beside its
-        usual outputs, ``(routing counts [L, E],)`` or ``()``."""
+        """A block's layers over x [T, D]: ``attend(i, q, rows)`` is
+        where prefill, chunk and step differ (it writes the rows layer i
+        caches for these positions and returns the attention output).
+        Returns (x, extra): ``extra`` is what the program returns beside
+        its usual outputs, ``(routing counts [layers that route, n],)``
+        or ``()``."""
         blk, counts = self.block, []
         for i in range(self.n_layers):
-            q, k, v = blk.qkv(params, x, i, positions)
-            ctx = attend(i, q, k.astype(self.cache.dtype),
-                         v.astype(self.cache.dtype))
+            q, *rows = blk.qkv(params, x, i, positions)
+            ctx = attend(i, q, [r.astype(self.cache.dtype) for r in rows])
             x, c = blk.after_attention(params, x, ctx, i, active)
-            counts.append(c)
-        return x, (() if counts[0] is None else (jnp.stack(counts),))
+            if c is not None:
+                counts.append(c)
+        return x, ((jnp.stack(counts),) if counts else ())
 
     @staticmethod
     def _place(params):
@@ -465,13 +489,23 @@ class DecodeEngine(object):
         outputs (``_layers``) -> the span's arguments and the engine's
         totals: assignments (top_k x tokens x layers), experts with a
         token (mean over layers; totalled over decode steps only), most
-        tokens on one expert."""
+        tokens on one expert.  Where the block holds a share of the
+        experts, the counts' last column is the assignments to experts
+        held elsewhere: ``moe_all_assignments`` counts them too, the
+        other three (``moe_held_assignments``, ``moe_held_touched``,
+        ``moe_max_load``) the held experts alone."""
         c = np.asarray(counts)
-        touched = float(np.mean(np.sum(c > 0, axis=1)))
-        span_args.update(moe_assignments=int(c.sum()), moe_touched=touched,
-                         moe_max_load=int(c.max()))
+        held = 'held_' if self.block.experts_share else ''
         tot = self.routing
-        tot['assignments'] += span_args['moe_assignments']
+        if held:
+            span_args['moe_all_assignments'] = int(c.sum())
+            tot['all_assignments'] += int(c.sum())
+            c = c[:, :-1]
+        touched = float(np.mean(np.sum(c > 0, axis=1)))
+        span_args.update({'moe_%sassignments' % held: int(c.sum()),
+                          'moe_%stouched' % held: touched,
+                          'moe_max_load': int(c.max())})
+        tot['assignments'] += int(c.sum())
         tot['max_load'] = max(tot['max_load'], span_args['moe_max_load'])
         if step:
             tot['touched'] += touched
@@ -486,9 +520,12 @@ class DecodeEngine(object):
         the number of running slots."""
         pts = np.asarray(page_tables)
         running = pts[:, 0] != self.cache.trash
-        live = int(np.sum(
-            np.asarray(ctx_lens)[running] // self.page_size + 1))
+        ctx = np.asarray(ctx_lens)[running]
+        live = int(np.sum(ctx // self.page_size + 1))
         span_args.update(kv_live_pages=live, kv_table_pages=pts.size)
+        if self.block.live_positions_arg:
+            # the positions a step's attention reads, the new one too
+            span_args[self.block.live_positions_arg] = int(np.sum(ctx + 1))
         self.kv_pages['live'] += live
         self.kv_pages['table'] += pts.size
         return int(np.sum(running))
@@ -496,19 +533,18 @@ class DecodeEngine(object):
     # -- the three programs: one loop, three ways to attend -------------
 
     @staticmethod
-    def _write_then(k_pool, v_pool, page_idx, offset, read):
-        """``attend`` of chunk and step: layer i's new rows land at
+    def _write_then(pools, page_idx, offset, read):
+        """``attend`` of chunk and step: the rows layer i caches land at
         (page, offset) of its own buffers, then ``read(i, q)`` attends
         over what was written."""
-        def attend(i, q, k, v):
-            k_pool[i] = k_pool[i].at[page_idx, offset].set(k)
-            v_pool[i] = v_pool[i].at[page_idx, offset].set(v)
+        def attend(i, q, rows):
+            for pool, r in zip(pools, rows):
+                pool[i] = pool[i].at[page_idx, offset].set(r)
             return read(i, q)
         return attend
 
     def _prefill_fn(self, bucket):
-        from ..ops.attention import _dense_attention
-        blk, H, Dh = self.block, self.n_heads, self.head_dim
+        blk = self.block
 
         def prefill(params, tokens, last):
             # ``last`` (the prompt's final position) is a traced
@@ -517,26 +553,26 @@ class DecodeEngine(object):
             # per-shape compile (~25-40ms) lands on the first stream
             # of every bucket — invisible to compiles_total
             pos = jnp.arange(bucket)
-            ks, vs = [], []
+            kept = [[] for _ in self.cache.rows]
 
-            def attend(i, q, k, v):
+            def attend(i, q, rows):
                 # attention reads the rows as the cache will hold them
                 # (already in the pools' dtype); pack writes them later
-                ks.append(k.reshape(bucket, H, Dh))
-                vs.append(v.reshape(bucket, H, Dh))
-                return _dense_attention(q[None], ks[-1][None],
-                                        vs[-1][None], True, None)[0]
+                ctx, keep = blk.attend_prefill(params, i, q, rows)
+                for layers, r in zip(kept, keep):
+                    layers.append(r)
+                return ctx
 
             x, extra = self._layers(
                 params, blk.embed(params, tokens, pos), pos, pos <= last,
                 attend)
-            return (blk.head(params, x[last][None])[0], jnp.stack(ks),
-                    jnp.stack(vs)) + extra
+            return (blk.head(params, x[last][None])[0],) + tuple(
+                jnp.stack(layers) for layers in kept) + extra
         return prefill
 
     def _chunk_rows(self, bucket, pt, pos0, n_valid):
         """A chunk's rows: positions, which of them hold a prompt token,
-        and where their K/V rows land."""
+        and where their cached rows land."""
         P, mpp = self.page_size, self.pages_per_stream
         # pos0 and n_valid are traced (host slicing would hide
         # per-shape gather compiles, the prefill lesson); padded
@@ -550,7 +586,7 @@ class DecodeEngine(object):
 
     def _step_rows(self, pt, ctx_len):
         """A decode step's rows, one a slot: positions and where their
-        K/V rows land."""
+        cached rows land."""
         P = self.page_size
         # ctx_len counts CACHED positions per slot; the incoming
         # token sits at position ctx_len and is cached this step
@@ -559,42 +595,35 @@ class DecodeEngine(object):
             pt, (pos // P)[:, None], axis=1)[:, 0]
         return pos, page_idx, pos % P
 
-    @staticmethod
-    def _chunk_read(k_pool, v_pool, pt, pos0):
-        chunk_att = get_op_impl('chunked_prefill_attention').compute
-
+    def _chunk_read(self, params, pools, pt, pos0):
         def read(i, q):
-            return chunk_att(None, {'Q': [q], 'KPool': [k_pool[i]],
-                                    'VPool': [v_pool[i]],
-                                    'PT': [pt], 'Pos0': [pos0]},
-                             {})['Out'][0]
+            return self.block.attend_chunk(
+                params, i, q, [pool[i] for pool in pools], pt, pos0)
         return read
 
-    @staticmethod
-    def _step_read(k_pool, v_pool, pt, pos):
-        paged = get_op_impl('paged_attention').compute
-
+    def _step_read(self, params, pools, pt, pos):
         def read(i, q):
-            return paged(None, {'Q': [q], 'KPool': [k_pool[i]],
-                                'VPool': [v_pool[i]], 'PT': [pt],
-                                'CtxLen': [pos + 1]}, {})['Out'][0]
+            return self.block.attend_step(
+                params, i, q, [pool[i] for pool in pools], pt, pos + 1)
         return read
 
     def _chunk_fn(self, bucket):
         blk, S, trash = self.block, self.max_streams, self.cache.trash
+        n = len(self.cache.rows)
 
-        def chunk(params, k_pool, v_pool, tokens, pt, pos0, n_valid,
-                  step_tokens, step_pt, ctx_len):
+        def chunk(params, *args):
             # one pass over S + bucket rows: the tick's decode rows (as
             # ``step`` takes them; all-trash page tables carry none),
             # then the chunk's.  Rows never mix in a layer, so each
             # group comes out as its own program would give it
+            pools = [list(pool) for pool in args[:n]]
+            tokens, pt, pos0, n_valid, step_tokens, step_pt, ctx_len = \
+                args[n:]
             spos, spage, soffset = self._step_rows(step_pt, ctx_len)
             pos, valid, page_idx, offset = self._chunk_rows(
                 bucket, pt, pos0, n_valid)
-            k_pool, v_pool = list(k_pool), list(v_pool)
-            read_step = self._step_read(k_pool, v_pool, step_pt, spos)
-            read_chunk = self._chunk_read(k_pool, v_pool, pt, pos0)
+            read_step = self._step_read(params, pools, step_pt, spos)
+            read_chunk = self._chunk_read(params, pools, pt, pos0)
 
             def read(i, q):
                 return jnp.concatenate([read_step(i, q[:S]),
@@ -609,63 +638,74 @@ class DecodeEngine(object):
                               [spos, jnp.clip(pos, 0, self.max_seq - 1)])),
                 jnp.concatenate([spos, pos]),
                 jnp.concatenate([step_pt[:, 0] != trash, valid]),
-                self._write_then(k_pool, v_pool,
+                self._write_then(pools,
                                  jnp.concatenate([spage, page_idx]),
                                  jnp.concatenate([soffset, offset]), read))
             # the head on the decode rows and the chunk's last valid row
             last = S + jnp.clip(n_valid - 1, 0, bucket - 1)
             logits = blk.head(params,
                               jnp.concatenate([x[:S], x[last][None]]))
-            return (k_pool, v_pool, logits[S],
-                    jnp.argmax(logits[:S], axis=-1), logits[:S]) + extra
+            return tuple(pools) + (
+                logits[S], jnp.argmax(logits[:S], axis=-1),
+                logits[:S]) + extra
         return self._bound(chunk)
 
     def _step_fn(self):
         blk, trash = self.block, self.cache.trash
+        n = len(self.cache.rows)
 
-        def step(params, k_pool, v_pool, tokens, pt, ctx_len):
+        def step(params, *args):
+            pools = [list(pool) for pool in args[:n]]
+            tokens, pt, ctx_len = args[n:]
             pos, page_idx, offset = self._step_rows(pt, ctx_len)
-            k_pool, v_pool = list(k_pool), list(v_pool)
             # an inactive slot's page table is all trash: it runs (its
             # rows never meet another slot's) and is not counted
             x, extra = self._layers(
                 params, blk.embed(params, tokens, pos), pos,
                 pt[:, 0] != trash,
-                self._write_then(k_pool, v_pool, page_idx, offset,
-                                 self._step_read(k_pool, v_pool, pt, pos)))
+                self._write_then(pools, page_idx, offset,
+                                 self._step_read(params, pools, pt, pos)))
             logits = blk.head(params, x)
-            return (k_pool, v_pool, logits,
-                    jnp.argmax(logits, axis=-1)) + extra
+            return tuple(pools) + (logits,
+                                   jnp.argmax(logits, axis=-1)) + extra
         return self._bound(step)
+
+    def _pools_out(self, out):
+        """A pool program's outputs: the pools go back into the cache,
+        the rest is returned."""
+        n = len(self.cache.rows)
+        self.cache.pools = [list(pool) for pool in out[:n]]
+        return out[n:]
 
     def _ensure_prefill(self, bucket):
         if bucket in self._prefill:
             return
-        L, H, Dh, P = (self.n_layers, self.n_heads, self.head_dim,
-                       self.page_size)
+        L, P, n = self.n_layers, self.page_size, len(self.cache.rows)
         n_pages = bucket // P
 
-        def pack(k_pool, v_pool, k, v, pages):
-            # scatter the prefill K/V into the claimed pages: [L, T, H,
-            # Dh] -> [L, n_pages, P, H * Dh], layer i written at
+        def pack(*args):
+            # scatter the rows the prefill kept into the claimed pages:
+            # [L, T, ...] -> [L, n_pages, P, width], layer i written at
             # ``pages`` of its own buffer (padded entries point at the
             # trash page)
-            kp = k.reshape(L, n_pages, P, H * Dh)
-            vp = v.reshape(L, n_pages, P, H * Dh)
-            return ([pool.at[pages].set(kp[i])
-                     for i, pool in enumerate(k_pool)],
-                    [pool.at[pages].set(vp[i])
-                     for i, pool in enumerate(v_pool)])
+            pools, kept, pages = args[:n], args[n:2 * n], args[2 * n]
+            out = []
+            for row_pools, rows in zip(pools, kept):
+                paged = rows.reshape(L, n_pages, P, -1)
+                out.append([pool.at[pages].set(paged[i])
+                            for i, pool in enumerate(row_pools)])
+            return tuple(out)
 
         toks = jnp.zeros((bucket,), jnp.int32)
+        prefill = self._prefill_fn(bucket)
         self._prefill[bucket] = self._compile(
-            self._prefill_fn(bucket), self.params, toks, jnp.int32(0),
-            bucket=bucket)
-        kv = jnp.zeros((L, bucket, H, Dh), self.cache.dtype)
+            prefill, self.params, toks, jnp.int32(0), bucket=bucket)
+        kept = jax.eval_shape(prefill, self.params, toks,
+                              jnp.int32(0))[1:1 + n]
         pages = jnp.zeros((n_pages,), jnp.int32)
         self._pack[bucket] = self._compile(
-            pack, self.cache.k, self.cache.v, kv, kv, pages,
-            donate=(0, 1), bucket=bucket)
+            pack, *self.cache.pools, *kept, pages,
+            donate=tuple(range(n)), bucket=bucket)
 
     def _ensure_chunk(self, bucket):
         """Chunked-prefill executable for one chunk bucket: a SINGLE
@@ -682,26 +722,22 @@ class DecodeEngine(object):
         rows' next tokens [S] and logits [S, V]."""
         if bucket in self._chunk:
             return
+        n = len(self.cache.rows)
         self._chunk[bucket] = self._compile(
-            self._chunk_fn(bucket), self.params, self.cache.k,
-            self.cache.v, jnp.zeros((bucket,), jnp.int32),
+            self._chunk_fn(bucket), self.params, *self.cache.pools,
+            jnp.zeros((bucket,), jnp.int32),
             jnp.full((self.pages_per_stream,), self.cache.trash,
                      jnp.int32),
             jnp.int32(0), jnp.int32(1), *self._idle_step,
-            donate=(1, 2), bucket=bucket)
+            donate=tuple(range(1, 1 + n)), bucket=bucket)
 
     def _ensure_step(self):
         if self._step is not None:
             return
-        from ..ops.attention import paged_attention_path
         self._step = self._compile(
-            self._step_fn(), self.params, self.cache.k, self.cache.v,
-            *self._idle_step, donate=(1, 2),
-            # what the op's dispatch takes for these shapes (the step
-            # calls it with no context: the default backend)
-            attention=paged_attention_path(
-                jax.default_backend(), self.head_dim, self.page_size,
-                self.cache.dtype))
+            self._step_fn(), self.params, *self.cache.pools,
+            *self._idle_step,
+            donate=tuple(range(1, 1 + len(self.cache.rows))))
 
     def warmup(self):
         """AOT-compile every prefill bucket, its pack, and the decode
@@ -725,28 +761,27 @@ class DecodeEngine(object):
             self._ensure_step()
             mpp = self.pages_per_stream
             for b in self.chunk_buckets:
-                self.cache.k, self.cache.v, logits = self._chunk[b](
-                    self.params, self.cache.k, self.cache.v,
+                logits = self._pools_out(self._chunk[b](
+                    self.params, *self.cache.pools,
                     jnp.zeros((b,), jnp.int32),
                     jnp.full((mpp,), trash, jnp.int32),
-                    jnp.int32(0), jnp.int32(b), *self._idle_step)[:3]
+                    jnp.int32(0), jnp.int32(b), *self._idle_step))[0]
                 jax.block_until_ready(logits)
         else:
             for b in self.buckets:
                 self._ensure_prefill(b)
             self._ensure_step()
             for b in self.buckets:
-                logits, k, v = self._prefill[b](
+                logits, *kept = self._prefill[b](
                     self.params, jnp.zeros((b,), jnp.int32),
-                    jnp.int32(0))[:3]
+                    jnp.int32(0))[:1 + len(self.cache.rows)]
                 all_trash = jnp.full((b // self.page_size,), trash,
                                      jnp.int32)
-                self.cache.k, self.cache.v = self._pack[b](
-                    self.cache.k, self.cache.v, k, v, all_trash)
+                self._pools_out(self._pack[b](
+                    *self.cache.pools, *kept, all_trash))
                 jax.block_until_ready(logits)
-        self.cache.k, self.cache.v, logits = self._step(
-            self.params, self.cache.k, self.cache.v,
-            *self._idle_step)[:3]
+        logits = self._pools_out(self._step(
+            self.params, *self.cache.pools, *self._idle_step))[0]
         jax.block_until_ready(logits)
         self._compiles_at_warmup = self.compiles_total
 
@@ -767,7 +802,7 @@ class DecodeEngine(object):
             % (prompt_len, self.buckets[-1]))
 
     def prefill_into(self, prompt, pages):
-        """Run one prompt's prefill and pack its K/V into ``pages``
+        """Run one prompt's prefill and pack its rows into ``pages``
         (the stream's claimed pages, page 0 of the stream first).
         Returns the last-position logits as numpy [V] — the first
         generated token's distribution, i.e. the TTFT payload."""
@@ -779,14 +814,16 @@ class DecodeEngine(object):
             self._ensure_prefill(bucket)
             toks = np.zeros((bucket,), np.int32)
             toks[:t] = prompt
-            logits, k, v, *extra = self._prefill[bucket](
+            n = len(self.cache.rows)
+            logits, *rest = self._prefill[bucket](
                 self.params, jnp.asarray(toks), jnp.int32(t - 1))
+            kept, extra = rest[:n], rest[n:]
             n_pages = bucket // self.page_size
             page_ids = np.full((n_pages,), self.cache.trash, np.int32)
             n_real = min(len(pages), n_pages)
             page_ids[:n_real] = pages[:n_real]
-            self.cache.k, self.cache.v = self._pack[bucket](
-                self.cache.k, self.cache.v, k, v, jnp.asarray(page_ids))
+            self._pools_out(self._pack[bucket](
+                *self.cache.pools, *kept, jnp.asarray(page_ids)))
             if extra:
                 self._routing(extra[0], args)
             return np.asarray(logits)
@@ -843,10 +880,10 @@ class DecodeEngine(object):
             carried = self._idle_step if step_tokens is None else tuple(
                 np.asarray(a, dtype=np.int32)
                 for a in (step_tokens, page_tables, ctx_lens))
-            self.cache.k, self.cache.v, logits, nxt, step_logits, \
-                *extra = self._chunk[bucket](
-                    self.params, self.cache.k, self.cache.v, toks, pt,
-                    np.int32(pos0), np.int32(c), *carried)
+            logits, nxt, step_logits, *extra = self._pools_out(
+                self._chunk[bucket](
+                    self.params, *self.cache.pools, toks, pt,
+                    np.int32(pos0), np.int32(c), *carried))
             if extra:
                 self._routing(extra[0], args)
             if step_tokens is None:
@@ -866,12 +903,11 @@ class DecodeEngine(object):
         args = {}   # the step's KV pages and routing counts
         with _obs.span('decode.step', args=args):
             with _obs.span('decode.step.dispatch'):
-                self.cache.k, self.cache.v, logits, nxt, *extra = \
-                    self._step(
-                        self.params, self.cache.k, self.cache.v,
-                        jnp.asarray(tokens, dtype=jnp.int32),
-                        jnp.asarray(page_tables, dtype=jnp.int32),
-                        jnp.asarray(ctx_lens, dtype=jnp.int32))
+                logits, nxt, *extra = self._pools_out(self._step(
+                    self.params, *self.cache.pools,
+                    jnp.asarray(tokens, dtype=jnp.int32),
+                    jnp.asarray(page_tables, dtype=jnp.int32),
+                    jnp.asarray(ctx_lens, dtype=jnp.int32)))
             with _obs.span('decode.step.fetch'):
                 self._kv_pages(page_tables, ctx_lens, args)
                 if extra:
@@ -1120,8 +1156,9 @@ class DecodeServer(object):
                 # pool resident_bytes already reports — this is the
                 # trie-held subset an eviction sweep could reclaim
                 'prefix_cached_bytes': prefix_cached_bytes(
-                    cached, eng.page_size, eng.n_heads, eng.head_dim,
-                    eng.cache.dtype, n_layers=eng.n_layers),
+                    cached, eng.page_size, dtype=eng.cache.dtype,
+                    n_layers=eng.n_layers,
+                    row_widths=eng.cache.row_widths()),
                 'submitted': self._submitted,
                 'completed': self._completed,
                 'dropped': 0,  # admission queues, never sheds
@@ -1140,6 +1177,10 @@ class DecodeServer(object):
                 # call, and the experts a decode step touched (mean over
                 # layers and steps)
                 'moe_assignments': self.engine.routing['assignments'],
+                # where the engine holds a share of the experts, those
+                # are the held experts' and these count the rest too
+                'moe_all_assignments':
+                    self.engine.routing['all_assignments'],
                 'moe_max_load': self.engine.routing['max_load'],
                 'moe_touched_mean': self.engine.routing['touched']
                 / max(self.engine.routing['steps'], 1),
@@ -1280,8 +1321,12 @@ class DecodeServer(object):
 
     def _plan_prefill_chunks(self, active):
         """This tick's prefill chunks, [(stream, lo, hi)] in the order
-        they run: the per-tick token budget, round-robin across streams
-        so one long prompt cannot starve another's TTFT.  Budget 0 =
+        they run: AT MOST the per-tick token budget (a tick always runs
+        one chunk; a further one only if it fits in what the first left,
+        so a prompt's ragged last chunk is not followed by another
+        prompt's whole one: every chunk is a pass over the weights, and
+        the budget is what bounds a tick), round-robin across streams so
+        one long prompt cannot starve another's TTFT.  Budget 0 =
         unlimited (whole prefill now)."""
         eng = self.engine
         budget = eng.chunk_tokens if eng.chunk_tokens > 0 else None
@@ -1293,13 +1338,13 @@ class DecodeServer(object):
         plan, used = [], 0
         for st in pending[rr:] + pending[:rr]:
             lo, t = st._prefill_pos, len(st._prompt_eff)
-            while lo < t and (budget is None or used < budget):
+            while lo < t:
                 hi = min(lo + eng.chunk_grid, t)
+                if budget is not None and plan and used + hi - lo > budget:
+                    return plan
                 plan.append((st, lo, hi))
                 used += hi - lo
                 lo = hi
-            if budget is not None and used >= budget:
-                break
         return plan
 
     def _run_prefill_chunks(self, plan, step_operands, rows):

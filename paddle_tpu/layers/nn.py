@@ -352,46 +352,76 @@ def rms_norm(input, epsilon=1e-05, param_attr=None, name=None, **kwargs):
     return out
 
 
-def rotary_embedding(x, pos=None, theta=10000.0, **kwargs):
+def rotary_embedding(x, pos=None, theta=10000.0, yarn=None,
+                     interleaved=False, **kwargs):
     """Rotary position embedding of ``x`` [..., T, H, Dh]; ``pos``
-    [..., T] int positions (0..T-1 when None)."""
+    [..., T] int positions (0..T-1 when None).  ``yarn`` = {factor,
+    beta_fast, beta_slow, original_max} takes YaRN's frequencies;
+    ``interleaved`` pairs lanes (2j, 2j + 1), not (j, j + Dh/2)."""
     helper = LayerHelper('rotary_embedding', **locals())
     out = helper.create_tmp_variable(x.dtype)
     inputs = {'X': [x]}
     if pos is not None:
         inputs['Pos'] = [pos]
+    attrs = {'theta': float(theta), 'interleaved': bool(interleaved)}
+    if yarn:
+        attrs.update(yarn_factor=float(yarn['factor']),
+                     yarn_beta_fast=float(yarn.get('beta_fast', 32.0)),
+                     yarn_beta_slow=float(yarn.get('beta_slow', 1.0)),
+                     yarn_original_max=int(yarn.get('original_max', 4096)))
     helper.append_op(type='rotary_embedding', inputs=inputs,
-                     outputs={'Out': [out]}, attrs={'theta': float(theta)})
+                     outputs={'Out': [out]}, attrs=attrs)
     return out
 
 
 def moe_ffn(input, num_experts, expert_size, top_k, norm_topk_prob=False,
             router_attr=None, gate_attr=None, up_attr=None, down_attr=None,
-            dtype='float32', name=None, **kwargs):
+            dtype='float32', name=None, n_group=0, topk_group=0,
+            routed_scaling_factor=1.0, bias_attr=None, router_width=None,
+            first_expert=None, shared_size=0, shared_gate_attr=None,
+            shared_up_attr=None, shared_down_attr=None, **kwargs):
     """Routed-expert FFN (ops/moe.py ``moe_ffn``): every token of
     ``input`` [..., D] reaches its ``top_k`` of ``num_experts`` SiLU-gated
     experts of width ``expert_size``; no capacity, nothing dropped.  The
     experts are three stacked parameters of ``dtype`` ([E, D, F], [E, D,
     F], [E, F, D]); the router [D, E] is float32.  Returns (out, counts)
-    — counts [E] int32, tokens routed to each expert."""
+    — counts [E] int32, tokens routed to each expert.
+
+    ``n_group`` > 0 takes the grouped sigmoid router (``topk_group``
+    groups kept, weights times ``routed_scaling_factor``, a float32
+    correction bias [router width] under ``bias_attr``).  With
+    ``first_expert`` the layer holds ``num_experts`` experts, ``first_expert
+    ..``, of a router ``router_width`` wide (counts gains a last entry:
+    assignments to experts held elsewhere).  ``shared_size`` > 0 adds a
+    shared expert of that width."""
     helper = LayerHelper('moe_ffn', **locals())
     d, e, f = int(input.shape[-1]), int(num_experts), int(expert_size)
+    r = int(router_width or e)
     to_attr = helper.param_attr.to_attr
-    params = {}
-    for slot, attr, shape, dt in (
-            ('RouterW', router_attr, [d, e], 'float32'),
-            ('GateW', gate_attr, [e, d, f], dtype),
-            ('UpW', up_attr, [e, d, f], dtype),
-            ('DownW', down_attr, [e, f, d], dtype)):
-        params[slot] = [helper.create_parameter(
-            attr=to_attr(attr), shape=shape, dtype=dt)]
+    slots = [('RouterW', router_attr, [d, r], 'float32'),
+             ('GateW', gate_attr, [e, d, f], dtype),
+             ('UpW', up_attr, [e, d, f], dtype),
+             ('DownW', down_attr, [e, f, d], dtype)]
+    attrs = {'top_k': int(top_k), 'norm_topk_prob': bool(norm_topk_prob)}
+    if n_group:
+        slots.append(('RouterBias', bias_attr, [r], 'float32'))
+        attrs.update(n_group=int(n_group), topk_group=int(topk_group),
+                     routed_scaling_factor=float(routed_scaling_factor))
+    if first_expert is not None:
+        attrs['first_expert'] = int(first_expert)
+    if shared_size:
+        fs = int(shared_size)
+        slots += [('SharedGateW', shared_gate_attr, [d, fs], dtype),
+                  ('SharedUpW', shared_up_attr, [d, fs], dtype),
+                  ('SharedDownW', shared_down_attr, [fs, d], dtype)]
+    params = {slot: [helper.create_parameter(
+        attr=to_attr(attr), shape=shape, dtype=dt)]
+        for slot, attr, shape, dt in slots}
     out = helper.create_tmp_variable(helper.input_dtype())
     counts = helper.create_tmp_variable('int32', stop_gradient=True)
     helper.append_op(
         type='moe_ffn', inputs=dict(params, X=[input]),
-        outputs={'Out': [out], 'Counts': [counts]},
-        attrs={'top_k': int(top_k),
-               'norm_topk_prob': bool(norm_topk_prob)})
+        outputs={'Out': [out], 'Counts': [counts]}, attrs=attrs)
     return out, counts
 
 

@@ -8,6 +8,8 @@ executor's place is NOT a TPU (ctx.backend), the op computes the same
 math densely in jnp — a CPUPlace run on a TPU-attached host must not
 compile Pallas for CPU, and interpret mode would be orders slower.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -149,6 +151,126 @@ def _paged_attention(ctx, ins, attrs):
     return out(attend(
         q, k_pool, v_pool, page_table.astype(jnp.int32),
         ctx_len.astype(jnp.int32), scale=attrs.get('scale', None)))
+
+
+# -- a shared latent row (multi-head latent attention) ---------------------
+#
+# DeepSeek-V2's MLA (arXiv:2405.04434) caches ONE row a position, shared
+# by every head: [the compressed KV latent after its norm | the rotary
+# key after rotation].  With the key and value up-projections absorbed
+# into the query and the output, attention over the cache is, per head,
+# softmax(q_h . row * scale) over the positions, times the rows' first
+# ``value_dim`` lanes (the latent alone).  ``q`` [.., H, W] are the
+# absorbed queries, W the row's width.
+
+def _latent_attend(q, rows, valid, scale, value_dim):
+    """q [R, H, W] over rows [R, T, W] where ``valid`` [R, T]."""
+    f32 = jnp.float32
+    rows = rows.astype(f32)
+    scores = jnp.einsum('rhw,rtw->rht', q.astype(f32), rows) * scale
+    scores = jnp.where(valid[:, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum('rht,rtc->rhc', probs, rows[..., :value_dim])
+
+
+def latent_paged_attention_math(q, pool, page_table, ctx_len, scale,
+                                value_dim):
+    """Decode-step attention against a paged latent cache: ``q``
+    [S, H, W], one token a slot; ``pool`` [N, P, W]; ``page_table``
+    [S, MPP]; ``ctx_len`` [S] valid positions, the current one included.
+    Returns float32 [S, H, value_dim].  Gathers whole page tables and
+    masks as ``paged_attention_math`` does."""
+    n, p, w = pool.shape
+    s, mpp = page_table.shape
+    rows = pool[jnp.clip(page_table, 0, n - 1)].reshape(s, mpp * p, w)
+    valid = jnp.arange(mpp * p)[None, :] < ctx_len[:, None]
+    return _latent_attend(q, rows, valid, scale, value_dim)
+
+
+def latent_chunked_prefill_attention_math(q, pool, page_table, pos0, scale,
+                                          value_dim):
+    """Chunked-prefill attention for ONE stream against its latent
+    pages: ``q`` [C, H, W], query ``i`` at absolute position ``pos0 +
+    i``; ``page_table`` [MPP]; a row at position ``j`` is valid for
+    query ``i`` iff ``j <= pos0 + i``, as in
+    ``chunked_prefill_attention_math``.  Returns float32
+    [C, H, value_dim]."""
+    n, p, w = pool.shape
+    c, mpp = q.shape[0], page_table.shape[0]
+    rows = pool[jnp.clip(page_table, 0, n - 1)].reshape(1, mpp * p, w)
+    valid = jnp.arange(mpp * p)[None, :] <= (pos0 + jnp.arange(c))[:, None]
+    return _latent_attend(q, jnp.broadcast_to(rows, (c,) + rows.shape[1:]),
+                          valid, scale, value_dim)
+
+
+# tokens of a prompt chunk that share one pass over the stream's pages
+# in the live-pages kernel: 8 x 128 heads = 1024 query rows a block
+_LATENT_CHUNK_GROUP = 8
+
+
+def latent_attention_path(backend, page_size, dtype):
+    """What the two latent ops run for these shapes: ``'pallas_latent'``
+    (ops/pallas/paged_attention.py ``latent_paged_attention``, live
+    pages only) on a TPU when a page is a whole number of the pool
+    dtype's sublane tiles, else ``'xla_gather'`` (the math above)."""
+    if backend == 'tpu':
+        from .pallas.paged_attention import latent_supported
+        if latent_supported(page_size, dtype):
+            return 'pallas_latent'
+    return 'xla_gather'
+
+
+@register_op('latent_paged_attention')
+def _latent_paged_attention(ctx, ins, attrs):
+    """Decode-step attention over a paged cache of ONE latent row a
+    position, shared by all heads (MLA, absorbed form): Q [S, H, W],
+    Pool [N, P, W], PT [S, MPP], CtxLen [S]; ``scale``; Out [S, H,
+    value_dim] float32 = softmax(Q . row) x row[:value_dim].  On a TPU
+    a Pallas kernel over the live pages, else a gather of the page
+    tables."""
+    q = first(ins, 'Q')
+    pool = first(ins, 'Pool')
+    pt = first(ins, 'PT').astype(jnp.int32)
+    ctx_len = first(ins, 'CtxLen').astype(jnp.int32)
+    scale = float(attrs.get('scale', q.shape[-1] ** -0.5))
+    value_dim = int(attrs.get('value_dim', q.shape[-1]))
+    backend = getattr(ctx, 'backend', jax.default_backend())
+    if latent_attention_path(backend, pool.shape[1],
+                             pool.dtype) == 'pallas_latent':
+        from .pallas.paged_attention import latent_paged_attention
+        return out(latent_paged_attention(q, pool, pt, ctx_len, scale,
+                                          value_dim))
+    return out(latent_paged_attention_math(q, pool, pt, ctx_len, scale,
+                                           value_dim))
+
+
+@register_op('latent_chunked_prefill_attention')
+def _latent_chunked_prefill_attention(ctx, ins, attrs):
+    """One stream's prompt chunk over its latent pages (MLA, absorbed
+    form): Q [C, H, W] at positions Pos0 .., Pool [N, P, W], PT [MPP];
+    causal on the absolute-position grid; Out [C, H, value_dim]
+    float32.  On a TPU the live-pages kernel, the chunk's rows in
+    groups that share a pass over the pages."""
+    q = first(ins, 'Q')
+    pool = first(ins, 'Pool')
+    pt = first(ins, 'PT').astype(jnp.int32)
+    pos0 = jnp.asarray(first(ins, 'Pos0'), jnp.int32).reshape(())
+    scale = float(attrs.get('scale', q.shape[-1] ** -0.5))
+    value_dim = int(attrs.get('value_dim', q.shape[-1]))
+    backend = getattr(ctx, 'backend', jax.default_backend())
+    c = q.shape[0]
+    group = math.gcd(c, _LATENT_CHUNK_GROUP)
+    if latent_attention_path(backend, pool.shape[1],
+                             pool.dtype) == 'pallas_latent':
+        from .pallas.paged_attention import latent_paged_attention
+        groups = c // group
+        # the positions the last token of each group sees
+        ctx_len = pos0 + (jnp.arange(groups) + 1) * group
+        return out(latent_paged_attention(
+            q, pool, jnp.broadcast_to(pt, (groups,) + pt.shape), ctx_len,
+            scale, value_dim, group=group))
+    return out(latent_chunked_prefill_attention_math(
+        q, pool, pt, pos0, scale, value_dim))
 
 
 @register_op('flash_attention')

@@ -36,7 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ['paged_attention', 'supported']
+__all__ = ['paged_attention', 'supported', 'latent_paged_attention',
+           'latent_supported']
 
 _NEG_INF = -1e30
 # positions a block: 8 pages of 16.  Measured on a v5e, one layer of 32
@@ -198,3 +199,173 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
       q.astype(jnp.float32).reshape(s, 1, hd),
       k_pool.reshape(n, page, hd), v_pool.reshape(n, page, hd))
     return out.reshape(s, h, d)
+
+
+# -- a shared latent row (multi-head latent attention) ---------------------
+
+# positions a block of the latent kernel: 16 pages of 16.  A page of one
+# 640-lane bf16 row is a 20 KB DMA, a tenth of a K + V page above, so the
+# work a block is what amortises a block's fixed cost.  Measured on a v5e
+# (my chip run, PR 32; one layer, 128 heads, bf16), 256 chunk rows in
+# groups of 8 over 256 / 2048 / 3840 positions: 0.44 / 2.25 / 4.03 ms at
+# 128, 0.39 / 1.31 / 2.21 at 256, 0.49 / 1.21 / 2.16 at 512 (groups of 16
+# or 32: within 10%); a decode step of 64 slots, 24 of them running over
+# 54k positions: 0.62 / 0.48 / 0.45 ms.  Tried and taken back: one copy
+# for a run of 16 pages that follow one another in the pool (0.73 ms a
+# step against 0.75: the copies' issue is not what a block waits for),
+# and blocks of 768 or 1024 positions for a chunk's rows (a chunk's
+# cost becomes a coarse step function of its context and the judged
+# 95th percentile hops between two steps: spread 2.6% and 3.6% over six
+# seeds against 1.2-1.6% at 256; PERF.md section 6, PR 32).
+_LATENT_BLOCK_POSITIONS = 256
+
+
+def latent_supported(page_size, dtype):
+    """Whether ``latent_paged_attention`` takes these shapes: pages that
+    are whole sublane tiles of the pool's dtype (the row may be any
+    width: a block is one [T, W] matrix every head multiplies)."""
+    return page_size % _sublane_rows(dtype) == 0
+
+
+def _latent_kernel(pt_ref, len_ref, q_ref, pool_hbm, o_ref, buf, sem,
+                   m_scr, l_scr, acc_scr, *, scale, page, ppb, mpp, heads,
+                   group, value_dim, precision):
+    g = pl.program_id(0)
+    ctx = len_ref[g]            # positions the group's LAST token sees
+    n_pages = pl.cdiv(ctx, page)
+    n_blocks = pl.cdiv(n_pages, ppb)
+    rows = group * heads
+    t = ppb * page
+
+    def on_live_pages(blk, slot, act):
+        for j in range(ppb):
+            @pl.when(blk * ppb + j < n_pages)
+            def _():
+                pid = pt_ref[g * mpp + blk * ppb + j]
+                act(pltpu.make_async_copy(
+                    pool_hbm.at[pid], buf.at[slot, pl.ds(j * page, page)],
+                    sem.at[slot]))
+
+    def start(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        on_live_pages(0, 0, start)
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    q = q_ref[0]                               # [rows, W], pool dtype
+    # token r of the group sits ``group - 1 - r`` positions before the
+    # last and sees that many fewer; its heads are rows r*H .. r*H+H-1
+    tok = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads
+    row_ctx = ctx - (group - 1 - tok)
+
+    def block(blk, carry):
+        slot = jax.lax.rem(blk, 2)
+
+        @pl.when(blk + 1 < n_blocks)
+        def _prefetch():
+            on_live_pages(blk + 1, 1 - slot, start)
+
+        on_live_pages(blk, slot, wait)
+
+        @pl.when(ctx < (blk + 1) * t)
+        def _zero_dead_rows():
+            # rows past the live positions hold whatever was there:
+            # their p is 0, and 0 * NaN is not
+            at = blk * t + jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)
+            buf[slot] = jnp.where(at < ctx, buf[slot],
+                                  jnp.zeros((), buf.dtype))
+
+        kv = buf[slot]                                      # [t, W]
+        sc = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=precision) * scale                    # [rows, t]
+        pos = blk * t + jax.lax.broadcasted_iota(jnp.int32, (rows, t), 1)
+        sc = jnp.where(pos < row_ctx, sc, _NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)     # every row sees position 0: m is real
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1,
+                                                  keepdims=True)
+        m_scr[...] = m_new
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(kv.dtype), kv[:, :value_dim],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    l = l_scr[...]
+    o_ref[0] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=('scale', 'value_dim', 'group',
+                                             'block_positions',
+                                             'interpret'))
+def latent_paged_attention(q, pool, page_table, ctx_len, scale, value_dim,
+                           group=1, block_positions=_LATENT_BLOCK_POSITIONS,
+                           interpret=False):
+    """``latent_paged_attention_math``'s result from the live pages
+    alone.  ``q`` [G * group, H, W]: G groups of ``group`` tokens at
+    consecutive positions of ONE stream (a decode step: group 1, a
+    group a slot; a prompt chunk: its rows cut into groups); ``pool``
+    [N, P, W], one row a position shared by every head; ``page_table``
+    [G, MPP] the group's stream's pages; ``ctx_len`` [G] the positions
+    the group's LAST token attends over, itself included (token r of
+    the group sees ``group - 1 - r`` fewer).  Returns float32
+    [G * group, H, value_dim]: softmax(q . row * scale) over a token's
+    positions, times the rows' first ``value_dim`` lanes.
+
+    One grid step is one group: its ``group * H`` query rows multiply a
+    block of pages as one ``[group * H, W] x [W, T]`` product (nothing
+    block-diagonal: the row is every head's), f32 online softmax over
+    blocks, ``p @ block[:, :value_dim]``.  The page table and the
+    lengths are scalar-prefetched, pages come one DMA each, the next
+    block in flight while this one is multiplied, as in
+    ``paged_attention``.  The caller tests ``latent_supported``."""
+    n_tok, heads, w = q.shape
+    n, page = pool.shape[0], pool.shape[1]
+    groups, mpp = page_table.shape
+    rows = group * heads
+    dtype = pool.dtype
+    ppb = max(1, min(mpp, block_positions // page))
+    kernel = functools.partial(
+        _latent_kernel, scale=float(scale), page=page, ppb=ppb, mpp=mpp,
+        heads=heads, group=group, value_dim=value_dim,
+        precision=(jax.lax.Precision.HIGHEST if dtype == jnp.float32
+                   else None))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((groups, rows, value_dim),
+                                       jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(groups,),
+            in_specs=[pl.BlockSpec((1, rows, w), lambda i, pt, ln: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, rows, value_dim),
+                                   lambda i, pt, ln: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb * page, w), dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, value_dim), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name='latent_paged_attention_live_pages',
+        interpret=interpret,
+    )(jnp.clip(page_table.astype(jnp.int32), 0, n - 1).reshape(-1),
+      jnp.clip(ctx_len.astype(jnp.int32), 0, mpp * page),
+      q.astype(dtype).reshape(groups, rows, w), pool)
+    return out.reshape(n_tok, heads, value_dim)

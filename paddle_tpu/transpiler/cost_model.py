@@ -315,6 +315,53 @@ def _macs_chunked_prefill_attention(ins, outs, attrs, unknown):
     return 2 * int(c) * int(h) * int(mpp) * int(p) * int(d)
 
 
+def _latent_span(ins, unknown):
+    # (query rows, heads, row width, gathered span T = MPP * page_size)
+    # of the two latent attention ops; the page table is [S, MPP] for a
+    # decode step, [MPP] for one stream's chunk
+    q = _first(ins, 'Q')
+    pool = _first(ins, 'Pool')
+    pt = _first(ins, 'PT')
+    if q is None or pool is None or pt is None:
+        return None
+    if len(q[0]) != 3 or len(pool[0]) != 3 or len(pt[0]) not in (1, 2):
+        return None
+    dims = tuple(q[0]) + (pool[0][1], pt[0][-1])
+    for v in dims:
+        if v is None or v < 0:
+            unknown[0] += 1
+            return None
+    r, h, w, p, mpp = (int(v) for v in dims)
+    return r, h, w, mpp * p
+
+
+def _macs_latent_attention(ins, outs, attrs, unknown):
+    # every query row of every head against the gathered span of the ONE
+    # shared row: W lanes for the scores, value_dim for p @ values.  The
+    # padded span is the compiled upper bound, as for paged_attention.
+    span = _latent_span(ins, unknown)
+    if span is None:
+        return None
+    r, h, w, t = span
+    return r * h * t * (w + int(attrs.get('value_dim', w)))
+
+
+def _bytes_latent_attention(ins, outs, attrs, unknown):
+    # the gathered span of each page table once (a chunk's rows share
+    # one), never the whole pool, plus q / out / table traffic
+    span = _latent_span(ins, unknown)
+    if span is None:
+        return None
+    _r, _h, w, t = span
+    pool, pt = _first(ins, 'Pool'), _first(ins, 'PT')
+    tables = int(pt[0][0]) if len(pt[0]) == 2 else 1
+    return (tables * t * w * _dtype_bytes(pool[1])
+            + sum(_spec_bytes(_first(ins, s), unknown)
+                  for s in ('Q', 'PT', 'CtxLen', 'Pos0')
+                  if _first(ins, s) is not None)
+            + _spec_bytes(_first(outs, 'Out'), unknown))
+
+
 def _macs_moe_ffn(ins, outs, attrs, unknown):
     # per token: the router's D*E, then gate + up (2*D*F) and down (F*D)
     # of ALL E experts — the op computes every expert and masks by the
@@ -325,7 +372,14 @@ def _macs_moe_ffn(ins, outs, attrs, unknown):
     if x is None or g is None or len(g[0]) != 3:
         return None
     e, d, f = (int(v) for v in g[0])
-    return _prod(x[0][:-1], unknown) * (d * e + e * 3 * d * f)
+    # the router may be wider than the experts held here, and a shared
+    # expert is three more matrices every token goes through
+    r = _first(ins, 'RouterW')
+    width = int(r[0][1]) if r is not None and len(r[0]) == 2 else e
+    sg = _first(ins, 'SharedGateW')
+    shared = 3 * d * int(sg[0][1]) if sg is not None and len(sg[0]) == 2 \
+        else 0
+    return _prod(x[0][:-1], unknown) * (d * width + e * 3 * d * f + shared)
 
 
 MAC_FORMULAS = {
@@ -349,6 +403,8 @@ MAC_FORMULAS = {
     'fused_linear_softmax_ce': _macs_vocab_ce,
     'vocab_parallel_ce': _macs_vocab_ce,
     'moe_ffn': _macs_moe_ffn,
+    'latent_paged_attention': _macs_latent_attention,
+    'latent_chunked_prefill_attention': _macs_latent_attention,
 }
 
 
@@ -405,6 +461,8 @@ def _bytes_chunked_prefill_attention(ins, outs, attrs, unknown):
 BYTES_FORMULAS = {
     'paged_attention': _bytes_paged_attention,
     'chunked_prefill_attention': _bytes_chunked_prefill_attention,
+    'latent_paged_attention': _bytes_latent_attention,
+    'latent_chunked_prefill_attention': _bytes_latent_attention,
 }
 
 

@@ -61,24 +61,30 @@ __all__ = ['analyze_memory', 'page_pool_bytes', 'prefix_cached_bytes',
            'WAIVED_OPS']
 
 
-def page_pool_bytes(num_pages, page_size, num_heads, head_dim,
-                    dtype='float32', n_layers=1, kv=2):
-    """Modeled HBM residency of the decode engine's paged KV cache:
-    ``n_layers x kv x num_pages x page_size x num_heads x head_dim x
-    dtype`` bytes.  The pools live OUTSIDE any program (engine-held,
-    donated chunk→chunk through the decode step), so the liveness walk
-    never sees them — this closed form is how the engine reports
-    ``resident_bytes`` and what the golden test pins
+def page_pool_bytes(num_pages, page_size, num_heads=None, head_dim=None,
+                    dtype='float32', n_layers=1, kv=2, row_widths=None):
+    """Modeled HBM residency of the decode engine's paged cache:
+    ``n_layers x num_pages x page_size x (what a position caches) x
+    dtype`` bytes, where a position caches the rows its block describes
+    (``row_widths``, as ``PagedKVCache.rows`` has them: one latent row,
+    say) or, by default, ``kv`` rows of ``num_heads x head_dim`` (a K
+    and a V of full multi-head attention).  The pools live OUTSIDE any
+    program (engine-held, donated chunk→chunk through the decode step),
+    so the liveness walk never sees them — this closed form is how the
+    engine reports ``resident_bytes`` and what the golden test pins
     (tests/test_memory_model.py)."""
     import numpy as np
     from ..core import datatypes
     itemsize = np.dtype(datatypes.as_numpy_dtype(dtype)).itemsize
-    return (int(n_layers) * int(kv) * int(num_pages) * int(page_size)
-            * int(num_heads) * int(head_dim) * int(itemsize))
+    per_position = sum(int(w) for w in row_widths) if row_widths \
+        is not None else int(kv) * int(num_heads) * int(head_dim)
+    return (int(n_layers) * int(num_pages) * int(page_size)
+            * per_position * int(itemsize))
 
 
-def prefix_cached_bytes(num_cached_pages, page_size, num_heads,
-                        head_dim, dtype='float32', n_layers=1):
+def prefix_cached_bytes(num_cached_pages, page_size, num_heads=None,
+                        head_dim=None, dtype='float32', n_layers=1,
+                        row_widths=None):
     """Bytes of pool residency currently HELD by the decode prefix
     cache.  Cached pages live inside the engine's page pools — a page
     referenced by three streams and the trie is ONE physical page, so
@@ -88,7 +94,8 @@ def prefix_cached_bytes(num_cached_pages, page_size, num_heads,
     ``prefix_cached_bytes`` stats key: how much of the pool an eviction
     sweep could reclaim at zero refs."""
     return page_pool_bytes(num_cached_pages, page_size, num_heads,
-                           head_dim, dtype, n_layers=n_layers)
+                           head_dim, dtype, n_layers=n_layers,
+                           row_widths=row_widths)
 
 # Ops with NO per-op live-bytes verdict — same data-dependent-extent
 # set the cost model waives (minus 'autodiff', which this model DOES
