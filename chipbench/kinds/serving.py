@@ -43,6 +43,33 @@ def all_prompt_lengths(run):
         [c['prompt_tokens'] for c in t['check']]
 
 
+# What makes an open loop's schedule "kept".  Both numbers are the
+# traffic's, none is the program's: a limit made of a step's time shrank
+# with every gain to the step, and failed sound runs (PERF.md section 6,
+# PR 40).
+# Poisson gaps have a coefficient of variation of 1: an arrival that
+# leaves under a tenth of the mean gap late leaves the arrival process
+# what the traffic file says.
+LATE_LIMIT_IN_GAPS = 0.1
+# The cells' normal lateness is ~1.1 ms at p99, 25-50 times under that
+# limit: a sender starved of the CPU is late on every wake-up and passes
+# one arrival in twenty at once; arrivals due inside a machine's pause
+# (1-3 of 67-154) do not, and cannot move a p95 over thousands of gaps.
+LATE_ARRIVALS_ONE_IN = 20
+
+
+def schedule_kept(late, rate_per_s):
+    """Whether the generator kept the schedule of an open loop: at most
+    one arrival in twenty left later than a tenth of the mean gap
+    between arrivals.  ``late`` holds sent - due of the window's
+    arrivals in seconds.  Counted, not interpolated, so the answer does
+    not turn on how many arrivals the window held.  Returns (kept, the
+    count over the limit, the limit in seconds)."""
+    limit = LATE_LIMIT_IN_GAPS / rate_per_s
+    over = sum(1 for x in late if x > limit)
+    return over * LATE_ARRIVALS_ONE_IN <= len(late), over, limit
+
+
 def build(run):
     """The served system, warmed, compared with the reference, started."""
     system = importlib.import_module('chipbench.systems.'
@@ -144,12 +171,18 @@ def measure(run, served, why, reqs, t_open, window_s, stop):
     step_s = float(np.median([s[1] - s[0] for s in steps])) if steps else 0.0
     late = [r.sent - r.due for r in attempted if r.due is not None]
     late_p99 = harness.percentile(late, 99) if late else None
-    if late_p99 is not None and late_p99 > step_s:
-        # (a toy step on the CPU is shorter than a sleep's overshoot:
-        # a rehearsal prints the line below and is not failed by it)
-        (print if run.rehearse else why.append)('the load generator ran %.1f ms late at p99, more than '
-                   'one decode step (%.1f ms)'
-                   % (1e3 * late_p99, 1e3 * step_s))
+    late_over = None
+    if late:    # an open loop; a closed loop has no schedule to keep
+        kept, over, limit = schedule_kept(late, float(t['rate_per_s']))
+        late_over = {'count': over, 'limit_ms': 1e3 * limit}
+        if not kept:
+            # (a rehearsal's 4 s hold ~40 arrivals, so two behind a busy
+            # CPU are over one in twenty: it prints the line below and
+            # is not failed by it)
+            (print if run.rehearse else why.append)(
+                '%d of %d arrivals left more than %.1f ms late (a tenth '
+                'of the gap between arrivals at %g/s)'
+                % (over, len(late), 1e3 * limit, t['rate_per_s']))
 
     fifth_rows = []
     for lo, hi in harness.fifths(t_open, t_host_end):
@@ -167,6 +200,9 @@ def measure(run, served, why, reqs, t_open, window_s, stop):
         'gaps': len(gaps), 'decode_steps': len(steps),
         'decode_step_host_ms': 1e3 * step_s,
         'late_p99_ms': None if late_p99 is None else 1e3 * late_p99,
+        'late_p50_ms': 1e3 * harness.percentile(late, 50) if late else None,
+        'late_max_ms': 1e3 * max(late) if late else None,
+        'late_over_limit': late_over,
         'queued_at_close': stats['queued'],
         'active_at_close': stats['active_streams'],
         'free_pages_at_close': stats['free_pages'],

@@ -75,6 +75,10 @@ def test_every_cell_rehearses(cell, trace, pending_root):
     assert res['attempted'] > 0 and res['failed'] == 0
     tags = {line.split(' ', 1)[0] for line in earlier}
     assert {'REFERENCE', 'FIFTHS', 'SETUP_PHASES', 'COMPILES'} <= tags
+    over = 'WINDOW' in tags and tagged(earlier, 'WINDOW')['late_over_limit']
+    if over:    # an open loop reports what its schedule was judged by
+        assert 0 <= over['count'] <= res['attempted']
+        assert over['limit_ms'] > 0
     ref = tagged(earlier, 'REFERENCE')
     # true f32 on both sides here: far inside the chip's tolerance
     if 'logits_rel_err' in ref:
