@@ -43,13 +43,15 @@ description supplies the rest, once, as pure functions of the weights (a
 - ``live_positions_arg``   the name under which a ``decode.step`` span
   reports the cached positions the step's attention reads (a block
   whose kernel reads one row a position says so); None: pages only;
-- ``head(p, x)`` -> logits [T, V] float32;
-- ``constant_weights``     how the weights enter the engine's ``step``
-  and ``chunk`` programs: False, as an operand (no program holds a
-  copy); True, bound as constants of each executable (XLA folds them:
-  float32 weights whose matmuls run as one bf16 pass are held and read
-  as bf16, at the price of a copy a program and a compile no cache can
-  keep).  ``prefill`` takes them as an operand under either.
+- ``head(p, x)`` -> logits [T, V] float32.
+
+Every function takes the weights ``p`` as traced values: the engine
+hands each of its programs the one placed copy as an operand, in the
+precision the configuration states, so a description never closes over
+an array of its own.  What a program returns stays on the device; an
+engine call copies to the host what its caller reads (next-token ids,
+routing counts, a prompt's last row of logits) and leaves the decode
+rows' ``[S, V]`` logits there.
 
 ``positions`` are absolute token positions [T]; a block with a learned
 position table indexes it in ``embed`` and the engine's ``max_seq``
@@ -120,10 +122,6 @@ class OptBlock(KVBlock):
     multi-head attention, a ReLU FFN, biases everywhere, a final
     LayerNorm and an untied head with a bias."""
 
-    # ROADMAP D2: as operands the chip's step reads float32 weights
-    # (about +1.7 ms of 61), and setup_s loses the ~100 s compile
-    constant_weights = True
-
     def __init__(self, n_heads, eps=1e-5):
         self.n_heads = int(n_heads)
         self.eps = float(eps)
@@ -179,8 +177,6 @@ class OlmoeBlock(KVBlock):
     (``renormalize`` False, the published ``norm_topk_prob``), every
     token reaching every one of its experts.  Keys are cached AFTER
     QK-norm and rotation, values as they are."""
-
-    constant_weights = False
 
     def __init__(self, n_heads, top_k=8, eps=1e-5, theta=10000.0,
                  renormalize=False):
@@ -264,7 +260,6 @@ class DotsVlmBlock(object):
     keeps its published width, the held experts' part is computed, what
     the others would add is left out (ops/moe.py ``moe_experts``)."""
 
-    constant_weights = False
     experts_share = True
     live_positions_arg = 'kv_latent_live_positions'
     LANES = 128

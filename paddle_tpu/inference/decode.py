@@ -56,7 +56,6 @@ Everything device-facing is AOT-compiled at ``warmup()`` via
 precompiled executables, and ``stats()['compiles_after_warmup']``
 counts any miss instead of hiding a multi-second stall.
 """
-import functools
 import itertools
 import threading
 import time
@@ -109,6 +108,15 @@ def decode_buckets(page_size, top):
     while sizes[-1] < top:
         sizes.append(min(sizes[-1] * 2, top))
     return sizes
+
+
+def _host_operands(tokens, page_tables, ctx_lens):
+    """A decode step's three arrays as the executables take them: the
+    host's numpy arrays as they are (the executable puts them on the
+    device in one batch; a ``jnp.asarray`` each would be three uploads
+    of their own, and a ``jnp`` scalar a device program)."""
+    return tuple(np.asarray(a, dtype=np.int32)
+                 for a in (tokens, page_tables, ctx_lens))
 
 
 class PagedKVCache(object):
@@ -318,10 +326,16 @@ class DecodeEngine(object):
     ONE loop over layers (``_layers``) that differs only in where a
     position's rows are written and which of the block's three attends
     reads them.  Every program
-    takes the weights as its first parameter; whether ``step`` and
-    ``chunk`` read them as that operand or as constants of the
-    executable is the block's to say (``_bound``), and the
-    ``argument_bytes`` of the ``decode.compile`` span shows which.
+    takes the weights as its first operand (``self.params``, placed
+    once): no executable holds a copy, each can be kept in the
+    persistent compile cache, and the ``argument_bytes`` of its
+    ``decode.compile`` span count them.
+
+    An engine call hands its executable the host's numpy arrays as they
+    are, waits for the program, and copies back in ONE transfer what its
+    caller reads (``_fetch``: ids, routing counts, a prompt's last row;
+    the span's ``fetched_bytes``).  The decode rows' ``[S, V]`` logits
+    stay on the device for whoever indexes them.
 
     Not thread-safe by design: exactly one caller (the DecodeServer
     worker) drives it, and the page pools move through donated
@@ -437,23 +451,6 @@ class DecodeEngine(object):
         self.compiles_total += 1
         return compiled
 
-    def _bound(self, fn):
-        """``fn(params, ...)`` as ``step`` and ``chunk`` are compiled:
-        how the weights enter them is the block's to say
-        (``constant_weights``), and this is where it is read.  As
-        constants, the traced program reads the engine's own weights
-        and leaves its first parameter unused, so jit drops that from
-        the executable: callers hand every program the weights all the
-        same, and only ``argument_bytes`` tells."""
-        if not self.block.constant_weights:
-            return fn
-        params = self.params
-
-        @functools.wraps(fn)    # the executable is still jit_<name>
-        def bound(_, *args):
-            return fn(params, *args)
-        return bound
-
     def _layers(self, params, x, positions, active, attend):
         """A block's layers over x [T, D]: ``attend(i, q, rows)`` is
         where prefill, chunk and step differ (it writes the rows layer i
@@ -484,17 +481,30 @@ class DecodeEngine(object):
                     out.values(), key=lambda v: v.nbytes).dtype))
         return out
 
-    def _routing(self, counts, span_args, step=False):
-        """The routing counts [L, E] a call returned beside its usual
-        outputs (``_layers``) -> the span's arguments and the engine's
-        totals: assignments (top_k x tokens x layers), experts with a
-        token (mean over layers; totalled over decode steps only), most
-        tokens on one expert.  Where the block holds a share of the
-        experts, the counts' last column is the assignments to experts
-        held elsewhere: ``moe_all_assignments`` counts them too, the
-        other three (``moe_held_assignments``, ``moe_held_touched``,
+    def _fetch(self, arrays, counts, span_args, step=False):
+        """An engine call's one copy to the host: ``arrays`` (what the
+        caller reads) and the routing counts the program returned beside
+        them (``counts``: one array or none) come back in one transfer,
+        which waits for the program.  The span gets ``fetched_bytes``
+        and what ``_routing`` makes of the counts; the arrays are
+        returned as numpy."""
+        got = jax.device_get(tuple(arrays) + tuple(counts))
+        span_args['fetched_bytes'] = sum(a.nbytes for a in got)
+        if counts:
+            self._routing(got[-1], span_args, step=step)
+        return got[:len(arrays)]
+
+    def _routing(self, c, span_args, step=False):
+        """The routing counts ``c`` [L, E] a call returned beside its
+        usual outputs (``_layers``), on the host (``_fetch``) -> the
+        span's arguments and the engine's totals: assignments (top_k x
+        tokens x layers), experts with a token (mean over layers;
+        totalled over decode steps only), most tokens on one expert.
+        Where the block holds a share of the experts, the counts' last
+        column is the assignments to experts held elsewhere:
+        ``moe_all_assignments`` counts them too, the other three
+        (``moe_held_assignments``, ``moe_held_touched``,
         ``moe_max_load``) the held experts alone."""
-        c = np.asarray(counts)
         held = 'held_' if self.block.experts_share else ''
         tot = self.routing
         if held:
@@ -648,7 +658,7 @@ class DecodeEngine(object):
             return tuple(pools) + (
                 logits[S], jnp.argmax(logits[:S], axis=-1),
                 logits[:S]) + extra
-        return self._bound(chunk)
+        return chunk
 
     def _step_fn(self):
         blk, trash = self.block, self.cache.trash
@@ -668,7 +678,7 @@ class DecodeEngine(object):
             logits = blk.head(params, x)
             return tuple(pools) + (logits,
                                    jnp.argmax(logits, axis=-1)) + extra
-        return self._bound(step)
+        return step
 
     def _pools_out(self, out):
         """A pool program's outputs: the pools go back into the cache,
@@ -717,9 +727,13 @@ class DecodeEngine(object):
         ``step`` (tokens [S], page tables [S, MPP], context lengths
         [S]; all-trash page tables carry none), each attending over its
         own pages as in ``step``: one read of the weights for both.
-        Returns the chunk's last VALID row's logits, so intermediate
-        chunks pay one row of the head, not [C, V], then the decode
-        rows' next tokens [S] and logits [S, V]."""
+        The program returns, all on the device, the chunk's last VALID
+        row's logits [V], so intermediate chunks pay one row of the
+        head, not [C, V], then the decode rows' next tokens [S] and
+        logits [S, V] (and the routing counts, where the block routes);
+        ``prefill_chunk`` copies the row, the ids and the counts to the
+        host and leaves [S, V] where it is.  The weights are the
+        program's first operand, as every program's."""
         if bucket in self._chunk:
             return
         n = len(self.cache.rows)
@@ -815,18 +829,16 @@ class DecodeEngine(object):
             toks = np.zeros((bucket,), np.int32)
             toks[:t] = prompt
             n = len(self.cache.rows)
-            logits, *rest = self._prefill[bucket](
-                self.params, jnp.asarray(toks), jnp.int32(t - 1))
-            kept, extra = rest[:n], rest[n:]
             n_pages = bucket // self.page_size
             page_ids = np.full((n_pages,), self.cache.trash, np.int32)
             n_real = min(len(pages), n_pages)
             page_ids[:n_real] = pages[:n_real]
+            # numpy in, as ``_host_operands`` says why
+            logits, *rest = self._prefill[bucket](
+                self.params, toks, np.int32(t - 1))
             self._pools_out(self._pack[bucket](
-                *self.cache.pools, *kept, jnp.asarray(page_ids)))
-            if extra:
-                self._routing(extra[0], args)
-            return np.asarray(logits)
+                *self.cache.pools, *rest[:n], page_ids))
+            return self._fetch((logits,), rest[n:], args)[0]
 
     def chunk_spans(self, prompt_len, start=0):
         """The grid-aligned chunk decomposition of positions
@@ -861,58 +873,60 @@ class DecodeEngine(object):
         then returns (last-row logits, next tokens [S] as numpy, the
         decode rows' logits [S, V] left on the device for whoever asks).
         The span's ``tokens`` and ``bucket`` stay the chunk's;
-        ``step_rows`` counts the running slots carried."""
+        ``step_rows`` counts the running slots carried, and
+        ``fetched_bytes`` what came back to the host: the last row, and
+        with rows carried their ids, beside the routing counts.  The
+        call's two halves are the spans ``.dispatch`` and ``.fetch``,
+        as ``step``'s."""
         tokens = np.asarray(tokens, dtype=np.int32)
         c = int(tokens.shape[0])
         bucket = self.bucket_for(c)
         args = {'tokens': c, 'bucket': bucket, 'step_rows': 0}
         with _obs.span('decode.prefill_chunk', args=args):
             self._ensure_chunk(bucket)
-            toks = np.zeros((bucket,), np.int32)
-            toks[:c] = tokens
-            mpp = self.pages_per_stream
-            pt = np.full((mpp,), self.cache.trash, np.int32)
-            n = min(len(pages), mpp)
-            pt[:n] = pages[:n]
-            # the host's arrays go in as they are (the executable puts
-            # them on the device in one batch; a ``jnp`` scalar would be
-            # a device program of its own)
-            carried = self._idle_step if step_tokens is None else tuple(
-                np.asarray(a, dtype=np.int32)
-                for a in (step_tokens, page_tables, ctx_lens))
-            logits, nxt, step_logits, *extra = self._pools_out(
-                self._chunk[bucket](
-                    self.params, *self.cache.pools, toks, pt,
-                    np.int32(pos0), np.int32(c), *carried))
-            if extra:
-                self._routing(extra[0], args)
-            if step_tokens is None:
-                return np.asarray(logits)
-            args['step_rows'] = self._kv_pages(page_tables, ctx_lens, args)
-            return np.asarray(logits), np.asarray(nxt), step_logits
+            with _obs.span('decode.prefill_chunk.dispatch'):
+                toks = np.zeros((bucket,), np.int32)
+                toks[:c] = tokens
+                mpp = self.pages_per_stream
+                pt = np.full((mpp,), self.cache.trash, np.int32)
+                n = min(len(pages), mpp)
+                pt[:n] = pages[:n]
+                carried = self._idle_step if step_tokens is None \
+                    else _host_operands(step_tokens, page_tables, ctx_lens)
+                logits, nxt, step_logits, *extra = self._pools_out(
+                    self._chunk[bucket](
+                        self.params, *self.cache.pools, toks, pt,
+                        np.int32(pos0), np.int32(c), *carried))
+            with _obs.span('decode.prefill_chunk.fetch'):
+                if step_tokens is None:
+                    return self._fetch((logits,), extra, args)[0]
+                args['step_rows'] = self._kv_pages(page_tables, ctx_lens,
+                                                   args)
+                return self._fetch((logits, nxt), extra, args) \
+                    + (step_logits,)
 
     def step(self, tokens, page_tables, ctx_lens):
         """One batched decode step over all ``max_streams`` slots.
         Inactive slots pass token 0 with an all-trash page-table row —
         their writes land in the trash page and their outputs are
-        ignored.  Returns (next_tokens [S], logits [S, V]) numpy."""
+        ignored.  Returns (next tokens [S] as numpy, the rows' logits
+        [S, V] left on the device for whoever indexes them): the ids and
+        the routing counts are all that is copied to the host
+        (``fetched_bytes`` on the span)."""
         self._ensure_step()
         # the two halves of the host's part: everything up to the call
         # into the executable returning, then the wait for the device
-        # and the copy back of tokens and [S, V] logits
-        args = {}   # the step's KV pages and routing counts
+        # and the copy back of the ids (and routing counts)
+        args = {}   # the step's KV pages, routing counts, fetched bytes
         with _obs.span('decode.step', args=args):
             with _obs.span('decode.step.dispatch'):
                 logits, nxt, *extra = self._pools_out(self._step(
                     self.params, *self.cache.pools,
-                    jnp.asarray(tokens, dtype=jnp.int32),
-                    jnp.asarray(page_tables, dtype=jnp.int32),
-                    jnp.asarray(ctx_lens, dtype=jnp.int32)))
+                    *_host_operands(tokens, page_tables, ctx_lens)))
             with _obs.span('decode.step.fetch'):
                 self._kv_pages(page_tables, ctx_lens, args)
-                if extra:
-                    self._routing(extra[0], args, step=True)
-                return np.asarray(nxt), np.asarray(logits)
+                return self._fetch((nxt,), extra, args, step=True) \
+                    + (logits,)
 
     def resident_bytes(self):
         return self.cache.resident_bytes()

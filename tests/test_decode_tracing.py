@@ -84,6 +84,9 @@ def test_span_names_and_nesting(params, chunked):
             prefill: 'server.admit', 'decode.step': 'server.tick',
             'decode.step.dispatch': 'decode.step',
             'decode.step.fetch': 'decode.step'}
+    if chunked:     # a chunk call has the step's two halves
+        want.update({prefill + '.dispatch': prefill,
+                     prefill + '.fetch': prefill})
     names = {e['name'] for e in evs}
     assert set(want) <= names
     assert 'decode.compile' not in names     # every shape was warmed
@@ -170,7 +173,9 @@ def test_a_new_bucket_compiles_once_under_its_prefill(params):
     assert len(calls) == 2
     first = min(calls, key=lambda e: e['ts'])
     assert {e['parent'] for e in comp} == {first['id']}
-    assert first['args'] == {'tokens': 20, 'bucket': 32}
+    # what came back to the host: the prompt's last row of logits
+    assert first['args'] == {'tokens': 20, 'bucket': 32,
+                             'fetched_bytes': 4 * V}
     assert eng.compiles_after_warmup == 2
 
 
@@ -185,7 +190,13 @@ def test_compile_spans_say_whether_the_pool_is_in_place(params, chunked):
                   if a['bucket']) == built
     assert [a['program'] for a in comp if a['bucket'] is None] == ['step']
     pool = eng.resident_bytes()
+    weights = sum(v.nbytes for v in eng.params.values())
     for a in comp:
+        # the weights are an operand of every program but pack, the
+        # pools of every program but prefill; the rest is a few rows
+        takes = weights * (a['program'] != 'pack') \
+            + pool * (a['program'] != 'prefill')
+        assert takes <= a['argument_bytes'] < takes + weights
         # the step also says which attention the op's dispatch took:
         # on the CPU, heads of any size, the gathered span
         assert set(a) == {'program', 'bucket', 'temp_bytes', 'alias_bytes',

@@ -506,21 +506,16 @@ def _weight_shaped_constants(compiled, params):
 
 @pytest.mark.parametrize('model', ['olmoe', 'opt'])
 def test_how_the_weights_enter_each_program(params, ring, model):
-    """The one thing a description says about how its programs are
-    built (``constant_weights``).  OLMoE: an operand of step, chunk and
-    prefill, no program holds a copy, and a compiled step serves
-    whatever weights it is handed.  OPT: constants of step and chunk
-    (``argument_bytes`` holds the pools and the host's arrays only, and
-    the handed weights are not read), an operand of prefill all the
-    same.  ``pack`` reads none under either."""
+    """Under every description the weights are an operand of step, chunk
+    and prefill (``argument_bytes`` counts them), no program's text
+    holds a constant of a weight's shape, and a compiled step computes
+    with the weights it is handed.  ``pack`` reads none."""
     if model == 'opt':
         params, other, block = make_opt_params(0), make_opt_params(21), \
             OptBlock(H)
         reference = ref_opt_logits
     else:
         other, block, reference = make_params(21), OlmoeBlock(H), ref_logits
-    constants = model == 'opt'
-    assert block.constant_weights is constants
     ring.clear()
     eng = make_engine(params, block, top=16)
     eng.warmup()
@@ -531,25 +526,16 @@ def test_how_the_weights_enter_each_program(params, ring, model):
     for e in spans(ring, 'decode.compile'):
         seen.setdefault(e['args']['program'], []).append(e['args'])
     assert {'step', 'prefill', 'chunk', 'pack'} == set(seen)
-    for program in ('step', 'chunk'):
+    for program in ('step', 'chunk', 'prefill'):
         for a in seen[program]:
-            assert (a['argument_bytes'] < weight_bytes) is constants, \
-                (program, a)
-    for a in seen['prefill']:
-        assert a['argument_bytes'] >= weight_bytes
+            assert a['argument_bytes'] >= weight_bytes, (program, a)
     for a in seen['pack']:
         assert a['argument_bytes'] < weight_bytes
-    # a constant of a weight's size in a program's text: in OPT's step
-    # and chunk, and nowhere else
     for compiled in [eng._step, chunked._step] \
-            + list(chunked._chunk.values()):
-        assert bool(_weight_shaped_constants(compiled, params)) \
-            is constants
-    for compiled in eng._prefill.values():
+            + list(chunked._chunk.values()) + list(eng._prefill.values()):
         assert not _weight_shaped_constants(compiled, params)
-    # one engine's compiled step, handed another engine's weights:
-    # as an operand they are what it computes with, as constants they
-    # are not read
+    # one engine's compiled step, handed another engine's weights,
+    # computes with those
     eng_b = make_engine(other, block, top=16)
     prompt = np.random.default_rng(10).integers(1, V, 12)
     pages = eng_b.cache.alloc(2)
@@ -559,12 +545,9 @@ def test_how_the_weights_enter_each_program(params, ring, model):
     assert rel(eng_b.step(t, pt, c)[1][1], reference(other, seq)[-1]) \
         < TOL_A
     # (the pools are donated: eng_b is spent after this call)
-    out = eng._step(eng_b.params, eng_b.cache.k, eng_b.cache.v,
-                    jnp.asarray(t), jnp.asarray(pt), jnp.asarray(c))
+    out = eng._step(eng_b.params, eng_b.cache.k, eng_b.cache.v, t, pt, c)
     got = np.asarray(out[2])[1]
-    # as constants: eng's weights over eng_b's cached rows, which is
-    # neither engine's answer
-    assert (rel(got, reference(other, seq)[-1]) < TOL_A) is not constants
+    assert rel(got, reference(other, seq)[-1]) < TOL_A
     assert rel(got, reference(params, seq)[-1]) > 0.1
 
 
@@ -720,9 +703,10 @@ def test_opt_engine_reports_no_routing(ring):
                 for c in ctx[pts[:, 0] != eng.cache.trash])
             for pts, ctx in handed]
     assert live == [1, 2, 2]    # position 7 is page 0's last row
-    for e, n in zip(steps, live):
-        assert e['args'] == {'kv_live_pages': n, 'kv_table_pages': 2 * 8}
-    assert all(set(e['args']) == {'tokens', 'bucket'}
+    for e, n in zip(steps, live):   # two slots' ids came back, no counts
+        assert e['args'] == {'kv_live_pages': n, 'kv_table_pages': 2 * 8,
+                             'fetched_bytes': 2 * 4}
+    assert all(set(e['args']) == {'tokens', 'bucket', 'fetched_bytes'}
                for e in spans(ring, 'decode.prefill_into'))
     assert (stats['moe_assignments'], stats['moe_max_load'],
             stats['moe_touched_mean']) == (0, 0, 0.0)

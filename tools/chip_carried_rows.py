@@ -81,7 +81,8 @@ def main():
     last_alone = eng.prefill_chunk(chunk, mine, 0)
     nxt_step, logits_step = eng.step(toks, pt, ctx)
     last, nxt, logits = eng.prefill_chunk(chunk, mine, 0, toks, pt, ctx)
-    logits = np.asarray(logits)
+    # both calls leave the decode rows' logits on the device
+    logits, logits_step = np.asarray(logits), np.asarray(logits_step)
     ref = jax.jit(lambda p, s: reference.logits(
         p, s, n_layers=served.layers, n_heads=served.heads))
     against_ref = []
