@@ -95,8 +95,8 @@ class KVBlock(object):
         # what the op's dispatch takes for these shapes (the step calls
         # it with no context: the default backend)
         return {'attention': paged_attention_path(
-            backend, sizes['d_model'] // self.n_heads, page_size, dtype)} \
-            if program == 'step' else {}
+            backend, self.n_heads, sizes['d_model'] // self.n_heads,
+            page_size, dtype)} if program == 'step' else {}
 
     def attend_prefill(self, p, i, q, rows):
         t, h, dh = q.shape

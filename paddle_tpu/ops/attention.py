@@ -120,18 +120,18 @@ def _chunked_prefill_attention(ctx, ins, attrs):
         scale=attrs.get('scale', None)))
 
 
-def paged_attention_path(backend, head_dim, page_size, dtype):
+def paged_attention_path(backend, n_heads, head_dim, page_size, dtype):
     """What the ``paged_attention`` op runs for these shapes:
     ``'pallas_paged'`` (ops/pallas/paged_attention.py, live pages only)
-    on a TPU when each head is a whole number of 128-lane registers and
-    a page a whole number of the pool dtype's sublane tiles, else
-    ``'xla_gather'`` (``paged_attention_math``).  Backend, shapes and
-    dtype decide, nothing else; the decode engine records the answer
-    with its step's ``decode.compile`` span."""
+    on a TPU when the row ``n_heads * head_dim`` is a whole number of
+    128-lane registers and a page a whole number of the pool dtype's
+    sublane tiles, else ``'xla_gather'`` (``paged_attention_math``).
+    Backend, shapes and dtype decide, nothing else; the decode engine
+    records the answer with its step's ``decode.compile`` span."""
     if backend == 'tpu':
         # lazy, as flash_attention below
         from .pallas.paged_attention import supported
-        if supported(head_dim, page_size, dtype):
+        if supported(n_heads, head_dim, page_size, dtype):
             return 'pallas_paged'
     return 'xla_gather'
 
@@ -145,7 +145,7 @@ def _paged_attention(ctx, ins, attrs):
     ctx_len = first(ins, 'CtxLen')   # [S] int32
     backend = getattr(ctx, 'backend', jax.default_backend())
     attend = paged_attention_math
-    if paged_attention_path(backend, q.shape[-1], k_pool.shape[1],
+    if paged_attention_path(backend, *q.shape[1:], k_pool.shape[1],
                             k_pool.dtype) == 'pallas_paged':
         from .pallas import paged_attention as attend
     return out(attend(

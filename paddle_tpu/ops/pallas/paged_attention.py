@@ -16,10 +16,10 @@ through the MXU at once against a block-diagonal query ``[H, H*D]``
 (row h holds q_h on head h's lanes, zeros elsewhere): scores
 ``[H, T]``, f32 online softmax over blocks, and ``p @ V`` gives
 ``[H, H*D]`` whose h-th row is head h's output on head h's lanes.  The
-diagonal is picked once, at the end.  That needs each head to be a
-whole number of 128-lane registers (``head_dim % 128 == 0``) and a page
-to be a whole number of the pool dtype's sublane tiles, which is what
-``supported`` tests; the op falls back to the math otherwise.
+diagonal is picked once, at the end.  The mask is lane arithmetic, so a
+head may be any width (OPT's 64: two to a register); the DMAs and tiles
+need a ROW ``H * D`` of whole 128-lane registers and a page of whole
+sublane tiles, which ``supported`` tests; else the op runs the math.
 
 Same mathematics as ``paged_attention_math``: every live position of
 every head, f32 scores, softmax and accumulation, K and V as stored;
@@ -53,12 +53,12 @@ def _sublane_rows(dtype):
     return 32 // jnp.dtype(dtype).itemsize
 
 
-def supported(head_dim, page_size, dtype):
-    """Whether the kernel takes these shapes: heads that are whole
-    128-lane registers of the ``H*D`` row, pages that are whole sublane
-    tiles of the pool's dtype (a page is one DMA into a tile-aligned
-    slice of the block)."""
-    return head_dim % 128 == 0 and \
+def supported(n_heads, head_dim, page_size, dtype):
+    """Whether the kernel takes these shapes: a row ``H * D`` of whole
+    128-lane registers, whatever the width of a head, and pages that are
+    whole sublane tiles of the pool's dtype (a page is one DMA into a
+    tile-aligned slice of the block)."""
+    return (n_heads * head_dim) % 128 == 0 and \
         page_size % _sublane_rows(dtype) == 0
 
 

@@ -27,14 +27,14 @@ CTX_LENS = [1, P - 1, P, P + 1, 77, MAX_SEQ]
 TOL = {'bfloat16': 1e-2, 'float32': 2e-5}
 
 
-def make(seed, h, d, dtype, ndim=3, page=P, n=N, s=len(CTX_LENS)):
+def make(seed, h, d, dtype, ndim=3, page=P, n=N, s=len(CTX_LENS), mpp=MPP):
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(s, h, d), jnp.float32)
     shape = (n, page, h * d) if ndim == 3 else (n, page, h, d)
     k = jnp.asarray(rng.randn(*shape), dtype)
     v = jnp.asarray(rng.randn(*shape), dtype)
     # a slot's pages distinct and scattered over the pool
-    pt = jnp.asarray(np.stack([rng.permutation(n)[:MPP]
+    pt = jnp.asarray(np.stack([rng.permutation(n)[:mpp]
                                for _ in range(s)]), jnp.int32)
     return q, k, v, pt
 
@@ -55,7 +55,7 @@ def close(got, want, dtype):
 
 @pytest.mark.parametrize('ndim', [3, 4])
 @pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
-@pytest.mark.parametrize('head_dim', [128, 256])
+@pytest.mark.parametrize('head_dim', [64, 128, 256])
 def test_kernel_matches_math(head_dim, dtype, ndim):
     q, k, v, pt = make(1, 2, head_dim, dtype, ndim)
     close(*both(q, k, v, pt, CTX_LENS), dtype)
@@ -67,6 +67,21 @@ def test_every_context_length_alone(ctx_len):
     # in the buffers or the running sums
     q, k, v, pt = make(2, 2, 128, jnp.float32, s=3)
     close(*both(q, k, v, pt, [ctx_len, 1, ctx_len]), jnp.float32)
+
+
+def test_the_opt_row_of_32_heads_of_64():
+    # two heads to a 128-lane register, 32 query rows against the 2048-
+    # wide row; contexts of one position, a page and one, 100 pages, and
+    # a slot that holds nothing
+    ctx = [1, 17, 100 * P, 0, 5 * P]
+    q, k, v, pt = make(9, 32, 64, jnp.float32, n=140, s=len(ctx), mpp=128)
+    assert supported(32, 64, P, jnp.float32)
+    got, want = both(q, k, v, pt, ctx)
+    live = np.array(ctx) > 0
+    close(got[live], want[live], jnp.float32)
+    # the math averages what an empty slot's table points at: the kernel
+    # reads nothing and says zero
+    assert np.array_equal(got[~live], np.zeros_like(got[~live]))
 
 
 def test_f32_pages_of_eight_rows_and_a_scale():
@@ -146,8 +161,8 @@ def run_op(backend, q, k, v, pt, ctx):
 
 
 @pytest.mark.parametrize('backend,head_dim,dtype,page', [
-    ('tpu', 64, jnp.bfloat16, 16),      # two heads share a 128-lane vreg
-    ('tpu', 64, jnp.float32, 16),
+    ('tpu', 48, jnp.bfloat16, 16),      # a row of 96 lanes: no whole vreg
+    ('tpu', 48, jnp.float32, 16),
     ('cpu', 128, jnp.bfloat16, 16),     # not a TPU
     ('gpu', 128, jnp.float32, 16),
     ('tpu', 128, jnp.bfloat16, 8),      # half a bf16 sublane tile a page
@@ -155,7 +170,7 @@ def run_op(backend, q, k, v, pt, ctx):
 ], ids=lambda x: getattr(x, '__name__', str(x)))
 def test_dispatch_falls_back_to_the_math_bit_for_bit(
         backend, head_dim, dtype, page):
-    assert paged_attention_path(backend, head_dim, page, dtype) \
+    assert paged_attention_path(backend, 2, head_dim, page, dtype) \
         == 'xla_gather'
     q, k, v, pt = make(7, 2, head_dim, dtype, page=page, s=3)
     ctx = [1, 3 * page + 1, MPP * page]
@@ -167,11 +182,12 @@ def test_dispatch_falls_back_to_the_math_bit_for_bit(
 @pytest.mark.parametrize('head_dim,dtype,page', [
     (128, jnp.bfloat16, 16), (128, jnp.bfloat16, 32), (256, jnp.bfloat16, 16),
     (128, jnp.float32, 8), (128, jnp.float32, 16), (256, jnp.float32, 16),
+    (64, jnp.bfloat16, 16), (64, jnp.float32, 16),  # two heads to a vreg
 ], ids=lambda x: getattr(x, '__name__', str(x)))
 def test_dispatch_takes_the_kernel_on_a_tpu(monkeypatch, head_dim, dtype,
                                             page):
-    assert supported(head_dim, page, dtype)
-    assert paged_attention_path('tpu', head_dim, page, dtype) \
+    assert supported(2, head_dim, page, dtype)
+    assert paged_attention_path('tpu', 2, head_dim, page, dtype) \
         == 'pallas_paged'
     # the op calls the package's entry point: here, interpreted
     calls = []
