@@ -57,6 +57,11 @@ CHIP = dict(
     flash=dict(B=2, T=8192, H=8, D=64),         # bench_attention.py
     # one layer of the olmoe-1b-7b_serve_chat32_chunked decode step
     paged=dict(S=32, H=16, D=128, P=16, MPP=64, N=2049),
+    # query heads in groups over 8 K/V heads: 48 over a stream's whole
+    # table, 72 over a ring that holds a window of 512 and a chunk of 512
+    grouped=dict(S=32, HKV=8, D=128, P=16, N=12289, C=512,
+                 kinds=dict(full=dict(H=48, MPP=1088, window=None),
+                            window=dict(H=72, MPP=65, window=512))),
     latent=dict(S=64, H=128, W=640, C=512, P=16, MPP=256, N=4097),
     lstm=dict(T=128, B=256, H=256),             # bench_lstm_lm.py
     gru=dict(T=64, B=512, H=512),               # bench_seq2seq.py
@@ -70,6 +75,9 @@ TOY = dict(
                bucket=32, prompt=(4, 20), new=(3, 6)),
     flash=dict(B=1, T=256, H=2, D=64),
     paged=dict(S=4, H=2, D=128, P=16, MPP=10, N=25),
+    grouped=dict(S=4, HKV=8, D=128, P=16, N=49, C=32,
+                 kinds=dict(full=dict(H=16, MPP=10, window=None),
+                            window=dict(H=24, MPP=5, window=40))),
     latent=dict(S=4, H=4, W=128, C=96, P=16, MPP=10, N=25),
     lstm=dict(T=6, B=8, H=128),
     gru=dict(T=6, B=8, H=128),
@@ -659,6 +667,56 @@ def kernel_cases(cfg):
         lambda q, k, v, pt, ctx, interpret: paged_attention(
             q, k, v, pt, ctx, interpret=interpret),
         paged_attention_math, *TOL_BF16, make=paged_make, timed=True))
+
+    # -- the same with query heads in groups over fewer K/V heads, over a
+    # whole table and over a ring that holds a window; and a prompt
+    # chunk's rows over the stream's live pages ----------------------------
+    from paddle_tpu.ops.attention import chunked_prefill_attention_math
+    from paddle_tpu.ops.pallas.paged_attention import chunk_paged_attention
+    gq = cfg['grouped']
+    gpool = ((gq['N'], gq['P'], gq['HKV'] * gq['D']), bf16)
+
+    def grouped_make(kind, chunk):
+        def make(rng):
+            g = dict(gq, **kind)
+            kv = [(rng.standard_normal(gpool[0]) * 0.5).astype(np.float32)
+                  .astype(bf16) for _ in range(2)]
+            pt, ctx = running_slots(rng, g)
+            if kind['window']:      # a ring holds the newest of any number
+                ctx = ctx + np.where(ctx > 1, 3 * g['MPP'] * g['P'], 0
+                                     ).astype(np.int32)
+            if chunk:   # one stream's chunk, the queries as the kernel
+                # rounds them, behind three chunks' worth of context
+                slot = int(np.argmax(ctx))
+                q = rng.standard_normal((g['C'], g['H'], g['D'])).astype(
+                    np.float32).astype(bf16).astype(np.float32)
+                return [q] + kv + [pt[slot], np.int32(3 * g['C'])]
+            return [rng.standard_normal((g['S'], g['H'], g['D']))
+                    .astype(np.float32)] + kv + [pt, ctx]
+        return make
+
+    for kind_name, kind in sorted(gq['kinds'].items()):
+        win = kind['window']
+        cases.append(KernelCase(
+            'paged_attention_grouped_%s' % kind_name,
+            [((gq['S'], kind['H'], gq['D']), f32), gpool, gpool,
+             ((gq['S'], kind['MPP']), jnp.int32), ((gq['S'],), jnp.int32)],
+            functools.partial(
+                lambda q, k, v, pt, ctx, interpret, win: paged_attention(
+                    q, k, v, pt, ctx, window=win, interpret=interpret),
+                win=win),
+            functools.partial(paged_attention_math, window=win),
+            *TOL_BF16, make=grouped_make(kind, False), timed=True))
+        cases.append(KernelCase(
+            'chunk_paged_attention_%s' % kind_name,
+            [((gq['C'], kind['H'], gq['D']), f32), gpool, gpool,
+             ((kind['MPP'],), jnp.int32), ((), jnp.int32)],
+            functools.partial(
+                lambda q, k, v, pt, pos0, interpret, win:
+                chunk_paged_attention(q, k, v, pt, pos0, window=win,
+                                      interpret=interpret), win=win),
+            functools.partial(chunked_prefill_attention_math, window=win),
+            *TOL_BF16, make=grouped_make(kind, True), timed=True))
 
     # -- the same over ONE latent row a position (MLA, absorbed form) ------
     from paddle_tpu.ops.attention import latent_paged_attention_math

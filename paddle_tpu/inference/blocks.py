@@ -11,10 +11,15 @@ description supplies the rest, once, as pure functions of the weights (a
 - ``sizes(params)``        ``d_model`` and ``vocab_size`` from shapes,
   and ``positions`` (a learned position table's rows) where it has one;
 - ``cache_rows(sizes)``    what a position caches in every layer, as
-  ``((name, width), ...)``: ``(('k', H * Dh), ('v', H * Dh))`` for full
-  multi-head attention, ``(('latent', W),)`` for a latent cache.  The
-  engine holds one page pool ``[pages, page_size, width]`` a row a layer
-  and nothing else about the cache;
+  ``((name, width), ...)``: ``(('k', Hkv * Dh), ('v', Hkv * Dh))`` for
+  attention over K/V heads, ``(('latent', W),)`` for a latent cache.
+  The engine holds one page pool ``[pages, page_size, width]`` a row a
+  layer and nothing else about the cache;
+- ``layer_kinds(n_layers)`` (optional) the kind of each layer's cache,
+  ``'full'`` (every position stays) or ``'window'`` (the ``window``
+  newest do), where a block has both: the engine then holds a page
+  group a kind, the window group's a ring a stream, and hands each
+  layer's attend its own group's table;
 - ``embed(p, tokens, positions)`` -> x [T, D] float32 (the positions
   it is handed lie inside the engine's ``max_seq``);
 - ``qkv(p, x, i, positions)`` -> (q, *rows): the queries as the block's
@@ -62,13 +67,15 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import get_op_impl
-from ..ops.attention import (_dense_attention, latent_attention_path,
+from ..ops.attention import (_dense_attention, _grouped,
+                             chunk_attention_path, latent_attention_path,
                              paged_attention_path)
 from ..ops.moe import (moe_counts, moe_experts, moe_route,
                        moe_route_grouped, rms_norm_math, rotary_math,
                        swiglu_math, yarn_mscale)
 
-__all__ = ['KVBlock', 'OptBlock', 'OlmoeBlock', 'DotsVlmBlock']
+__all__ = ['KVBlock', 'OptBlock', 'OlmoeBlock', 'DotsVlmBlock',
+           'LagunaBlock']
 
 
 def _mm(x, w):
@@ -79,40 +86,62 @@ def _mm(x, w):
 
 
 class KVBlock(object):
-    """The cache and the attention of full multi-head attention: a
-    position caches one K and one V row of ``n_heads * head_dim``,
-    ``qkv`` returns q [T, H, Dh] and k, v [T, H * Dh], and the three
-    attends are the dense causal one, ``chunked_prefill_attention`` and
-    ``paged_attention`` (ops/attention.py)."""
+    """The cache and the attention of attention over K/V heads: a
+    position caches one K and one V row of ``n_kv_heads * head_dim``
+    (``n_kv_heads`` is ``n_heads`` unless the block says otherwise:
+    full multi-head attention), ``qkv`` returns q [T, H, Dh] and k, v
+    [T, Hkv * Dh], and the three attends are the dense causal one,
+    ``chunked_prefill_attention`` and ``paged_attention``
+    (ops/attention.py), which take the K/V heads from the row they are
+    handed and group the query heads over them.  ``window_of(i)`` is
+    how many of the newest positions layer i reads (None: all)."""
 
     experts_share = False
     live_positions_arg = None
 
+    @property
+    def n_kv_heads(self):
+        return self.n_heads
+
+    def head_dim(self, sizes):
+        return sizes['d_model'] // self.n_heads
+
+    def window_of(self, i):
+        return None
+
     def cache_rows(self, sizes):
-        return (('k', sizes['d_model']), ('v', sizes['d_model']))
+        width = self.n_kv_heads * self.head_dim(sizes)
+        return (('k', width), ('v', width))
 
     def describe(self, program, sizes, backend, page_size, dtype):
         # what the op's dispatch takes for these shapes (the step calls
         # it with no context: the default backend)
         return {'attention': paged_attention_path(
-            backend, self.n_heads, sizes['d_model'] // self.n_heads,
-            page_size, dtype)} if program == 'step' else {}
+            backend, self.n_kv_heads, self.head_dim(sizes), page_size,
+            dtype, self.n_heads // self.n_kv_heads)} \
+            if program == 'step' else {}
 
     def attend_prefill(self, p, i, q, rows):
         t, h, dh = q.shape
-        k, v = (r.reshape(t, h, dh) for r in rows)
-        return _dense_attention(q[None], k[None], v[None], True,
-                                None)[0], (k, v)
+        k, v = (r.reshape(t, -1, dh) for r in rows)
+        kq, vq = (_grouped(r, h) for r in (k, v))
+        return _dense_attention(q[None], kq[None], vq[None], True, None,
+                                **self._window(i))[0], (k, v)
+
+    def _window(self, i):
+        w = self.window_of(i)
+        return {} if w is None else {'window': w}
 
     def attend_chunk(self, p, i, q, pools, pt, pos0):
         return get_op_impl('chunked_prefill_attention').compute(
             None, {'Q': [q], 'KPool': [pools[0]], 'VPool': [pools[1]],
-                   'PT': [pt], 'Pos0': [pos0]}, {})['Out'][0]
+                   'PT': [pt], 'Pos0': [pos0]}, self._window(i))['Out'][0]
 
     def attend_step(self, p, i, q, pools, pt, ctx_len):
         return get_op_impl('paged_attention').compute(
             None, {'Q': [q], 'KPool': [pools[0]], 'VPool': [pools[1]],
-                   'PT': [pt], 'CtxLen': [ctx_len]}, {})['Out'][0]
+                   'PT': [pt], 'CtxLen': [ctx_len]},
+            self._window(i))['Out'][0]
 
 
 class OptBlock(KVBlock):
@@ -423,3 +452,139 @@ class DotsVlmBlock(object):
 
     def head(self, p, x):
         return _mm(self.norm(x, p['dots_norm_f_w']), p['dots_head_w'])
+
+
+class LagunaBlock(KVBlock):
+    """The layer of poolside's Laguna-S-2.1 (models/laguna.py declares
+    the same parameters; chipbench/reference/laguna.py is its plain
+    reference): pre-RMSNorm; attention of ``heads[i]`` query heads over
+    ``n_kv_heads`` K/V heads, more query heads on the layers that read a
+    WINDOW of the newest positions (``kinds[i] == 'window'``) than on
+    those that read everything; rotary positions by kind (``rope``: a
+    dict a kind of ``theta``, the lanes of a head that turn, ``yarn``
+    and the factor on cos and sin); a gate a head, the sigmoid of a
+    projection of the layer's normed input, on the attended values
+    before the output projection; then ``first_dense`` leading layers
+    with a dense SwiGLU FFN and after them routed experts under a
+    softmax router whose ``top_k`` weights are renormalised and scaled,
+    beside a shared expert.  Keys are cached after rotation.
+
+    A position caches K and V of ``n_kv_heads * head_dim`` lanes in
+    every layer, whatever the layer's query heads.  The window layers'
+    pages are a group of their own (``layer_kinds``): a stream holds a
+    ring there, not its whole context.  This chip may hold a SHARE of
+    each layer's routed experts, ``first_expert ..``, as ``DotsVlmBlock``
+    does."""
+
+    experts_share = True
+    n_heads = None          # by layer: ``heads``
+
+    def __init__(self, heads, n_kv_heads, head_dim, kinds, window, rope,
+                 top_k=10, routed_scaling_factor=2.5, first_expert=0,
+                 first_dense=1, eps=1e-6):
+        self.heads = tuple(int(h) for h in heads)
+        self._kv_heads, self._head_dim = int(n_kv_heads), int(head_dim)
+        self.kinds = tuple(kinds)
+        if set(self.kinds) - {'full', 'window'} or \
+                len(self.kinds) != len(self.heads):
+            raise ValueError("a kind a layer, 'full' or 'window': %r"
+                             % (self.kinds,))
+        self.window = int(window)
+        self.rope = {k: dict(v) for k, v in rope.items()}
+        self.top_k = int(top_k)
+        self.routed_scale = float(routed_scaling_factor)
+        self.first_expert, self.first_dense = int(first_expert), \
+            int(first_dense)
+        self.eps = float(eps)
+
+    @property
+    def n_kv_heads(self):
+        return self._kv_heads
+
+    def head_dim(self, sizes):
+        return self._head_dim
+
+    def window_of(self, i):
+        return self.window if self.kinds[i] == 'window' else None
+
+    def layer_kinds(self, n_layers):
+        return self.kinds[:n_layers]
+
+    def names(self, n_layers):
+        from ..models.laguna import param_names
+        return param_names(n_layers, self.first_dense)
+
+    def sizes(self, params):
+        v, d = params['laguna_embed'].shape
+        return {'d_model': int(d), 'vocab_size': int(v)}
+
+    def describe(self, program, sizes, backend, page_size, dtype):
+        if program not in ('step', 'chunk'):
+            return {}
+        by_kind = {k: self.heads[self.kinds.index(k)]
+                   for k in sorted(set(self.kinds))}
+        row = 2 * self._kv_heads * self._head_dim \
+            * jnp.dtype(dtype).itemsize
+        step = {k: paged_attention_path(
+            backend, self._kv_heads, self._head_dim, page_size, dtype,
+            h // self._kv_heads) for k, h in by_kind.items()}
+        out = {'attention': step, 'heads': by_kind,
+               'kv_heads': self._kv_heads, 'window': self.window,
+               'cache_bytes_per_position': {k: row for k in by_kind}}
+        if program == 'chunk':
+            out['attention'] = {k: '%s+%s' % (step[k], chunk_attention_path(
+                backend, self._kv_heads, self._head_dim, page_size, dtype,
+                by_kind[k], sizes['table_pages'][k]))
+                for k in by_kind}
+        return out
+
+    def norm(self, x, w):
+        return rms_norm_math(x, w, self.eps)
+
+    def rotate(self, u, positions, kind):
+        """u [T, H, Dh]: the first ``lanes`` of every head turn, the
+        others pass; cos and sin carry the kind's ``factor``."""
+        r = self.rope[kind]
+        lanes = int(r.get('lanes', u.shape[-1]))
+        turned = rotary_math(u[..., :lanes], positions, r['theta'],
+                             r.get('yarn')) * r.get('factor', 1.0)
+        return turned if lanes == u.shape[-1] else jnp.concatenate(
+            [turned, u[..., lanes:].astype(jnp.float32)], axis=-1)
+
+    def embed(self, p, tokens, positions):
+        return p['laguna_embed'][tokens].astype(jnp.float32)
+
+    def qkv(self, p, x, i, positions):
+        n = 'laguna_l%d_' % i
+        t, dh = x.shape[0], self._head_dim
+        u = self.norm(x, p[n + 'in_norm_w'])
+        q = _mm(u, p[n + 'q_w']).reshape(t, self.heads[i], dh)
+        k = _mm(u, p[n + 'k_w']).reshape(t, self._kv_heads, dh)
+        q = self.rotate(q, positions, self.kinds[i])
+        k = self.rotate(k, positions, self.kinds[i]).reshape(t, -1)
+        return q, k, _mm(u, p[n + 'v_w'])
+
+    def after_attention(self, p, x, ctx, i, active):
+        n = 'laguna_l%d_' % i
+        # the gate a head, from the layer's normed input (the same
+        # ``u`` as ``qkv``'s: the compiler keeps one)
+        u = self.norm(x, p[n + 'in_norm_w'])
+        gate = jax.nn.sigmoid(_mm(u, p[n + 'g_w']))            # [T, H]
+        x = x + _mm((ctx.astype(jnp.float32)
+                     * gate[:, :, None]).reshape(x.shape[0], -1),
+                    p[n + 'o_w'])
+        h = self.norm(x, p[n + 'post_norm_w'])
+        ffn = p[n + 'gate_w'], p[n + 'up_w'], p[n + 'down_w']
+        if i < self.first_dense:       # a leading dense layer
+            return x + swiglu_math(h, *ffn), None
+        w, idx = moe_route(h, p[n + 'router_w'], self.top_k, True,
+                           self.routed_scale)
+        y = moe_experts(h, w, idx, *ffn, first=self.first_expert,
+                        shared=(p[n + 'shared_gate_w'],
+                                p[n + 'shared_up_w'],
+                                p[n + 'shared_down_w']))
+        return x + y, moe_counts(idx, ffn[0].shape[0], active,
+                                 first=self.first_expert)
+
+    def head(self, p, x):
+        return _mm(self.norm(x, p['laguna_norm_f_w']), p['laguna_head_w'])

@@ -46,10 +46,22 @@ inference-throughput fix for decoder-only LMs, TPU-native:
 
 There is ONE definition of a decoder's serving path: which decoder is
 served is a block description (inference/blocks.py: ``OptBlock``, the
-default, ``OlmoeBlock``, ``DotsVlmBlock``) that supplies the layer's
-equations, what a position caches and how to attend over it, and the
-engine's ``prefill``, ``chunk`` and ``step`` are one loop over layers
-around them.  No code here names a model or a parameter.
+default, ``OlmoeBlock``, ``DotsVlmBlock``, ``LagunaBlock``) that
+supplies the layer's equations, what a position caches and how to
+attend over it, and the engine's ``prefill``, ``chunk`` and ``step`` are
+one loop over layers around them.  No code here names a model or a
+parameter.
+
+Where a block's layers differ in what they KEEP (``layer_kinds``: some
+every position, some a window of the newest), the cache holds a page
+group a kind, sized apart.  A stream holds pages of its whole context in
+the one and a RING in the other (``ring_pages``: the window's positions
+before a call's first row, the call's own rows, a page to spare;
+logical page j in column ``j % ring_pages``), claimed together at
+admission and given back together; the page table a call takes is the
+stream's pages and then its ring (``table_row``), and each layer's
+attend is handed its own group's part.  A block with one kind sees none
+of this: one group, one table, as before.
 
 Everything device-facing is AOT-compiled at ``warmup()`` via
 ``jit(...).lower(...).compile()`` — the serving loop only ever calls
@@ -119,7 +131,33 @@ def _host_operands(tokens, page_tables, ctx_lens):
                  for a in (tokens, page_tables, ctx_lens))
 
 
-class PagedKVCache(object):
+class _PageGroup(object):
+    """A free list over ``num_pages`` pages and, one past them, the
+    group's TRASH page (padded page-table entries and inactive slots
+    direct their writes there, so a compiled program needs no masking
+    on its scatter).  Host state of the server's worker thread."""
+
+    def __init__(self, num_pages):
+        self.num_pages = int(num_pages)
+        self.trash = self.num_pages
+        self._free = list(range(self.num_pages))
+
+    def free_pages(self):
+        return len(self._free)
+
+    def alloc(self, n):
+        """Claim ``n`` pages or None when the group can't supply them —
+        the caller (admission) keeps the stream queued, never drops."""
+        if n > len(self._free):
+            return None
+        pages, self._free = self._free[:n], self._free[n:]
+        return pages
+
+    def free(self, pages):
+        self._free.extend(pages)
+
+
+class PagedKVCache(_PageGroup):
     """Device page pools + host free list.  ``rows`` is what a position
     caches in every layer, ``((name, width), ...)`` as the block
     describes it (two rows ``k`` and ``v`` of ``n_heads * head_dim``
@@ -134,25 +172,42 @@ class PagedKVCache(object):
     it re-lays-out the whole pool at its edge (PERF.md section 6,
     PR 25).  The free list / page tables are host state (the server's
     worker thread owns them — no lock needed beyond the server's
-    own)."""
+    own).
+
+    The cache itself is the group of the layers that keep every
+    position (``alloc``, ``free``, ``trash``, ``free_pages``).  Layers
+    that read a window of the newest positions (``window_layers``) are
+    a second group, ``cache.window``, sized apart (``window_pages``)
+    with a free list and a trash page of its own: their buffers are
+    ``[window_pages + 1, page_size, width]``, and a stream holds a ring
+    of them, not a page a page of its context."""
 
     def __init__(self, n_layers, num_pages, page_size, n_heads=None,
-                 head_dim=None, dtype=jnp.float32, rows=None):
+                 head_dim=None, dtype=jnp.float32, rows=None,
+                 window_layers=(), window_pages=0):
+        _PageGroup.__init__(self, num_pages)
         self.n_layers = int(n_layers)
-        self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         if rows is None:
             rows = (('k', n_heads * head_dim), ('v', n_heads * head_dim))
         self.rows = tuple((str(n), int(w)) for n, w in rows)
-        # one extra TRASH page (index num_pages): padded page-table
-        # entries and inactive slots direct their writes there, so the
-        # compiled step needs no masking on the scatter
-        self.trash = self.num_pages
         self.dtype = jnp.dtype(dtype)
+        self.window_layers = frozenset(int(i) for i in window_layers)
+        self.window = _PageGroup(window_pages) if self.window_layers \
+            else None
         self.pools = [
-            [jnp.zeros((self.num_pages + 1, self.page_size, w), dtype)
-             for _ in range(self.n_layers)] for _n, w in self.rows]
-        self._free = list(range(self.num_pages))
+            [jnp.zeros((self.group_of(i).num_pages + 1, self.page_size, w),
+                       dtype)
+             for i in range(self.n_layers)] for _n, w in self.rows]
+
+    @property
+    def groups(self):
+        """The page groups: this one, then the window layers' if any."""
+        return [self] if self.window is None else [self, self.window]
+
+    def group_of(self, layer):
+        """The page group that holds ``layer``'s rows."""
+        return self.window if layer in self.window_layers else self
 
     def __getattr__(self, name):
         # a row's buffers by the row's name (only reached for names the
@@ -162,29 +217,28 @@ class PagedKVCache(object):
                 return self.pools[j]
         raise AttributeError(name)
 
-    def free_pages(self):
-        return len(self._free)
-
-    def alloc(self, n):
-        """Claim ``n`` pages or None when the pool can't supply them —
-        the caller (admission) keeps the stream queued, never drops."""
-        if n > len(self._free):
-            return None
-        pages, self._free = self._free[:n], self._free[n:]
-        return pages
-
-    def free(self, pages):
-        self._free.extend(pages)
-
     def row_widths(self):
         return [w for _n, w in self.rows]
 
+    def group_bytes(self):
+        """Resident bytes a group: layers x pages x page_size x the
+        rows' widths x dtype (trash pages included — they are
+        resident)."""
+        n_window = len(self.window_layers)
+        out = {'full': page_pool_bytes(
+            self.num_pages + 1, self.page_size, dtype=self.dtype,
+            n_layers=self.n_layers - n_window,
+            row_widths=self.row_widths())}
+        if self.window is not None:
+            out['window'] = page_pool_bytes(
+                self.window.num_pages + 1, self.page_size,
+                dtype=self.dtype, n_layers=n_window,
+                row_widths=self.row_widths())
+        return out
+
     def resident_bytes(self):
-        """Golden closed form: layers x pages x page_size x the rows'
-        widths x dtype (trash page included — it is resident)."""
-        return page_pool_bytes(self.num_pages + 1, self.page_size,
-                               dtype=self.dtype, n_layers=self.n_layers,
-                               row_widths=self.row_widths())
+        """Golden closed form, every group's."""
+        return sum(self.group_bytes().values())
 
 
 class _PrefixNode(object):
@@ -345,13 +399,15 @@ class DecodeEngine(object):
     def __init__(self, params, n_layers, n_heads, page_size=None,
                  num_pages=None, max_streams=None, prefill_bucket=None,
                  prefix_cache=None, prefill_chunk_tokens=None,
-                 dtype=jnp.float32, max_seq=None, block=None):
+                 dtype=jnp.float32, max_seq=None, block=None,
+                 window_pages=None):
         from ..flags import FLAGS
         enable_compile_cache()
         self.n_layers = int(n_layers)
         self.n_heads = int(n_heads)
         self.block = block = block or OptBlock(self.n_heads)
-        if block.n_heads != self.n_heads:
+        # (a block that says its heads layer by layer has no one count)
+        if block.n_heads is not None and block.n_heads != self.n_heads:
             raise ValueError("block has %d heads, engine %d"
                              % (block.n_heads, self.n_heads))
         self.params = self._place(params)
@@ -371,8 +427,11 @@ class DecodeEngine(object):
         self.routing = {'assignments': 0, 'all_assignments': 0,
                         'max_load': 0, 'touched': 0.0, 'steps': 0}
         # KV pages over the engine's decode steps (``_kv_pages``): the
-        # running slots' live ones, and the page tables' entries
-        self.kv_pages = {'live': 0, 'table': 0}
+        # running slots' live ones, and the page tables' entries; where
+        # there are rings, their live pages and the columns that took a
+        # newer page of their stream's (``_recycled``)
+        self.kv_pages = {'live': 0, 'table': 0, 'window_live': 0,
+                         'window_recycled': 0}
         self.page_size = int(page_size or FLAGS.decode_page_size)
         self.max_streams = int(max_streams or FLAGS.decode_max_streams)
         if self.max_seq % self.page_size:
@@ -384,9 +443,6 @@ class DecodeEngine(object):
         top = int(prefill_bucket or FLAGS.decode_prefill_bucket)
         self.buckets = decode_buckets(self.page_size,
                                       min(top, self.max_seq))
-        self.cache = PagedKVCache(self.n_layers, num_pages,
-                                  self.page_size, dtype=dtype,
-                                  rows=block.cache_rows(sizes))
         self.prefix_enabled = bool(FLAGS.decode_prefix_cache
                                    if prefix_cache is None
                                    else prefix_cache)
@@ -410,6 +466,39 @@ class DecodeEngine(object):
         else:
             self.chunk_grid = None
             self.chunk_buckets = []
+        # the kinds of the block's layers: those that read a window of
+        # the newest positions are a page group of their own, where a
+        # stream holds a RING (logical page j in column j % ring_pages):
+        # the window's positions before a call's first row and the
+        # call's own rows, and a page to spare for a window that starts
+        # inside one
+        kinds = block.layer_kinds(self.n_layers) \
+            if hasattr(block, 'layer_kinds') else ()
+        window_layers = [i for i, k in enumerate(kinds) if k == 'window']
+        self.ring_pages = 0
+        if window_layers:
+            if self.prefix_enabled:
+                raise ValueError(
+                    "prefix_cache=True with layers that read a window: "
+                    "what a shared prefix leaves in a window layer is "
+                    "the ring of whoever computed it, not pages another "
+                    "stream can claim; serve this block with the prefix "
+                    "cache off")
+            rows = self.chunk_grid if self.chunked else 1
+            self.ring_pages = min(
+                -(-(block.window - 1 + rows) // self.page_size) + 1,
+                self.pages_per_stream)
+        self.cache = PagedKVCache(
+            self.n_layers, num_pages, self.page_size, dtype=dtype,
+            rows=block.cache_rows(sizes), window_layers=window_layers,
+            window_pages=self.max_streams * self.ring_pages
+            if window_pages is None else window_pages)
+        # a layer's group: 0 the pages of the whole context, 1 the rings
+        self._group = [int(i in self.cache.window_layers)
+                       for i in range(self.n_layers)]
+        if window_layers:
+            sizes['table_pages'] = {'full': self.pages_per_stream,
+                                    'window': self.ring_pages}
         self.prefix = PrefixCache(self.page_size) \
             if self.prefix_enabled else None
         self.compiles_total = 0
@@ -422,10 +511,10 @@ class DecodeEngine(object):
         # tables): what warm-up runs, and what a chunk carries when it
         # carries no decode rows
         S = self.max_streams
+        self.idle_row = self.table_row(())   # a slot that holds nothing
         self._idle_step = (
             jnp.zeros((S,), jnp.int32),
-            jnp.full((S, self.pages_per_stream), self.cache.trash,
-                     jnp.int32),
+            jnp.asarray(np.tile(self.idle_row, (S, 1))),
             jnp.zeros((S,), jnp.int32))
 
     # -- compiled function builders ------------------------------------
@@ -447,9 +536,43 @@ class DecodeEngine(object):
                 temp_bytes=int(mem.temp_size_in_bytes),
                 alias_bytes=int(mem.alias_size_in_bytes),
                 argument_bytes=int(mem.argument_size_in_bytes),
-                pool_bytes=self.cache.resident_bytes())
+                pool_bytes=self.cache.group_bytes() if self.ring_pages
+                else self.cache.resident_bytes())
         self.compiles_total += 1
         return compiled
+
+    # -- a stream's pages, group by group -------------------------------
+
+    def table_row(self, pages):
+        """A stream's row of a page table, int32 [MPP (+ ring_pages)]:
+        its pages of the whole context, then (where there are window
+        layers) its ring, each padded with its own group's trash page.
+        ``pages`` is the stream's page list, or with a ring the pair
+        (pages, ring)."""
+        mpp, ring = self.pages_per_stream, ()
+        if self.ring_pages:
+            pages, ring = pages or ((), ())
+        row = np.full((mpp + self.ring_pages,), self.cache.trash, np.int32)
+        n = min(len(pages), mpp)
+        row[:n] = pages[:n]
+        if self.ring_pages:
+            row[mpp:] = self.cache.window.trash
+            row[mpp:mpp + len(ring)] = ring
+        return row
+
+    def ring_for(self, span):
+        """The ring pages a stream of ``span`` positions claims."""
+        return min(self.ring_pages, -(-int(span) // self.page_size))
+
+    def _tables(self, pt):
+        """A page table [..., MPP (+ ring_pages)] -> one table a group."""
+        if not self.ring_pages:
+            return (pt,)
+        mpp = self.pages_per_stream
+        return pt[..., :mpp], pt[..., mpp:]
+
+    def _trashes(self):
+        return [g.trash for g in self.cache.groups]
 
     def _layers(self, params, x, positions, active, attend):
         """A block's layers over x [T, D]: ``attend(i, q, rows)`` is
@@ -536,20 +659,44 @@ class DecodeEngine(object):
         if self.block.live_positions_arg:
             # the positions a step's attention reads, the new one too
             span_args[self.block.live_positions_arg] = int(np.sum(ctx + 1))
+        if self.ring_pages:
+            # by group: the pages above, and the rings' pages that hold
+            # a window's positions (the new one too)
+            P, w = self.page_size, self.block.window
+            ring = int(np.sum(ctx // P - np.maximum(ctx + 1 - w, 0) // P
+                              + 1))
+            span_args.update(
+                kv_full_live_pages=live, kv_window_live_pages=ring,
+                # and the positions in them that attention reads
+                kv_full_live_positions=int(np.sum(ctx + 1)),
+                kv_window_live_positions=int(np.sum(
+                    np.minimum(ctx + 1, w))))
+            self.kv_pages['window_live'] += ring
+            self._recycled(ctx, ctx + 1)
         self.kv_pages['live'] += live
         self.kv_pages['table'] += pts.size
         return int(np.sum(running))
 
+    def _recycled(self, lo, hi):
+        """Count the ring columns that positions [lo, hi) (arrays or
+        numbers, a stream each) begin a page in when the page is not the
+        column's first: a page given back to its own stream."""
+        P, R = self.page_size, self.ring_pages
+        first = np.maximum(-(-np.asarray(lo) // P), R)
+        self.kv_pages['window_recycled'] += int(np.sum(np.maximum(
+            -(-np.asarray(hi) // P) - first, 0)))
+
     # -- the three programs: one loop, three ways to attend -------------
 
-    @staticmethod
-    def _write_then(pools, page_idx, offset, read):
+    def _write_then(self, pools, page_idx, offset, read):
         """``attend`` of chunk and step: the rows layer i caches land at
-        (page, offset) of its own buffers, then ``read(i, q)`` attends
-        over what was written."""
+        (page, offset) of its own buffers (``page_idx``: the pages, one
+        array a group), then ``read(i, q)`` attends over what was
+        written."""
         def attend(i, q, rows):
             for pool, r in zip(pools, rows):
-                pool[i] = pool[i].at[page_idx, offset].set(r)
+                pool[i] = pool[i].at[page_idx[self._group[i]],
+                                     offset].set(r)
             return read(i, q)
         return attend
 
@@ -590,8 +737,13 @@ class DecodeEngine(object):
         # counted, and their outputs never leave the executable
         pos = pos0 + jnp.arange(bucket)
         valid = jnp.arange(bucket) < n_valid
-        page_idx = pt[jnp.clip(pos // P, 0, mpp - 1)]
-        page_idx = jnp.where(valid, page_idx, self.cache.trash)
+        tables = self._tables(pt)
+        page_idx = tables[0][jnp.clip(pos // P, 0, mpp - 1)]
+        page_idx = [jnp.where(valid, page_idx, self.cache.trash)]
+        if self.ring_pages:
+            page_idx.append(jnp.where(
+                valid, tables[1][(pos // P) % self.ring_pages],
+                self.cache.window.trash))
         return pos, valid, page_idx, pos % P
 
     def _step_rows(self, pt, ctx_len):
@@ -601,20 +753,31 @@ class DecodeEngine(object):
         # ctx_len counts CACHED positions per slot; the incoming
         # token sits at position ctx_len and is cached this step
         pos = jnp.clip(ctx_len, 0, self.max_seq - 1)
-        page_idx = jnp.take_along_axis(
-            pt, (pos // P)[:, None], axis=1)[:, 0]
+        tables = self._tables(pt)
+        page_idx = [jnp.take_along_axis(
+            tables[0], (pos // P)[:, None], axis=1)[:, 0]]
+        if self.ring_pages:
+            page_idx.append(jnp.take_along_axis(
+                tables[1], ((pos // P) % self.ring_pages)[:, None],
+                axis=1)[:, 0])
         return pos, page_idx, pos % P
 
     def _chunk_read(self, params, pools, pt, pos0):
+        tables = self._tables(pt)
+
         def read(i, q):
             return self.block.attend_chunk(
-                params, i, q, [pool[i] for pool in pools], pt, pos0)
+                params, i, q, [pool[i] for pool in pools],
+                tables[self._group[i]], pos0)
         return read
 
     def _step_read(self, params, pools, pt, pos):
+        tables = self._tables(pt)
+
         def read(i, q):
             return self.block.attend_step(
-                params, i, q, [pool[i] for pool in pools], pt, pos + 1)
+                params, i, q, [pool[i] for pool in pools],
+                tables[self._group[i]], pos + 1)
         return read
 
     def _chunk_fn(self, bucket):
@@ -636,8 +799,15 @@ class DecodeEngine(object):
             read_chunk = self._chunk_read(params, pools, pt, pos0)
 
             def read(i, q):
-                return jnp.concatenate([read_step(i, q[:S]),
-                                        read_chunk(i, q[S:])])
+                step_rows = read_step(i, q[:S])
+                rows = read_chunk(i, q[S:])
+                if self.ring_pages:
+                    # a padded row reads positions nobody wrote, in a
+                    # ring whatever the page's last holder left: it
+                    # attends to nothing, so that what it writes to the
+                    # trash pages stays finite for whoever gathers them
+                    rows = jnp.where(valid[:, None, None], rows, 0.0)
+                return jnp.concatenate([step_rows, rows])
 
             # a last chunk's padded rows point past the prompt: ``embed``
             # (which may index a position table) gets them inside max_seq
@@ -649,7 +819,8 @@ class DecodeEngine(object):
                 jnp.concatenate([spos, pos]),
                 jnp.concatenate([step_pt[:, 0] != trash, valid]),
                 self._write_then(pools,
-                                 jnp.concatenate([spage, page_idx]),
+                                 [jnp.concatenate(both) for both
+                                  in zip(spage, page_idx)],
                                  jnp.concatenate([soffset, offset]), read))
             # the head on the decode rows and the chunk's last valid row
             last = S + jnp.clip(n_valid - 1, 0, bucket - 1)
@@ -696,13 +867,14 @@ class DecodeEngine(object):
         def pack(*args):
             # scatter the rows the prefill kept into the claimed pages:
             # [L, T, ...] -> [L, n_pages, P, width], layer i written at
-            # ``pages`` of its own buffer (padded entries point at the
+            # its group's ``pages`` of its own buffer (padded entries,
+            # and in a ring the pages behind the newest, point at the
             # trash page)
-            pools, kept, pages = args[:n], args[n:2 * n], args[2 * n]
+            pools, kept, pages = args[:n], args[n:2 * n], args[2 * n:]
             out = []
             for row_pools, rows in zip(pools, kept):
                 paged = rows.reshape(L, n_pages, P, -1)
-                out.append([pool.at[pages].set(paged[i])
+                out.append([pool.at[pages[self._group[i]]].set(paged[i])
                             for i, pool in enumerate(row_pools)])
             return tuple(out)
 
@@ -712,9 +884,9 @@ class DecodeEngine(object):
             prefill, self.params, toks, jnp.int32(0), bucket=bucket)
         kept = jax.eval_shape(prefill, self.params, toks,
                               jnp.int32(0))[1:1 + n]
-        pages = jnp.zeros((n_pages,), jnp.int32)
+        pages = [jnp.zeros((n_pages,), jnp.int32) for _ in self._trashes()]
         self._pack[bucket] = self._compile(
-            pack, *self.cache.pools, *kept, pages,
+            pack, *self.cache.pools, *kept, *pages,
             donate=tuple(range(n)), bucket=bucket)
 
     def _ensure_chunk(self, bucket):
@@ -740,8 +912,7 @@ class DecodeEngine(object):
         self._chunk[bucket] = self._compile(
             self._chunk_fn(bucket), self.params, *self.cache.pools,
             jnp.zeros((bucket,), jnp.int32),
-            jnp.full((self.pages_per_stream,), self.cache.trash,
-                     jnp.int32),
+            jnp.asarray(self.idle_row),
             jnp.int32(0), jnp.int32(1), *self._idle_step,
             donate=tuple(range(1, 1 + n)), bucket=bucket)
 
@@ -765,7 +936,6 @@ class DecodeEngine(object):
         executables (compiles_after_warmup counts any miss)."""
         if self._compiles_at_warmup == self.compiles_total:
             return  # already compiled AND warm-executed, nothing new
-        trash = self.cache.trash
         if self.chunked:
             # chunked path: all prefill (cold included) runs the chunk
             # executables — the monolithic prefill/pack pair is never
@@ -773,12 +943,11 @@ class DecodeEngine(object):
             for b in self.chunk_buckets:
                 self._ensure_chunk(b)
             self._ensure_step()
-            mpp = self.pages_per_stream
             for b in self.chunk_buckets:
                 logits = self._pools_out(self._chunk[b](
                     self.params, *self.cache.pools,
                     jnp.zeros((b,), jnp.int32),
-                    jnp.full((mpp,), trash, jnp.int32),
+                    jnp.asarray(self.idle_row),
                     jnp.int32(0), jnp.int32(b), *self._idle_step))[0]
                 jax.block_until_ready(logits)
         else:
@@ -789,10 +958,10 @@ class DecodeEngine(object):
                 logits, *kept = self._prefill[b](
                     self.params, jnp.zeros((b,), jnp.int32),
                     jnp.int32(0))[:1 + len(self.cache.rows)]
-                all_trash = jnp.full((b // self.page_size,), trash,
-                                     jnp.int32)
+                all_trash = [jnp.full((b // self.page_size,), t, jnp.int32)
+                             for t in self._trashes()]
                 self._pools_out(self._pack[b](
-                    *self.cache.pools, *kept, all_trash))
+                    *self.cache.pools, *kept, *all_trash))
                 jax.block_until_ready(logits)
         logits = self._pools_out(self._step(
             self.params, *self.cache.pools, *self._idle_step))[0]
@@ -817,7 +986,8 @@ class DecodeEngine(object):
 
     def prefill_into(self, prompt, pages):
         """Run one prompt's prefill and pack its rows into ``pages``
-        (the stream's claimed pages, page 0 of the stream first).
+        (the stream's claimed pages, page 0 of the stream first; with
+        window layers the pair (pages, ring), as ``table_row`` takes it).
         Returns the last-position logits as numpy [V] — the first
         generated token's distribution, i.e. the TTFT payload."""
         prompt = np.asarray(prompt, dtype=np.int32)
@@ -830,14 +1000,26 @@ class DecodeEngine(object):
             toks[:t] = prompt
             n = len(self.cache.rows)
             n_pages = bucket // self.page_size
-            page_ids = np.full((n_pages,), self.cache.trash, np.int32)
+            ring = ()
+            if self.ring_pages:
+                pages, ring = pages
+            page_ids = [np.full((n_pages,), self.cache.trash, np.int32)]
             n_real = min(len(pages), n_pages)
-            page_ids[:n_real] = pages[:n_real]
+            page_ids[0][:n_real] = pages[:n_real]
+            if self.ring_pages:
+                # the prompt's newest pages into their ring columns,
+                # the pages behind them nowhere
+                R, last = self.ring_pages, (t - 1) // self.page_size
+                ids = np.full((n_pages,), self.cache.window.trash, np.int32)
+                j = np.arange(max(last - len(ring) + 1, 0), last + 1)
+                ids[j] = np.asarray(ring, np.int32)[j % R]
+                page_ids.append(ids)
+                self._recycled(0, t)
             # numpy in, as ``_host_operands`` says why
             logits, *rest = self._prefill[bucket](
                 self.params, toks, np.int32(t - 1))
             self._pools_out(self._pack[bucket](
-                *self.cache.pools, *rest[:n], page_ids))
+                *self.cache.pools, *rest[:n], *page_ids))
             return self._fetch((logits,), rest[n:], args)[0]
 
     def chunk_spans(self, prompt_len, start=0):
@@ -863,7 +1045,8 @@ class DecodeEngine(object):
         """Run ONE prefill chunk for a single stream: ``tokens`` [c]
         (c <= chunk_grid) land at absolute positions pos0..pos0+c-1 in
         the pages named by ``pages`` (the stream's page table; entries
-        past it route to trash).  Returns the chunk's last-row logits
+        past it route to trash; with window layers the pair (pages,
+        ring)).  Returns the chunk's last-row logits
         as numpy [V] — only the final chunk's matter (the TTFT
         payload), earlier chunks' are a one-row head by-product.
 
@@ -887,10 +1070,9 @@ class DecodeEngine(object):
             with _obs.span('decode.prefill_chunk.dispatch'):
                 toks = np.zeros((bucket,), np.int32)
                 toks[:c] = tokens
-                mpp = self.pages_per_stream
-                pt = np.full((mpp,), self.cache.trash, np.int32)
-                n = min(len(pages), mpp)
-                pt[:n] = pages[:n]
+                pt = self.table_row(pages)
+                if self.ring_pages:
+                    self._recycled(pos0, pos0 + c)
                 carried = self._idle_step if step_tokens is None \
                     else _host_operands(step_tokens, page_tables, ctx_lens)
                 logits, nxt, step_logits, *extra = self._pools_out(
@@ -1021,6 +1203,7 @@ class DecodeStream(object):
         # worker-side state
         self._slot = None
         self._pages = None
+        self._ring = []           # its pages of the window group, a ring
         self._ctx_len = 0         # cached positions
         # chunked-path worker state
         self._prefill_pos = None  # next uncomputed position, else None
@@ -1179,6 +1362,14 @@ class DecodeServer(object):
                 'active_streams': active,
                 'queued': len(self._queue),
                 'free_pages': self.engine.cache.free_pages(),
+                # where some layers read a window: their group's free
+                # pages, its rings' live pages over the decode steps,
+                # and the ring columns that took a newer page of their
+                # own stream's
+                'window_free_pages': eng.cache.window.free_pages()
+                if eng.cache.window is not None else 0,
+                'kv_window_live_pages': eng.kv_pages['window_live'],
+                'window_pages_recycled': eng.kv_pages['window_recycled'],
                 'generated_tokens': int(self._m.tokens.value),
                 'decode_steps': int(self._m.steps.value),
                 'compiles_total': self.engine.compiles_total,
@@ -1213,6 +1404,29 @@ class DecodeServer(object):
         span = len(st.prompt) + st.max_new_tokens
         return -(-span // self.engine.page_size)
 
+    def _claim(self, st, n, span):
+        """``n`` pages of the whole context and, where some layers read
+        a window, the stream's ring with them: both or neither (the
+        stream then stays queued)."""
+        cache = self.engine.cache
+        pages = cache.alloc(n)
+        if pages is not None and cache.window is not None:
+            st._ring = cache.window.alloc(self.engine.ring_for(span))
+            if st._ring is None:
+                cache.free(pages)
+                st._ring, pages = [], None
+        return pages
+
+    def _stream_pages(self, st):
+        """A stream's pages as the engine's calls take them."""
+        return (st._pages, st._ring) if self.engine.ring_pages \
+            else st._pages
+
+    def _free_ring(self, st):
+        if st._ring:
+            self.engine.cache.window.free(st._ring)
+            st._ring = []
+
     def _admit(self, st):
         """Page claim + prefill for a slot-reserved stream.  Runs on
         the worker OUTSIDE the lock (device work); the slot itself was
@@ -1221,12 +1435,13 @@ class DecodeServer(object):
         with _obs.span('server.admit', args={'rid': st.request_id}):
             if eng.chunked:
                 return self._admit_chunked(st)
-            pages = eng.cache.alloc(self._pages_needed(st))
+            pages = self._claim(st, self._pages_needed(st),
+                                len(st.prompt) + st.max_new_tokens)
             if pages is None:
                 return False
             st._pages = pages
             self._m.pages_allocated.inc(len(pages))
-            logits = eng.prefill_into(st.prompt, pages)
+            logits = eng.prefill_into(st.prompt, self._stream_pages(st))
             first = int(np.argmax(logits))
             now = time.perf_counter()
             st.first_token_t = now
@@ -1284,7 +1499,8 @@ class DecodeServer(object):
             self._evict(short)
         owned = None
         if eng.cache.free_pages() >= n_tail + self._reserve:
-            owned = eng.cache.alloc(n_tail)
+            owned = self._claim(st, n_tail, t + st.max_new_tokens
+                                - len(st.tokens))
         if owned is None:
             if nodes:
                 eng.prefix.release(nodes)
@@ -1371,8 +1587,8 @@ class DecodeServer(object):
         for n, (st, lo, hi) in enumerate(plan):
             carry = step_operands if rows and n == len(plan) - 1 else ()
             with _obs.span('server.admit', args={'rid': st.request_id}):
-                out = eng.prefill_chunk(st._prompt_eff[lo:hi], st._pages,
-                                        lo, *carry)
+                out = eng.prefill_chunk(st._prompt_eff[lo:hi],
+                                        self._stream_pages(st), lo, *carry)
                 logits, nxt = out[:2] if carry else (out, None)
                 self._m.prefill_chunks.inc()
                 if carry:
@@ -1409,6 +1625,7 @@ class DecodeServer(object):
             eng.cache.free(st._owned)
             self._m.pages_freed.inc(len(st._owned))
             st._owned = []
+        self._free_ring(st)
         st._pages = None
         st._prompt_eff = None
         st._prefill_pos = None
@@ -1440,6 +1657,7 @@ class DecodeServer(object):
         else:
             eng.cache.free(st._pages)
             self._m.pages_freed.inc(len(st._pages))
+        self._free_ring(st)
         st._pages = None
         st.done_t = time.perf_counter()
         # the request's three spans, from the stamps the stream kept
@@ -1479,8 +1697,7 @@ class DecodeServer(object):
         accounting after the call is the same whichever ran.  Fills
         ``args`` (the tick span's) with what the tick did."""
         eng = self.engine
-        S, mpp = eng.max_streams, eng.pages_per_stream
-        trash = eng.cache.trash
+        S = eng.max_streams
         with self._cv:
             # admission at step granularity: continuous mode fills
             # any free slot; static mode only starts a fresh
@@ -1538,12 +1755,14 @@ class DecodeServer(object):
         args['running'] = len(decoding)
         # build the batched step inputs from host stream state
         tokens = np.zeros((S,), np.int32)
-        pts = np.full((S, mpp), trash, np.int32)
+        pts = np.tile(eng.idle_row, (S, 1))
         ctx = np.zeros((S,), np.int32)
+        mpp = eng.pages_per_stream
         for st in decoding:
             i = st._slot
             tokens[i] = st.tokens[-1]
             pts[i, :len(st._pages)] = st._pages
+            pts[i, mpp:mpp + len(st._ring)] = st._ring
             ctx[i] = st._ctx_len
         if chunks:
             nxt = self._run_prefill_chunks(chunks, (tokens, pts, ctx),

@@ -17,7 +17,9 @@ from ..core.registry import register_op
 from .common import first, out
 
 
-def _dense_attention(q, k, v, causal, scale):
+def _dense_attention(q, k, v, causal, scale, window=None):
+    """``window``: a query reads the ``window`` positions up to and with
+    its own and none before them (None: everything behind it)."""
     squeeze = q.ndim == 3
     if squeeze:
         q, k, v = (x[:, :, None, :] for x in (q, k, v))
@@ -28,14 +30,51 @@ def _dense_attention(q, k, v, causal, scale):
     if causal:
         tq, tk = s.shape[2], s.shape[3]
         mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        if window is not None:
+            mask &= jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :] \
+                < window
         s = jnp.where(mask[None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum('bhqk,bkhd->bqhd', p, v.astype(jnp.float32))
     return o[:, :, 0, :] if squeeze else o
 
 
+def _kv_heads(pool, head_dim):
+    """K/V heads of a pool's row, [N, P, Hkv, D] or [N, P, Hkv * D]."""
+    return math.prod(pool.shape[2:]) // head_dim
+
+
+def _grouped(rows, n_heads):
+    """Gathered K or V rows [..., Hkv, D] under ``n_heads`` query heads:
+    query head h reads K/V head ``h // (n_heads / Hkv)``."""
+    group = n_heads // rows.shape[-2]
+    return rows if group == 1 else jnp.repeat(rows, group, axis=-2)
+
+
+def _ring_positions(ctx_len, columns, page):
+    """The positions a ring of ``columns`` pages holds for a reader whose
+    newest position is ``ctx_len - 1``: logical page j lives in column
+    ``j % columns``, so a column holds the newest page of its residue
+    that is not past the reader's last.  [R, columns * page] int32 for
+    ``ctx_len`` [R]; negative where the column has no such page.  (A
+    table as long as the stream is the ring that never wraps.)"""
+    last = (ctx_len - 1) // page
+    j = last[:, None] - jnp.mod(last[:, None] - jnp.arange(columns)[None],
+                                columns)
+    return (j[:, :, None] * page + jnp.arange(page)).reshape(
+        ctx_len.shape[0], columns * page)
+
+
+def _window_valid(ctx_len, columns, page, window):
+    """[R, columns * page]: the ring's positions inside a reader's
+    window, the ``window`` newest of its ``ctx_len``."""
+    pos = _ring_positions(ctx_len, columns, page)
+    return (pos >= jnp.maximum(ctx_len - window, 0)[:, None]) \
+        & (pos < ctx_len[:, None])
+
+
 def paged_attention_math(q, k_pool, v_pool, page_table, ctx_len,
-                         scale=None):
+                         scale=None, window=None):
     """Decode-step attention against a paged KV cache, the jnp math the
     registered op and the decode engine share.
 
@@ -46,7 +85,11 @@ def paged_attention_math(q, k_pool, v_pool, page_table, ctx_len,
     int32 page ids per stream (unused entries may point anywhere — typically
     the trash page — their keys are masked); ``ctx_len`` [S] int32
     VALID key count per stream, current token included.  Returns
-    [S, H, D].  Gathers each stream's pages, masks positions >= ctx_len
+    [S, H, D].  The pool's row says how many K/V heads there are: with
+    fewer than H, query head h reads K/V head ``h // (H / Hkv)``.
+    ``window`` (None: everything) is how many of the newest positions a
+    slot reads, and makes the table a RING: logical page j is column
+    ``j % MPP`` (``_ring_positions``).  Gathers each stream's pages, masks positions >= ctx_len
     to -1e30, and softmaxes in f32 — identical masking/accumulation to
     ``_dense_attention``, so paged decode logits sit within ulps of the
     full-context recompute (tests/test_decode.py pins it).
@@ -57,11 +100,18 @@ def paged_attention_math(q, k_pool, v_pool, page_table, ctx_len,
     s, h, d = q.shape
     mpp = page_table.shape[1]
     idx = jnp.clip(page_table, 0, n - 1)
-    k = k_pool[idx].reshape(s, mpp * p, h, d)   # [S, T, H, D]
-    v = v_pool[idx].reshape(s, mpp * p, h, d)
+    hkv = _kv_heads(k_pool, d)
+    k = _grouped(k_pool[idx].reshape(s, mpp * p, hkv, d), h)  # [S, T, H, D]
+    v = _grouped(v_pool[idx].reshape(s, mpp * p, hkv, d), h)
     scores = jnp.einsum('shd,sthd->sht', q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
-    valid = jnp.arange(mpp * p)[None, :] < ctx_len[:, None]  # [S, T]
+    if window is None:
+        valid = jnp.arange(mpp * p)[None, :] < ctx_len[:, None]  # [S, T]
+    else:
+        valid = _window_valid(ctx_len, mpp, p, window)
+        # a ring's columns behind the window hold what was there, as the
+        # kernel's dead rows do: their p is 0, and 0 * NaN is not
+        v = jnp.where(valid[:, :, None, None], v, jnp.zeros((), v.dtype))
     scores = jnp.where(valid[:, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum('sht,sthd->shd', probs, v.astype(jnp.float32))
@@ -69,7 +119,7 @@ def paged_attention_math(q, k_pool, v_pool, page_table, ctx_len,
 
 
 def chunked_prefill_attention_math(q, k_pool, v_pool, page_table, pos0,
-                                   scale=None):
+                                   scale=None, window=None):
     """Chunked-prefill attention for ONE stream against a partial page
     table: chunk queries attend over every already-cached position —
     prior chunks AND the chunk's own keys (scattered before the call) —
@@ -87,7 +137,9 @@ def chunked_prefill_attention_math(q, k_pool, v_pool, page_table, pos0,
     chunk's padded tail all mask out.  f32 scores/softmax, identical
     accumulation order to ``paged_attention_math``: a chunk sequence
     over the same cached pages reproduces the prefix bitwise
-    (tests/test_decode_prefix.py pins hit-vs-cold equality).
+    (tests/test_decode_prefix.py pins hit-vs-cold equality).  K/V heads
+    from the pool's row and ``window`` (a query's newest positions, the
+    table a ring) as ``paged_attention_math`` takes them.
     """
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
@@ -95,62 +147,127 @@ def chunked_prefill_attention_math(q, k_pool, v_pool, page_table, pos0,
     c, h, d = q.shape
     mpp = page_table.shape[0]
     idx = jnp.clip(page_table, 0, n - 1)
-    k = k_pool[idx].reshape(mpp * p, h, d)      # [T, H, D]
-    v = v_pool[idx].reshape(mpp * p, h, d)
+    hkv = _kv_heads(k_pool, d)
+    k = _grouped(k_pool[idx].reshape(mpp * p, hkv, d), h)     # [T, H, D]
+    v = _grouped(v_pool[idx].reshape(mpp * p, hkv, d), h)
     scores = jnp.einsum('chd,thd->cht', q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     qpos = pos0 + jnp.arange(c)                  # absolute positions
-    valid = jnp.arange(mpp * p)[None, :] <= qpos[:, None]  # [C, T]
+    if window is None:
+        valid = jnp.arange(mpp * p)[None, :] <= qpos[:, None]  # [C, T]
+    else:
+        valid = _window_valid(qpos + 1, mpp, p, window)
+        v = jnp.where(jnp.any(valid, axis=0)[:, None, None], v,
+                      jnp.zeros((), v.dtype))
     scores = jnp.where(valid[:, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum('cht,thd->chd', probs, v.astype(jnp.float32))
     return o.astype(q.dtype)
 
 
-@register_op('chunked_prefill_attention')
-def _chunked_prefill_attention(ctx, ins, attrs):
-    q = first(ins, 'Q')              # [C, H, D]
-    k_pool = first(ins, 'KPool')     # [N, P, H, D]
-    v_pool = first(ins, 'VPool')
-    page_table = first(ins, 'PT')    # [MPP] int32
-    pos0 = first(ins, 'Pos0')        # scalar int32
-    return out(chunked_prefill_attention_math(
-        q, k_pool, v_pool, page_table.astype(jnp.int32),
-        jnp.asarray(pos0, jnp.int32).reshape(()),
-        scale=attrs.get('scale', None)))
+# A query row's float32 scores over a whole table, [H, MPP * P], above
+# which a chunk's rows take the live-pages kernel on a TPU.  Under it
+# the gather is the faster form (16 heads over a table of 1024
+# positions, 64 KB a row: 0.1 ms a layer for a chunk of 128; PERF.md
+# section 6, PR 28); 72 heads over a ring of 1040 positions are 300 KB a
+# row and 48 over a table of 17408 are 3.3 MB, 2.6 GB for a chunk of
+# 512, which no chip holds nine layers of.
+_GATHER_ROW_SCORES_LIMIT = 128 * 1024
 
 
-def paged_attention_path(backend, n_heads, head_dim, page_size, dtype):
+def paged_attention_path(backend, n_kv_heads, head_dim, page_size, dtype,
+                         group=1):
     """What the ``paged_attention`` op runs for these shapes:
     ``'pallas_paged'`` (ops/pallas/paged_attention.py, live pages only)
-    on a TPU when the row ``n_heads * head_dim`` is a whole number of
-    128-lane registers and a page a whole number of the pool dtype's
-    sublane tiles, else ``'xla_gather'`` (``paged_attention_math``).
-    Backend, shapes and dtype decide, nothing else; the decode engine
-    records the answer with its step's ``decode.compile`` span."""
+    on a TPU when the K/V ROW ``n_kv_heads * head_dim`` is a whole
+    number of 128-lane registers and a page a whole number of the pool
+    dtype's sublane tiles (and, with ``group`` query heads over a K/V
+    head, the K/V heads fill whole sublane tiles), else
+    ``'xla_gather'`` (``paged_attention_math``).  Backend, shapes and
+    dtype decide, nothing else; the decode engine records the answer
+    with its step's ``decode.compile`` span."""
     if backend == 'tpu':
         # lazy, as flash_attention below
         from .pallas.paged_attention import supported
-        if supported(n_heads, head_dim, page_size, dtype):
+        if supported(n_kv_heads, head_dim, page_size, dtype, group):
             return 'pallas_paged'
     return 'xla_gather'
 
 
+def chunk_attention_path(backend, n_kv_heads, head_dim, page_size, dtype,
+                         n_heads, table_pages):
+    """What ``chunked_prefill_attention`` runs for a chunk under
+    ``n_heads`` query heads over a table of ``table_pages``:
+    ``'pallas_paged'`` (``chunk_paged_attention``, the stream's live
+    pages) on a TPU when the kernel takes the K/V row and a query row's
+    float32 scores over the whole table would pass
+    ``_GATHER_ROW_SCORES_LIMIT``, else ``'xla_gather'``."""
+    if backend == 'tpu' and n_heads * table_pages * page_size * 4 \
+            > _GATHER_ROW_SCORES_LIMIT:
+        from .pallas.paged_attention import chunk_supported
+        if chunk_supported(n_kv_heads, head_dim, page_size, dtype):
+            return 'pallas_paged'
+    return 'xla_gather'
+
+
+def _window_attrs(attrs):
+    """The attention ops' attributes as keywords: ``scale``, and
+    ``window`` where the layer has one."""
+    kw = {'scale': attrs.get('scale', None)}
+    if attrs.get('window') is not None:
+        kw['window'] = int(attrs['window'])
+    return kw
+
+
+@register_op('chunked_prefill_attention')
+def _chunked_prefill_attention(ctx, ins, attrs):
+    """One stream's prompt chunk over its K/V pages: Q [C, H, D] at
+    positions Pos0 .., KPool / VPool [N, P, Hkv * D] (the row says how
+    many K/V heads the H query heads share), PT [MPP]; causal on the
+    absolute-position grid; ``window``: a query reads that many of the
+    newest positions and PT is a ring.  Out [C, H, D].  On a TPU the
+    live-pages kernel where a query row's scores over the whole table
+    are not worth gathering, else a gather of the table."""
+    q = first(ins, 'Q')              # [C, H, D]
+    k_pool = first(ins, 'KPool')     # [N, P, Hkv, D] or [N, P, Hkv*D]
+    v_pool = first(ins, 'VPool')
+    page_table = first(ins, 'PT')    # [MPP] int32
+    pos0 = first(ins, 'Pos0')        # scalar int32
+    backend = getattr(ctx, 'backend', jax.default_backend())
+    attend = chunked_prefill_attention_math
+    c, h, d = q.shape
+    if chunk_attention_path(backend, _kv_heads(k_pool, d), d,
+                            k_pool.shape[1], k_pool.dtype, h,
+                            page_table.shape[0]) == 'pallas_paged':
+        from .pallas.paged_attention import chunk_paged_attention as attend
+    return out(attend(
+        q, k_pool, v_pool, page_table.astype(jnp.int32),
+        jnp.asarray(pos0, jnp.int32).reshape(()), **_window_attrs(attrs)))
+
+
 @register_op('paged_attention')
 def _paged_attention(ctx, ins, attrs):
+    """Decode-step attention over a paged K/V cache: Q [S, H, D], one
+    token a slot, KPool / VPool [N, P, Hkv * D] (the row says how many
+    K/V heads the H query heads share), PT [S, MPP], CtxLen [S];
+    ``window``: a slot reads that many of its newest positions and PT
+    is a ring.  Out [S, H, D].  On a TPU a Pallas kernel over the live
+    pages, else a gather of the page tables."""
     q = first(ins, 'Q')              # [S, H, D]
-    k_pool = first(ins, 'KPool')     # [N, P, H, D] or [N, P, H*D]
+    k_pool = first(ins, 'KPool')     # [N, P, Hkv, D] or [N, P, Hkv*D]
     v_pool = first(ins, 'VPool')
     page_table = first(ins, 'PT')    # [S, MPP] int32
     ctx_len = first(ins, 'CtxLen')   # [S] int32
     backend = getattr(ctx, 'backend', jax.default_backend())
     attend = paged_attention_math
-    if paged_attention_path(backend, *q.shape[1:], k_pool.shape[1],
-                            k_pool.dtype) == 'pallas_paged':
+    h, d = q.shape[1:]
+    hkv = _kv_heads(k_pool, d)
+    if paged_attention_path(backend, hkv, d, k_pool.shape[1],
+                            k_pool.dtype, h // hkv) == 'pallas_paged':
         from .pallas import paged_attention as attend
     return out(attend(
         q, k_pool, v_pool, page_table.astype(jnp.int32),
-        ctx_len.astype(jnp.int32), scale=attrs.get('scale', None)))
+        ctx_len.astype(jnp.int32), **_window_attrs(attrs)))
 
 
 # -- a shared latent row (multi-head latent attention) ---------------------

@@ -94,19 +94,22 @@ def rotary_math(x, positions, theta=10000.0, yarn=None, interleaved=False):
     return xf * jnp.cos(ang) + rot * jnp.sin(ang)
 
 
-def moe_route(x, router_w, top_k, renormalize=False):
+def moe_route(x, router_w, top_k, renormalize=False, scale=None):
     """Router of ``moe_ffn``: softmax over the experts in float32 at
     ``highest`` matmul precision (the published code computes routing
     weights in float32), then the ``top_k`` largest per token (ties:
     lower index first, as ``lax.top_k``).  Returns (weights [N, k] f32,
     indices [N, k] int32); weights are the softmax values as they are
-    unless ``renormalize``."""
+    unless ``renormalize`` (divided by their sum), times ``scale`` where
+    the router has a scaling factor."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=_HIGHEST)
     r = jax.nn.softmax(logits, axis=-1)
     w, idx = jax.lax.top_k(r, int(top_k))
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if scale is not None:
+        w = w * scale
     return w, idx.astype(jnp.int32)
 
 

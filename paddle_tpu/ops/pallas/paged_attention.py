@@ -30,14 +30,15 @@ and are left out here.  The products take the pool's dtype as input
 zeros (the math averages whatever its table points at).
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ['paged_attention', 'supported', 'latent_paged_attention',
-           'latent_supported']
+__all__ = ['paged_attention', 'supported', 'chunk_paged_attention',
+           'chunk_supported', 'latent_paged_attention', 'latent_supported']
 
 _NEG_INF = -1e30
 # positions a block: 8 pages of 16.  Measured on a v5e, one layer of 32
@@ -53,21 +54,36 @@ def _sublane_rows(dtype):
     return 32 // jnp.dtype(dtype).itemsize
 
 
-def supported(n_heads, head_dim, page_size, dtype):
-    """Whether the kernel takes these shapes: a row ``H * D`` of whole
-    128-lane registers, whatever the width of a head, and pages that are
-    whole sublane tiles of the pool's dtype (a page is one DMA into a
-    tile-aligned slice of the block)."""
-    return (n_heads * head_dim) % 128 == 0 and \
-        page_size % _sublane_rows(dtype) == 0
+def supported(n_kv_heads, head_dim, page_size, dtype, group=1):
+    """Whether the kernel takes these shapes: a K/V row ``Hkv * D`` of
+    whole 128-lane registers, whatever the width of a head, and pages
+    that are whole sublane tiles of the pool's dtype (a page is one DMA
+    into a tile-aligned slice of the block).  ``group`` query heads over
+    each K/V head are laid out a group member at a time, ``Hkv`` rows
+    each, which then have to be whole float32 sublane tiles."""
+    return (n_kv_heads * head_dim) % 128 == 0 and \
+        page_size % _sublane_rows(dtype) == 0 and \
+        (group == 1 or n_kv_heads % 8 == 0)
+
+
+def _live_pages(ctx, page, window):
+    """(first live page, live pages, first live position) of a reader
+    of ``ctx`` positions: everything, or the ``window`` newest."""
+    if window is None:
+        return 0, pl.cdiv(ctx, page), 0
+    lo = jnp.maximum(ctx - window, 0)
+    first = lo // page
+    return first, pl.cdiv(ctx, page) - first, lo
 
 
 def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
             sem, m_scr, l_scr, acc_scr, *, scale, page, ppb, mpp,
-            head_dim, precision):
+            head_dim, kv_heads, group, window, precision):
     s = pl.program_id(0)
     ctx = len_ref[s]
-    n_pages = pl.cdiv(ctx, page)          # live pages of this slot
+    # live pages of this slot: with a window, the pages that hold its
+    # ``window`` newest positions, in a table that is a ring
+    first, n_pages, lo = _live_pages(ctx, page, window)
     n_blocks = pl.cdiv(n_pages, ppb)
     hp, hd = acc_scr.shape
     t = ppb * page
@@ -77,7 +93,11 @@ def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
         for j in range(ppb):
             @pl.when(blk * ppb + j < n_pages)
             def _():
-                pid = pt_ref[s * mpp + blk * ppb + j]
+                if window is None:
+                    pid = pt_ref[s * mpp + blk * ppb + j]
+                else:
+                    pid = pt_ref[s * mpp + jax.lax.rem(
+                        first + blk * ppb + j, mpp)]
                 rows = pl.ds(j * page, page)
                 act(pltpu.make_async_copy(
                     k_hbm.at[pid], k_buf.at[slot, rows], sem.at[0, slot]))
@@ -97,11 +117,29 @@ def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
     m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
-    # row h of the block-diagonal query: q_h on head h's lanes
+    # row r of the block-diagonal query holds a query head on the lanes
+    # of the K/V head it reads.  One query head a K/V head: row h is q_h
+    # on head h's lanes.  A group of them: member g of every group in
+    # rows g * Hkv .., so row r reads K/V head r % Hkv
     row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+    if group > 1:
+        row = jax.lax.rem(row, kv_heads)
     diag = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
-    qbd = jnp.where(diag, q_ref[0], 0.0).astype(k_buf.dtype)
+    if group == 1:
+        q_rows = q_ref[0]
+    else:
+        pieces = [jnp.broadcast_to(q_ref[0, g:g + 1], (kv_heads, hd))
+                  for g in range(group)]
+        if hp > group * kv_heads:       # up to a whole tile of rows
+            pieces.append(jnp.zeros((hp - group * kv_heads, hd),
+                                    jnp.float32))
+        q_rows = jnp.concatenate(pieces, axis=0)
+    qbd = jnp.where(diag, q_rows, 0.0).astype(k_buf.dtype)
+
+    def position(x):
+        """A position among the live pages -> the stream's."""
+        return x if window is None else first * page + x
 
     def block(blk, carry):
         slot = jax.lax.rem(blk, 2)
@@ -112,13 +150,13 @@ def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
 
         on_live_pages(blk, slot, wait)
 
-        @pl.when(ctx < (blk + 1) * t)
+        @pl.when(ctx < position((blk + 1) * t))
         def _zero_dead_rows():
             # the last block's rows past ctx_len (a last page's tail,
             # pages not copied) hold whatever was there: their p is 0,
             # and 0 * NaN is not
-            rows = blk * t + jax.lax.broadcasted_iota(
-                jnp.int32, (t, 1), 0)
+            rows = position(blk * t + jax.lax.broadcasted_iota(
+                jnp.int32, (t, 1), 0))
             v_buf[slot] = jnp.where(rows < ctx, v_buf[slot],
                                     jnp.zeros((), v_buf.dtype))
 
@@ -126,8 +164,14 @@ def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
             qbd, k_buf[slot], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=precision) * scale               # [hp, t]
-        pos = blk * t + jax.lax.broadcasted_iota(jnp.int32, (hp, t), 1)
-        sc = jnp.where(pos < ctx, sc, _NEG_INF)
+        pos = position(
+            blk * t + jax.lax.broadcasted_iota(jnp.int32, (hp, t), 1))
+        live = pos < ctx
+        if window is not None:
+            # the first live page's positions before the window (its
+            # rows are the stream's own: finite, and p is 0)
+            live &= pos >= lo
+        sc = jnp.where(live, sc, _NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -144,23 +188,36 @@ def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
 
     l = l_scr[...]
     out = acc_scr[...] / jnp.where(l > 0, l, 1.0)
-    o_ref[0] = jnp.sum(jnp.where(diag, out, 0.0), axis=0,
-                       keepdims=True).astype(o_ref.dtype)
+    if group == 1:
+        o_ref[0] = jnp.sum(jnp.where(diag, out, 0.0), axis=0,
+                           keepdims=True).astype(o_ref.dtype)
+    else:
+        out = jnp.where(diag, out, 0.0)
+        for g in range(group):
+            o_ref[0, g:g + 1] = jnp.sum(
+                out[g * kv_heads:(g + 1) * kv_heads], axis=0,
+                keepdims=True).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=('scale', 'interpret'))
+@functools.partial(jax.jit, static_argnames=('scale', 'window', 'interpret'))
 def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
-                    interpret=False):
+                    window=None, interpret=False):
     """``paged_attention_math``'s signature and result: ``q`` [S, H, D],
-    pools [N, P, H*D] (a 4-D [N, P, H, D] pool is viewed flat; on the
-    TPU that view is a copy unless the pool is held flat, as the decode
-    engine holds it), ``page_table`` [S, MPP], ``ctx_len`` [S].  Page
-    ids are clipped to the pool as the math clips them.  The caller
-    tests ``supported`` first."""
+    pools [N, P, Hkv*D] (a 4-D [N, P, Hkv, D] pool is viewed flat; on
+    the TPU that view is a copy unless the pool is held flat, as the
+    decode engine holds it), ``page_table`` [S, MPP], ``ctx_len`` [S].
+    The pool's row says how many K/V heads there are; the H query heads
+    are grouped over them (``h // (H / Hkv)``).  With ``window`` a slot
+    reads its ``window`` newest positions: pages wholly before them are
+    neither copied nor scored, and the table is a ring (logical page j
+    in column ``j % MPP``).  Page ids are clipped to the pool as the
+    math clips them.  The caller tests ``supported`` first."""
     s, h, d = q.shape
     n, page = k_pool.shape[0], k_pool.shape[1]
     mpp = page_table.shape[1]
-    hd = h * d
+    hd = math.prod(k_pool.shape[2:])
+    kv_heads = hd // d
+    group = h // kv_heads
     if scale is None:
         scale = float(d) ** -0.5
     dtype = k_pool.dtype
@@ -168,12 +225,22 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
     hp = -(-h // 16) * 16         # query rows, a whole tile in any dtype
     kernel = functools.partial(
         _kernel, scale=scale, page=page, ppb=ppb, mpp=mpp, head_dim=d,
+        kv_heads=kv_heads, group=group, window=window,
         precision=(jax.lax.Precision.HIGHEST if dtype == jnp.float32
                    else None))
-    row = pl.BlockSpec((1, 1, hd), lambda i, pt, ln: (i, 0, 0))
+
+    def queries():
+        qf = q.astype(jnp.float32)
+        if group == 1:
+            return qf.reshape(s, 1, hd)
+        # member g of every group side by side on its K/V head's lanes
+        return qf.reshape(s, kv_heads, group, d).transpose(
+            0, 2, 1, 3).reshape(s, group, hd)
+
+    row = pl.BlockSpec((1, group, hd), lambda i, pt, ln: (i, 0, 0))
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((s, 1, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, group, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s,),
@@ -195,10 +262,208 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
         name='paged_attention_live_pages',
         interpret=interpret,
     )(jnp.clip(page_table.astype(jnp.int32), 0, n - 1).reshape(-1),
-      jnp.clip(ctx_len.astype(jnp.int32), 0, mpp * page),
-      q.astype(jnp.float32).reshape(s, 1, hd),
-      k_pool.reshape(n, page, hd), v_pool.reshape(n, page, hd))
-    return out.reshape(s, h, d)
+      # (a ring holds the newest of any number of positions)
+      jnp.clip(ctx_len.astype(jnp.int32), 0, mpp * page)
+      if window is None else jnp.maximum(ctx_len.astype(jnp.int32), 0),
+      queries(), k_pool.reshape(n, page, hd), v_pool.reshape(n, page, hd))
+    if group == 1:
+        return out.reshape(s, h, d)
+    return out.reshape(s, group, kv_heads, d).transpose(
+        0, 2, 1, 3).reshape(s, h, d)
+
+
+# -- a prompt chunk's rows over the stream's live pages --------------------
+
+# tokens of a chunk that share one pass over the stream's pages, and
+# the positions a block of that pass holds.  Every pass copies the whole
+# live context again, one DMA a page: at 32 tokens a pass a chunk of
+# 512 over 16 k positions under 48 heads took 2.8 ms a layer on a v5e,
+# 5.5 us a block of a pass whose products need 2 (my chip run, PR 51),
+# so a chunk tick's cost rose by half between a short and a long
+# context; at 128 a pass, a quarter of the copies, it takes 2.5 ms: what
+# is left are the products and the softmax's vector work, the same
+# whatever the pass (206 GFLOP a layer are 1.05 ms at the peak)
+_CHUNK_TOKENS = 128
+_CHUNK_BLOCK_POSITIONS = 512
+
+
+def chunk_supported(n_kv_heads, head_dim, page_size, dtype):
+    """Whether ``chunk_paged_attention`` takes these shapes: what
+    ``supported`` asks of the row and the page, and heads of whole
+    128-lane registers (a K/V head is a lane slice of the block)."""
+    return supported(n_kv_heads, head_dim, page_size, dtype) and \
+        head_dim % 128 == 0
+
+
+def _chunk_kernel(pt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                  v_buf, sem, m_scr, l_scr, acc_scr, *, scale, page, ppb,
+                  mpp, head_dim, kv_heads, group, tokens, window,
+                  precision):
+    i = pl.program_id(0)
+    tok0 = pos_ref[0] + i * tokens   # the position of the first token
+    ctx = tok0 + tokens              # what the LAST token reads, itself too
+    # the pages between the first token's oldest position and the last
+    # token's own
+    first = 0 if window is None else \
+        jnp.maximum(tok0 + 1 - window, 0) // page
+    n_pages = pl.cdiv(ctx, page) - first
+    n_blocks = pl.cdiv(n_pages, ppb)
+    rows = tokens * group
+    t = ppb * page
+
+    def on_live_pages(blk, slot, act):
+        for j in range(ppb):
+            @pl.when(blk * ppb + j < n_pages)
+            def _():
+                at = first + blk * ppb + j
+                if window is not None:
+                    at = jax.lax.rem(at, mpp)
+                pid = pt_ref[at]
+                at = pl.ds(j * page, page)
+                act(pltpu.make_async_copy(
+                    k_hbm.at[pid], k_buf.at[slot, at], sem.at[0, slot]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[pid], v_buf.at[slot, at], sem.at[1, slot]))
+
+    def start(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        on_live_pages(0, 0, start)
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    # row r of a K/V head's queries is token r // group of this pass
+    row_ctx = tok0 + 1 + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0) // group
+
+    def block(blk, carry):
+        slot = jax.lax.rem(blk, 2)
+        at = first * page + blk * t
+
+        @pl.when(blk + 1 < n_blocks)
+        def _prefetch():
+            on_live_pages(blk + 1, 1 - slot, start)
+
+        on_live_pages(blk, slot, wait)
+
+        @pl.when(ctx < at + t)
+        def _zero_dead_rows():
+            # rows past the last token hold whatever was there: their p
+            # is 0, and 0 * NaN is not
+            pos = at + jax.lax.broadcasted_iota(jnp.int32, (t, 1), 0)
+            v_buf[slot] = jnp.where(pos < ctx, v_buf[slot],
+                                    jnp.zeros((), v_buf.dtype))
+
+        pos = at + jax.lax.broadcasted_iota(jnp.int32, (rows, t), 1)
+        live = pos < row_ctx
+        if window is not None:
+            live &= pos >= row_ctx - window
+        for kv in range(kv_heads):
+            lanes = slice(kv * head_dim, (kv + 1) * head_dim)
+            sc = jax.lax.dot_general(
+                q_ref[0, kv], k_buf[slot, :, lanes],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=precision) * scale               # [rows, t]
+            sc = jnp.where(live, sc, _NEG_INF)
+            m_prev = m_scr[kv]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a block may hold no position of a row (its window starts
+            # after it, or the block starts after the row): p is 0 there
+            p = jnp.where(live, jnp.exp(sc - m_new), 0.0)
+            l_scr[kv] = alpha * l_scr[kv] + jnp.sum(p, axis=1,
+                                                    keepdims=True)
+            m_scr[kv] = m_new
+            acc_scr[kv] = acc_scr[kv] * alpha + jax.lax.dot_general(
+                p.astype(v_buf.dtype), v_buf[slot, :, lanes],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    l = l_scr[...]
+    o_ref[0] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=('scale', 'window', 'tokens',
+                                             'block_positions',
+                                             'interpret'))
+def chunk_paged_attention(q, k_pool, v_pool, page_table, pos0, scale=None,
+                          window=None, tokens=_CHUNK_TOKENS,
+                          block_positions=_CHUNK_BLOCK_POSITIONS,
+                          interpret=False):
+    """``chunked_prefill_attention_math``'s signature and result from
+    the stream's live pages alone: ``q`` [C, H, D], query j at absolute
+    position ``pos0 + j``; pools [N, P, Hkv*D]; ``page_table`` [MPP]
+    (a ring under ``window``, as ``paged_attention`` takes it).
+
+    One grid step is ``tokens`` consecutive rows of the chunk (the
+    largest common divisor of C and it): they share one pass over the pages
+    between the first row's oldest position and the last row's own.  A
+    block of pages is [T, Hkv*D] in VMEM; K/V head by K/V head, the
+    ``tokens * (H / Hkv)`` query rows that read it multiply its lane
+    slice, [rows, D] x [D, T] (no block-diagonal: a head is whole
+    registers), with a causal and window mask a row, float32 online
+    softmax over blocks and ``p @ V``'s slice.  The caller tests
+    ``chunk_supported``."""
+    c, h, d = q.shape
+    n, page = k_pool.shape[0], k_pool.shape[1]
+    mpp = page_table.shape[0]
+    hd = math.prod(k_pool.shape[2:])
+    kv_heads = hd // d
+    group = h // kv_heads
+    if scale is None:
+        scale = float(d) ** -0.5
+    dtype = k_pool.dtype
+    tokens = math.gcd(c, tokens)
+    passes, rows = c // tokens, tokens * group
+    ppb = max(1, min(mpp, block_positions // page))
+    kernel = functools.partial(
+        _chunk_kernel, scale=scale, page=page, ppb=ppb, mpp=mpp,
+        head_dim=d, kv_heads=kv_heads, group=group, tokens=tokens,
+        window=window,
+        precision=(jax.lax.Precision.HIGHEST if dtype == jnp.float32
+                   else None))
+    # [passes, Hkv, tokens * group, D]: a K/V head's query rows together
+    qg = q.astype(dtype).reshape(passes, tokens, kv_heads, group, d) \
+        .transpose(0, 2, 1, 3, 4).reshape(passes, kv_heads, rows, d)
+    spec = pl.BlockSpec((1, kv_heads, rows, d),
+                        lambda i, pt, p0: (i, 0, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((passes, kv_heads, rows, d),
+                                       jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(passes,),
+            in_specs=[spec, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb * page, hd), dtype),
+                pltpu.VMEM((2, ppb * page, hd), dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+                pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+                pltpu.VMEM((kv_heads, rows, d), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name='chunk_paged_attention_live_pages',
+        interpret=interpret,
+    )(jnp.clip(page_table.astype(jnp.int32), 0, n - 1),
+      jnp.asarray(pos0, jnp.int32).reshape(1),
+      qg, k_pool.reshape(n, page, hd), v_pool.reshape(n, page, hd))
+    return out.reshape(passes, kv_heads, tokens, group, d).transpose(
+        0, 2, 1, 3, 4).reshape(c, h, d).astype(q.dtype)
 
 
 # -- a shared latent row (multi-head latent attention) ---------------------

@@ -5,6 +5,7 @@ Runs interpret=True on the CPU backend — same kernel code that compiles
 to Mosaic on TPU (tests/test_tpu_lowering.py compiles it at the decode
 engine's shapes).
 """
+import functools
 from types import SimpleNamespace
 
 import numpy as np
@@ -202,3 +203,151 @@ def test_dispatch_takes_the_kernel_on_a_tpu(monkeypatch, head_dim, dtype,
     assert calls == [{'scale': None}]
     close(got, np.asarray(paged_attention_math(
         q, k, v, pt, jnp.asarray(ctx, jnp.int32))), dtype)
+
+
+# -- query heads in groups over fewer K/V heads; a window of the newest
+# positions, the page table a ring; a prompt chunk's rows ------------------
+
+from paddle_tpu.ops.attention import (chunk_attention_path,  # noqa: E402
+                                      chunked_prefill_attention_math)
+from paddle_tpu.ops.pallas.paged_attention import (  # noqa: E402
+    chunk_paged_attention, chunk_supported)
+
+HKV, DG = 2, 32
+# a window, the chunk rows that go with it, and the ring that holds both
+# and a page to spare
+RINGS = {None: (8, 12), 512: (16, 34), 5: (8, 2)}
+
+
+def grouped(seed, group, window, dtype=jnp.float32, n=60):
+    rng = np.random.RandomState(seed)
+    rows, mpp = RINGS[window]
+    mpp = max(mpp, -(-((window or 1) - 1 + rows) // P) + 1)
+    k = jnp.asarray(rng.randn(n, P, HKV * DG), dtype)
+    v = jnp.asarray(rng.randn(n, P, HKV * DG), dtype)
+    return rng, rows, mpp, k, v
+
+
+@pytest.mark.parametrize('window', [None, 512, 5])
+@pytest.mark.parametrize('group', [1, 6, 9])
+def test_step_rows_of_grouped_heads_under_a_window(group, window):
+    rng, _rows, mpp, k, v = grouped(11, group, window)
+    # with a window a context is any length: the ring holds its newest
+    ctx = [1, P, P + 1, 77, mpp * P] if window is None else \
+        [1, P, P + 1, 77, mpp * P, 513, 600, 2000]
+    s, h = len(ctx), HKV * group
+    q = jnp.asarray(rng.randn(s, h, DG), jnp.float32)
+    pt = jnp.asarray(np.stack([rng.permutation(60)[:mpp]
+                               for _ in range(s)]), jnp.int32)
+    kw = {} if window is None else {'window': window}
+    close(*both(q, k, v, pt, ctx, **kw), jnp.float32)
+
+
+@pytest.mark.parametrize('window', [None, 512, 5])
+@pytest.mark.parametrize('group', [1, 6, 9])
+def test_chunk_rows_of_grouped_heads_under_a_window(group, window):
+    rng, rows, mpp, k, v = grouped(12, group, window)
+    pt = jnp.asarray(rng.permutation(60)[:mpp], jnp.int32)
+    kw = {} if window is None else {'window': window}
+    # the first chunk, one in mid-table, and with a window one far behind
+    # which the ring has wrapped many times
+    for pos0 in [0, 3 * rows] + ([40 * rows] if window else []):
+        q = jnp.asarray(rng.randn(rows, HKV * group, DG), jnp.float32)
+        want = chunked_prefill_attention_math(q, k, v, pt, jnp.int32(pos0),
+                                              **kw)
+        # passes of 4 tokens over blocks of 2 pages, and the defaults
+        for tile in ({'tokens': 4, 'block_positions': 2 * P}, {})[
+                window == 512:]:
+            got = chunk_paged_attention(q, k, v, pt, jnp.int32(pos0),
+                                        interpret=True, **kw, **tile)
+            assert got.shape == want.shape == q.shape
+            close(np.asarray(got), np.asarray(want), jnp.float32)
+
+
+def test_bf16_pools_under_grouped_heads():
+    rng, rows, mpp, k, v = grouped(13, 6, 512, jnp.bfloat16)
+    pt = jnp.asarray(rng.permutation(60)[:mpp], jnp.int32)
+    q = jnp.asarray(rng.randn(rows, HKV * 6, DG), jnp.float32)
+    close(np.asarray(chunk_paged_attention(
+        q, k, v, pt, jnp.int32(700), window=512, interpret=True)),
+        np.asarray(chunked_prefill_attention_math(
+            q, k, v, pt, jnp.int32(700), window=512)), jnp.bfloat16)
+    ctx = jnp.asarray([3, 700], jnp.int32)
+    close(*both(q[:2], k, v, jnp.stack([pt, pt]), ctx, window=512),
+          jnp.bfloat16)
+
+
+def test_a_window_leaves_the_pages_behind_it_unread():
+    """Pages wholly before the window, the ring's columns that hold them
+    and the pool's other pages are NaN: neither the kernels nor the math
+    are moved."""
+    rng, rows, mpp, k, v = grouped(14, 6, 5)
+    pt = np.asarray(rng.permutation(59)[:mpp])
+    ctx, pos0 = 3 * P + 2, 3 * P + 2 - rows
+    live = {int(pt[j % mpp]) for j in range((pos0 + 1 - 5) // P,
+                                            (ctx - 1) // P + 1)}
+    dead = np.array([g for g in range(60) if g not in live])
+    kn, vn = (a.at[dead].set(jnp.nan) for a in (k, v))
+    q = jnp.asarray(rng.randn(rows, HKV * 6, DG), jnp.float32)
+    pt = jnp.asarray(pt, jnp.int32)
+    want = chunked_prefill_attention_math(q, k, v, pt, jnp.int32(pos0),
+                                          window=5)
+    for attend in (chunked_prefill_attention_math,
+                   functools.partial(chunk_paged_attention, interpret=True)):
+        got = np.asarray(attend(q, kn, vn, pt, jnp.int32(pos0), window=5))
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got, np.asarray(want), atol=2e-5)
+    step = jnp.asarray([ctx], jnp.int32)
+    got, want = both(q[:1], kn, vn, pt[None], step, window=5)
+    assert np.all(np.isfinite(got)) and np.all(np.isfinite(want))
+    close(got, np.asarray(paged_attention_math(
+        q[:1], k, v, pt[None], step, window=5)), jnp.float32)
+
+
+def test_paths_by_the_row_the_group_and_the_table():
+    bf16 = jnp.bfloat16
+    # the step: the K/V row and the page decide; a group of query heads
+    # needs K/V heads that fill sublane tiles
+    assert supported(8, 128, 16, bf16, 6) and supported(8, 128, 16, bf16, 9)
+    assert not supported(2, 128, 16, bf16, 6)
+    assert paged_attention_path('tpu', 8, 128, 16, bf16, 9) == 'pallas_paged'
+    assert paged_attention_path('tpu', 2, 128, 16, bf16, 9) == 'xla_gather'
+    assert paged_attention_path('cpu', 8, 128, 16, bf16, 9) == 'xla_gather'
+    # a chunk's rows: heads of whole registers, and a table wide enough
+    # that a row's scores are not worth gathering
+    assert chunk_supported(8, 128, 16, bf16)
+    assert not chunk_supported(32, 64, 16, bf16)
+    for heads, table, want in ((16, 64, 'xla_gather'),      # 1024 positions
+                               (72, 65, 'pallas_paged'),    # a ring of 1040
+                               (48, 1088, 'pallas_paged')):
+        assert chunk_attention_path('tpu', 8, 128, 16, bf16, heads,
+                                    table) == want
+        assert chunk_attention_path('cpu', 8, 128, 16, bf16, heads,
+                                    table) == 'xla_gather'
+
+
+def test_the_chunk_op_takes_the_kernel_where_the_path_says(monkeypatch):
+    import sys
+    calls = []
+
+    def kernel(*args, **kw):
+        calls.append(kw)
+        return chunk_paged_attention(*args, interpret=True, **kw)
+    # (the package re-exports a function under the module's name)
+    kernels = sys.modules['paddle_tpu.ops.pallas.paged_attention']
+    monkeypatch.setattr(kernels, 'chunk_paged_attention', kernel)
+    rng = np.random.RandomState(15)
+    k = jnp.asarray(rng.randn(80, P, 8 * 128), jnp.float32)
+    v = jnp.asarray(rng.randn(80, P, 8 * 128), jnp.float32)
+    q = jnp.asarray(rng.randn(8, 72, 128), jnp.float32)
+    pt = jnp.asarray(rng.permutation(79)[:65], jnp.int32)
+    op = get_op_impl('chunked_prefill_attention')
+    ins = {'Q': [q], 'KPool': [k], 'VPool': [v], 'PT': [pt],
+           'Pos0': [jnp.int32(600)]}
+    got = op.compute(SimpleNamespace(backend='tpu'), ins,
+                     {'window': 512})['Out'][0]
+    assert calls == [{'scale': None, 'window': 512}]
+    want = op.compute(SimpleNamespace(backend='cpu'), ins,
+                      {'window': 512})['Out'][0]
+    assert calls == [{'scale': None, 'window': 512}]    # the math
+    close(np.asarray(got), np.asarray(want), jnp.float32)
