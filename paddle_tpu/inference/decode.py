@@ -122,15 +122,6 @@ def decode_buckets(page_size, top):
     return sizes
 
 
-def _host_operands(tokens, page_tables, ctx_lens):
-    """A decode step's three arrays as the executables take them: the
-    host's numpy arrays as they are (the executable puts them on the
-    device in one batch; a ``jnp.asarray`` each would be three uploads
-    of their own, and a ``jnp`` scalar a device program)."""
-    return tuple(np.asarray(a, dtype=np.int32)
-                 for a in (tokens, page_tables, ctx_lens))
-
-
 class _PageGroup(object):
     """A free list over ``num_pages`` pages and, one past them, the
     group's TRASH page (padded page-table entries and inactive slots
@@ -389,7 +380,11 @@ class DecodeEngine(object):
     are, waits for the program, and copies back in ONE transfer what its
     caller reads (``_fetch``: ids, routing counts, a prompt's last row;
     the span's ``fetched_bytes``).  The decode rows' ``[S, V]`` logits
-    stay on the device for whoever indexes them.
+    stay on the device for whoever indexes them.  So do the decode
+    rows' inputs of the next call (``_next_rows``): a call passes each
+    of a step's three arrays from the host only where the device does
+    not already hold that array's content (``_decode_operands``; the
+    span's ``host_operands``, the totals in ``calls``).
 
     Not thread-safe by design: exactly one caller (the DecodeServer
     worker) drives it, and the page pools move through donated
@@ -432,6 +427,13 @@ class DecodeEngine(object):
         # newer page of their stream's (``_recycled``)
         self.kv_pages = {'live': 0, 'table': 0, 'window_live': 0,
                          'window_recycled': 0}
+        # the calls that ran decode rows, and how many of their three
+        # arrays went in from the host (``_decode_operands``)
+        self.calls = {'step_calls': 0, 'step_host_operands': 0}
+        # what the last such call left on the device for the next one,
+        # and the host's copies of it: (ids, page tables, context
+        # lengths) twice, or None before the first
+        self._held = None
         self.page_size = int(page_size or FLAGS.decode_page_size)
         self.max_streams = int(max_streams or FLAGS.decode_max_streams)
         if self.max_seq % self.page_size:
@@ -603,6 +605,55 @@ class DecodeEngine(object):
                 tensors=len(out), dtype=str(max(
                     out.values(), key=lambda v: v.nbytes).dtype))
         return out
+
+    def _decode_operands(self, tokens, page_tables, ctx_lens, span_args):
+        """A decode step's three arrays as the executables take them.
+        Each goes in as the device array the last call left (``_held``)
+        where the host's array equals, on every row, what that array
+        holds: between two plain steps the ids are the last call's own
+        output, the context lengths its own plus one on the running
+        rows, the page tables unchanged, and nothing is handed over from
+        the host (a numpy array costs the executable's call 0.15 ms:
+        PERF.md section 6, PR 52).  An array that differs anywhere (a
+        stream admitted or retired, a page claimed, another caller's
+        rows) goes in from the host: ids and context lengths as numpy,
+        as they are (where uploads are rare that is the cheapest way in:
+        a ``jax.device_put`` that runs once in 40 calls costs 0.3 ms, a
+        ``jnp.asarray`` is an upload of its own and a ``jnp`` scalar a
+        device program), the page tables put on the device once, here,
+        so that the calls after this one find them there.  Either way
+        the program sees bit for bit what the host holds, idle rows
+        included.  Returns (the operands, the host's arrays as int32);
+        the span gets ``host_operands``, how many of the three came from
+        the host."""
+        host = [np.asarray(a, dtype=np.int32)
+                for a in (tokens, page_tables, ctx_lens)]
+        ops, sent = list(host), 3
+        if self._held is not None:
+            for k, (kept, handle) in enumerate(zip(*self._held)):
+                if np.array_equal(host[k], kept):
+                    host[k], ops[k], sent = kept, handle, sent - 1
+        if ops[1] is host[1]:
+            # a copy of its own: the caller may write into its array,
+            # and a backend may share the host's memory with the handle
+            host[1] = host[1].copy()
+            ops[1] = jax.device_put(host[1])
+        span_args['host_operands'] = sent
+        self.calls['step_calls'] += 1
+        self.calls['step_host_operands'] += sent
+        return ops, host
+
+    def _hold(self, ops, host, nxt, next_ids, next_ctx):
+        """After a call that ran decode rows: keep what it left on the
+        device for the next call (``next_ids``, the page tables it was
+        called with, ``next_ctx``: ``_next_rows``) and what those arrays
+        hold, worked out on the host from the ids the call fetched
+        anyway (``nxt``)."""
+        running = host[1][:, 0] != self.cache.trash
+        self._held = (
+            (np.where(running, nxt, 0).astype(np.int32), host[1],
+             host[2] + running.astype(np.int32)),
+            (next_ids, ops[1], next_ctx))
 
     def _fetch(self, arrays, counts, span_args, step=False):
         """An engine call's one copy to the host: ``arrays`` (what the
@@ -780,6 +831,15 @@ class DecodeEngine(object):
                 tables[self._group[i]], pos + 1)
         return read
 
+    def _next_rows(self, pt, ctx_len, nxt):
+        """What a program returns beside the decode rows' ids: those
+        rows' ids and context lengths as the NEXT call takes them if
+        nothing else happens, an idle row 0 in both (``_hold`` works
+        out the same on the host).  They stay on the device."""
+        running = pt[:, 0] != self.cache.trash
+        return (jnp.where(running, nxt, 0).astype(jnp.int32),
+                ctx_len + running.astype(jnp.int32))
+
     def _chunk_fn(self, bucket):
         blk, S, trash = self.block, self.max_streams, self.cache.trash
         n = len(self.cache.rows)
@@ -826,9 +886,9 @@ class DecodeEngine(object):
             last = S + jnp.clip(n_valid - 1, 0, bucket - 1)
             logits = blk.head(params,
                               jnp.concatenate([x[:S], x[last][None]]))
-            return tuple(pools) + (
-                logits[S], jnp.argmax(logits[:S], axis=-1),
-                logits[:S]) + extra
+            nxt = jnp.argmax(logits[:S], axis=-1)
+            return tuple(pools) + (logits[S], nxt, logits[:S]) \
+                + self._next_rows(step_pt, ctx_len, nxt) + extra
         return chunk
 
     def _step_fn(self):
@@ -847,8 +907,9 @@ class DecodeEngine(object):
                 self._write_then(pools, page_idx, offset,
                                  self._step_read(params, pools, pt, pos)))
             logits = blk.head(params, x)
-            return tuple(pools) + (logits,
-                                   jnp.argmax(logits, axis=-1)) + extra
+            nxt = jnp.argmax(logits, axis=-1)
+            return tuple(pools) + (logits, nxt) \
+                + self._next_rows(pt, ctx_len, nxt) + extra
         return step
 
     def _pools_out(self, out):
@@ -1015,7 +1076,7 @@ class DecodeEngine(object):
                 ids[j] = np.asarray(ring, np.int32)[j % R]
                 page_ids.append(ids)
                 self._recycled(0, t)
-            # numpy in, as ``_host_operands`` says why
+            # numpy in: ``jnp`` scalars would be device programs
             logits, *rest = self._prefill[bucket](
                 self.params, toks, np.int32(t - 1))
             self._pools_out(self._pack[bucket](
@@ -1058,7 +1119,10 @@ class DecodeEngine(object):
         The span's ``tokens`` and ``bucket`` stay the chunk's;
         ``step_rows`` counts the running slots carried, and
         ``fetched_bytes`` what came back to the host: the last row, and
-        with rows carried their ids, beside the routing counts.  The
+        with rows carried their ids, beside the routing counts; the
+        carried three go in as ``step``'s do, and ``host_operands``
+        counts those that came from the host (a chunk that carries no
+        rows has none, and leaves what the last step left alone).  The
         call's two halves are the spans ``.dispatch`` and ``.fetch``,
         as ``step``'s."""
         tokens = np.asarray(tokens, dtype=np.int32)
@@ -1073,19 +1137,22 @@ class DecodeEngine(object):
                 pt = self.table_row(pages)
                 if self.ring_pages:
                     self._recycled(pos0, pos0 + c)
-                carried = self._idle_step if step_tokens is None \
-                    else _host_operands(step_tokens, page_tables, ctx_lens)
-                logits, nxt, step_logits, *extra = self._pools_out(
-                    self._chunk[bucket](
+                carried, host = (self._idle_step, None) \
+                    if step_tokens is None else self._decode_operands(
+                        step_tokens, page_tables, ctx_lens, args)
+                logits, nxt, step_logits, ids, ctx, *extra = \
+                    self._pools_out(self._chunk[bucket](
                         self.params, *self.cache.pools, toks, pt,
                         np.int32(pos0), np.int32(c), *carried))
             with _obs.span('decode.prefill_chunk.fetch'):
                 if step_tokens is None:
+                    # no decode rows ran: what is held stays as it is
                     return self._fetch((logits,), extra, args)[0]
                 args['step_rows'] = self._kv_pages(page_tables, ctx_lens,
                                                    args)
-                return self._fetch((logits, nxt), extra, args) \
-                    + (step_logits,)
+                logits, nxt = self._fetch((logits, nxt), extra, args)
+                self._hold(carried, host, nxt, ids, ctx)
+                return logits, nxt, step_logits
 
     def step(self, tokens, page_tables, ctx_lens):
         """One batched decode step over all ``max_streams`` slots.
@@ -1094,7 +1161,12 @@ class DecodeEngine(object):
         ignored.  Returns (next tokens [S] as numpy, the rows' logits
         [S, V] left on the device for whoever indexes them): the ids and
         the routing counts are all that is copied to the host
-        (``fetched_bytes`` on the span)."""
+        (``fetched_bytes`` on the span).  Of the three arrays, those
+        that equal what the last call left on the device are not
+        uploaded again (``_decode_operands``): the span's
+        ``host_operands`` counts the ones that were, 0 to 3, and
+        ``calls`` totals them (``step_calls``,
+        ``step_host_operands``)."""
         self._ensure_step()
         # the two halves of the host's part: everything up to the call
         # into the executable returning, then the wait for the device
@@ -1102,13 +1174,15 @@ class DecodeEngine(object):
         args = {}   # the step's KV pages, routing counts, fetched bytes
         with _obs.span('decode.step', args=args):
             with _obs.span('decode.step.dispatch'):
-                logits, nxt, *extra = self._pools_out(self._step(
-                    self.params, *self.cache.pools,
-                    *_host_operands(tokens, page_tables, ctx_lens)))
+                ops, host = self._decode_operands(
+                    tokens, page_tables, ctx_lens, args)
+                logits, nxt, ids, ctx, *extra = self._pools_out(
+                    self._step(self.params, *self.cache.pools, *ops))
             with _obs.span('decode.step.fetch'):
                 self._kv_pages(page_tables, ctx_lens, args)
-                return self._fetch((nxt,), extra, args, step=True) \
-                    + (logits,)
+                nxt, = self._fetch((nxt,), extra, args, step=True)
+                self._hold(ops, host, nxt, ids, ctx)
+                return nxt, logits
 
     def resident_bytes(self):
         return self.cache.resident_bytes()
@@ -1393,6 +1467,13 @@ class DecodeServer(object):
                 # of the running slots, page-table entries
                 'kv_live_pages': self.engine.kv_pages['live'],
                 'kv_table_pages': self.engine.kv_pages['table'],
+                # engine calls that ran decode rows (steps and carrying
+                # chunks), and how many of their three arrays (ids, page
+                # tables, context lengths) went in from the host and not
+                # from what the call before left on the device
+                'step_calls': self.engine.calls['step_calls'],
+                'step_host_operands':
+                    self.engine.calls['step_host_operands'],
             }
 
     # -- worker side ---------------------------------------------------

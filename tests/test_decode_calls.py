@@ -5,7 +5,9 @@ host's numpy arrays go into the executable as they are, ONE copy brings
 back what the caller reads (next-token ids, routing counts, a prompt's
 last row: the span's ``fetched_bytes``), the decode rows' ``[S, V]``
 logits stay on the device, and nothing of that compiles a program after
-``warmup()``.
+``warmup()``.  Of a step's three arrays, a call uploads those the device
+does not already hold (the span's ``host_operands``): the program sees
+bit for bit what the host holds either way.
 """
 import numpy as np
 import pytest
@@ -13,7 +15,8 @@ import pytest
 import jax
 
 from paddle_tpu.inference.blocks import OptBlock
-from paddle_tpu.inference.decode import DecodeEngine, DecodeServer
+from paddle_tpu.inference.decode import (DecodeEngine, DecodeServer,
+                                         DecodeStream)
 from paddle_tpu.observability import timeline
 
 import reference_dots_vlm
@@ -101,6 +104,78 @@ def serve(eng, prompts, n_new):
         server.close()
 
 
+def serve_at_once(eng, prompts, n_new):
+    """Every request in the queue before the worker's first tick, so
+    that two runs make the same calls with the same rows (``submit``
+    races the worker: a later request may find the first tick gone)."""
+    server = DecodeServer(eng, warmup=False)
+    try:
+        streams = [DecodeStream('r%d' % i, p, n)
+                   for i, (p, n) in enumerate(zip(prompts, n_new))]
+        with server._cv:
+            server._queue.extend(streams)
+            server._submitted += len(streams)
+            server._cv.notify()
+        return [list(st.result(timeout=120.0)) for st in streams], \
+            server.stats()
+    finally:
+        server.close()
+
+
+def record_calls(eng):
+    """Keep, of every ``step`` and ``prefill_chunk`` the engine is
+    asked for: the ids and the logits of the rows that ran (a chunk's
+    last row first)."""
+    seen, trash = [], eng.cache.trash
+    step, chunk = eng.step, eng.prefill_chunk
+
+    def wrapped_step(tokens, page_tables, ctx_lens):
+        ids, rows = step(tokens, page_tables, ctx_lens)
+        run = page_tables[:, 0] != trash
+        seen.append((ids[run], np.asarray(rows)[run]))
+        return ids, rows
+
+    def wrapped_chunk(*args):
+        out = chunk(*args)
+        if len(args) == 3:
+            seen.append((np.asarray(out),))
+        else:
+            run = args[4][:, 0] != trash
+            seen.append((out[0], out[1][run], np.asarray(out[2])[run]))
+        return out
+    eng.step, eng.prefill_chunk = wrapped_step, wrapped_chunk
+    return seen
+
+
+def count_bytes(model, mod):
+    """The routing counts a program returns: [layers that route, experts
+    (+ 1 column for those held elsewhere)] int32, none without experts."""
+    return {'opt': 0, 'olmoe': mod.L * olmoe_t.E * 4,
+            'dots': (mod.L - 1) * (dots_t.HELD + 1) * 4}[model]
+
+
+def host_operands(ring):
+    """``host_operands`` of the calls that ran decode rows, in order."""
+    evs = [e for e in ring.events(cat='span')
+           if e['name'] in ('decode.step', 'decode.prefill_chunk')
+           and 'host_operands' in e['args']]
+    return [e['args']['host_operands']
+            for e in sorted(evs, key=lambda e: e['ts'])]
+
+
+def rows_of(eng, rows):
+    """A step's three arrays for ``rows`` {slot: (token, the stream's
+    pages so far, cached positions)}, built anew as a server's tick
+    builds them; every other slot idle."""
+    S = eng.max_streams
+    t, c = np.zeros(S, np.int32), np.zeros(S, np.int32)
+    pt = np.full((S, eng.pages_per_stream), eng.cache.trash, np.int32)
+    for slot, (tok, pages, ctx) in rows.items():
+        t[slot], c[slot] = tok, ctx
+        pt[slot, :len(pages)] = pages
+    return t, pt, c
+
+
 def on_device(x):
     return isinstance(x, jax.Array) and not isinstance(x, np.ndarray)
 
@@ -145,10 +220,7 @@ def test_fetched_bytes_are_the_ids_the_counts_and_a_chunks_last_row(
         model, ring):
     mod, params, eng, _ = engine(model, chunked=True)
     S, V = mod.STREAMS, mod.V
-    # the routing counts a program returns: [layers that route, experts
-    # (+ 1 column for those held elsewhere)] int32, none without experts
-    counts = {'opt': 0, 'olmoe': mod.L * olmoe_t.E * 4,
-              'dots': (mod.L - 1) * (dots_t.HELD + 1) * 4}[model]
+    counts = count_bytes(model, mod)
     prompt = np.random.default_rng(5).integers(1, V, 12)
     pages = eng.cache.alloc(2)
     tok = int(np.argmax(prefill(eng, prompt, pages)))
@@ -235,3 +307,150 @@ def test_the_server_compiles_nothing_and_serves_the_greedy_ids(
         assert len(toks) == 6
         rows = reference(params, list(prompt) + toks[:-1])[len(prompt) - 1:]
         assert [int(t) for t in np.argmax(rows, -1)] == toks
+
+
+@pytest.mark.parametrize('chunked', [False, True])
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_a_server_that_uploads_every_array_serves_the_same_bits(
+        model, chunked, ring, monkeypatch):
+    """The same requests through two servers, one as it is and one whose
+    engine keeps nothing on the device (every call uploads its three
+    arrays, as before this change): the same calls, the same ids, and
+    bit for bit the same logits on every row that ran."""
+    rng = np.random.default_rng(31)
+    prompts = [rng.integers(1, MODELS[model][0].V, n)
+               for n in SERVED_PROMPTS]
+    n_new = (6, 3, 6, 5)     # two retire while the others still decode
+    runs = []
+    for always_upload in (False, True):
+        mod, params, eng, _ = engine(model, chunked)
+        eng.warmup()
+        if always_upload:
+            monkeypatch.setattr(eng, '_hold', lambda *a: None)
+        seen = record_calls(eng)
+        ring.clear()
+        served, stats = serve_at_once(eng, prompts, n_new)
+        runs.append((served, seen, host_operands(ring), stats))
+    (served, seen, sent, stats), (served_u, seen_u, sent_u, stats_u) = runs
+    assert served == served_u \
+        == [ids[:n] for ids, n in zip(SERVED_IDS[model], n_new)]
+    assert len(seen) == len(seen_u) and len(sent) == len(sent_u)
+    for call, call_u in zip(seen, seen_u):
+        assert len(call) == len(call_u)
+        for got, want in zip(call, call_u):
+            assert got.dtype == want.dtype and np.array_equal(got, want)
+    assert set(sent_u) == {3} and sent[0] == 3 and 0 in sent
+    assert stats['step_calls'] == stats_u['step_calls'] == len(sent)
+    assert stats['step_host_operands'] == sum(sent) < sum(sent_u) \
+        == stats_u['step_host_operands'] == 3 * len(sent)
+    if not chunked:
+        # every page claimed at admission: the table moves only where a
+        # stream retires, and then its ids and context lengths with it
+        assert sent == [3, 0, 3, 0, 3]
+
+
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_host_operands_count_what_the_device_does_not_hold(
+        model, ring, backend_compiles):
+    """By hand, as a server that claims pages as contexts grow: 3 on the
+    first call, 0 between two page crossings, 1 (the page tables) on a
+    crossing, all three where a stream is admitted or retires (its row's
+    id and context length change with its table row)."""
+    mod, params, eng, reference = engine(model)
+    eng.warmup()
+    del backend_compiles[:]
+    rng = np.random.default_rng(52)
+    P = mod.PAGE
+    first, second = rng.integers(1, mod.V, 12), rng.integers(1, mod.V, 3)
+    pages = {1: eng.cache.alloc(4), 2: eng.cache.alloc(2)}
+    seq = {1: list(first) + [int(np.argmax(prefill(eng, first, pages[1])))]}
+    ring.clear()
+
+    def step(slots):
+        ids, _ = eng.step(*rows_of(eng, {
+            i: (seq[i][-1], pages[i][:(len(seq[i]) - 1) // P + 1],
+                len(seq[i]) - 1) for i in slots}))
+        for i in slots:
+            seq[i].append(int(ids[i]))
+
+    for _ in range(6):          # contexts 12 .. 17: a crossing at 16
+        step([1])
+    seq[2] = list(second) + [int(np.argmax(prefill(eng, second, pages[2])))]
+    for slots in ([1, 2], [1, 2], [2], [2]):    # admitted, then 1 retires
+        step(slots)
+    assert host_operands(ring) == [3, 0, 0, 0, 1, 0, 3, 0, 3, 0]
+    assert eng.calls == {'step_calls': 10, 'step_host_operands': 10}
+    # neither the uploads nor the reuse compiled anything, and a call
+    # that uploads nothing copies back what any step does
+    assert backend_compiles == [] and eng.compiles_after_warmup == 0
+    for e in spans(ring, 'decode.step'):
+        assert e['args']['fetched_bytes'] \
+            == 4 * mod.STREAMS + count_bytes(model, mod)
+    # and the ids are the greedy continuation, reuse or not
+    for i, prompt in ((1, first), (2, second)):
+        rows = reference(params, seq[i][:-1])[len(prompt) - 1:]
+        assert [int(t) for t in np.argmax(rows, -1)] == seq[i][len(prompt):]
+
+
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_an_idle_row_is_zero_trash_zero_on_the_device(model, ring):
+    """After a stream retired, what the device holds for its row is
+    what the host would upload, token 0, an all-trash table row, context
+    length 0 (what the live-pages kernels read as "reads nothing"), and
+    stays so over the calls that upload nothing."""
+    mod, params, eng, _ = engine(model)
+    rng = np.random.default_rng(7)
+    pages, toks = {}, {}
+    for slot, n in ((0, 9), (2, 5)):
+        pages[slot] = eng.cache.alloc(2)
+        toks[slot] = int(np.argmax(prefill(
+            eng, rng.integers(1, mod.V, n), pages[slot])))
+    ctx = {0: 9, 2: 5}
+    ring.clear()
+    for slots in ([0, 2], [0, 2], [2], [2], [2]):
+        ids, _ = eng.step(*rows_of(
+            eng, {i: (toks[i], pages[i], ctx[i]) for i in slots}))
+        for i in slots:
+            toks[i], ctx[i] = int(ids[i]), ctx[i] + 1
+    assert host_operands(ring) == [3, 0, 3, 0, 0]
+    host, held = eng._held
+    want = rows_of(eng, {2: (toks[2], pages[2], ctx[2])})
+    for got_host, handle, w in zip(host, held, want):
+        assert on_device(handle) and handle.dtype == np.int32
+        assert np.array_equal(np.asarray(handle), w)
+        assert np.array_equal(got_host, w)
+    idle = [0, 1, 3]
+    assert not np.asarray(held[0])[idle].any()
+    assert (np.asarray(held[1])[idle] == eng.cache.trash).all()
+    assert not np.asarray(held[2])[idle].any()
+
+
+@pytest.mark.parametrize('chunked', [False, True])
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_a_replay_by_hand_with_int64_ids_reuses_and_meets_the_reference(
+        model, chunked, ring):
+    """chipbench's ``Served.replay``: one running row, ids as int64, a
+    table built by hand, every call from new arrays.  After the first
+    step nothing is uploaded, and every position's logits are the
+    reference's."""
+    mod, params, eng, reference = engine(model, chunked)
+    prompt = np.random.default_rng(13).integers(1, mod.V, 11)
+    n_new = 9
+    pages = eng.cache.alloc(-(-(len(prompt) + n_new) // mod.PAGE))
+    rows = [prefill(eng, prompt, pages)]
+    toks = [int(np.argmax(rows[0]))]
+    ring.clear()
+    for j in range(n_new - 1):
+        pt = np.full((eng.max_streams, eng.pages_per_stream),
+                     eng.cache.trash, np.int32)
+        pt[0, :len(pages)] = pages
+        t_in = np.zeros((eng.max_streams,), np.int64)
+        t_in[0] = toks[-1]
+        ctx = np.zeros((eng.max_streams,), np.int32)
+        ctx[0] = len(prompt) + j
+        rows.append(np.asarray(eng.step(t_in, pt, ctx)[1][0]))
+        toks.append(int(np.argmax(rows[-1])))
+    assert host_operands(ring) == [3] + [0] * (n_new - 2)
+    want = reference(params, list(prompt) + toks[:-1])[len(prompt) - 1:]
+    for got, w in zip(rows, want):
+        assert mod.rel(got, w) < TOL

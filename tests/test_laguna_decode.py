@@ -29,6 +29,7 @@ from paddle_tpu.observability import timeline
 from paddle_tpu.ops import moe
 
 import reference_laguna as ref
+from test_decode_calls import host_operands
 
 TOL = 2e-5
 V, D, HKV, DH = 97, 64, 2, 16
@@ -526,3 +527,83 @@ def test_spans_counters_and_server(params, ring):
         assert e['args']['moe_all_assignments'] % (TOP_K * 3) == 0
     assert stats['moe_all_assignments'] > stats['moe_assignments'] > 0
     assert 0 < stats['kv_window_live_pages'] < stats['kv_live_pages']
+
+
+@pytest.mark.parametrize('chunk_pages', [0, 2])
+def test_steps_reuse_what_the_device_holds_of_both_groups(
+        params, ring, chunk_pages):
+    """By hand over a table of pages AND ring: the first step uploads
+    its three arrays, the steps after it none, the ring turning under
+    them (context 20 to 29 over a ring of 5 or 6 pages of 4: the table
+    stays, the column a position lands in is the program's arithmetic);
+    a second stream's admission uploads all three.  Every row's logits
+    are the reference's, and an engine that keeps nothing on the device
+    gives the same bits."""
+    runs = []
+    for always_upload in (False, True):
+        eng = make_engine(params, prefill_chunk_tokens=chunk_pages * PAGE)
+        if always_upload:
+            eng._hold = lambda *a: None
+        rng = np.random.default_rng(33)
+        first, second = rng.integers(1, V, 20), rng.integers(1, V, 6)
+        pages = {0: claim(eng, 40), 2: claim(eng, 16)}
+        rows = {}
+
+        def prefill(slot, prompt):
+            out = chunked_prefill(eng, prompt, pages[slot]) if eng.chunked \
+                else eng.prefill_into(prompt, pages[slot])
+            rows[slot] = [out]
+            return list(prompt) + [int(np.argmax(out))]
+        seq = {0: prefill(0, first)}
+        ring.clear()
+        for n in range(14):
+            if n == 10:
+                seq[2] = prefill(2, second)
+            pt = np.tile(eng.idle_row, (STREAMS, 1))
+            t, c = np.zeros(STREAMS, np.int64), np.zeros(STREAMS, np.int32)
+            for i in seq:
+                pt[i] = eng.table_row(pages[i])
+                t[i], c[i] = seq[i][-1], len(seq[i]) - 1
+            ids, logits = eng.step(t, pt, c)
+            for i in seq:
+                rows[i].append(np.asarray(logits[i]))
+                seq[i].append(int(ids[i]))
+        runs.append((host_operands(ring), rows, seq))
+        if not always_upload:
+            assert eng.kv_pages['window_recycled'] > 0
+            assert eng.calls == {'step_calls': 14, 'step_host_operands': 6}
+    (sent, rows, seq), (sent_u, rows_u, seq_u) = runs
+    assert sent == [3] + [0] * 9 + [3] + [0] * 3 and sent_u == [3] * 14
+    assert seq == seq_u
+    for i, prompt_len in ((0, 20), (2, 6)):
+        want = ref_logits(params, seq[i][:-1])[prompt_len - 1:]
+        assert len(rows[i]) == len(want)
+        for got, got_u, w in zip(rows[i], rows_u[i], want):
+            assert np.array_equal(got, got_u) and rel(got, w) < TOL
+
+
+@pytest.mark.parametrize('chunk_pages', [0, 2])
+def test_a_server_reuses_and_serves_what_an_uploading_one_serves(
+        params, ring, chunk_pages):
+    """Three requests through the server: the ids of a server whose
+    engine uploads every array every call, with fewer arrays sent; a
+    chunked server claims a page a stream every fourth position, and
+    sends the page tables alone there."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, V, n) for n in (5, 29, 12)]
+    runs = []
+    for always_upload in (False, True):
+        eng = make_engine(params, prefill_chunk_tokens=chunk_pages * PAGE)
+        if always_upload:
+            eng._hold = lambda *a: None
+        ring.clear()
+        toks, stats = serve(eng, prompts, (12, 6, 9))
+        runs.append((toks, stats, host_operands(ring)))
+    (toks, stats, sent), (toks_u, stats_u, sent_u) = runs
+    assert toks == toks_u
+    assert set(sent_u) == {3} and sent[0] == 3 and 0 in sent
+    assert stats['step_calls'] == len(sent) and \
+        stats['step_host_operands'] == sum(sent) < 3 * len(sent)
+    assert stats_u['step_host_operands'] == 3 * stats_u['step_calls']
+    assert (1 in sent) is bool(chunk_pages)
+    assert stats['compiles_after_warmup'] == 0

@@ -703,9 +703,11 @@ def test_opt_engine_reports_no_routing(ring):
                 for c in ctx[pts[:, 0] != eng.cache.trash])
             for pts, ctx in handed]
     assert live == [1, 2, 2]    # position 7 is page 0's last row
-    for e, n in zip(steps, live):   # two slots' ids came back, no counts
+    # two slots' ids came back, no counts; the first step's three arrays
+    # went in from the host, the next two steps' none
+    for e, n, sent in zip(steps, live, (3, 0, 0)):
         assert e['args'] == {'kv_live_pages': n, 'kv_table_pages': 2 * 8,
-                             'fetched_bytes': 2 * 4}
+                             'fetched_bytes': 2 * 4, 'host_operands': sent}
     assert all(set(e['args']) == {'tokens', 'bucket', 'fetched_bytes'}
                for e in spans(ring, 'decode.prefill_into'))
     assert (stats['moe_assignments'], stats['moe_max_load'],
