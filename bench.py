@@ -32,7 +32,7 @@ BASELINE_IMG_S = 61.0  # reference P40 fp32, batch 64
 # flag-scope tunables this bench searches (applied via env overrides);
 # train_batch / run_steps_k are bench-scope: searched by rebuilding the
 # program / resizing the scan below
-_FLAG_TUNABLES = ('amp', 'flat_tile_budget', 'device_prefetch_chunk')
+_FLAG_TUNABLES = ('amp', 'device_prefetch_chunk')
 _BENCH_TUNABLES = ('train_batch', 'run_steps_k')
 
 
@@ -70,7 +70,6 @@ def _autotune(mode, build_prog, image_shape, classes, batch0, k0,
         clamp = {'train_batch': tuple(
                      v for v in registry.tunable('train_batch').domain
                      if v <= max(batch0, 32)),
-                 'flat_tile_budget': (1 << 20, 4 << 20),
                  'device_prefetch_chunk': (0, 2)}
         tun = [registry.Tunable(t.name, clamp.get(t.name, t.domain),
                                 t.default, t.subsystem, t.env,

@@ -33,7 +33,7 @@ def bench_cli(argv=None):
 
 # flag-scope tunables the generic bench driver searches for a fixed
 # program (batch/K live in bench.py, which rebuilds per candidate)
-_BENCH_TUNABLES = ('amp', 'flat_tile_budget', 'device_prefetch_chunk')
+_BENCH_TUNABLES = ('amp', 'device_prefetch_chunk')
 
 
 def _tune_bench(build, feed_fn, mode, tunables=_BENCH_TUNABLES):

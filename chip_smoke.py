@@ -65,7 +65,6 @@ CHIP = dict(
     latent=dict(S=64, H=128, W=640, C=512, P=16, MPP=256, N=4097),
     lstm=dict(T=128, B=256, H=256),             # bench_lstm_lm.py
     gru=dict(T=64, B=512, H=512),               # bench_seq2seq.py
-    dense=[(2048, 1000), (7, 7, 3, 64), (64,)],  # fc head, stem, a bias
     sparse=dict(H=1000003, D=16, K=32768),      # bench_ctr.py
 )
 TOY = dict(
@@ -81,7 +80,6 @@ TOY = dict(
     latent=dict(S=4, H=4, W=128, C=96, P=16, MPP=10, N=25),
     lstm=dict(T=6, B=8, H=128),
     gru=dict(T=6, B=8, H=128),
-    dense=[(40, 30), (3, 3, 3, 8), (64,)],
     sparse=dict(H=1003, D=16, K=64),
 )
 
@@ -237,7 +235,6 @@ class TrainRig(object):
 
 def phase_train(smoke):
     import jax
-    from paddle_tpu.ops.pallas.dense_update import dense_apply_mode
     from paddle_tpu.runtime import FeedPipeline, native
     rig = smoke.rig()
     info = {'config': 'resnet%d %dx%d b%d bf16 NHWC momentum' % (
@@ -249,19 +246,6 @@ def phase_train(smoke):
     info['compile_plus_first_step_wall_s'] = round(walls[0], 2)
     info['run_numpy_feed_synced_step_wall_s'] = round(min(walls[1:]), 4)
     info['passes'] = rig.passes_ok()
-
-    # the kernel the mode names must be the kernel in the step: an
-    # interpreted or substituted apply would lower without the call
-    mode = dense_apply_mode()
-    info['dense_apply_mode'] = mode
-    batch = rig.feed(0)
-    fn, args = rig.exe.compile(rig.main, feed=batch,
-                               fetch_list=[rig.loss], scope=rig.scope)
-    n_calls = fn.lower(*args).as_text().count('tpu_custom_call')
-    info['tpu_custom_calls_in_step'] = n_calls
-    check((n_calls > 0) == (mode == 'pallas'),
-          'dense_apply_mode=%s but the lowered step holds %d '
-          'tpu_custom_call' % (mode, n_calls))
 
     # three more through the README's feed path (a second plan: these
     # feeds arrive as device arrays the executor must not donate)
@@ -288,6 +272,7 @@ def phase_train(smoke):
 
     # one chain: K steps on one repeated batch as a single computation
     k = smoke.cfg['train']['chain']
+    batch = rig.feed(0)
     chain_walls = []
     for _ in range(2):  # the first call compiles the scan
         t0 = time.perf_counter()
@@ -593,7 +578,6 @@ def kernel_cases(cfg):
     # (ops.pallas re-exports a function under the flash module's name)
     fa = importlib.import_module('paddle_tpu.ops.pallas.flash_attention')
     from paddle_tpu.core.selected_rows import merge_duplicate_rows
-    from paddle_tpu.ops.pallas import dense_update as du
     from paddle_tpu.ops.pallas import lstm_cell as lc
     from paddle_tpu.ops.pallas import table_update as tu
     f32, bf16 = jnp.float32, jnp.bfloat16
@@ -695,6 +679,16 @@ def kernel_cases(cfg):
                     .astype(np.float32)] + kv + [pt, ctx]
         return make
 
+    def slot_blocks(math, block=8):
+        # over all 32 slots at once the expression's gathered K/V,
+        # repeated to 48 query heads, is 15 GB: more than the chip has
+        def blocked(q, k, v, pt, ctx):
+            return jnp.concatenate([
+                math(q[i:i + block], k, v, pt[i:i + block],
+                     ctx[i:i + block])
+                for i in range(0, q.shape[0], block)])
+        return blocked
+
     for kind_name, kind in sorted(gq['kinds'].items()):
         win = kind['window']
         cases.append(KernelCase(
@@ -705,7 +699,8 @@ def kernel_cases(cfg):
                 lambda q, k, v, pt, ctx, interpret, win: paged_attention(
                     q, k, v, pt, ctx, window=win, interpret=interpret),
                 win=win),
-            functools.partial(paged_attention_math, window=win),
+            slot_blocks(functools.partial(paged_attention_math,
+                                          window=win)),
             *TOL_BF16, make=grouped_make(kind, False), timed=True))
         cases.append(KernelCase(
             'chunk_paged_attention_%s' % kind_name,
@@ -782,48 +777,10 @@ def kernel_cases(cfg):
         lambda x, w, interpret: lc.gru_scan(x, w, interpret=interpret),
         lc._gru_scan_reference))
 
-    # -- dense optimizer applies (ops/optim_ops.py dense branches) -------
-    b1, b2, eps, mu = 0.9, 0.999, 1e-8, 0.9
-
-    def dense_make(shape, n_arrays, last_nonneg=False):
-        def make(rng):
-            ops = [rng.standard_normal(shape).astype(np.float32)
-                   for _ in range(n_arrays)]
-            if last_nonneg:  # a second moment
-                ops[-1] = np.abs(ops[-1])
-            return ops + [np.float32(0.01)]
-        return make
-
-    def adam_ref(p, g, m, v, lr):
-        m_new = b1 * m + (1 - b1) * g
-        v_new = b2 * v + (1 - b2) * jnp.square(g)
-        return (p - lr * m_new / (jnp.sqrt(v_new) + eps), m_new, v_new)
-
-    for shape in cfg['dense']:
-        tag = 'x'.join(str(d) for d in shape)
-        t, s = (shape, f32), ((), f32)
-        cases.append(KernelCase(
-            'dense_apply_sgd_' + tag, [t, t, s],
-            lambda p, g, lr, interpret: du.dense_apply_sgd(
-                p, g, lr, interpret=interpret),
-            lambda p, g, lr: p - lr * g,
-            *TOL_F32, make=dense_make(shape, 2), timed=True))
-        cases.append(KernelCase(
-            'dense_apply_momentum_' + tag, [t, t, t, s],
-            lambda p, g, v, lr, interpret: du.dense_apply_momentum(
-                p, v, g, lr, mu, interpret=interpret),
-            lambda p, g, v, lr: (p - lr * (mu * v + g), mu * v + g),
-            *TOL_F32, make=dense_make(shape, 3), timed=True))
-        cases.append(KernelCase(
-            'dense_apply_adam_' + tag, [t, t, t, t, s],
-            lambda p, g, m, v, lr, interpret: du.dense_apply_adam(
-                p, m, v, g, lr, b1, b2, eps, interpret=interpret),
-            adam_ref, *TOL_F32,
-            make=dense_make(shape, 4, last_nonneg=True), timed=True))
-
     # -- row-sparse applies (ops/optim_ops.py sparse branches) -----------
     c = cfg['sparse']
     hgt, wid, k = c['H'], c['D'], c['K']
+    b1, b2, eps = 0.9, 0.999, 1e-8
     tab, rows_s, vals_s = ((hgt, wid), f32), ((k,), jnp.int32), ((k, wid), f32)
 
     def sparse_make(n_tabs):
@@ -888,11 +845,9 @@ def _median_wall_ms(fn, ops, reps=5):
 
 def phase_kernels(smoke):
     import jax
-    from paddle_tpu.ops.pallas.dense_update import dense_apply_mode
     from paddle_tpu.ops.pallas.table_update import sparse_apply_mode
     interpret = smoke.rehearse  # on the chip Mosaic compiles every one
     info = {'interpret': interpret, 'kernels': {},
-            'dense_apply_mode': dense_apply_mode(),
             'sparse_apply_mode': sparse_apply_mode()}
     check(interpret or jax.default_backend() == 'tpu',
           'kernels would be interpreted')
@@ -933,7 +888,6 @@ def phase_kernels(smoke):
     check(not failed, 'kernels failed: %r' % failed)
     return {'compiled_and_matched': sorted(info['kernels']),
             'interpret': interpret,
-            'dense_apply_mode': info['dense_apply_mode'],
             'sparse_apply_mode': info['sparse_apply_mode']}
 
 

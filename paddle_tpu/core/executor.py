@@ -239,8 +239,8 @@ def _shard_put(v, sh):
 def _pass_plan_key(program):
     """The composite pass-configuration component of every plan cache
     key — graph-opt level (with the memory_optimize floor), AMP mode
-    (+ loss-scale knobs), verify mode, and the sparse/dense apply
-    lowerings, all re-read per build so a flag flip is never served a
+    (+ loss-scale knobs), verify mode, and the sparse apply
+    lowering, all re-read per build so a flag flip is never served a
     stale trace.  ONE code path (transpiler/pass_manager.plan_key)
     feeds both the run and run_steps keys."""
     from ..transpiler import pass_manager
@@ -1237,7 +1237,7 @@ class Executor(object):
         # gc and would alias a fresh scope's plans with a dead one's.
         # The pass configuration participates as ONE composite component
         # (pass_manager.plan_key): graph-opt level, AMP mode, verify
-        # mode, sparse/dense apply lowerings, mesh spec — a flip of any
+        # mode, sparse apply lowering, mesh spec — a flip of any
         # must not be
         # served a plan built under the old configuration.
         # feed_donate keys the donation variant: a plan jitted with the
@@ -2108,8 +2108,7 @@ class Executor(object):
     def reset_cache(self):
         """Drop every cached plan.  The next plan build re-reads
         PADDLE_TPU_GRAPH_OPT_LEVEL, PADDLE_TPU_SPARSE_APPLY,
-        PADDLE_TPU_DENSE_APPLY, PADDLE_TPU_AMP, and
-        PADDLE_TPU_VERIFY_IR (all folded into the composite
+        PADDLE_TPU_AMP, and PADDLE_TPU_VERIFY_IR (all folded into the composite
         pass-configuration component of every plan key, so flips
         invalidate naturally — this just frees the old plans).
         PADDLE_TPU_DEVICE_PREFETCH is re-read on every run_steps call

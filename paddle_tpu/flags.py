@@ -123,18 +123,6 @@ DEFINE_string('sparse_apply', 'xla',
               'engine\'s shard_map.  Resolved per trace and part of the '
               'executor plan cache key, so flips take effect on the '
               'next plan build')
-DEFINE_string('dense_apply', 'xla',
-              'lowering for the dense optimizer apply (sgd/momentum/'
-              'adam dense branches): "xla" (default) keeps the jnp '
-              'expression chains; "pallas" runs the fused one-pass '
-              'flat-walk kernels (ops/pallas/dense_update.py, interpret '
-              'mode off-TPU), which compile and match on a v5e but cost '
-              'a relayout of every parameter there (ResNet-50 step 79 '
-              'ms against 28; PERF.md, chip bring-up) and which jax '
-              'refuses inside a PADDLE_TPU_MESH step.  Resolved per '
-              'trace and part of the executor plan cache key, so flips '
-              '(including after Executor.reset_cache()) take effect '
-              'on the next plan build')
 DEFINE_bool('device_prefetch', False,
             'device-resident double-buffered feed for '
             'Executor.run_steps with per-step feeds: the K-step feed '
@@ -437,13 +425,6 @@ DEFINE_int('tune_measure_budget', 24,
            'candidates are free; past the budget remaining candidates '
            'are pruned as measure-budget).  Bounds bench wall time on '
            'slow backends')
-DEFINE_int('flat_tile_budget', 0,
-           'per-block VMEM budget in bytes for the Pallas dense-apply '
-           'flat tile chooser (ops/pallas/dense_update.pick_flat_tile): '
-           '0 (default) keeps the baked-in 4 MiB; the autotuner '
-           'searches {1,2,4,8,16} MiB through this override.  Read at '
-           'trace time and part of the composite plan-cache key, so a '
-           'flip retraces instead of serving a stale tile size')
 DEFINE_float('serving_max_wait_ms', 5.0,
              'default deadline flush for BatchingInferenceServer when '
              'the constructor is not passed max_wait_ms= explicitly: '

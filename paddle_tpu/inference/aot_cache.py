@@ -23,8 +23,12 @@ runtime produces a different key, i.e. a plain miss and a normal
 compile, never a wrong executable.
 
 File format: one JSON header line (schema-versioned, carries the
-source-artifact path for the orphan sweep) followed by the pickled
-``(payload, in_tree, out_tree)`` triple.  Writes are atomic
+source-artifact path for the orphan sweep and the ids of the devices
+the executable was compiled for) followed by the pickled
+``(payload, in_tree, out_tree)`` triple.  A load hands the executable
+back to exactly those devices: one compiled for one device is never
+loaded for every local device, and one compiled for devices this
+process does not have is a counted miss.  Writes are atomic
 (``tmp.<pid>`` + ``os.replace``), so a shared directory behaves under
 concurrent fleets the same way the XLA compilation cache does.
 
@@ -55,7 +59,7 @@ except Exception:  # pragma: no cover - container jax has it
 
 __all__ = ['AotCache']
 
-_SCHEMA = 1
+_SCHEMA = 2  # 2: the header carries the executable's device ids
 
 # process-wide counters mirrored into the observability registry when
 # metrics are enabled — tests read the plain dict, dashboards the
@@ -161,9 +165,17 @@ class AotCache(object):
                 or hdr.get('device_kind') != _device_kind():
             _count('misses')  # schema-versioned header mismatch
             return None
+        by_id = {d.id: d for d in jax.devices()}
+        ids = hdr.get('devices')
+        if not isinstance(ids, list) or not ids \
+                or any(i not in by_id for i in ids):
+            _count('misses')  # compiled for devices this process lacks
+            return None
         try:
             payload, in_tree, out_tree = pickle.loads(body)
-            fn = _se.deserialize_and_load(payload, in_tree, out_tree)
+            fn = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in ids])
         except Exception:
             _count('corrupt')
             return None
@@ -182,10 +194,12 @@ class AotCache(object):
         try:
             payload, in_tree, out_tree = _se.serialize(compiled)
             body = pickle.dumps((payload, in_tree, out_tree))
+            devices = [d.id for d in
+                       compiled.runtime_executable().local_devices()]
         except Exception:
             return False  # backend can't serialize: quiet degrade
         hdr = {'schema': _SCHEMA, 'jax': jax.__version__,
-               'device_kind': _device_kind(),
+               'device_kind': _device_kind(), 'devices': devices,
                'artifact': (os.path.abspath(artifact)
                             if artifact else None),
                'bucket': int(bucket) if bucket is not None else None}

@@ -27,19 +27,6 @@ per trace by ops/pallas/table_update.sparse_apply_mode():
 
 PADDLE_TPU_SPARSE_APPLY=pallas pins the kernel path; the resolved mode
 is part of the executor's plan cache key, so a flip retraces.
-
-The DENSE applies of sgd/momentum/adam have the same two lowerings,
-selected by ops/pallas/dense_update.dense_apply_mode()
-(PADDLE_TPU_DENSE_APPLY, same default and cache-key contract):
-
-  'xla'    — the jnp expression chains below, verbatim.
-  'pallas' — ops/pallas/dense_update.py: ONE grid walk over the
-             flattened param applies the whole rule through
-             input_output_aliases.  Matches the XLA path (tier-1
-             tests/test_pallas_dense_update.py; exactly, on a v5e),
-             AMP f32-master grads included; on the v5e the flat view
-             costs a relayout of every parameter (PERF.md, chip
-             bring-up).
 """
 import jax.numpy as jnp
 
@@ -73,19 +60,6 @@ def _embed_ways(attrs, p, values):
     if ways > 1 and _pallas_rowwise(p, values):
         return ways
     return 0
-
-
-def _pallas_dense(p, g):
-    """True when the fused flat-walk kernel should serve this dense
-    update: mode resolves to pallas and grad/param agree in shape (the
-    kernels flatten, so any rank qualifies; a broadcasting or empty
-    operand falls back to the jnp chain)."""
-    if getattr(p, 'shape', None) != getattr(g, 'shape', None):
-        return False
-    if getattr(p, 'size', 0) == 0:
-        return False
-    from .pallas.dense_update import dense_apply_mode
-    return dense_apply_mode() == 'pallas'
 
 
 def _p32(x):
@@ -142,12 +116,6 @@ def _sgd(ctx, ins, attrs):
     # optional fused L2 weight decay (the scale+sum pair
     # append_regularization_ops would otherwise weave as separate ops)
     wd = attrs.get('weight_decay', 0.0)
-    if _pallas_dense(p, g):
-        from .pallas.dense_update import dense_apply_sgd
-        p_new = dense_apply_sgd(
-            _p32(p), g, lr,
-            weight_decay=jnp.float32(wd) if wd else None)
-        return {'ParamOut': [p_new.astype(p.dtype)]}
     if wd:
         return {'ParamOut': [
             (_p32(p) - lr * (g + jnp.float32(wd) * _p32(p))).astype(
@@ -162,13 +130,6 @@ def _momentum(ctx, ins, attrs):
     v = _p32(first(ins, 'Velocity'))
     lr = _p32(first(ins, 'LearningRate')).reshape(())
     mu = attrs.get('mu', 0.9)
-    if _pallas_dense(p, g):
-        from .pallas.dense_update import dense_apply_momentum
-        p_new, v_new = dense_apply_momentum(
-            _p32(p), v, g, lr, mu,
-            use_nesterov=attrs.get('use_nesterov', False))
-        return {'ParamOut': [p_new.astype(p.dtype)],
-                'VelocityOut': [v_new]}
     v_new = mu * v + g
     if attrs.get('use_nesterov', False):
         p_new = _p32(p) - (g + mu * v_new) * lr
@@ -222,12 +183,6 @@ def _adam(ctx, ins, attrs):
         return {'ParamOut': [p_new.astype(p.dtype)], 'Moment1Out': [m_new],
                 'Moment2Out': [v_new]}
     g = _p32(grad)
-    if _pallas_dense(p, g):
-        from .pallas.dense_update import dense_apply_adam
-        p_new, m_new, v_new = dense_apply_adam(
-            _p32(p), m, v, g, lr_t, b1, b2, eps)
-        return {'ParamOut': [p_new.astype(p.dtype)],
-                'Moment1Out': [m_new], 'Moment2Out': [v_new]}
     m_new = b1 * m + (1 - b1) * g
     v_new = b2 * v + (1 - b2) * jnp.square(g)
     p_new = _p32(p) - lr_t * m_new / (jnp.sqrt(v_new) + eps)

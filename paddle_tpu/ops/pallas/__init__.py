@@ -3,9 +3,6 @@
 Tests run them with interpret=True on CPU; on a TPU backend the same
 kernels compile to Mosaic.
 """
-from .dense_update import (dense_apply_adam,  # noqa: F401
-                           dense_apply_mode, dense_apply_momentum,
-                           dense_apply_sgd)
 from .flash_attention import flash_attention  # noqa: F401
 from .lstm_cell import gru_scan, lstm_scan  # noqa: F401
 from .paged_attention import paged_attention  # noqa: F401
@@ -16,6 +13,4 @@ from .table_update import (sparse_apply_adagrad,  # noqa: F401
 __all__ = ['flash_attention', 'paged_attention',
            'lstm_scan', 'gru_scan',
            'sparse_apply_sgd', 'sparse_apply_adagrad',
-           'sparse_apply_adam', 'sparse_apply_mode',
-           'dense_apply_sgd', 'dense_apply_momentum',
-           'dense_apply_adam', 'dense_apply_mode']
+           'sparse_apply_adam', 'sparse_apply_mode']

@@ -297,23 +297,18 @@ def plan_key(program=None):
     configuration — the ONE code path both Executor.run and run_steps
     key their caches on.  Covers every knob that changes what a plan
     build produces: graph-opt level, AMP mode (+ loss-scale knobs),
-    verify mode, the sparse/dense optimizer-apply lowerings baked
-    into the traced ops, the SPMD mesh (PADDLE_TPU_MESH) the
-    sharding pass propagates and the executor pjit-lowers with, and
-    the Pallas flat-tile VMEM budget (PADDLE_TPU_FLAT_TILE_BUDGET —
-    the autotuner's dense-apply hook) baked into traced kernels."""
+    verify mode, the sparse optimizer-apply lowering baked into the
+    traced ops, and the SPMD mesh (PADDLE_TPU_MESH) the sharding pass
+    propagates and the executor pjit-lowers with."""
     from .amp import plan_key_component
     from ..distributed.mesh_flag import mesh_key
     from ..ops.pallas.table_update import sparse_apply_mode
-    from ..ops.pallas.dense_update import dense_apply_mode, \
-        flat_tile_budget
     from .sharding import embed_plan_key
     from .overlap import overlap_plan_key
     from ..flags import FLAGS
     return ('pm', resolve_level(program), plan_key_component(),
             verify_mod.resolve_mode(None), sparse_apply_mode(),
-            dense_apply_mode(), mesh_key(), embed_plan_key(),
-            flat_tile_budget(), overlap_plan_key(),
+            mesh_key(), embed_plan_key(), overlap_plan_key(),
             int(FLAGS.pp_microbatches or 0))
 
 

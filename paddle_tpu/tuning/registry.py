@@ -184,7 +184,7 @@ def base_env():
 
 
 # ---------------------------------------------------------------------------
-# the registrations — every hand-set constant ISSUE 16 names
+# the registrations — the hand-set constants the autotuner may search
 # ---------------------------------------------------------------------------
 
 def _mesh_feasible(spec):
@@ -210,16 +210,6 @@ def _mesh_feasible(spec):
         return False
 
 
-_MIB = 1024 * 1024
-
-register_tunable(
-    'flat_tile_budget', (1 * _MIB, 2 * _MIB, 4 * _MIB, 8 * _MIB,
-                         16 * _MIB),
-    default=4 * _MIB, subsystem='ops.pallas',
-    env='PADDLE_TPU_FLAT_TILE_BUDGET',
-    help='per-block VMEM budget for the dense-apply flat tile walk '
-         '(pick_flat_tile); larger tiles amortize grid overhead, '
-         'smaller ones leave VMEM headroom for fusion')
 register_tunable(
     'device_prefetch_chunk', (0, 1, 2, 4, 8, 16, 32),
     default=0, subsystem='runtime.prefetch',

@@ -49,7 +49,7 @@ def _fake_tunables():
     """A private two-knob registry slice for search unit tests."""
     return [
         registry.Tunable('tile', (1, 2, 4), 2, 'test',
-                         env='PADDLE_TPU_FLAT_TILE_BUDGET'),
+                         env='PADDLE_TPU_DEVICE_PREFETCH_CHUNK'),
         registry.Tunable('mode', ('a', 'b'), 'a', 'test',
                          env='PADDLE_TPU_AMP'),
     ]
@@ -61,7 +61,7 @@ def _fake_tunables():
 
 def test_registry_covers_the_hand_set_tunables():
     names = [t.name for t in registry.registered_tunables()]
-    for expected in ('flat_tile_budget', 'device_prefetch_chunk', 'amp',
+    for expected in ('device_prefetch_chunk', 'amp',
                      'mesh', 'embed_bucket_tile', 'embed_cache_rows',
                      'serving_max_wait_ms', 'serving_max_batch',
                      'train_batch', 'run_steps_k'):
@@ -88,15 +88,15 @@ def test_pinning_and_applied_restore(monkeypatch):
 
 def test_apply_persistent_masks_in_base_env_and_never_repins(
         monkeypatch):
-    t = registry.tunable('flat_tile_budget')
-    done = registry.apply_persistent({t.name: 1 << 20})
-    assert done == {t.name: 1 << 20}
-    assert os.environ[t.env] == str(1 << 20)
+    t = registry.tunable('device_prefetch_chunk')
+    done = registry.apply_persistent({t.name: 4})
+    assert done == {t.name: 4}
+    assert os.environ[t.env] == '4'
     # the tuner set it, so it does NOT pin and base_env masks it
     assert not registry.is_pinned(t)
     with registry.base_env():
         assert t.env not in os.environ
-    assert os.environ[t.env] == str(1 << 20)
+    assert os.environ[t.env] == '4'
     # a user-pinned tunable is never overwritten
     p = registry.tunable('amp')
     monkeypatch.setenv(p.env, 'bf16')
@@ -130,7 +130,7 @@ def test_search_deterministic_fixed_measurements():
 
 def test_ties_keep_the_incumbent():
     tun = [registry.Tunable('tile', (1, 2), 1, 'test',
-                            env='PADDLE_TPU_FLAT_TILE_BUDGET')]
+                            env='PADDLE_TPU_DEVICE_PREFETCH_CHUNK')]
     tuner = tsearch.Autotuner(
         model_fn=lambda c: {'score': 1.0, 'peak_bytes': 0},
         measure_fn=lambda c: 1.0, tunables=tun, hbm_budget_bytes=0,
@@ -146,7 +146,7 @@ def test_hbm_budget_prunes_without_measuring():
         return 1.0
 
     tun = [registry.Tunable('tile', (1, 2, 4), 1, 'test',
-                            env='PADDLE_TPU_FLAT_TILE_BUDGET')]
+                            env='PADDLE_TPU_DEVICE_PREFETCH_CHUNK')]
     tuner = tsearch.Autotuner(
         model_fn=lambda c: {'score': 1.0,
                             'peak_bytes': c['tile'] * 10 ** 9},
@@ -162,7 +162,7 @@ def test_hbm_budget_prunes_without_measuring():
 def test_modeled_worse_prunes_and_budget_bounds_measurements():
     measured = []
     tun = [registry.Tunable('tile', (1, 2, 4, 8), 1, 'test',
-                            env='PADDLE_TPU_FLAT_TILE_BUDGET')]
+                            env='PADDLE_TPU_DEVICE_PREFETCH_CHUNK')]
     tuner = tsearch.Autotuner(
         model_fn=lambda c: {'score': float(c['tile']), 'peak_bytes': 0},
         measure_fn=lambda c: measured.append(dict(c)) or 1.0,
@@ -314,7 +314,7 @@ def test_dryrun_smoke_chosen_config_modeled_no_worse(tmp_path):
     prog, _startup, cost = _small_program()
     feed_specs = {'x': ((8, 32), 'float32'), 'label': ((8, 1), 'int32')}
     tun = [registry.tunable('amp'),
-           registry.tunable('flat_tile_budget')]
+           registry.tunable('device_prefetch_chunk')]
 
     def model_fn(cfg):
         with registry.applied(cfg):

@@ -38,7 +38,7 @@ _MAX_DOMAIN = 64
 # silently un-tunes the knob (the flag keeps working, the search just
 # stops seeing it), so the lint pins a floor under the registry.
 _REQUIRED = (
-    'flat_tile_budget', 'amp', 'mesh',
+    'amp', 'mesh',
     'overlap', 'overlap_bucket_mb', 'pp_microbatches',
     'decode_page_size', 'decode_max_streams', 'decode_prefill_bucket',
     'decode_prefix_cache', 'decode_prefill_chunk_tokens',
