@@ -48,7 +48,15 @@ description supplies the rest, once, as pure functions of the weights (a
 - ``live_positions_arg``   the name under which a ``decode.step`` span
   reports the cached positions the step's attention reads (a block
   whose kernel reads one row a position says so); None: pages only;
-- ``head(p, x)`` -> logits [T, V] float32.
+- ``head(p, x)`` -> logits [T, V] float32;
+- (optional) ``between(p, x, t)`` -> (x, gate): a block that has it
+  runs its layers ``ut_steps`` times a token over the one set of
+  weights (a recurrence into cache slots of its own: the engine's
+  ``_layers``).  It is called where recurrence ``t`` ends, with the
+  stream x [T, D]; the x it returns is what the next recurrence starts
+  from (after the last, what ``head`` gets) and ``gate`` [T] float32 is
+  each row's exit gate there.  ``exit_distribution(gates [R, T])`` ->
+  p [R, T]: the share of a row that leaves at each recurrence.
 
 Every function takes the weights ``p`` as traced values: the engine
 hands each of its programs the one placed copy as an operand, in the
@@ -75,7 +83,7 @@ from ..ops.moe import (moe_counts, moe_experts, moe_route,
                        swiglu_math, yarn_mscale)
 
 __all__ = ['KVBlock', 'OptBlock', 'OlmoeBlock', 'DotsVlmBlock',
-           'LagunaBlock']
+           'LagunaBlock', 'OuroBlock']
 
 
 def _mm(x, w):
@@ -588,3 +596,93 @@ class LagunaBlock(KVBlock):
 
     def head(self, p, x):
         return _mm(self.norm(x, p['laguna_norm_f_w']), p['laguna_head_w'])
+
+
+class OuroBlock(KVBlock):
+    """The layer of ByteDance's Ouro (models/ouro.py declares the same
+    parameters; chipbench/reference/ouro.py is its plain reference), a
+    stack that runs ``ut_steps`` times a token over ONE set of weights:
+    RMSNorm on each branch's way in AND on its way out ("sandwich"),
+    full multi-head attention with rotary positions over the whole head
+    (half-split pairing; keys cached after rotation), a SwiGLU FFN, no
+    biases.  ``between`` closes a recurrence with the final norm, whose
+    output the next recurrence starts from, and reads the exit gate off
+    it: one sigmoid a row.  ``head`` is therefore the plain product
+    with the head's matrix: the last recurrence's closing norm has
+    already normalised what it is handed.
+
+    A recurrence has K/V of its own: a position is cached in ``ut_steps``
+    slots a layer, none shared (the engine's cache counts them).
+
+    The exit rule: with gates g[t], p[t] = g[t] prod_{s<t} (1 - g[s])
+    and the last recurrence takes the remainder; a token would leave at
+    the first t whose running sum of p reaches ``early_exit_threshold``.
+    At the published 1.0 none leaves early and every recurrence runs for
+    every row, which is what is served: rows of one batch leaving at
+    different recurrences is not built, and a lower threshold is
+    refused."""
+
+    def __init__(self, n_heads, ut_steps=4, early_exit_threshold=1.0,
+                 eps=1e-6, theta=1e6):
+        if float(early_exit_threshold) < 1.0:
+            raise ValueError(
+                "early_exit_threshold %g: only 1.0 (the published value: "
+                "every recurrence runs for every token) is served; rows "
+                "of one batch leaving the loop at different recurrences, "
+                "and what their skipped cache slots hold for later "
+                "tokens, are not built" % early_exit_threshold)
+        self.n_heads = int(n_heads)
+        self.ut_steps = int(ut_steps)
+        self.eps, self.theta = float(eps), float(theta)
+
+    @staticmethod
+    def names(n_layers):
+        from ..models.ouro import param_names
+        return param_names(n_layers)
+
+    def sizes(self, params):
+        v, d = params['ouro_embed'].shape
+        return {'d_model': int(d), 'vocab_size': int(v)}
+
+    def norm(self, x, w):
+        return rms_norm_math(x, w, self.eps)
+
+    def embed(self, p, tokens, positions):
+        return p['ouro_embed'][tokens].astype(jnp.float32)
+
+    def qkv(self, p, x, i, positions):
+        n = 'ouro_l%d_' % i
+        t, d = x.shape
+        heads = (t, self.n_heads, d // self.n_heads)
+        a = self.norm(x, p[n + 'in_norm_w'])
+        q = rotary_math(_mm(a, p[n + 'q_w']).reshape(heads), positions,
+                        self.theta)
+        k = rotary_math(_mm(a, p[n + 'k_w']).reshape(heads), positions,
+                        self.theta).reshape(t, d)
+        return q, k, _mm(a, p[n + 'v_w'])
+
+    def out_norm(self, y, w):
+        """The norm on a branch's way out."""
+        return self.norm(y, w)
+
+    def after_attention(self, p, x, ctx, i, active):
+        n = 'ouro_l%d_' % i
+        x = x + self.out_norm(_mm(ctx.reshape(x.shape), p[n + 'o_w']),
+                              p[n + 'in_norm2_w'])
+        m = self.norm(x, p[n + 'post_norm_w'])
+        y = swiglu_math(m, p[n + 'gate_w'], p[n + 'up_w'], p[n + 'down_w'])
+        return x + self.out_norm(y, p[n + 'post_norm2_w']), None
+
+    def between(self, p, x, t):
+        x = self.norm(x, p['ouro_norm_f_w'])
+        return x, jax.nn.sigmoid(x @ p['ouro_exit_w'].astype(jnp.float32)
+                                 + p['ouro_exit_b'][0])
+
+    @staticmethod
+    def exit_distribution(gates):
+        stay = jnp.cumprod(1.0 - gates, axis=0)
+        stay = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+        return jnp.concatenate([gates[:-1] * stay[:-1], stay[-1:]])
+
+    def head(self, p, x):
+        return _mm(x, p['ouro_head_w'])

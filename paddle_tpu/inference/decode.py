@@ -46,11 +46,21 @@ inference-throughput fix for decoder-only LMs, TPU-native:
 
 There is ONE definition of a decoder's serving path: which decoder is
 served is a block description (inference/blocks.py: ``OptBlock``, the
-default, ``OlmoeBlock``, ``DotsVlmBlock``, ``LagunaBlock``) that
-supplies the layer's equations, what a position caches and how to
-attend over it, and the engine's ``prefill``, ``chunk`` and ``step`` are
-one loop over layers around them.  No code here names a model or a
-parameter.
+default, ``OlmoeBlock``, ``DotsVlmBlock``, ``LagunaBlock``,
+``OuroBlock``) that supplies the layer's equations, what a position
+caches and how to attend over it, and the engine's ``prefill``,
+``chunk`` and ``step`` are one loop over layers around them.  No code
+here names a model or a parameter.
+
+Where a block runs its layers SEVERAL times a token over the one set of
+weights (it has ``between`` and says ``ut_steps``), weight layers and
+cache layers are two counts: the weights are ``n_layers`` layers, held
+and passed once, and a position is cached once a layer a recurrence
+(``PagedKVCache.slots``).  Slot (t, l) is a range of pages of layer l's
+own buffers, reached through the stream's one page table shifted by t
+ranges, so the allocator, the page tables and the kernels know nothing
+of recurrences; the recurrences are one traced body under ``lax.scan``
+in each of the three programs (``_layers``).
 
 Where a block's layers differ in what they KEEP (``layer_kinds``: some
 every position, some a window of the newest), the cache holds a page
@@ -122,6 +132,33 @@ def decode_buckets(page_size, top):
     return sizes
 
 
+_ROOMY = []    # the function ``_in_a_roomy_frame`` calls through, once built
+
+
+def _in_a_roomy_frame(f):
+    """``f()``, called from a frame with 65,536 unused local slots.
+
+    CPython (3.11 and later) keeps a thread's frames in chunks of 16 KB
+    and unmaps a chunk the moment the first frame in it returns, so a
+    call path that happens to start on a chunk's edge maps and unmaps
+    16 KB at every call.  Tracing and lowering an engine program with a
+    Pallas kernel in it makes a few hundred thousand calls a couple of
+    hundred frames deep: what that costs followed the DEPTH it was
+    started from, a frame more or fewer in the caller moving it by a
+    third, and on the chip's host, where an unmap is dear, it was most
+    of set-up (a cell's seven programs, all from the compile cache: 72 s
+    from a plain frame, 15 s from this one; PERF.md section 6, PR 56).
+    A frame this large gets a 1 MB chunk to itself, and all that ``f``
+    calls lives in the half of it the frame leaves free, wherever the
+    caller stood."""
+    if not _ROOMY:
+        scope = {}
+        exec('def roomy(f):\n    return f()\n    %s = None'
+             % ' = '.join('_%d' % i for i in range(1 << 16)), scope)
+        _ROOMY.append(scope['roomy'])
+    return _ROOMY[0](f)
+
+
 class _PageGroup(object):
     """A free list over ``num_pages`` pages and, one past them, the
     group's TRASH page (padded page-table entries and inactive slots
@@ -171,13 +208,24 @@ class PagedKVCache(_PageGroup):
     a second group, ``cache.window``, sized apart (``window_pages``)
     with a free list and a trash page of its own: their buffers are
     ``[window_pages + 1, page_size, width]``, and a stream holds a ring
-    of them, not a page a page of its context."""
+    of them, not a page a page of its context.
+
+    A block that runs its ``n_layers`` weight layers ``recurrences``
+    times a token caches a position once a layer a RECURRENCE:
+    ``slots`` = recurrences x n_layers cache slots.  Slot (t, l) is
+    pages ``t * (pages + 1) ..`` of layer l's buffers, which are
+    ``recurrences`` times as long: recurrence t writes and reads
+    through the stream's page table shifted by that many pages
+    (``shift``), so one table, one free list and one claim serve every
+    slot, each recurrence has a trash page of its own, and a kernel
+    that takes (pool, page table) takes a slot as it takes a layer."""
 
     def __init__(self, n_layers, num_pages, page_size, n_heads=None,
                  head_dim=None, dtype=jnp.float32, rows=None,
-                 window_layers=(), window_pages=0):
+                 window_layers=(), window_pages=0, recurrences=1):
         _PageGroup.__init__(self, num_pages)
         self.n_layers = int(n_layers)
+        self.recurrences = int(recurrences)
         self.page_size = int(page_size)
         if rows is None:
             rows = (('k', n_heads * head_dim), ('v', n_heads * head_dim))
@@ -187,9 +235,26 @@ class PagedKVCache(_PageGroup):
         self.window = _PageGroup(window_pages) if self.window_layers \
             else None
         self.pools = [
-            [jnp.zeros((self.group_of(i).num_pages + 1, self.page_size, w),
-                       dtype)
+            [jnp.zeros((self.recurrences
+                        * (self.group_of(i).num_pages + 1),
+                        self.page_size, w), dtype)
              for i in range(self.n_layers)] for _n, w in self.rows]
+
+    @property
+    def slots(self):
+        """Cache layers: what a position is cached in, a weight layer a
+        recurrence."""
+        return self.recurrences * self.n_layers
+
+    def shift(self, pages, t):
+        """Page ids ``pages`` (one array a group, trash entries too) as
+        recurrence ``t`` (a number or a traced scalar) finds them in the
+        buffers; a cache of one recurrence hands them back as they
+        are."""
+        if self.recurrences == 1:
+            return pages
+        return [p + t * (g.num_pages + 1)
+                for p, g in zip(pages, self.groups)]
 
     @property
     def groups(self):
@@ -212,18 +277,18 @@ class PagedKVCache(_PageGroup):
         return [w for _n, w in self.rows]
 
     def group_bytes(self):
-        """Resident bytes a group: layers x pages x page_size x the
-        rows' widths x dtype (trash pages included — they are
+        """Resident bytes a group: cache slots x pages x page_size x
+        the rows' widths x dtype (trash pages included — they are
         resident)."""
-        n_window = len(self.window_layers)
+        n_window, T = len(self.window_layers), self.recurrences
         out = {'full': page_pool_bytes(
             self.num_pages + 1, self.page_size, dtype=self.dtype,
-            n_layers=self.n_layers - n_window,
+            n_layers=T * (self.n_layers - n_window),
             row_widths=self.row_widths())}
         if self.window is not None:
             out['window'] = page_pool_bytes(
                 self.window.num_pages + 1, self.page_size,
-                dtype=self.dtype, n_layers=n_window,
+                dtype=self.dtype, n_layers=T * n_window,
                 row_widths=self.row_widths())
         return out
 
@@ -407,6 +472,11 @@ class DecodeEngine(object):
                              % (block.n_heads, self.n_heads))
         self.params = self._place(params)
         self.sizes = sizes = block.sizes(self.params)
+        # a block that says ``between`` runs its layers ``ut_steps``
+        # times a token over the one set of weights, each recurrence
+        # into cache slots of its own (``_layers``)
+        self.looped = hasattr(block, 'between')
+        self.ut_steps = int(block.ut_steps) if self.looped else 1
         self.d_model = sizes['d_model']
         self.vocab_size = sizes['vocab_size']
         table = sizes.get('positions')
@@ -430,6 +500,9 @@ class DecodeEngine(object):
         # the calls that ran decode rows, and how many of their three
         # arrays went in from the host (``_decode_operands``)
         self.calls = {'step_calls': 0, 'step_host_operands': 0}
+        # passes over a weight layer that the decode rows' calls ran,
+        # as their programs' fetches count them (``_fetch``)
+        self.loop_passes = 0
         # what the last such call left on the device for the next one,
         # and the host's copies of it: (ids, page tables, context
         # lengths) twice, or None before the first
@@ -494,7 +567,8 @@ class DecodeEngine(object):
             self.n_layers, num_pages, self.page_size, dtype=dtype,
             rows=block.cache_rows(sizes), window_layers=window_layers,
             window_pages=self.max_streams * self.ring_pages
-            if window_pages is None else window_pages)
+            if window_pages is None else window_pages,
+            recurrences=self.ut_steps)
         # a layer's group: 0 the pages of the whole context, 1 the rings
         self._group = [int(i in self.cache.window_layers)
                        for i in range(self.n_layers)]
@@ -527,9 +601,15 @@ class DecodeEngine(object):
         span_args.update(self.block.describe(
             fn.__name__, self.sizes, jax.default_backend(),
             self.page_size, self.cache.dtype))
+        if self.looped:
+            span_args.update(
+                ut_steps=self.ut_steps, cache_slots=self.cache.slots,
+                weight_layers=self.n_layers,
+                cache_bytes_per_position=self.cache.slots * sum(
+                    self.cache.row_widths()) * self.cache.dtype.itemsize)
         with _obs.span('decode.compile', args=span_args):
-            compiled = jax.jit(fn, donate_argnums=donate).lower(
-                *args).compile()
+            compiled = _in_a_roomy_frame(lambda: jax.jit(
+                fn, donate_argnums=donate).lower(*args).compile())
             # whether the pools are updated in place, as the compiler
             # declares it: the donated bytes it aliased to outputs and
             # the scratch the program needs beside its arguments
@@ -576,21 +656,90 @@ class DecodeEngine(object):
     def _trashes(self):
         return [g.trash for g in self.cache.groups]
 
-    def _layers(self, params, x, positions, active, attend):
-        """A block's layers over x [T, D]: ``attend(i, q, rows)`` is
-        where prefill, chunk and step differ (it writes the rows layer i
-        caches for these positions and returns the attention output).
-        Returns (x, extra): ``extra`` is what the program returns beside
-        its usual outputs, ``(routing counts [layers that route, n],)``
-        or ``()``."""
-        blk, counts = self.block, []
-        for i in range(self.n_layers):
-            q, *rows = blk.qkv(params, x, i, positions)
-            ctx = attend(i, q, [r.astype(self.cache.dtype) for r in rows])
-            x, c = blk.after_attention(params, x, ctx, i, active)
-            if c is not None:
-                counts.append(c)
-        return x, ((jnp.stack(counts),) if counts else ())
+    def _layers(self, params, x, positions, active, attend, pools=(),
+                pages=(), decoding=None):
+        """A block's layers over x [rows, D], as many times as the block
+        runs them (``ut_steps`` recurrences over the one set of weights,
+        recurrence t into cache slots of its own).  ``attend(i, q, rows,
+        pools, *pages)`` is where prefill, chunk and step differ: it
+        writes the rows layer i caches for these positions into
+        ``pools`` (lists of the program's own, one a cache row) and
+        returns the attention output and what a whole-prompt prefill
+        keeps for ``pack`` (a tuple, one array a cache row, or ``()``).
+        ``pages`` is what the program reaches the pools through (page
+        ids and page tables, each one array a group): a recurrence
+        hands ``attend`` each as that recurrence finds it
+        (``PagedKVCache.shift``), so ``attend`` knows nothing of
+        recurrences.  Where a recurrence ends the block's ``between``
+        closes it (the stream the next one starts from, and the exit
+        gate of every row).
+
+        One recurrence is the loop over layers as it stands; several
+        are ONE traced body under ``lax.scan`` with the pools as its
+        carry, whatever the block (on the chip, 12 layers four times:
+        the six programs compile in 41 s where 48 unrolled passes took
+        148, and a step is 9.8 ms on the device where theirs was 10.9:
+        PERF.md section 6, PR 56).
+
+        Returns (x, pools, kept, extra): ``kept`` what a prefill keeps,
+        a list a cache row of one array a layer, [rows, width] or under
+        the scan [ut_steps, rows, width] (``_by_slot`` stacks them for
+        ``pack``), ``extra`` what the program
+        returns beside its usual outputs: the routing counts [layers
+        that route x recurrences, n] where the block routes, then for a
+        block that loops the exit distribution's mean over the
+        ``active`` rows among the first ``decoding`` (the decode rows
+        lead a chunk's; None: every row), [ut_steps] float32."""
+        blk, T = self.block, self.ut_steps
+
+        def recurrence(t, x, pools):
+            pools = [list(pool) for pool in pools]
+            at = [self.cache.shift(p, t) for p in pages]
+            counts, kept, out = [], [], {}
+            for i in range(self.n_layers):
+                q, *rows = blk.qkv(params, x, i, positions)
+                ctx, keep = attend(
+                    i, q, [r.astype(self.cache.dtype) for r in rows],
+                    pools, *at)
+                kept.append(keep)
+                x, c = blk.after_attention(params, x, ctx, i, active)
+                if c is not None:
+                    counts.append(c)
+            if counts:
+                out['counts'] = jnp.stack(counts)
+            if kept[0]:
+                out['kept'] = [list(r) for r in zip(*kept)]
+            if self.looped:
+                x, out['gate'] = blk.between(params, x, t)
+            return x, pools, out
+
+        if T == 1:
+            x, pools, out = recurrence(0, x, pools)
+        else:
+            def body(carry, t):
+                x, pools, out = recurrence(t, *carry)
+                return (x, pools), out
+
+            (x, pools), out = jax.lax.scan(
+                body, (x, [list(pool) for pool in pools]), jnp.arange(T))
+            if 'counts' in out:     # [T, layers, n] -> [T x layers, n]
+                out['counts'] = out['counts'].reshape(
+                    (-1,) + out['counts'].shape[2:])
+        extra = (out['counts'],) if 'counts' in out else ()
+        if self.looped:
+            w = active[:decoding].astype(jnp.float32)
+            gates = out['gate'].reshape(T, -1)[:, :decoding]
+            extra += (blk.exit_distribution(gates) @ w
+                      / jnp.maximum(jnp.sum(w), 1.0),)
+        return x, pools, out.get('kept', ()), extra
+
+    def _by_slot(self, layers):
+        """What ``_layers`` kept of one cache row, an array a layer ->
+        [cache slots, rows, width], slot t x n_layers + l."""
+        if self.ut_steps == 1:
+            return jnp.stack(layers)
+        both = jnp.stack(layers, axis=1)        # [T, L, rows, width]
+        return both.reshape((-1,) + both.shape[2:])
 
     @staticmethod
     def _place(params):
@@ -655,17 +804,30 @@ class DecodeEngine(object):
              host[2] + running.astype(np.int32)),
             (next_ids, ops[1], next_ctx))
 
-    def _fetch(self, arrays, counts, span_args, step=False):
+    def _fetch(self, arrays, extra, span_args, step=False, decoded=True):
         """An engine call's one copy to the host: ``arrays`` (what the
-        caller reads) and the routing counts the program returned beside
-        them (``counts``: one array or none) come back in one transfer,
-        which waits for the program.  The span gets ``fetched_bytes``
-        and what ``_routing`` makes of the counts; the arrays are
-        returned as numpy."""
-        got = jax.device_get(tuple(arrays) + tuple(counts))
+        caller reads) and what the program returned beside them
+        (``extra``, as ``_layers`` orders it: the routing counts where
+        the block routes, the exit distribution's mean where it loops)
+        come back in one transfer, which waits for the program.  The
+        span gets ``fetched_bytes``, what ``_routing`` makes of the
+        counts and, where the call ran decode rows (``decoded``),
+        ``loop_exit_mass`` and ``loop_passes`` (its length, the
+        recurrences the program ran, times the weight layers); the
+        arrays are returned as numpy."""
+        got = jax.device_get(tuple(arrays) + tuple(extra))
         span_args['fetched_bytes'] = sum(a.nbytes for a in got)
-        if counts:
-            self._routing(got[-1], span_args, step=step)
+        extra = list(got[len(arrays):])
+        if self.looped:
+            mass = extra.pop()
+            if decoded:
+                # one mean a recurrence the program ran, each a pass
+                # over every weight layer
+                span_args['loop_exit_mass'] = mass.tolist()
+                span_args['loop_passes'] = len(mass) * self.n_layers
+                self.loop_passes += span_args['loop_passes']
+        if extra:
+            self._routing(extra[0], span_args, step=step)
         return got[:len(arrays)]
 
     def _routing(self, c, span_args, step=False):
@@ -710,6 +872,13 @@ class DecodeEngine(object):
         if self.block.live_positions_arg:
             # the positions a step's attention reads, the new one too
             span_args[self.block.live_positions_arg] = int(np.sum(ctx + 1))
+        if self.looped:
+            # every recurrence reads the running slots' positions in a
+            # slot of its own
+            T = self.ut_steps
+            span_args.update(
+                ut_steps=T,
+                kv_loop_live_positions=T * int(np.sum(ctx + 1)))
         if self.ring_pages:
             # by group: the pages above, and the rings' pages that hold
             # a window's positions (the new one too)
@@ -739,16 +908,15 @@ class DecodeEngine(object):
 
     # -- the three programs: one loop, three ways to attend -------------
 
-    def _write_then(self, pools, page_idx, offset, read):
+    def _write_then(self, offset, read):
         """``attend`` of chunk and step: the rows layer i caches land at
-        (page, offset) of its own buffers (``page_idx``: the pages, one
-        array a group), then ``read(i, q)`` attends over what was
-        written."""
-        def attend(i, q, rows):
+        (page, offset) of its own buffers (``at``: the pages, one array
+        a group, as the recurrence finds them), then ``read(i, q, pools,
+        *tables)`` attends over what was written."""
+        def attend(i, q, rows, pools, at, *tables):
             for pool, r in zip(pools, rows):
-                pool[i] = pool[i].at[page_idx[self._group[i]],
-                                     offset].set(r)
-            return read(i, q)
+                pool[i] = pool[i].at[at[self._group[i]], offset].set(r)
+            return read(i, q, pools, *tables), ()
         return attend
 
     def _prefill_fn(self, bucket):
@@ -761,21 +929,17 @@ class DecodeEngine(object):
             # per-shape compile (~25-40ms) lands on the first stream
             # of every bucket — invisible to compiles_total
             pos = jnp.arange(bucket)
-            kept = [[] for _ in self.cache.rows]
 
-            def attend(i, q, rows):
+            def attend(i, q, rows, pools):
                 # attention reads the rows as the cache will hold them
                 # (already in the pools' dtype); pack writes them later
-                ctx, keep = blk.attend_prefill(params, i, q, rows)
-                for layers, r in zip(kept, keep):
-                    layers.append(r)
-                return ctx
+                return blk.attend_prefill(params, i, q, rows)
 
-            x, extra = self._layers(
+            x, _pools, kept, extra = self._layers(
                 params, blk.embed(params, tokens, pos), pos, pos <= last,
                 attend)
             return (blk.head(params, x[last][None])[0],) + tuple(
-                jnp.stack(layers) for layers in kept) + extra
+                self._by_slot(layers) for layers in kept) + extra
         return prefill
 
     def _chunk_rows(self, bucket, pt, pos0, n_valid):
@@ -813,19 +977,15 @@ class DecodeEngine(object):
                 axis=1)[:, 0])
         return pos, page_idx, pos % P
 
-    def _chunk_read(self, params, pools, pt, pos0):
-        tables = self._tables(pt)
-
-        def read(i, q):
+    def _chunk_read(self, params, pos0):
+        def read(i, q, pools, tables):
             return self.block.attend_chunk(
                 params, i, q, [pool[i] for pool in pools],
                 tables[self._group[i]], pos0)
         return read
 
-    def _step_read(self, params, pools, pt, pos):
-        tables = self._tables(pt)
-
-        def read(i, q):
+    def _step_read(self, params, pos):
+        def read(i, q, pools, tables):
             return self.block.attend_step(
                 params, i, q, [pool[i] for pool in pools],
                 tables[self._group[i]], pos + 1)
@@ -849,18 +1009,18 @@ class DecodeEngine(object):
             # ``step`` takes them; all-trash page tables carry none),
             # then the chunk's.  Rows never mix in a layer, so each
             # group comes out as its own program would give it
-            pools = [list(pool) for pool in args[:n]]
             tokens, pt, pos0, n_valid, step_tokens, step_pt, ctx_len = \
                 args[n:]
             spos, spage, soffset = self._step_rows(step_pt, ctx_len)
             pos, valid, page_idx, offset = self._chunk_rows(
                 bucket, pt, pos0, n_valid)
-            read_step = self._step_read(params, pools, step_pt, spos)
-            read_chunk = self._chunk_read(params, pools, pt, pos0)
+            read_step = self._step_read(params, spos)
+            read_chunk = self._chunk_read(params, pos0)
+            step_tables, tables = self._tables(step_pt), self._tables(pt)
 
-            def read(i, q):
-                step_rows = read_step(i, q[:S])
-                rows = read_chunk(i, q[S:])
+            def read(i, q, pools, step_tables, tables):
+                step_rows = read_step(i, q[:S], pools, step_tables)
+                rows = read_chunk(i, q[S:], pools, tables)
                 if self.ring_pages:
                     # a padded row reads positions nobody wrote, in a
                     # ring whatever the page's last holder left: it
@@ -871,17 +1031,20 @@ class DecodeEngine(object):
 
             # a last chunk's padded rows point past the prompt: ``embed``
             # (which may index a position table) gets them inside max_seq
-            x, extra = self._layers(
+            # (``pages`` before ``attend``: the order the operations
+            # have always had in the program)
+            x, pools, _kept, extra = self._layers(
                 params,
                 blk.embed(params, jnp.concatenate([step_tokens, tokens]),
                           jnp.concatenate(
                               [spos, jnp.clip(pos, 0, self.max_seq - 1)])),
                 jnp.concatenate([spos, pos]),
                 jnp.concatenate([step_pt[:, 0] != trash, valid]),
-                self._write_then(pools,
-                                 [jnp.concatenate(both) for both
-                                  in zip(spage, page_idx)],
-                                 jnp.concatenate([soffset, offset]), read))
+                pages=([jnp.concatenate(both) for both
+                        in zip(spage, page_idx)], step_tables, tables),
+                attend=self._write_then(
+                    jnp.concatenate([soffset, offset]), read),
+                pools=args[:n], decoding=S)
             # the head on the decode rows and the chunk's last valid row
             last = S + jnp.clip(n_valid - 1, 0, bucket - 1)
             logits = blk.head(params,
@@ -896,16 +1059,15 @@ class DecodeEngine(object):
         n = len(self.cache.rows)
 
         def step(params, *args):
-            pools = [list(pool) for pool in args[:n]]
             tokens, pt, ctx_len = args[n:]
             pos, page_idx, offset = self._step_rows(pt, ctx_len)
             # an inactive slot's page table is all trash: it runs (its
             # rows never meet another slot's) and is not counted
-            x, extra = self._layers(
+            x, pools, _kept, extra = self._layers(
                 params, blk.embed(params, tokens, pos), pos,
                 pt[:, 0] != trash,
-                self._write_then(pools, page_idx, offset,
-                                 self._step_read(params, pools, pt, pos)))
+                self._write_then(offset, self._step_read(params, pos)),
+                pools=args[:n], pages=(page_idx, self._tables(pt)))
             logits = blk.head(params, x)
             nxt = jnp.argmax(logits, axis=-1)
             return tuple(pools) + (logits, nxt) \
@@ -927,16 +1089,19 @@ class DecodeEngine(object):
 
         def pack(*args):
             # scatter the rows the prefill kept into the claimed pages:
-            # [L, T, ...] -> [L, n_pages, P, width], layer i written at
-            # its group's ``pages`` of its own buffer (padded entries,
-            # and in a ring the pages behind the newest, point at the
-            # trash page)
+            # [slots, T, ...] -> [slots, n_pages, P, width], slot (t, i)
+            # written at its group's ``pages``, where recurrence t finds
+            # them, of layer i's own buffer (padded entries, and in a
+            # ring the pages behind the newest, point at the trash page)
             pools, kept, pages = args[:n], args[n:2 * n], args[2 * n:]
-            out = []
-            for row_pools, rows in zip(pools, kept):
-                paged = rows.reshape(L, n_pages, P, -1)
-                out.append([pool.at[pages[self._group[i]]].set(paged[i])
-                            for i, pool in enumerate(row_pools)])
+            out = [list(row_pools) for row_pools in pools]
+            for row_pools, rows in zip(out, kept):
+                paged = rows.reshape(self.cache.slots, n_pages, P, -1)
+                for t in range(self.ut_steps):
+                    at = self.cache.shift(pages, t)
+                    for i, pool in enumerate(row_pools):
+                        row_pools[i] = pool.at[at[self._group[i]]].set(
+                            paged[t * L + i])
             return tuple(out)
 
         toks = jnp.zeros((bucket,), jnp.int32)
@@ -1081,7 +1246,8 @@ class DecodeEngine(object):
                 self.params, toks, np.int32(t - 1))
             self._pools_out(self._pack[bucket](
                 *self.cache.pools, *rest[:n], *page_ids))
-            return self._fetch((logits,), rest[n:], args)[0]
+            return self._fetch((logits,), rest[n:], args,
+                               decoded=False)[0]
 
     def chunk_spans(self, prompt_len, start=0):
         """The grid-aligned chunk decomposition of positions
@@ -1147,7 +1313,8 @@ class DecodeEngine(object):
             with _obs.span('decode.prefill_chunk.fetch'):
                 if step_tokens is None:
                     # no decode rows ran: what is held stays as it is
-                    return self._fetch((logits,), extra, args)[0]
+                    return self._fetch((logits,), extra, args,
+                                       decoded=False)[0]
                 args['step_rows'] = self._kv_pages(page_tables, ctx_lens,
                                                    args)
                 logits, nxt = self._fetch((logits, nxt), extra, args)
@@ -1428,7 +1595,7 @@ class DecodeServer(object):
                 # trie-held subset an eviction sweep could reclaim
                 'prefix_cached_bytes': prefix_cached_bytes(
                     cached, eng.page_size, dtype=eng.cache.dtype,
-                    n_layers=eng.n_layers,
+                    n_layers=eng.cache.slots,
                     row_widths=eng.cache.row_widths()),
                 'submitted': self._submitted,
                 'completed': self._completed,
@@ -1474,6 +1641,9 @@ class DecodeServer(object):
                 'step_calls': self.engine.calls['step_calls'],
                 'step_host_operands':
                     self.engine.calls['step_host_operands'],
+                # where the block runs its layers several times a token:
+                # the passes over a weight layer those calls ran
+                'loop_passes': self.engine.loop_passes,
             }
 
     # -- worker side ---------------------------------------------------

@@ -272,3 +272,48 @@ def test_span_ids_parents_and_threads(tmp_path):
         doc_args = {e['name']: e['args']
                     for e in json.load(f)['traceEvents'] if e['ph'] == 'X'}
     assert doc_args['t.inner']['parent'] == evs['t.outer']['id']
+
+
+LOOP_ARGS = ('ut_steps', 'loop_passes', 'kv_loop_live_positions',
+             'loop_exit_mass')
+LOOP_COMPILE_ARGS = ('ut_steps', 'cache_slots', 'weight_layers',
+                     'cache_bytes_per_position')
+
+
+@pytest.mark.parametrize('ut_steps', [None, 1, 3])
+def test_a_loops_spans_say_what_it_ran(params, ut_steps):
+    """``decode.step``, a carrying ``decode.prefill_chunk`` and
+    ``decode.compile`` of a block that runs its layers ``ut_steps`` times;
+    a block that runs them once (None: ``OptBlock``) says none of it."""
+    import test_ouro_decode as ouro     # its tiny engine: D, H, PAGE as here
+    eng = make_engine(params, prefill_chunk_tokens=PAGE) \
+        if ut_steps is None else ouro.engine(ut_steps, L, chunk=PAGE)
+    eng.warmup()
+    rng = np.random.default_rng(0)
+    serve(eng, [rng.integers(1, ouro.V, n) for n in (5, 20, 9)], 6)
+    evs = spans()
+    steps = [e['args'] for e in evs if e['name'] == 'decode.step']
+    chunks = [e['args'] for e in evs if e['name'] == 'decode.prefill_chunk']
+    compiles = [e['args'] for e in evs if e['name'] == 'decode.compile']
+    carrying = [a for a in chunks if a['step_rows']]
+    assert steps and carrying and compiles
+    alone = [a for a in chunks if not a['step_rows']]
+    if ut_steps is None:
+        for a in steps + chunks:
+            assert not set(LOOP_ARGS) & set(a)
+        for a in compiles:
+            assert not set(LOOP_COMPILE_ARGS) & set(a)
+        return
+    for a in steps + carrying:
+        assert a['ut_steps'] == ut_steps
+        assert a['loop_passes'] == ut_steps * L
+        assert a['kv_loop_live_positions'] > 0 \
+            and a['kv_loop_live_positions'] % ut_steps == 0
+        assert len(a['loop_exit_mass']) == ut_steps
+        assert abs(sum(a['loop_exit_mass']) - 1.0) < 1e-5
+    for a in alone:     # no decode rows ran: nothing left the loop
+        assert not set(LOOP_ARGS) & set(a)
+    for a in compiles:
+        assert a['ut_steps'] == ut_steps and a['weight_layers'] == L
+        assert a['cache_slots'] == ut_steps * L
+        assert a['cache_bytes_per_position'] == ut_steps * L * 2 * D * 4
