@@ -92,7 +92,7 @@ from .. import observability as _obs
 from ..analysis import lockdebug as _lkd
 from ..compile_cache import enable_compile_cache
 from ..transpiler.memory_model import page_pool_bytes
-from .blocks import OptBlock
+from .blocks import KVBlock, OptBlock
 
 __all__ = ['DecodeEngine', 'DecodeServer', 'DecodeStream',
            'extract_params', 'decode_buckets', 'PrefixCache',
@@ -1311,6 +1311,7 @@ class DecodeEngine(object):
                         self.params, *self.cache.pools, toks, pt,
                         np.int32(pos0), np.int32(c), *carried))
             with _obs.span('decode.prefill_chunk.fetch'):
+                self._attn_blocks(pos0, bucket, args)
                 if step_tokens is None:
                     # no decode rows ran: what is held stays as it is
                     return self._fetch((logits,), extra, args,
@@ -1353,6 +1354,52 @@ class DecodeEngine(object):
 
     def resident_bytes(self):
         return self.cache.resident_bytes()
+
+    # -- blocks of pages the chunk rows' kernel walks ---------------------
+    # (state and code down here, below every function a program is traced
+    # from: their line numbers are part of what a compiled kernel is keyed
+    # by, and the other blocks' programs keep theirs)
+
+    attn_blocks = 0             # blocks walked, over the engine's life
+    attn_whole_blocks = 0       # those of them every row saw whole
+    _chunk_kernel_layers = None     # built by the first chunk
+
+    def _attn_blocks(self, pos0, rows, span_args):
+        """Where some layers' chunk rows take the live-pages kernel
+        (``chunk_attention_path``, as the op's dispatch asks it): the
+        blocks of pages it walks for ``rows`` rows from ``pos0`` and
+        those of them that every row of their pass sees whole (no mask
+        can bite there; the kernel masks them all the same), summed
+        over these layers by ``chunk_blocks`` (host integers, the
+        kernel's own arithmetic) -> the span's ``attn_blocks``,
+        ``attn_whole_blocks`` and the engine's totals.  Where they
+        gather, nothing."""
+        layers = self._chunk_kernel_layers
+        if layers is None:
+            # {(window, table pages): layers that take the kernel}
+            from ..ops.attention import chunk_attention_path
+            blk, layers = self.block, {}
+            for i, g in enumerate(self._group if isinstance(blk, KVBlock)
+                                  else ()):
+                pages = (self.pages_per_stream, self.ring_pages)[g]
+                if chunk_attention_path(
+                        jax.default_backend(), blk.n_kv_heads,
+                        blk.head_dim(self.sizes), self.page_size,
+                        self.cache.dtype, blk.n_heads or blk.heads[i],
+                        pages) == 'pallas_paged':
+                    kind = (blk.window_of(i), pages)
+                    layers[kind] = layers.get(kind, 0) + 1
+            self._chunk_kernel_layers = layers
+        if not layers:
+            return
+        from ..ops.pallas.paged_attention import chunk_blocks
+        blocks = whole = 0
+        for (window, pages), n in layers.items():
+            b, w = chunk_blocks(pos0, rows, window, self.page_size, pages)
+            blocks, whole = blocks + n * b, whole + n * w
+        span_args.update(attn_blocks=blocks, attn_whole_blocks=whole)
+        self.attn_blocks += blocks
+        self.attn_whole_blocks += whole
 
 
 class _DecodeMetrics(object):
@@ -1644,6 +1691,10 @@ class DecodeServer(object):
                 # where the block runs its layers several times a token:
                 # the passes over a weight layer those calls ran
                 'loop_passes': self.engine.loop_passes,
+                # where chunk rows take the live-pages kernel: the
+                # blocks it walked, and those every row saw whole
+                'attn_blocks': self.engine.attn_blocks,
+                'attn_whole_blocks': self.engine.attn_whole_blocks,
             }
 
     # -- worker side ---------------------------------------------------

@@ -1,36 +1,35 @@
-"""Paged decode attention as a Pallas TPU kernel: live pages only.
+"""Paged attention as Pallas TPU kernels: live pages only.
 
 ``ops/attention.py`` ``paged_attention_math`` gathers ``MPP * P``
 positions for every slot whatever its context is, widens them to f32
 and re-lays them out for XLA's multiply-reduce: three passes over
-``S * max_seq`` rows of HBM a layer.  This kernel reads each slot's
-``ceil(ctx_len / P)`` live pages straight from the pool as the decode
-engine holds it, ``[N, P, H*D]``, and nothing else: the pools stay in
-HBM, the page table and the context lengths are scalar-prefetched, and
-the kernel copies blocks of pages to VMEM itself (one DMA a page, the
-next block in flight while this one is multiplied).
+``S * max_seq`` rows of HBM a layer.  The decode kernel reads each
+slot's ``ceil(ctx_len / P)`` live pages straight from the pool as the
+engine holds it, ``[N, P, H*D]``: the pools stay in HBM, the page table
+and the context lengths are scalar-prefetched, and the kernel copies
+blocks of pages to VMEM itself (one DMA a page, the next block in
+flight while this one is multiplied).  One grid step is one slot.  A
+block is ``[T, H*D]`` with every head's keys side by side on the lanes,
+so all heads go through the MXU at once against a block-diagonal query
+``[H, H*D]`` (row h holds q_h on head h's lanes): scores ``[H, T]``,
+f32 online softmax over blocks, ``p @ V`` ``[H, H*D]`` whose diagonal
+is picked once, at the end.  The mask is lane arithmetic, so a head may
+be any width (OPT's 64); ``supported`` asks for a ROW ``H * D`` of
+whole 128-lane registers and pages of whole sublane tiles.
 
-One grid step is one slot.  A block of pages is ``[T, H*D]`` in VMEM
-with every head's keys side by side on the lanes, so all heads go
-through the MXU at once against a block-diagonal query ``[H, H*D]``
-(row h holds q_h on head h's lanes, zeros elsewhere): scores
-``[H, T]``, f32 online softmax over blocks, and ``p @ V`` gives
-``[H, H*D]`` whose h-th row is head h's output on head h's lanes.  The
-diagonal is picked once, at the end.  The mask is lane arithmetic, so a
-head may be any width (OPT's 64: two to a register); the DMAs and tiles
-need a ROW ``H * D`` of whole 128-lane registers and a page of whole
-sublane tiles, which ``supported`` tests; else the op runs the math.
-
-Same mathematics as ``paged_attention_math``: every live position of
-every head, f32 scores, softmax and accumulation, K and V as stored;
-positions ``>= ctx_len`` have probability ``exp(-1e30 - m) == 0`` there
-and are left out here.  The products take the pool's dtype as input
-(bf16 pools: bf16 inputs, f32 accumulation; f32 pools: f32 at
-``highest``).  A slot with ``ctx_len == 0`` reads nothing and returns
-zeros (the math averages whatever its table points at).
+Same mathematics as the math: every live position of every head, f32
+scores, softmax and accumulation, K and V as stored (bf16 pools: bf16
+inputs to the products, f32 accumulation; f32 pools: ``highest``);
+positions ``>= ctx_len`` are left out, and a slot with ``ctx_len == 0``
+reads nothing and returns zeros.  A prompt chunk's rows walk the same
+pages 128 tokens a pass (``chunk_paged_attention``; what a block of
+it costs, and what was measured of it, stands over ``_CHUNK_TOKENS``);
+a shared latent row has its own kernel (``latent_paged_attention``).
 """
 import functools
 import math
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +37,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ['paged_attention', 'supported', 'chunk_paged_attention',
-           'chunk_supported', 'latent_paged_attention', 'latent_supported']
+           'chunk_supported', 'chunk_blocks', 'latent_paged_attention',
+           'latent_supported']
 
 _NEG_INF = -1e30
 # positions a block: 8 pages of 16.  Measured on a v5e, one layer of 32
@@ -275,14 +275,19 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
 # -- a prompt chunk's rows over the stream's live pages --------------------
 
 # tokens of a chunk that share one pass over the stream's pages, and
-# the positions a block of that pass holds.  Every pass copies the whole
-# live context again, one DMA a page: at 32 tokens a pass a chunk of
-# 512 over 16 k positions under 48 heads took 2.8 ms a layer on a v5e,
-# 5.5 us a block of a pass whose products need 2 (my chip run, PR 51),
-# so a chunk tick's cost rose by half between a short and a long
-# context; at 128 a pass, a quarter of the copies, it takes 2.5 ms: what
-# is left are the products and the softmax's vector work, the same
-# whatever the pass (206 GFLOP a layer are 1.05 ms at the peak)
+# the positions a block of that pass holds.  Every pass copies the live
+# context again, a DMA a page: 128 tokens a pass are a quarter of the
+# copies of 32 (2.8 -> 2.5 ms a layer at 16 k, PR 51).  What a block
+# costs (my chip runs, PR 57: 512 rows, 48 heads over 8, 16 k, bf16, the
+# op with its re-layouts): 2.70 ms a layer; 2.56 with the copies off, so
+# it computes; the two products alone 1.35 (206 GFLOP: 1.05 at the peak),
+# with the scores' softmax and no ``p @ V`` 1.63: products and softmax
+# add up.  The mask is NOT the softmax's cost: a body without it on the
+# 31 blocks of 32 that every row sees whole (22.9 k bundles for 27.6 k,
+# ~6.5 vector operations a score for ~10) took 2.69 ms and 7 s more of
+# every set-up to trace: not kept.  Blocks of 128 / 256 / 384: 4.12 /
+# 2.78 / 3.47.  Under a window ONE block for all a pass sees: 0.277 ms
+# for 0.395 (``_chunk_tiles``).
 _CHUNK_TOKENS = 128
 _CHUNK_BLOCK_POSITIONS = 512
 
@@ -303,11 +308,9 @@ def _chunk_kernel(pt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     tok0 = pos_ref[0] + i * tokens   # the position of the first token
     ctx = tok0 + tokens              # what the LAST token reads, itself too
     # the pages between the first token's oldest position and the last
-    # token's own
-    first = 0 if window is None else \
-        jnp.maximum(tok0 + 1 - window, 0) // page
-    n_pages = pl.cdiv(ctx, page) - first
-    n_blocks = pl.cdiv(n_pages, ppb)
+    # token's own, in blocks (the host counts them by the same function)
+    first, n_pages, n_blocks = _chunk_pass(
+        jnp, tok0, tokens, window, page, ppb)
     rows = tokens * group
     t = ppb * page
 
@@ -398,8 +401,7 @@ def _chunk_kernel(pt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
                                              'interpret'))
 def chunk_paged_attention(q, k_pool, v_pool, page_table, pos0, scale=None,
                           window=None, tokens=_CHUNK_TOKENS,
-                          block_positions=_CHUNK_BLOCK_POSITIONS,
-                          interpret=False):
+                          block_positions=None, interpret=False):
     """``chunked_prefill_attention_math``'s signature and result from
     the stream's live pages alone: ``q`` [C, H, D], query j at absolute
     position ``pos0 + j``; pools [N, P, Hkv*D]; ``page_table`` [MPP]
@@ -423,9 +425,8 @@ def chunk_paged_attention(q, k_pool, v_pool, page_table, pos0, scale=None,
     if scale is None:
         scale = float(d) ** -0.5
     dtype = k_pool.dtype
-    tokens = math.gcd(c, tokens)
+    tokens, ppb = _chunk_tiles(c, tokens, window, block_positions, page, mpp)
     passes, rows = c // tokens, tokens * group
-    ppb = max(1, min(mpp, block_positions // page))
     kernel = functools.partial(
         _chunk_kernel, scale=scale, page=page, ppb=ppb, mpp=mpp,
         head_dim=d, kv_heads=kv_heads, group=group, tokens=tokens,
@@ -464,7 +465,6 @@ def chunk_paged_attention(q, k_pool, v_pool, page_table, pos0, scale=None,
       qg, k_pool.reshape(n, page, hd), v_pool.reshape(n, page, hd))
     return out.reshape(passes, kv_heads, tokens, group, d).transpose(
         0, 2, 1, 3, 4).reshape(c, h, d).astype(q.dtype)
-
 
 # -- a shared latent row (multi-head latent attention) ---------------------
 
@@ -634,3 +634,58 @@ def latent_paged_attention(q, pool, page_table, ctx_len, scale, value_dim,
       jnp.clip(ctx_len.astype(jnp.int32), 0, mpp * page),
       q.astype(dtype).reshape(groups, rows, w), pool)
     return out.reshape(n_tok, heads, value_dim)
+
+
+# -- the chunk kernel's blocks, in the kernel and counted on the host (down
+# here so that the latent kernel's lines stay where the compiled modules
+# of the programs that hold it have them) ---------------------------------
+
+def _chunk_tiles(c, tokens, window, block_positions, page, mpp):
+    """(tokens a pass, pages a block) of a chunk of ``c`` rows.  Where
+    no block size is given: ``_CHUNK_BLOCK_POSITIONS``, or under a
+    window ONE block, in whole lane tiles, for all a pass can see
+    (``window + tokens`` positions) unless that is over two such.
+    Measured (my chip run, PR 57: 512 rows, 72 heads over 8, window
+    512, bf16, the op with its re-layouts): two blocks of 512 as
+    before 0.395 ms a layer, one of 640 0.277; split so that some
+    blocks are whole, in 128 / 256 / 384: 0.417 / 0.386 / 0.545 (a
+    block's fixed cost, the row reductions and the softmax state's
+    columns, outweighs the masks it saves)."""
+    tokens = math.gcd(c, tokens)
+    if block_positions is None:
+        block_positions = _CHUNK_BLOCK_POSITIONS
+        if window is not None and window + tokens <= 2 * block_positions:
+            block_positions = -(-(window + tokens) // 128) * 128
+    return tokens, max(1, min(mpp, block_positions // page))
+
+
+def _chunk_pass(xp, tok0, tokens, window, page, ppb):
+    """One pass of ``tokens`` rows from position ``tok0`` over blocks of
+    ``ppb`` pages -> (first page, pages, blocks): the pages from the
+    first row's oldest position to the last row's own.  ``xp`` is
+    ``jnp`` in the kernel and ``numpy`` for the host's count: one
+    arithmetic."""
+    first = 0 if window is None else \
+        xp.maximum(tok0 + 1 - window, 0) // page
+    n_pages = (tok0 + tokens + page - 1) // page - first
+    return first, n_pages, (n_pages + ppb - 1) // ppb
+
+
+def chunk_blocks(pos0, rows, window, page, table_pages,
+                 tokens=_CHUNK_TOKENS, block_positions=None):
+    """(blocks, whole blocks) ``chunk_paged_attention`` walks for a
+    chunk of ``rows`` rows from position ``pos0``, summed over its
+    passes, as host integers.  A block is WHOLE when every row of its
+    pass sees every position of it, so that no mask can bite: it ends
+    no later than the first row's own position and, under a window,
+    begins no earlier than the last row's oldest.  (The kernel masks
+    such a block like any other: see ``_CHUNK_TOKENS``.)"""
+    tokens, ppb = _chunk_tiles(rows, tokens, window, block_positions,
+                               page, table_pages)
+    t = ppb * page
+    tok0 = int(pos0) + tokens * np.arange(rows // tokens)
+    first, _, n_blocks = _chunk_pass(np, tok0, tokens, window, page, ppb)
+    hi = (tok0 + 1 - first * page) // t
+    lo = 0 if window is None else np.minimum(
+        -(-np.maximum(tok0 + tokens - window - first * page, 0) // t), hi)
+    return int(np.sum(n_blocks)), int(np.sum(hi - lo))

@@ -317,3 +317,73 @@ def test_a_loops_spans_say_what_it_ran(params, ut_steps):
         assert a['ut_steps'] == ut_steps and a['weight_layers'] == L
         assert a['cache_slots'] == ut_steps * L
         assert a['cache_bytes_per_position'] == ut_steps * L * 2 * D * 4
+
+
+def test_a_chunks_span_counts_the_kernels_blocks(monkeypatch):
+    """``attn_blocks`` / ``attn_whole_blocks`` on ``decode.prefill_chunk``
+    and in ``stats()`` where the chunk rows take the live-pages kernel
+    (the path's answer and the kernel's interpreter put in, as
+    ``test_the_chunk_op_takes_the_kernel_where_the_path_says`` does),
+    summed over the layers by the kernel's own arithmetic (whole: a
+    block that every row of its pass sees entirely); where they gather,
+    neither is there."""
+    import sys
+    import test_laguna_decode as lag    # its tiny engine: two layer kinds
+    from paddle_tpu.inference import blocks
+    from paddle_tpu.ops import attention
+    from paddle_tpu.ops.pallas.paged_attention import (
+        chunk_blocks, chunk_paged_attention)
+    params = lag.make_params(0)
+    prompts = [np.random.default_rng(n).integers(1, lag.V, n)
+               for n in (5, 41, 19)]
+
+    def run():
+        timeline.reset()
+        server = DecodeServer(lag.make_engine(
+            params, top=64, prefill_chunk_tokens=2 * lag.PAGE),
+            warmup=False)
+        try:
+            streams = [server.submit(p, max_new_tokens=4) for p in prompts]
+            tokens = [list(st.result(timeout=300.0)) for st in streams]
+            return server.engine, tokens, server.stats(), [
+                e['args'] for e in spans()
+                if e['name'] == 'decode.prefill_chunk']
+        finally:
+            server.close()
+
+    _eng, gathered, stats, chunks = run()
+    assert chunks and stats['attn_blocks'] == stats['attn_whole_blocks'] == 0
+    for a in chunks:
+        assert 'attn_blocks' not in a and 'attn_whole_blocks' not in a
+
+    kernels = sys.modules['paddle_tpu.ops.pallas.paged_attention']
+    for mod in (blocks, attention):
+        monkeypatch.setattr(mod, 'chunk_attention_path',
+                            lambda *a: 'pallas_paged')
+    monkeypatch.setattr(
+        kernels, 'chunk_paged_attention',
+        lambda *a, **kw: chunk_paged_attention(*a, interpret=True, **kw))
+    # blocks of two pages, so that a prompt of 41 has some behind it
+    monkeypatch.setattr(kernels, '_CHUNK_BLOCK_POSITIONS', 2 * lag.PAGE)
+    eng, tokens, stats, chunks = run()
+    assert tokens == gathered
+    compiled = [e['args'] for e in spans() if e['name'] == 'decode.compile'
+                and e['args']['program'] == 'chunk']
+    assert compiled and all(
+        a['attention'] == {'full': 'xla_gather+pallas_paged',
+                           'window': 'xla_gather+pallas_paged'}
+        for a in compiled)
+    for e in spans():
+        if e['name'] in ('decode.step', 'decode.prefill_into'):
+            assert 'attn_blocks' not in e['args']
+    for a in chunks:
+        assert 0 <= a['attn_whole_blocks'] < a['attn_blocks']
+    assert stats['attn_blocks'] == sum(a['attn_blocks'] for a in chunks)
+    assert stats['attn_whole_blocks'] == sum(
+        a['attn_whole_blocks'] for a in chunks) > 0
+    # one chunk by hand: 8 rows from position 32, two layers of a kind
+    full = chunk_blocks(32, 8, None, lag.PAGE, eng.pages_per_stream)
+    ring = chunk_blocks(32, 8, lag.WINDOW, lag.PAGE, eng.ring_pages)
+    assert full == (5, 4)
+    assert (2 * (full[0] + ring[0]), 2 * (full[1] + ring[1])) in [
+        (a['attn_blocks'], a['attn_whole_blocks']) for a in chunks]

@@ -6,6 +6,7 @@ to Mosaic on TPU (tests/test_tpu_lowering.py compiles it at the decode
 engine's shapes).
 """
 import functools
+import math
 from types import SimpleNamespace
 
 import numpy as np
@@ -211,7 +212,7 @@ def test_dispatch_takes_the_kernel_on_a_tpu(monkeypatch, head_dim, dtype,
 from paddle_tpu.ops.attention import (chunk_attention_path,  # noqa: E402
                                       chunked_prefill_attention_math)
 from paddle_tpu.ops.pallas.paged_attention import (  # noqa: E402
-    chunk_paged_attention, chunk_supported)
+    chunk_blocks, chunk_paged_attention, chunk_supported)
 
 HKV, DG = 2, 32
 # a window, the chunk rows that go with it, and the ring that holds both
@@ -302,6 +303,105 @@ def test_a_window_leaves_the_pages_behind_it_unread():
     assert np.all(np.isfinite(got)) and np.all(np.isfinite(want))
     close(got, np.asarray(paged_attention_math(
         q[:1], k, v, pt[None], step, window=5)), jnp.float32)
+
+
+# -- the chunk kernel's blocks: those that every row of a pass sees whole
+# (no mask can bite there), those an edge of the mask crosses, and what
+# ``chunk_blocks`` counts of both ---------------------------------------------
+
+# passes of 4 tokens over blocks of 2 pages (32 positions), chunks of 8
+# rows: (window, pos0, whole blocks of the chunk's two passes)
+TILE = {'tokens': 4, 'block_positions': 2 * P}
+SPLITS = {
+    'first_chunk': (None, 0, 0),            # every block holds the rows
+    # the first pass's ``tok0 + 1`` one position before a block's edge,
+    # on it, one past it (the second pass's is 4 further on)
+    'before_the_edge': (None, 62, 1 + 2),
+    'on_the_edge': (None, 63, 2 + 2),
+    'past_the_edge': (None, 64, 2 + 2),
+    '33_blocks': (None, 33 * 32 - 8, 32 + 32),
+    # rings that have wrapped: blocks wholly inside every row's window
+    'window_512': (512, 1500, 15 + 15),
+    'window_5': (5, 83, 0),
+}
+
+
+def split_case(name, group, dtype=jnp.float32):
+    window, pos0, whole = SPLITS[name]
+    rng = np.random.RandomState(len(name))
+    mpp = 70 if window is None else -(-(window - 1 + 8) // P) + 1
+    k = jnp.asarray(rng.randn(80, P, HKV * DG), dtype)
+    v = jnp.asarray(rng.randn(80, P, HKV * DG), dtype)
+    q = jnp.asarray(rng.randn(8, HKV * group, DG), jnp.float32)
+    pt = rng.permutation(79)[:mpp]
+    kw = {} if window is None else {'window': window}
+    return q, k, v, pt, pos0, kw, whole, mpp
+
+
+@pytest.mark.parametrize('group', [6, 9])
+@pytest.mark.parametrize('name', sorted(SPLITS))
+def test_chunk_blocks_whole_and_crossed_by_the_mask(name, group):
+    q, k, v, pt, pos0, kw, whole, mpp = split_case(name, group)
+    assert chunk_blocks(pos0, 8, kw.get('window'), P, mpp, **TILE)[1] \
+        == whole
+    pt = jnp.asarray(pt, jnp.int32)
+    want = chunked_prefill_attention_math(q, k, v, pt, jnp.int32(pos0), **kw)
+    # the tile that puts the edges where the case says, and the defaults
+    for tile in (TILE, {}):
+        got = chunk_paged_attention(q, k, v, pt, jnp.int32(pos0),
+                                    interpret=True, **kw, **tile)
+        close(np.asarray(got), np.asarray(want), jnp.float32)
+
+
+@pytest.mark.parametrize('name', ['33_blocks', 'window_512', 'window_5'])
+def test_a_chunk_reads_nothing_outside_its_rows_pages(name):
+    """NaN in every page before the first row's oldest position's and
+    after the last row's own, and in that last page's rows past the last
+    row: the result is finite and the clean pool's, through whole blocks
+    and blocks the mask crosses alike."""
+    q, k, v, pt, pos0, kw, _whole, mpp = split_case(name, 6)
+    window, ctx = kw.get('window'), pos0 + 8
+    lo = 0 if window is None else max(pos0 + 1 - window, 0)
+    live = {int(pt[j % mpp]) for j in range(lo // P, (ctx - 1) // P + 1)}
+    dead = np.array([g for g in range(80) if g not in live])
+    kn, vn = (a.at[dead].set(jnp.nan).at[int(pt[(ctx - 1) // P % mpp]),
+                                         (ctx - 1) % P + 1:].set(jnp.nan)
+              for a in (k, v))
+    pt = jnp.asarray(pt, jnp.int32)
+    for tile in (TILE, {}):
+        clean, got = (np.asarray(chunk_paged_attention(
+            q, kk, vv, pt, jnp.int32(pos0), interpret=True, **kw, **tile))
+            for kk, vv in ((k, v), (kn, vn)))
+        assert np.all(np.isfinite(got))
+        assert np.array_equal(got, clean)
+
+
+@pytest.mark.parametrize('window', [None, 5, 40, 512])
+@pytest.mark.parametrize('block_positions', [P, 2 * P, 128])
+def test_the_count_of_whole_blocks_is_the_masks_own(window, block_positions):
+    """``chunk_blocks`` against the blocks a pass covers and the mask a
+    row applies, written out position by position."""
+    mpp = 70 if window is None else -(-(window - 1 + 24) // P) + 1
+    for rows, tokens in ((8, 4), (24, 8), (16, 128)):
+        for pos0 in list(range(0, 70)) + [127, 128, 500, 1001]:
+            if window is None and pos0 + rows > mpp * P:
+                continue
+            blocks = whole = 0
+            per, t = math.gcd(rows, tokens), \
+                max(1, min(mpp, block_positions // P)) * P
+            for tok0 in range(pos0, pos0 + rows, per):
+                first = 0 if window is None else \
+                    max(tok0 + 1 - window, 0) // P * P
+                row = tok0 + np.arange(per)[:, None]
+                for at in range(first, tok0 + per, t):
+                    pos = at + np.arange(t)[None, :]
+                    live = pos <= row
+                    if window is not None:
+                        live &= pos > row - window
+                    blocks, whole = blocks + 1, whole + bool(live.all())
+            assert chunk_blocks(pos0, rows, window, P, mpp, tokens=tokens,
+                                block_positions=block_positions) \
+                == (blocks, whole), (rows, tokens, pos0)
 
 
 def test_paths_by_the_row_the_group_and_the_table():
