@@ -909,10 +909,10 @@ class DecodeEngine(object):
     # -- the three programs: one loop, three ways to attend -------------
 
     def _write_then(self, offset, read):
-        """``attend`` of chunk and step: the rows layer i caches land at
-        (page, offset) of its own buffers (``at``: the pages, one array
-        a group, as the recurrence finds them), then ``read(i, q, pools,
-        *tables)`` attends over what was written."""
+        """``attend`` of step (a chunk's: ``_pages_then``): a row a slot
+        lands at (page, offset) of layer i's own buffers (``at``: the
+        pages, one array a group, as the recurrence finds them), then
+        ``read(i, q, pools, *tables)`` attends over what was written."""
         def attend(i, q, rows, pools, at, *tables):
             for pool, r in zip(pools, rows):
                 pool[i] = pool[i].at[at[self._group[i]], offset].set(r)
@@ -944,22 +944,22 @@ class DecodeEngine(object):
 
     def _chunk_rows(self, bucket, pt, pos0, n_valid):
         """A chunk's rows: positions, which of them hold a prompt token,
-        and where their cached rows land."""
+        and the pages they are cached in, an id a PAGE (``_pages_then``)."""
         P, mpp = self.page_size, self.pages_per_stream
-        # pos0 and n_valid are traced (host slicing would hide
-        # per-shape gather compiles, the prefill lesson); padded
-        # rows (i >= n_valid) write to the trash page, are not
-        # counted, and their outputs never leave the executable
+        # pos0 and n_valid are traced (host slicing would hide per-shape
+        # gather compiles, the prefill lesson); a page that holds no
+        # prompt token is written to the trash page
         pos = pos0 + jnp.arange(bucket)
         valid = jnp.arange(bucket) < n_valid
+        j = jnp.arange(bucket // P)
+        page, live = pos0 // P + j, j * P < n_valid
         tables = self._tables(pt)
-        page_idx = tables[0][jnp.clip(pos // P, 0, mpp - 1)]
-        page_idx = [jnp.where(valid, page_idx, self.cache.trash)]
+        page_ids = [jnp.where(live, tables[0][jnp.clip(page, 0, mpp - 1)],
+                              self.cache.trash)]
         if self.ring_pages:
-            page_idx.append(jnp.where(
-                valid, tables[1][(pos // P) % self.ring_pages],
-                self.cache.window.trash))
-        return pos, valid, page_idx, pos % P
+            page_ids.append(jnp.where(live, tables[1][page % self.ring_pages],
+                                      self.cache.window.trash))
+        return pos, valid, page_ids
 
     def _step_rows(self, pt, ctx_len):
         """A decode step's rows, one a slot: positions and where their
@@ -1012,7 +1012,7 @@ class DecodeEngine(object):
             tokens, pt, pos0, n_valid, step_tokens, step_pt, ctx_len = \
                 args[n:]
             spos, spage, soffset = self._step_rows(step_pt, ctx_len)
-            pos, valid, page_idx, offset = self._chunk_rows(
+            pos, valid, page_ids = self._chunk_rows(
                 bucket, pt, pos0, n_valid)
             read_step = self._step_read(params, spos)
             read_chunk = self._chunk_read(params, pos0)
@@ -1031,8 +1031,10 @@ class DecodeEngine(object):
 
             # a last chunk's padded rows point past the prompt: ``embed``
             # (which may index a position table) gets them inside max_seq
-            # (``pages`` before ``attend``: the order the operations
-            # have always had in the program)
+            # (the decode rows are cached a row at a time, as ``step``
+            # caches them, and the chunk's a page at a time: each group
+            # of rows has page ids of its own, which a recurrence
+            # shifts alike)
             x, pools, _kept, extra = self._layers(
                 params,
                 blk.embed(params, jnp.concatenate([step_tokens, tokens]),
@@ -1040,10 +1042,8 @@ class DecodeEngine(object):
                               [spos, jnp.clip(pos, 0, self.max_seq - 1)])),
                 jnp.concatenate([spos, pos]),
                 jnp.concatenate([step_pt[:, 0] != trash, valid]),
-                pages=([jnp.concatenate(both) for both
-                        in zip(spage, page_idx)], step_tables, tables),
-                attend=self._write_then(
-                    jnp.concatenate([soffset, offset]), read),
+                pages=(spage, page_ids, step_tables, tables),
+                attend=self._pages_then(S, self._write_then(soffset, read)),
                 pools=args[:n], decoding=S)
             # the head on the decode rows and the chunk's last valid row
             last = S + jnp.clip(n_valid - 1, 0, bucket - 1)
@@ -1118,10 +1118,10 @@ class DecodeEngine(object):
     def _ensure_chunk(self, bucket):
         """Chunked-prefill executable for one chunk bucket: a SINGLE
         stream's prompt chunk of up to ``bucket`` tokens at absolute
-        positions pos0.., scattered into the stream's pages and
-        attending over chunks 0..N via the page table (the KV-carry is
-        the donated pool itself — the run_steps carry pattern at pool
-        granularity), and in the same pass the decode rows of one
+        positions pos0.. (on the page grid), written a page at a time
+        into the stream's pages (``_pages_then``) and attending over
+        chunks 0..N via the page table (the KV-carry is the donated
+        pool itself), and in the same pass the decode rows of one
         ``step`` (tokens [S], page tables [S, MPP], context lengths
         [S]; all-trash page tables carry none), each attending over its
         own pages as in ``step``: one read of the weights for both.
@@ -1273,7 +1273,11 @@ class DecodeEngine(object):
         (c <= chunk_grid) land at absolute positions pos0..pos0+c-1 in
         the pages named by ``pages`` (the stream's page table; entries
         past it route to trash; with window layers the pair (pages,
-        ring)).  Returns the chunk's last-row logits
+        ring)).  ``pos0`` is a multiple of the page size (``chunk_spans``
+        keeps it on the chunk grid, which is one; anything else is a
+        ``ValueError``): the chunk's rows are then whole pages in
+        order, and are written a page at a time (``_pages_then``).
+        Returns the chunk's last-row logits
         as numpy [V] — only the final chunk's matter (the TTFT
         payload), earlier chunks' are a one-row head by-product.
 
@@ -1283,7 +1287,10 @@ class DecodeEngine(object):
         then returns (last-row logits, next tokens [S] as numpy, the
         decode rows' logits [S, V] left on the device for whoever asks).
         The span's ``tokens`` and ``bucket`` stay the chunk's;
-        ``step_rows`` counts the running slots carried, and
+        ``step_rows`` counts the running slots carried,
+        ``kv_write_pages`` / ``kv_write_rows`` the pages the chunk's
+        rows were cached as and the carried rows cached one at a time
+        (``_kv_writes``), and
         ``fetched_bytes`` what came back to the host: the last row, and
         with rows carried their ids, beside the routing counts; the
         carried three go in as ``step``'s do, and ``host_operands``
@@ -1293,6 +1300,9 @@ class DecodeEngine(object):
         as ``step``'s."""
         tokens = np.asarray(tokens, dtype=np.int32)
         c = int(tokens.shape[0])
+        if pos0 % self.page_size:
+            raise ValueError("chunk start %d off the %d-token page grid"
+                             % (pos0, self.page_size))
         bucket = self.bucket_for(c)
         args = {'tokens': c, 'bucket': bucket, 'step_rows': 0}
         with _obs.span('decode.prefill_chunk', args=args):
@@ -1312,12 +1322,14 @@ class DecodeEngine(object):
                         np.int32(pos0), np.int32(c), *carried))
             with _obs.span('decode.prefill_chunk.fetch'):
                 self._attn_blocks(pos0, bucket, args)
+                if step_tokens is not None:
+                    args['step_rows'] = self._kv_pages(page_tables,
+                                                       ctx_lens, args)
+                self._kv_writes(c, args['step_rows'], args)
                 if step_tokens is None:
                     # no decode rows ran: what is held stays as it is
                     return self._fetch((logits,), extra, args,
                                        decoded=False)[0]
-                args['step_rows'] = self._kv_pages(page_tables, ctx_lens,
-                                                   args)
                 logits, nxt = self._fetch((logits, nxt), extra, args)
                 self._hold(carried, host, nxt, ids, ctx)
                 return logits, nxt, step_logits
@@ -1400,6 +1412,63 @@ class DecodeEngine(object):
         span_args.update(attn_blocks=blocks, attn_whole_blocks=whole)
         self.attn_blocks += blocks
         self.attn_whole_blocks += whole
+
+    # -- a chunk's rows into the pools, a page at a time -----------------
+
+    kv_write_pages = 0          # pages chunks' rows were cached as
+    kv_write_rows = 0           # carried rows cached one at a time
+
+    def _pages_then(self, n, attend):
+        """``attend`` of chunk: of the rows layer i caches, the first
+        ``n`` are the carried decode rows, one a slot, and ``attend``
+        (``_write_then``) caches them a row at a time as ``step`` does
+        and reads; the rows after them are the chunk's.  Those are
+        ``bucket // P`` WHOLE pages in order (the chunk grid and every
+        bucket are page multiples, and ``prefill_chunk`` holds ``pos0``
+        to the page grid), so they are written as ``pack`` writes a
+        monolithic prefill's: ``[bucket // P, P, width]`` at
+        ``page_at`` (``_chunk_rows``: a page id a PAGE, one array a
+        group, as the recurrence finds them): ``bucket // P`` updates a
+        scatter where a row at a time took ``bucket`` (18 scatters of
+        544 rows were 1.31 of a Laguna chunk's 16.4 ms on the chip:
+        PERF.md section 6, PR 58).
+
+        What a page holds then differs from a row at a time in ONE
+        place: a prompt's ragged last page is written whole, so its
+        rows past the prompt hold the padded rows' K/V, which used to go
+        to the trash page.  They are positions >= the stream's context
+        length: every reader leaves them out (the kernels' and the
+        math's ``pos < ctx`` masks), the stream's own decode steps
+        overwrite them in order before any read, and they are finite (a
+        padded row's K/V is token 0's through finite weights; under a
+        window it attends to nothing, ``_chunk_fn``).  In a ring that
+        page's tail held positions ``ring_pages * P`` back, outside
+        every window that can still read (``ring_pages`` covers
+        ``window - 1 + chunk_grid`` and a page).  The prefix trie only
+        ever holds whole valid pages, so nothing shared changes."""
+        P = self.page_size
+
+        def by_page(i, q, rows, pools, at, page_at, *tables):
+            ids = page_at[self._group[i]]
+            for pool, r in zip(pools, rows):
+                pool[i] = pool[i].at[ids].set(
+                    r[n:].reshape(-1, P, r.shape[-1]))
+            return attend(i, q, [r[:n] for r in rows], pools, at, *tables)
+        return by_page
+
+    def _kv_writes(self, tokens, step_rows, span_args):
+        """How a chunk call's rows reached the pools, host integers ->
+        the span's ``kv_write_pages`` (the pages the chunk's ``tokens``
+        were written as) and ``kv_write_rows`` (the ``step_rows``
+        carried rows, written one at a time), each times the cache rows
+        and the cache slots (a layer a recurrence), and the engine's
+        totals."""
+        each = len(self.cache.rows) * self.cache.slots
+        pages = -(-tokens // self.page_size) * each
+        span_args.update(kv_write_pages=pages,
+                         kv_write_rows=step_rows * each)
+        self.kv_write_pages += pages
+        self.kv_write_rows += step_rows * each
 
 
 class _DecodeMetrics(object):
@@ -1695,6 +1764,11 @@ class DecodeServer(object):
                 # blocks it walked, and those every row saw whole
                 'attn_blocks': self.engine.attn_blocks,
                 'attn_whole_blocks': self.engine.attn_whole_blocks,
+                # how chunk calls' rows reached the pools: the pages the
+                # chunks' rows were written as, and the carried decode
+                # rows written one at a time (x cache rows x slots)
+                'kv_write_pages': self.engine.kv_write_pages,
+                'kv_write_rows': self.engine.kv_write_rows,
             }
 
     # -- worker side ---------------------------------------------------

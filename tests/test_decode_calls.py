@@ -9,10 +9,13 @@ logits stay on the device, and nothing of that compiles a program after
 does not already hold (the span's ``host_operands``): the program sees
 bit for bit what the host holds either way.
 """
+import functools
+
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from paddle_tpu.inference.blocks import OptBlock
 from paddle_tpu.inference.decode import (DecodeEngine, DecodeServer,
@@ -166,13 +169,13 @@ def host_operands(ring):
 def rows_of(eng, rows):
     """A step's three arrays for ``rows`` {slot: (token, the stream's
     pages so far, cached positions)}, built anew as a server's tick
-    builds them; every other slot idle."""
+    builds them; every other slot idle.  (The pages as the engine's
+    calls take them: with window layers the pair (pages, ring).)"""
     S = eng.max_streams
     t, c = np.zeros(S, np.int32), np.zeros(S, np.int32)
-    pt = np.full((S, eng.pages_per_stream), eng.cache.trash, np.int32)
+    pt = np.tile(eng.idle_row, (S, 1))
     for slot, (tok, pages, ctx) in rows.items():
-        t[slot], c[slot] = tok, ctx
-        pt[slot, :len(pages)] = pages
+        t[slot], c[slot], pt[slot] = tok, ctx, eng.table_row(pages)
     return t, pt, c
 
 
@@ -454,3 +457,197 @@ def test_a_replay_by_hand_with_int64_ids_reuses_and_meets_the_reference(
     want = reference(params, list(prompt) + toks[:-1])[len(prompt) - 1:]
     for got, w in zip(rows, want):
         assert mod.rel(got, w) < TOL
+
+
+# -- a chunk's rows reach the pools a page at a time ----------------------
+# (the cases here for a K/V block and the latent row; a ring in
+# tests/test_laguna_decode.py, a block that loops in
+# tests/test_ouro_decode.py, through ``chunk_writes_match_row_by_row``)
+
+def write_row_by_row(eng):
+    """``eng`` made to cache a chunk's rows as the tree did before it
+    wrote them by pages, the reference of the cases below: every row of
+    the bucket at (its page, its offset), a padded row at the trash
+    page."""
+    P, by_page = eng.page_size, eng._chunk_rows
+
+    def chunk_rows(bucket, pt, pos0, n_valid):
+        pos, valid, page_ids = by_page(bucket, pt, pos0, n_valid)
+        return pos, valid, [jnp.where(valid, jnp.repeat(ids, P), trash)
+                            for ids, trash in zip(page_ids, eng._trashes())]
+
+    def pages_then(n, attend):
+        def by_row(i, q, rows, pools, at, row_at, *tables):
+            ids = row_at[eng._group[i]]
+            for pool, r in zip(pools, rows):
+                pool[i] = pool[i].at[ids, jnp.arange(len(ids)) % P].set(
+                    r[n:])
+            return attend(i, q, [r[:n] for r in rows], pools, at, *tables)
+        return by_row
+    eng._chunk_rows, eng._pages_then = chunk_rows, pages_then
+    return eng
+
+
+def claim_pages(eng, span):
+    """A stream's pages as the engine's calls take them: with window
+    layers the pair (pages, ring)."""
+    pages = eng.cache.alloc(-(-span // eng.page_size))
+    return (pages, eng.cache.window.alloc(eng.ring_for(span))) \
+        if eng.ring_pages else pages
+
+
+def give_pages_back(eng, pages):
+    if eng.ring_pages:
+        eng.cache.window.free(pages[1])
+        pages = pages[0]
+    eng.cache.free(pages)
+
+
+# the tokens of a prompt's last chunk, past two whole chunks of two pages
+# (P the page size), and whether the chunks carry another stream's rows
+CHUNK_WRITE_CASES = {
+    'whole_chunks': (lambda P: 0, False),
+    'one_token': (lambda P: 1, False),
+    'a_page_less_one': (lambda P: P - 1, False),
+    'a_page_and_one': (lambda P: P + 1, False),
+    'carrying_decode_rows': (lambda P: P + 1, True),
+}
+
+
+def chunk_writes_match_row_by_row(new, old, vocab, case):
+    """One prompt through ``new`` (the tree's engine) and ``old`` (the
+    same engine under ``write_row_by_row``), in chunks of two pages:
+    afterwards every pool of every layer holds bit for bit the same,
+    off the trash pages, but for the rows of the prompt's last page
+    past the prompt, which are finite; 2 x P greedy steps then give the
+    same ids, and overwrite those rows, after which nothing differs."""
+    ragged, carry = CHUNK_WRITE_CASES[case]
+    P, G = new.page_size, new.chunk_grid
+    assert G == 2 * P == old.chunk_grid
+    rng = np.random.default_rng(58)
+    prompt = rng.integers(1, vocab, 2 * G + ragged(P))
+    other = rng.integers(1, vocab, G)      # whole pages: no tail of its own
+    n, runs = len(prompt), []
+    for eng in (new, old):
+        eng.cache.pools = [[jnp.zeros_like(b) for b in pool]
+                           for pool in eng.cache.pools]
+        mine = claim_pages(eng, n + 2 * P + 1)
+        theirs = claim_pages(eng, len(other) + 4)
+        for lo, hi in eng.chunk_spans(len(other)):
+            first = eng.prefill_chunk(other[lo:hi], theirs, lo)
+        tok, ctx = int(np.argmax(first)), len(other)
+        for lo, hi in eng.chunk_spans(n):
+            if carry:
+                last, nxt, _ = eng.prefill_chunk(
+                    prompt[lo:hi], mine, lo,
+                    *rows_of(eng, {0: (tok, theirs, ctx)}))
+                tok, ctx = int(nxt[0]), ctx + 1
+            else:
+                last = eng.prefill_chunk(prompt[lo:hi], mine, lo)
+        after_prompt = [[np.asarray(b) for b in pool]
+                        for pool in eng.cache.pools]
+        ids = [int(np.argmax(last))]
+        for k in range(2 * P):
+            nxt, _ = eng.step(*rows_of(eng, {1: (ids[-1], mine, n + k)}))
+            ids.append(int(nxt[1]))
+        runs.append((mine, after_prompt, ids, [
+            [np.asarray(b) for b in pool] for pool in eng.cache.pools]))
+        give_pages_back(eng, mine)
+        give_pages_back(eng, theirs)
+    (mine, got, ids, got_end), (mine_old, want, ids_old, want_end) = runs
+    assert mine == mine_old                 # the same pages on both sides
+    cache, last_page, tails = new.cache, (n - 1) // P, 0
+    for r in range(len(cache.rows)):
+        for i in range(cache.n_layers):
+            a, b = got[r][i], want[r][i]
+            group, ring = cache.group_of(i), new._group[i]
+            page = mine[1][last_page % new.ring_pages] if ring \
+                else (mine[0] if new.ring_pages else mine)[last_page]
+            same = np.ones(a.shape[:2], bool)
+            for t in range(cache.recurrences):
+                at = t * (group.num_pages + 1)
+                same[at + group.trash] = False
+                if n % P:
+                    same[at + page, n % P:] = False
+                    tail = a[at + page, n % P:]
+                    assert np.isfinite(tail.astype(np.float32)).all()
+                    # written with its page, where a row at a time
+                    # left what was there
+                    assert not np.array_equal(tail, b[at + page, n % P:])
+                    tails += 1
+            assert np.array_equal(a[same], b[same])
+            same[:] = True
+            same[group.trash::group.num_pages + 1] = False
+            assert np.array_equal(got_end[r][i][same], want_end[r][i][same])
+    assert tails == (len(cache.rows) * cache.slots if n % P else 0)
+    assert ids == ids_old and len(ids) == 2 * P + 1
+
+
+def pool_scatters(eng, bucket):
+    """The updates of each scatter onto a page pool in the ``chunk``
+    program of ``bucket``, as its jaxpr has them (a scanned body
+    counted once)."""
+    shapes = {b.shape for pool in eng.cache.pools for b in pool}
+    sizes = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == 'scatter' \
+                    and eqn.invars[0].aval.shape in shapes:
+                sizes.append(eqn.invars[1].aval.shape[0])
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, 'jaxpr', sub)
+                    if hasattr(sub, 'eqns'):
+                        walk(sub)
+    walk(jax.make_jaxpr(eng._chunk_fn(bucket))(
+        eng.params, *eng.cache.pools, jnp.zeros((bucket,), jnp.int32),
+        jnp.asarray(eng.idle_row), jnp.int32(0), jnp.int32(1),
+        *eng._idle_step).jaxpr)
+    return sorted(sizes)
+
+
+def chunk_scatters_a_page_an_update(eng):
+    """Every layer's every cache row: one scatter of ``bucket // P``
+    pages for the chunk's rows and one of S rows for the decode rows."""
+    each = len(eng.cache.rows) * eng.n_layers
+    for bucket in eng.chunk_buckets:
+        n_pages = bucket // eng.page_size
+        assert pool_scatters(eng, bucket) == sorted(
+            each * [n_pages] + each * [eng.max_streams])
+
+
+@functools.lru_cache(maxsize=None)
+def two_page_chunk_engines(model):
+    """(the module, the tree's engine, the same written row by row),
+    chunks of two pages."""
+    mod, make_params, make_block, _ = MODELS[model]
+    new, old = (mod.make_engine(make_params(0), make_block(), top=16,
+                                prefill_chunk_tokens=2 * mod.PAGE)
+                for _ in range(2))
+    return mod, new, write_row_by_row(old)
+
+
+@pytest.mark.parametrize('case', sorted(CHUNK_WRITE_CASES))
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_chunk_rows_written_by_pages_leave_what_row_by_row_left(
+        model, case):
+    mod, new, old = two_page_chunk_engines(model)
+    chunk_writes_match_row_by_row(new, old, mod.V, case)
+
+
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_a_chunk_scatters_pages_for_its_rows_and_rows_for_the_carried(
+        model):
+    chunk_scatters_a_page_an_update(two_page_chunk_engines(model)[1])
+
+
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_a_chunk_off_the_page_grid_is_refused(model):
+    mod, eng, _ = two_page_chunk_engines(model)
+    pages = eng.cache.alloc(2)
+    for pos0 in (1, mod.PAGE - 1, mod.PAGE + 3):
+        with pytest.raises(ValueError, match='page grid'):
+            eng.prefill_chunk(np.arange(1, 4), pages, pos0)
+    eng.prefill_chunk(np.arange(1, 4), pages, mod.PAGE)     # on it: fine
+    eng.cache.free(pages)

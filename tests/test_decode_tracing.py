@@ -298,6 +298,12 @@ def test_a_loops_spans_say_what_it_ran(params, ut_steps):
     carrying = [a for a in chunks if a['step_rows']]
     assert steps and carrying and compiles
     alone = [a for a in chunks if not a['step_rows']]
+    # how the chunks' rows reached the pools: a cache slot a layer a
+    # recurrence, K and V
+    slots = (ut_steps or 1) * L
+    for a in chunks:
+        assert a['kv_write_pages'] == -(-a['tokens'] // PAGE) * 2 * slots
+        assert a['kv_write_rows'] == a['step_rows'] * 2 * slots
     if ut_steps is None:
         for a in steps + chunks:
             assert not set(LOOP_ARGS) & set(a)
@@ -387,3 +393,58 @@ def test_a_chunks_span_counts_the_kernels_blocks(monkeypatch):
     assert full == (5, 4)
     assert (2 * (full[0] + ring[0]), 2 * (full[1] + ring[1])) in [
         (a['attn_blocks'], a['attn_whole_blocks']) for a in chunks]
+
+
+def test_a_chunks_span_counts_how_its_rows_reached_the_pools(params):
+    """``kv_write_pages`` / ``kv_write_rows`` on ``decode.prefill_chunk``:
+    the pages the chunk's tokens were cached as and the carried decode
+    rows cached one at a time, each times the cache rows (K and V) and
+    the layers; on a chunk alone, on one that carries, and totalled by
+    ``stats()``; a step's span has neither."""
+    eng = make_engine(params, prefill_chunk_tokens=2 * PAGE)
+    each = 2 * L
+    pages = eng.cache.alloc(4)
+    prompt = np.arange(1, 2 * PAGE + 4)          # 16 + 3 tokens
+    for lo, hi in eng.chunk_spans(len(prompt)):
+        first = eng.prefill_chunk(prompt[lo:hi], pages, lo)
+    alone = [e['args'] for e in spans() if e['name'] == 'decode.prefill_chunk']
+    assert [(a['kv_write_pages'], a['kv_write_rows']) for a in alone] \
+        == [(2 * each, 0), (1 * each, 0)]
+    # a chunk of a page and one token that carries the first stream's row
+    tok = np.zeros(STREAMS, np.int32)
+    ctx = np.zeros(STREAMS, np.int32)
+    pt = np.full((STREAMS, eng.pages_per_stream), eng.cache.trash, np.int32)
+    tok[1], ctx[1], pt[1, :4] = int(np.argmax(first)), len(prompt), pages
+    eng.prefill_chunk(np.arange(1, PAGE + 2), eng.cache.alloc(2), 0,
+                      tok, pt, ctx)
+    carrying = [e['args'] for e in spans()
+                if e['name'] == 'decode.prefill_chunk'][-1]
+    assert carrying['step_rows'] == 1
+    assert (carrying['kv_write_pages'], carrying['kv_write_rows']) \
+        == (2 * each, 1 * each)
+    eng.step(tok, pt, ctx + 1)
+    step, = [e['args'] for e in spans() if e['name'] == 'decode.step']
+    assert 'kv_write_pages' not in step and 'kv_write_rows' not in step
+    assert (eng.kv_write_pages, eng.kv_write_rows) == (5 * each, each)
+
+
+def test_stats_total_the_pages_and_rows_chunks_wrote(params):
+    eng = make_engine(params, prefill_chunk_tokens=PAGE)
+    rng = np.random.default_rng(3)
+    server = DecodeServer(eng, warmup=False)
+    try:
+        streams = [server.submit(rng.integers(1, V, n), max_new_tokens=5)
+                   for n in (5, 20, 9)]
+        for st in streams:
+            st.result(timeout=120.0)
+        stats = server.stats()
+    finally:
+        server.close()
+    chunks = [e['args'] for e in spans()
+              if e['name'] == 'decode.prefill_chunk']
+    assert stats['kv_write_pages'] == sum(
+        a['kv_write_pages'] for a in chunks) \
+        == 2 * L * sum(-(-a['tokens'] // PAGE) for a in chunks) > 0
+    assert stats['kv_write_rows'] == sum(
+        a['kv_write_rows'] for a in chunks) \
+        == 2 * L * sum(a['step_rows'] for a in chunks) > 0

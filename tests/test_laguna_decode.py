@@ -14,6 +14,8 @@ pages are a group of their own, a ring of 5 pages a stream when nothing
 is chunked and of 6 under chunks of 8 (7 + 8 positions, and a page to
 spare), against 16 pages for a stream's whole context.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -29,7 +31,10 @@ from paddle_tpu.observability import timeline
 from paddle_tpu.ops import moe
 
 import reference_laguna as ref
-from test_decode_calls import host_operands
+from test_decode_calls import (CHUNK_WRITE_CASES,
+                               chunk_scatters_a_page_an_update,
+                               chunk_writes_match_row_by_row, host_operands,
+                               write_row_by_row)
 
 TOL = 2e-5
 V, D, HKV, DH = 97, 64, 2, 16
@@ -233,6 +238,36 @@ def test_chunked_prefill_with_carried_rows(params):
     assert rel(out, ref_logits(params, b)[-1]) < TOL
     give_back(eng, pa)
     give_back(eng, pb)
+
+
+@functools.lru_cache(maxsize=None)
+def two_page_chunk_engines():
+    """(the tree's engine, the same made to write a chunk's rows one at
+    a time), chunks of two pages: a ring of 5 pages of 4."""
+    new, old = (make_engine(make_params(0), prefill_chunk_tokens=2 * PAGE)
+                for _ in range(2))
+    return new, write_row_by_row(old)
+
+
+@pytest.mark.parametrize('case', sorted(CHUNK_WRITE_CASES))
+def test_chunk_rows_written_by_pages_leave_what_row_by_row_left(case):
+    """Both page groups: a prompt of 16 tokens and more wraps the ring,
+    so the last page's tail is where positions a ring back were."""
+    new, old = two_page_chunk_engines()
+    assert new.ring_pages == 5
+    chunk_writes_match_row_by_row(new, old, V, case)
+
+
+def test_a_chunk_scatters_pages_for_its_rows_and_rows_for_the_carried():
+    chunk_scatters_a_page_an_update(two_page_chunk_engines()[0])
+
+
+def test_a_chunk_off_the_page_grid_is_refused():
+    eng = two_page_chunk_engines()[0]
+    pages = claim(eng, 16)
+    with pytest.raises(ValueError, match='page grid'):
+        eng.prefill_chunk(np.arange(1, 4), pages, PAGE + 1)
+    give_back(eng, pages)
 
 
 def test_two_streams_equal_each_alone(params):

@@ -22,6 +22,10 @@ from paddle_tpu.models.ouro import NORMS, param_names
 from paddle_tpu.observability import timeline
 from paddle_tpu.transpiler.memory_model import (page_pool_bytes,
                                                 prefix_cached_bytes)
+from test_decode_calls import (CHUNK_WRITE_CASES,
+                               chunk_scatters_a_page_an_update,
+                               chunk_writes_match_row_by_row,
+                               write_row_by_row)
 
 D, F, H, V = 32, 48, 4, 61
 PAGE, STREAMS, PAGES, SEQ = 8, 3, 24, 64
@@ -164,6 +168,37 @@ def test_chunked_prefill_with_carried_rows_matches_the_reference(T, L):
     assert rel(s['rows'], logits[len(s['a']) - 1:]) < TOL
     b_logits, _p = want(L, T, s['b'])
     assert rel(s['b_last'], b_logits[-1]) < TOL
+
+
+# -- a chunk's rows reach every recurrence's slots a page at a time -------
+
+@functools.lru_cache(maxsize=None)
+def two_page_chunk_engines(T, L):
+    """(the tree's engine, the same made to write a chunk's rows one at
+    a time), chunks of two pages."""
+    new, old = (engine(T, L, chunk=2 * PAGE) for _ in range(2))
+    return new, write_row_by_row(old)
+
+
+@pytest.mark.parametrize('case', sorted(CHUNK_WRITE_CASES))
+def test_chunk_rows_written_by_pages_leave_what_row_by_row_left(case):
+    """Under the scan, with the pools as its carry: every recurrence's
+    slots, each through page ids shifted to it."""
+    new, old = two_page_chunk_engines(2, 2)
+    chunk_writes_match_row_by_row(new, old, V, case)
+
+
+@pytest.mark.parametrize('T', [1, 2])
+def test_a_chunk_scatters_pages_for_its_rows_and_rows_for_the_carried(T):
+    chunk_scatters_a_page_an_update(two_page_chunk_engines(T, 2)[0])
+
+
+def test_a_chunk_off_the_page_grid_is_refused():
+    eng = two_page_chunk_engines(2, 2)[0]
+    pages = eng.cache.alloc(2)
+    with pytest.raises(ValueError, match='page grid'):
+        eng.prefill_chunk(np.arange(1, 4), pages, 3)
+    eng.cache.free(pages)
 
 
 # -- the loop is the loop the other blocks run ----------------------------
