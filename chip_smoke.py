@@ -63,6 +63,12 @@ CHIP = dict(
                  kinds=dict(full=dict(H=48, MPP=1088, window=None),
                             window=dict(H=72, MPP=65, window=512))),
     latent=dict(S=64, H=128, W=640, C=512, P=16, MPP=256, N=4097),
+    # 20 query heads over ONE K/V head (the two attention layers of
+    # ai21-jamba2-3b_serve_longdoc64_chunked), and a chunk's selective
+    # scan in one of its 26 state-space layers
+    mqa=dict(S=64, H=20, HKV=1, D=128, P=16, MPP=1088, N=8705, C=512,
+             window=None),
+    ssm=dict(T=512, DC=5120, NS=16, valid=301),
     lstm=dict(T=128, B=256, H=256),             # bench_lstm_lm.py
     gru=dict(T=64, B=512, H=512),               # bench_seq2seq.py
     sparse=dict(H=1000003, D=16, K=32768),      # bench_ctr.py
@@ -78,6 +84,9 @@ TOY = dict(
                  kinds=dict(full=dict(H=16, MPP=10, window=None),
                             window=dict(H=24, MPP=5, window=40))),
     latent=dict(S=4, H=4, W=128, C=96, P=16, MPP=10, N=25),
+    mqa=dict(S=4, H=20, HKV=1, D=128, P=16, MPP=10, N=49, C=32,
+             window=None),
+    ssm=dict(T=32, DC=256, NS=16, valid=19),
     lstm=dict(T=6, B=8, H=128),
     gru=dict(T=6, B=8, H=128),
     sparse=dict(H=1003, D=16, K=64),
@@ -660,7 +669,7 @@ def kernel_cases(cfg):
     gq = cfg['grouped']
     gpool = ((gq['N'], gq['P'], gq['HKV'] * gq['D']), bf16)
 
-    def grouped_make(kind, chunk):
+    def grouped_make(kind, chunk, gq=gq, gpool=gpool):
         def make(rng):
             g = dict(gq, **kind)
             kv = [(rng.standard_normal(gpool[0]) * 0.5).astype(np.float32)
@@ -712,6 +721,57 @@ def kernel_cases(cfg):
                                       interpret=interpret), win=win),
             functools.partial(chunked_prefill_attention_math, window=win),
             *TOL_BF16, make=grouped_make(kind, True), timed=True))
+
+    # -- every query head over ONE K/V head (multi-query) --------------------
+    mq = cfg['mqa']
+    mpool = ((mq['N'], mq['P'], mq['D']), bf16)
+    cases.append(KernelCase(
+        'paged_attention_mqa',
+        [((mq['S'], mq['H'], mq['D']), f32), mpool, mpool,
+         ((mq['S'], mq['MPP']), jnp.int32), ((mq['S'],), jnp.int32)],
+        lambda q, k, v, pt, ctx, interpret: paged_attention(
+            q, k, v, pt, ctx, interpret=interpret),
+        slot_blocks(paged_attention_math),
+        *TOL_BF16, make=grouped_make(mq, False, mq, mpool), timed=True))
+    cases.append(KernelCase(
+        'chunk_paged_attention_mqa',
+        [((mq['C'], mq['H'], mq['D']), f32), mpool, mpool,
+         ((mq['MPP'],), jnp.int32), ((), jnp.int32)],
+        lambda q, k, v, pt, pos0, interpret: chunk_paged_attention(
+            q, k, v, pt, pos0, interpret=interpret),
+        chunked_prefill_attention_math,
+        *TOL_BF16, make=grouped_make(mq, True, mq, mpool), timed=True))
+
+    # -- a chunk's selective scan, the state in VMEM for all its tokens -----
+    from paddle_tpu.ops.pallas.selective_scan import selective_scan
+    from paddle_tpu.ops.ssm import selective_scan_math
+    sm = cfg['ssm']
+    seq, lanes = (sm['T'], sm['DC']), (sm['NS'], sm['DC'])
+
+    def ssm_make(rng):
+        f = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+        return [f(*seq), np.log1p(np.exp(f(*seq) - 3.0)),
+                -np.exp(f(*lanes) * 0.5), f(sm['T'], sm['NS']),
+                f(sm['T'], sm['NS']), f(sm['DC']), f(*lanes),
+                np.int32(sm['valid'])]
+
+    def scan_both(fn):
+        # the rows past ``valid`` are padding: whatever a form leaves there
+        def f(*ops, **kw):
+            y, s = fn(*ops, **kw)
+            return jnp.where(jnp.arange(sm['T'])[:, None] < ops[-1], y,
+                             0.0), s
+        return f
+
+    cases.append(KernelCase(
+        'selective_scan',
+        [(seq, f32), (seq, f32), (lanes, f32), ((sm['T'], sm['NS']), f32),
+         ((sm['T'], sm['NS']), f32), ((sm['DC'],), f32), (lanes, f32),
+         ((), jnp.int32)],
+        scan_both(selective_scan), scan_both(selective_scan_math),
+        2e-5, 'float32 on both sides: the order of the sums over the '
+        'state\'s lanes and the expansion of exp differ',
+        make=ssm_make, timed=True))
 
     # -- the same over ONE latent row a position (MLA, absorbed form) ------
     from paddle_tpu.ops.attention import latent_paged_attention_math

@@ -80,6 +80,9 @@ AMP_BLACK = frozenset({
     'exp', 'log', 'pow', 'square',
     # position angles up to max_seq: sin/cos want the float32 mantissa
     'rotary_embedding',
+    # a state-space recurrence: exp of a step times a decay, carried
+    # over thousands of tokens (and the convolution that feeds it)
+    'causal_conv1d', 'selective_scan', 'selective_state_update',
     # metrics
     'accuracy', 'auc', 'precision_recall', 'positive_negative_pair',
     'chunk_eval', 'edit_distance', 'detection_output',

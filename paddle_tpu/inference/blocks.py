@@ -19,7 +19,10 @@ description supplies the rest, once, as pure functions of the weights (a
   ``'full'`` (every position stays) or ``'window'`` (the ``window``
   newest do), where a block has both: the engine then holds a page
   group a kind, the window group's a ring a stream, and hands each
-  layer's attend its own group's table;
+  layer's attend its own group's table.  A third kind, ``'state'``, is
+  a layer that caches NOTHING a position and a fixed-size state a
+  STREAM, which it reads and rewrites (a state-space mixer): see
+  "State layers" below;
 - ``embed(p, tokens, positions)`` -> x [T, D] float32 (the positions
   it is handed lie inside the engine's ``max_seq``);
 - ``qkv(p, x, i, positions)`` -> (q, *rows): the queries as the block's
@@ -58,6 +61,37 @@ description supplies the rest, once, as pure functions of the weights (a
   each row's exit gate there.  ``exit_distribution(gates [R, T])`` ->
   p [R, T]: the share of a row that leaves at each recurrence.
 
+State layers.  A block whose ``layer_kinds`` names ``'state'`` also
+supplies:
+
+- ``state_rows(sizes)`` -> what a STREAM holds in every state layer, as
+  ``((name, shape, dtype), ...)`` (a shape's minor dimension whole
+  128-lane registers).  The engine holds, a row a RUN of
+  consecutive state layers, one pool ``[layers of the run, max_streams
+  + 1, *shape]`` indexed by the server's slot (the last row is the idle
+  rows' trash), and nothing else about the state;
+- ``state_runs(n_layers)`` -> ``[(first layer, layers), ...]`` and
+  ``state_weights(p, r)`` -> run r's weights, ``{name: [layers, ...]}``
+  stacked: the engine walks a run under ONE ``lax.scan`` (the body is
+  traced once, the pools are its carry) and hands the body a layer's
+  slice ``w``;
+- ``state_layer(w, x, rows=None, seq=None)`` -> (x, rows' new state,
+  seq's new state): the whole layer over x [T, D] whose first rows are
+  ONE TOKEN each on a state of their own (``rows`` = (state [R, ...] a
+  state row ..., live [R]): a decode step's, or the ones a chunk
+  carries) and whose other rows are ONE SEQUENCE continuing one state
+  (``seq`` = (state [...] a state row ..., n_valid): a prompt or a
+  chunk of it, rows from ``n_valid`` on padding that must leave the
+  state where token ``n_valid - 1`` left it).  The three programs are
+  its three forms, as the attends are attention's: a whole prompt is
+  ``seq`` alone from zeros, a step ``rows`` alone, a chunk both;
+- ``scan_path(sizes, backend, tokens)`` -> the form a sequence of
+  ``tokens`` rows takes in its state layers (the ``decode.compile``
+  span's ``ssm``).
+
+Such a block's ``qkv`` / attends / ``after_attention`` are only ever
+called for its other layers.
+
 Every function takes the weights ``p`` as traced values: the engine
 hands each of its programs the one placed copy as an operand, in the
 precision the configuration states, so a description never closes over
@@ -81,9 +115,11 @@ from ..ops.attention import (_dense_attention, _grouped,
 from ..ops.moe import (moe_counts, moe_experts, moe_route,
                        moe_route_grouped, rms_norm_math, rotary_math,
                        swiglu_math, yarn_mscale)
+from ..ops.ssm import (causal_conv1d_math, selective_scan,
+                       selective_scan_path, selective_state_update_math)
 
 __all__ = ['KVBlock', 'OptBlock', 'OlmoeBlock', 'DotsVlmBlock',
-           'LagunaBlock', 'OuroBlock']
+           'LagunaBlock', 'OuroBlock', 'JambaBlock']
 
 
 def _mm(x, w):
@@ -686,3 +722,177 @@ class OuroBlock(KVBlock):
 
     def head(self, p, x):
         return _mm(x, p['ouro_head_w'])
+
+
+class JambaBlock(KVBlock):
+    """The layers of AI21's Jamba (models/jamba.py declares the same
+    parameters; chipbench/reference/jamba.py is its plain reference):
+    layer i is attention where ``i % period == offset`` and a Mamba-1
+    mixer everywhere else (``layer_kinds``: ``'full'`` and ``'state'``),
+    each behind a pre-RMSNorm and followed by a pre-normed gated MLP
+    (SiLU); a final RMSNorm; the head is the embedding, transposed.
+
+    Attention: ``n_heads`` query heads over ``n_kv_heads`` K/V heads,
+    no bias and NO positional encoding: ``qkv`` turns nothing, keys are
+    cached as projected.
+
+    The Mamba mixer, for its normed input h: ``[u, z] = h W_in``; ``v =
+    silu(conv(u))``, a causal depthwise convolution over the last
+    ``d_conv`` inputs; ``[dt, B, C] = v W_x``, each through an RMSNorm
+    of its own (Jamba's); ``dt = softplus(dt W_dt + b_dt)``; the
+    selective scan ``s = exp(dt A) s + (dt v) B``, ``y = s . C + D v``
+    with ``A = -exp(a_log)``; out ``(y silu(z)) W_out``.  A stream holds
+    ``s`` [d_state, d_inner] and the convolution's last ``d_conv - 1``
+    inputs, float32, in every such layer (``state_rows``), and no K/V
+    there.  The matrices are multiplied once for all the rows of a
+    call; the carried decode rows take ``selective_state_update``, the
+    sequence rows ``selective_scan`` (ops/ssm.py; on the chip the
+    Pallas kernel)."""
+
+    def __init__(self, n_heads, n_kv_heads, head_dim, period, offset,
+                 eps=1e-6):
+        self.n_heads = int(n_heads)
+        self._kv_heads, self._head_dim = int(n_kv_heads), int(head_dim)
+        self.period, self.offset = int(period), int(offset)
+        self.eps = float(eps)
+
+    @property
+    def n_kv_heads(self):
+        return self._kv_heads
+
+    def head_dim(self, sizes):
+        return self._head_dim
+
+    def layer_kinds(self, n_layers):
+        from ..models.jamba import layer_kinds
+        return layer_kinds(n_layers, self.period, self.offset)
+
+    def state_runs(self, n_layers):
+        from ..models.jamba import state_runs
+        return state_runs(self.layer_kinds(n_layers))
+
+    def names(self, n_layers):
+        from ..models.jamba import param_names
+        return param_names(n_layers, self.period, self.offset)
+
+    def sizes(self, params):
+        v, d = params['jamba_embed'].shape
+        _n, k, dc = params['jamba_r0_conv_w'].shape
+        return {'d_model': int(d), 'vocab_size': int(v),
+                'd_inner': int(dc), 'd_conv': int(k),
+                'd_state': int(params['jamba_r0_a_log'].shape[1])}
+
+    def state_rows(self, sizes):
+        # the convolution's carried inputs as ONE row of (d_conv - 1) x
+        # d_inner lanes: held [.., slots, d_conv - 1, d_inner] the
+        # compiler keeps the pool slots-minor and re-lays the whole of
+        # it out at every program's edge and once a layer (1.98 ms of a
+        # 30.9 ms chunk on the chip: PERF.md section 6, PR 59)
+        dc = sizes['d_inner']
+        return (('ssm', (sizes['d_state'], dc), jnp.float32),
+                ('conv', ((sizes['d_conv'] - 1) * dc,), jnp.float32))
+
+    @staticmethod
+    def state_weights(p, r):
+        from ..models.jamba import STATE_LAYER
+        return {s: p['jamba_r%d_%s' % (r, s)] for s in STATE_LAYER}
+
+    def scan_path(self, sizes, backend, tokens):
+        return selective_scan_path(backend, tokens, sizes['d_inner'],
+                                   sizes['d_state'])
+
+    def norm(self, x, w):
+        return rms_norm_math(x, w, self.eps)
+
+    def embed(self, p, tokens, positions):
+        return p['jamba_embed'][tokens].astype(jnp.float32)
+
+    def mlp(self, w, x):
+        h = self.norm(x, w['post_norm_w'])
+        return swiglu_math(h, w['gate_w'], w['up_w'], w['down_w'])
+
+    # -- the attention layers ----------------------------------------------
+
+    @staticmethod
+    def _attention_weights(p, i):
+        from ..models.jamba import ATTENTION_LAYER
+        return {s: p['jamba_l%d_%s' % (i, s)] for s in ATTENTION_LAYER}
+
+    def qkv(self, p, x, i, positions):
+        w = self._attention_weights(p, i)
+        h = self.norm(x, w['in_norm_w'])
+        q = _mm(h, w['q_w']).reshape(x.shape[0], self.n_heads, -1)
+        return q, _mm(h, w['k_w']), _mm(h, w['v_w'])
+
+    def after_attention(self, p, x, ctx, i, active):
+        w = self._attention_weights(p, i)
+        x = x + _mm(ctx.reshape(x.shape[0], -1), w['o_w'])
+        return x + self.mlp(w, x), None
+
+    # -- the state layers -----------------------------------------------------
+
+    def seq_valid(self, n_valid, rows):
+        """How many of a sequence's ``rows`` rows advance its state."""
+        return n_valid
+
+    def carried(self, c):
+        """The convolution's inputs from before a sequence's first."""
+        return c
+
+    def small_norms(self, w, dt, b, c):
+        return (self.norm(dt, w['dt_norm_w']), self.norm(b, w['b_norm_w']),
+                self.norm(c, w['c_norm_w']))
+
+    def state_layer(self, w, x, rows=None, seq=None):
+        y, rows, seq = self.mixer(w, self.norm(x, w['in_norm_w']), rows,
+                                  seq)
+        x = x + y
+        return x + self.mlp(w, x), rows, seq
+
+    def mixer(self, w, h, rows, seq):
+        f32 = jnp.float32
+        uz = _mm(h, w['in_w'])
+        dc = uz.shape[1] // 2
+        u, z = uz[:, :dc], uz[:, dc:]
+        R = rows[0].shape[0] if rows else 0
+        conv = w['conv_w'], w['conv_b']
+        vs = []
+        if rows:
+            s_r, c_r, live = rows
+            v, c_r = causal_conv1d_math(u[:R], *conv,
+                                        c_r.reshape(R, -1, dc), live)
+            vs.append(v)
+        if seq:
+            s_q, c_q, n_valid = seq
+            n_valid = self.seq_valid(n_valid, u.shape[0] - R)
+            v, c_q = causal_conv1d_math(
+                u[R:], *conv, self.carried(c_q.reshape(-1, dc)), n_valid)
+            vs.append(v)
+        v = jnp.concatenate(vs) if len(vs) > 1 else vs[0]
+        n = w['a_log'].shape[0]
+        dbc = _mm(v, w['x_w'])
+        dt, b, c = self.small_norms(
+            w, dbc[:, :-2 * n], dbc[:, -2 * n:-n], dbc[:, -n:])
+        dt = jax.nn.softplus(_mm(dt, w['dt_w']) + w['dt_b'].astype(f32))
+        a, d = -jnp.exp(w['a_log'].astype(f32)), w['d'].astype(f32)
+        ys = []
+        if rows:
+            y, s_r = selective_state_update_math(
+                s_r, v[:R], dt[:R], a, b[:R], c[:R], d, live)
+            ys.append(y)
+            rows = (s_r, c_r.reshape(R, -1))
+        if seq:
+            y, s_q = selective_scan(v[R:], dt[R:], a, b[R:], c[R:], d,
+                                    s_q, n_valid)
+            ys.append(y)
+            seq = (s_q, c_q.reshape(-1))
+        y = jnp.concatenate(ys) if len(ys) > 1 else ys[0]
+        return _mm(y * jax.nn.silu(z), w['out_w']), rows, seq
+
+    def head(self, p, x):
+        # x E^T, contracted on the embedding's minor dimension as it
+        # lies: no transposed copy of the table
+        e = p['jamba_embed']
+        return jax.lax.dot_general(
+            self.norm(x, p['jamba_norm_f_w']).astype(e.dtype), e,
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)

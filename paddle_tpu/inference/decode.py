@@ -47,7 +47,7 @@ inference-throughput fix for decoder-only LMs, TPU-native:
 There is ONE definition of a decoder's serving path: which decoder is
 served is a block description (inference/blocks.py: ``OptBlock``, the
 default, ``OlmoeBlock``, ``DotsVlmBlock``, ``LagunaBlock``,
-``OuroBlock``) that supplies the layer's equations, what a position
+``OuroBlock``, ``JambaBlock``) that supplies the layer's equations, what a position
 caches and how to attend over it, and the engine's ``prefill``,
 ``chunk`` and ``step`` are one loop over layers around them.  No code
 here names a model or a parameter.
@@ -72,6 +72,18 @@ admission and given back together; the page table a call takes is the
 stream's pages and then its ring (``table_row``), and each layer's
 attend is handed its own group's part.  A block with one kind sees none
 of this: one group, one table, as before.
+
+Where some layers keep a STATE A STREAM and nothing a position
+(``layer_kinds``: ``'state'``; a state-space mixer), the cache holds a
+third group that does not page: pools ``[layers of a run, max_streams +
+1, ...]`` a state row a run of such layers (``PagedKVCache.state``),
+indexed by the server's own batch slot, donated and updated in place as
+the page pools are.  A run is walked under one ``lax.scan``
+(``_state_run``); a step updates row r's state for slot r, a chunk those
+and then its own stream's (``_advance_rows``, ``_advance_chunk``), told
+which by the slot that comes with the stream's pages (``_slot_of``); a
+chunk at position 0 starts from zeros (``_from_zero``), so no slot is
+ever reset and a preempted stream recomputes its state with its pages.
 
 Everything device-facing is AOT-compiled at ``warmup()`` via
 ``jit(...).lower(...).compile()`` — the serving loop only ever calls
@@ -222,7 +234,8 @@ class PagedKVCache(_PageGroup):
 
     def __init__(self, n_layers, num_pages, page_size, n_heads=None,
                  head_dim=None, dtype=jnp.float32, rows=None,
-                 window_layers=(), window_pages=0, recurrences=1):
+                 window_layers=(), window_pages=0, recurrences=1,
+                 state_rows=(), state_runs=(), max_streams=0):
         _PageGroup.__init__(self, num_pages)
         self.n_layers = int(n_layers)
         self.recurrences = int(recurrences)
@@ -234,8 +247,25 @@ class PagedKVCache(_PageGroup):
         self.window_layers = frozenset(int(i) for i in window_layers)
         self.window = _PageGroup(window_pages) if self.window_layers \
             else None
+        # layers that cache nothing a position and a state a STREAM:
+        # no page buffer (None holds their place in a row's list), and a
+        # row of ``state`` a RUN of them, [layers of the run, max_streams
+        # + 1, *shape], indexed by the server's slot (the last row is
+        # the idle rows' trash)
+        self.state_rows = tuple((str(n), tuple(int(d) for d in shape),
+                                 jnp.dtype(dt))
+                                for n, shape, dt in state_rows)
+        self.state_runs = tuple((int(f), int(n)) for f, n in state_runs)
+        self.state_layers = frozenset(
+            i for f, n in self.state_runs for i in range(f, f + n))
+        self.state_slots = int(max_streams)
+        self.state = [
+            [jnp.zeros((n, self.state_slots + 1) + shape, dt)
+             for _f, n in self.state_runs]
+            for _name, shape, dt in self.state_rows]
         self.pools = [
-            [jnp.zeros((self.recurrences
+            [None if i in self.state_layers else
+             jnp.zeros((self.recurrences
                         * (self.group_of(i).num_pages + 1),
                         self.page_size, w), dtype)
              for i in range(self.n_layers)] for _n, w in self.rows]
@@ -243,8 +273,15 @@ class PagedKVCache(_PageGroup):
     @property
     def slots(self):
         """Cache layers: what a position is cached in, a weight layer a
-        recurrence."""
-        return self.recurrences * self.n_layers
+        recurrence (the layers that keep a state a stream cache no
+        position and are not counted)."""
+        return self.recurrences * (self.n_layers - len(self.state_layers))
+
+    def state_bytes_per_stream(self):
+        """What a stream holds in the state layers, all of them."""
+        return len(self.state_layers) * sum(
+            int(np.prod(shape)) * dt.itemsize
+            for _n, shape, dt in self.state_rows)
 
     def shift(self, pages, t):
         """Page ids ``pages`` (one array a group, trash entries too) as
@@ -271,6 +308,10 @@ class PagedKVCache(_PageGroup):
         for j, (row, _w) in enumerate(self.__dict__.get('rows', ())):
             if row == name:
                 return self.pools[j]
+        # (a state row's buffers, a run each, likewise)
+        for j, row in enumerate(self.__dict__.get('state_rows', ())):
+            if row[0] == name:
+                return self.state[j]
         raise AttributeError(name)
 
     def row_widths(self):
@@ -283,8 +324,13 @@ class PagedKVCache(_PageGroup):
         n_window, T = len(self.window_layers), self.recurrences
         out = {'full': page_pool_bytes(
             self.num_pages + 1, self.page_size, dtype=self.dtype,
-            n_layers=T * (self.n_layers - n_window),
+            n_layers=T * (self.n_layers - n_window
+                          - len(self.state_layers)),
             row_widths=self.row_widths())}
+        if self.state_layers:
+            # a state a slot a state layer, the trash row too
+            out['state'] = (self.state_slots + 1) \
+                * self.state_bytes_per_stream()
         if self.window is not None:
             out['window'] = page_pool_bytes(
                 self.window.num_pages + 1, self.page_size,
@@ -550,6 +596,20 @@ class DecodeEngine(object):
         kinds = block.layer_kinds(self.n_layers) \
             if hasattr(block, 'layer_kinds') else ()
         window_layers = [i for i, k in enumerate(kinds) if k == 'window']
+        # layers that keep a state a STREAM and nothing a position: runs
+        # of them, each walked under one ``lax.scan`` (``_layers``)
+        self.state_runs = list(block.state_runs(self.n_layers)) \
+            if 'state' in kinds else []
+        if self.state_runs and self.prefix_enabled:
+            raise ValueError(
+                "prefix_cache=True with layers that keep a state a "
+                "stream: what a shared prefix leaves there is the state "
+                "at its last token, not pages another stream can claim "
+                "(no state is kept at page boundaries); serve this "
+                "block with the prefix cache off")
+        if self.state_runs and self.looped:
+            raise ValueError("state layers under a block that loops over "
+                             "its layers are not built")
         self.ring_pages = 0
         if window_layers:
             if self.prefix_enabled:
@@ -568,7 +628,16 @@ class DecodeEngine(object):
             rows=block.cache_rows(sizes), window_layers=window_layers,
             window_pages=self.max_streams * self.ring_pages
             if window_pages is None else window_pages,
-            recurrences=self.ut_steps)
+            recurrences=self.ut_steps,
+            state_rows=block.state_rows(sizes) if self.state_runs else (),
+            state_runs=self.state_runs, max_streams=self.max_streams)
+        # what ``_layers`` walks: a layer, or (run, first layer, layers)
+        # where a run of state layers starts
+        at = {f: (r, f, n) for r, (f, n) in enumerate(self.state_runs)}
+        self._walk = [at.get(i, i) for i in range(self.n_layers)
+                      if i in at or i not in self.cache.state_layers]
+        # the layers that cache K/V, in order (``pack``)
+        self._kv_layers = [i for i in self._walk if isinstance(i, int)]
         # a layer's group: 0 the pages of the whole context, 1 the rings
         self._group = [int(i in self.cache.window_layers)
                        for i in range(self.n_layers)]
@@ -592,6 +661,9 @@ class DecodeEngine(object):
             jnp.zeros((S,), jnp.int32),
             jnp.asarray(np.tile(self.idle_row, (S, 1))),
             jnp.zeros((S,), jnp.int32))
+        # where there are state layers, a chunk's last operand: the row
+        # of the state pools its stream holds; warm-up's is the trash row
+        self._idle_slot = (jnp.int32(S),) if self.state_runs else ()
 
     # -- compiled function builders ------------------------------------
 
@@ -607,6 +679,21 @@ class DecodeEngine(object):
                 weight_layers=self.n_layers,
                 cache_bytes_per_position=self.cache.slots * sum(
                     self.cache.row_widths()) * self.cache.dtype.itemsize)
+        if self.state_runs:
+            cache = self.cache
+            kinds = self.block.layer_kinds(self.n_layers)
+            span_args.update(
+                layer_kinds={k: kinds.count(k) for k in sorted(set(kinds))},
+                state_rows={n: list(shape)
+                            for n, shape, _dt in cache.state_rows},
+                state_bytes_per_stream=cache.state_bytes_per_stream(),
+                state_pool_bytes=cache.group_bytes()['state'],
+                # the form the state layers' scan takes, and in how many
+                # layers: a step has none (its rows are one token each)
+                ssm={self.block.scan_path(
+                    self.sizes, jax.default_backend(), bucket):
+                    len(cache.state_layers)}
+                if fn.__name__ in ('prefill', 'chunk') else {})
         with _obs.span('decode.compile', args=span_args):
             compiled = _in_a_roomy_frame(lambda: jax.jit(
                 fn, donate_argnums=donate).lower(*args).compile())
@@ -618,7 +705,8 @@ class DecodeEngine(object):
                 temp_bytes=int(mem.temp_size_in_bytes),
                 alias_bytes=int(mem.alias_size_in_bytes),
                 argument_bytes=int(mem.argument_size_in_bytes),
-                pool_bytes=self.cache.group_bytes() if self.ring_pages
+                pool_bytes=self.cache.group_bytes()
+                if self.ring_pages or self.state_runs
                 else self.cache.resident_bytes())
         self.compiles_total += 1
         return compiled
@@ -657,7 +745,7 @@ class DecodeEngine(object):
         return [g.trash for g in self.cache.groups]
 
     def _layers(self, params, x, positions, active, attend, pools=(),
-                pages=(), decoding=None):
+                pages=(), decoding=None, advance=None):
         """A block's layers over x [rows, D], as many times as the block
         runs them (``ut_steps`` recurrences over the one set of weights,
         recurrence t into cache slots of its own).  ``attend(i, q, rows,
@@ -681,6 +769,12 @@ class DecodeEngine(object):
         148, and a step is 9.8 ms on the device where theirs was 10.9:
         PERF.md section 6, PR 56).
 
+        A RUN of state layers (``_walk``) is one ``lax.scan`` over the
+        run's stacked weights (``_state_run``), whatever its length, the
+        run's state pools its carry: ``pools`` holds them after the page
+        pools, a state row each, and ``advance`` is where the three
+        programs differ there.
+
         Returns (x, pools, kept, extra): ``kept`` what a prefill keeps,
         a list a cache row of one array a layer, [rows, width] or under
         the scan [ut_steps, rows, width] (``_by_slot`` stacks them for
@@ -694,20 +788,27 @@ class DecodeEngine(object):
 
         def recurrence(t, x, pools):
             pools = [list(pool) for pool in pools]
+            # the page pools, a cache row each, then the state pools
+            paged = pools[:len(self.cache.rows)]
             at = [self.cache.shift(p, t) for p in pages]
             counts, kept, out = [], [], {}
-            for i in range(self.n_layers):
+            for i in self._walk:
+                if not isinstance(i, int):      # a run of state layers
+                    x, keep = self._state_run(
+                        params, i[0], x, pools[len(paged):], advance)
+                    out.setdefault('state', []).append(keep)
+                    continue
                 q, *rows = blk.qkv(params, x, i, positions)
                 ctx, keep = attend(
                     i, q, [r.astype(self.cache.dtype) for r in rows],
-                    pools, *at)
+                    paged, *at)
                 kept.append(keep)
                 x, c = blk.after_attention(params, x, ctx, i, active)
                 if c is not None:
                     counts.append(c)
             if counts:
                 out['counts'] = jnp.stack(counts)
-            if kept[0]:
+            if kept and kept[0]:
                 out['kept'] = [list(r) for r in zip(*kept)]
             if self.looped:
                 x, out['gate'] = blk.between(params, x, t)
@@ -731,7 +832,95 @@ class DecodeEngine(object):
             gates = out['gate'].reshape(T, -1)[:, :decoding]
             extra += (blk.exit_distribution(gates) @ w
                       / jnp.maximum(jnp.sum(w), 1.0),)
+        if 'state' in out and out['state'][0]:
+            # what a whole-prompt prefill keeps of the states: a state
+            # row's runs, each [layers of the run, *shape]
+            out['kept'] = out.get('kept', []) + [
+                list(run) for run in zip(*out['state'])]
         return x, pools, out.get('kept', ()), extra
+
+    def _state_run(self, params, r, x, state, advance):
+        """Run ``r`` of state layers over x: ONE traced body under
+        ``lax.scan`` over the run's stacked weights
+        (``block.state_weights``), the run's buffers of ``state`` (a list
+        a state row of a list a run; written back here) its carry.
+        ``advance(w, x, bufs, j)`` -> (x, bufs, keep) is the program's
+        own: layer j of the run, weights ``w``, on buffers ``[layers,
+        slots + 1, ...]`` a state row.  Returns (x, what the layers
+        kept, stacked a layer: a whole-prompt prefill's final states,
+        else ``()``)."""
+        n = self.state_runs[r][1]
+
+        def body(carry, xs):
+            x, bufs, keep = advance(xs[0], *carry, xs[1])
+            return (x, bufs), keep
+
+        (x, bufs), kept = jax.lax.scan(
+            body, (x, [row[r] for row in state]),
+            (self.block.state_weights(params, r), jnp.arange(n)))
+        for row, buf in zip(state, bufs):
+            row[r] = buf
+        return x, kept
+
+    def _from_zero(self, pos0):
+        """Whether a chunk at ``pos0`` starts its stream's state from
+        zeros, whatever the slot holds: a reused slot needs no reset,
+        and a preempted stream recomputes its state with its pages."""
+        return pos0 == 0
+
+    @staticmethod
+    def _state_rows(bufs, j, row0, n):
+        """Rows ``row0 .. row0 + n`` of layer ``j`` of a run's buffers
+        (``[layers, slots + 1, ...]`` a state row)."""
+        return [jax.lax.dynamic_slice(
+            b, (j, row0) + (0,) * (b.ndim - 2), (1, n) + b.shape[2:])[0]
+            for b in bufs]
+
+    @staticmethod
+    def _state_put(bufs, j, row0, rows):
+        return [jax.lax.dynamic_update_slice(
+            b, r[None].astype(b.dtype), (j, row0) + (0,) * (b.ndim - 2))
+            for b, r in zip(bufs, rows)]
+
+    def _advance_rows(self, live):
+        """``advance`` of step: row r is a token of slot r, on state
+        row r; a row that is not ``live`` leaves its slot's state as it
+        is (the slot may hold a stream whose prompt is still going in)."""
+        S = self.max_streams
+
+        def advance(w, x, bufs, j):
+            x, rows, _ = self.block.state_layer(
+                w, x, rows=(*self._state_rows(bufs, j, 0, S), live))
+            return x, self._state_put(bufs, j, 0, rows), ()
+        return advance
+
+    def _advance_chunk(self, live, slot, pos0, n_valid):
+        """``advance`` of chunk: the first ``max_streams`` rows are the
+        carried decode rows, as step's; the others one stream's chunk,
+        which continues the state in row ``slot`` (from zeros at
+        ``_from_zero``) for ``n_valid`` tokens."""
+        S, zero = self.max_streams, self._from_zero(pos0)
+
+        def advance(w, x, bufs, j):
+            mine = [jnp.where(zero, 0.0, s[0])
+                    for s in self._state_rows(bufs, j, slot, 1)]
+            x, rows, seq = self.block.state_layer(
+                w, x, rows=(*self._state_rows(bufs, j, 0, S), live),
+                seq=(*mine, n_valid))
+            bufs = self._state_put(bufs, j, 0, rows)
+            return x, self._state_put(bufs, j, slot,
+                                      [s[None] for s in seq]), ()
+        return advance
+
+    def _advance_prompt(self, n_valid):
+        """``advance`` of a whole-prompt prefill: one sequence from
+        zeros; what it keeps is the final state (``pack`` writes it)."""
+        def advance(w, x, bufs, j):
+            zeros = [jnp.zeros(shape, dt)
+                     for _n, shape, dt in self.cache.state_rows]
+            x, _, seq = self.block.state_layer(w, x, seq=(*zeros, n_valid))
+            return x, bufs, tuple(seq)
+        return advance
 
     def _by_slot(self, layers):
         """What ``_layers`` kept of one cache row, an array a layer ->
@@ -895,7 +1084,15 @@ class DecodeEngine(object):
             self._recycled(ctx, ctx + 1)
         self.kv_pages['live'] += live
         self.kv_pages['table'] += pts.size
-        return int(np.sum(running))
+        n = int(np.sum(running))
+        if self.state_runs:
+            # the running slots' states, every state layer's: what the
+            # rows' update reads and writes
+            span_args.update(
+                ssm_live_slots=n,
+                ssm_state_bytes=n * self.cache.state_bytes_per_stream())
+            self.ssm_state_bytes += span_args['ssm_state_bytes']
+        return n
 
     def _recycled(self, lo, hi):
         """Count the ring columns that positions [lo, hi) (arrays or
@@ -937,9 +1134,12 @@ class DecodeEngine(object):
 
             x, _pools, kept, extra = self._layers(
                 params, blk.embed(params, tokens, pos), pos, pos <= last,
-                attend)
+                attend, advance=self._advance_prompt(last + 1))
+            n = len(self.cache.rows)
+            # (then the final states, a state row's runs one after another)
             return (blk.head(params, x[last][None])[0],) + tuple(
-                self._by_slot(layers) for layers in kept) + extra
+                self._by_slot(layers) for layers in kept[:n]) + tuple(
+                run for row in kept[n:] for run in row) + extra
         return prefill
 
     def _chunk_rows(self, bucket, pt, pos0, n_valid):
@@ -1002,7 +1202,7 @@ class DecodeEngine(object):
 
     def _chunk_fn(self, bucket):
         blk, S, trash = self.block, self.max_streams, self.cache.trash
-        n = len(self.cache.rows)
+        n = self._n_pools
 
         def chunk(params, *args):
             # one pass over S + bucket rows: the tick's decode rows (as
@@ -1010,7 +1210,7 @@ class DecodeEngine(object):
             # then the chunk's.  Rows never mix in a layer, so each
             # group comes out as its own program would give it
             tokens, pt, pos0, n_valid, step_tokens, step_pt, ctx_len = \
-                args[n:]
+                args[n:n + 7]
             spos, spage, soffset = self._step_rows(step_pt, ctx_len)
             pos, valid, page_ids = self._chunk_rows(
                 bucket, pt, pos0, n_valid)
@@ -1044,7 +1244,12 @@ class DecodeEngine(object):
                 jnp.concatenate([step_pt[:, 0] != trash, valid]),
                 pages=(spage, page_ids, step_tables, tables),
                 attend=self._pages_then(S, self._write_then(soffset, read)),
-                pools=args[:n], decoding=S)
+                pools=args[:n], decoding=S,
+                # (the chunk's stream's slot is the last operand, where
+                # there are state layers)
+                advance=self._advance_chunk(
+                    step_pt[:, 0] != trash, args[-1], pos0, n_valid)
+                if self.state_runs else None)
             # the head on the decode rows and the chunk's last valid row
             last = S + jnp.clip(n_valid - 1, 0, bucket - 1)
             logits = blk.head(params,
@@ -1056,7 +1261,7 @@ class DecodeEngine(object):
 
     def _step_fn(self):
         blk, trash = self.block, self.cache.trash
-        n = len(self.cache.rows)
+        n = self._n_pools
 
         def step(params, *args):
             tokens, pt, ctx_len = args[n:]
@@ -1067,7 +1272,9 @@ class DecodeEngine(object):
                 params, blk.embed(params, tokens, pos), pos,
                 pt[:, 0] != trash,
                 self._write_then(offset, self._step_read(params, pos)),
-                pools=args[:n], pages=(page_idx, self._tables(pt)))
+                pools=args[:n], pages=(page_idx, self._tables(pt)),
+                advance=self._advance_rows(pt[:, 0] != trash)
+                if self.state_runs else None)
             logits = blk.head(params, x)
             nxt = jnp.argmax(logits, axis=-1)
             return tuple(pools) + (logits, nxt) \
@@ -1079,12 +1286,32 @@ class DecodeEngine(object):
         the rest is returned."""
         n = len(self.cache.rows)
         self.cache.pools = [list(pool) for pool in out[:n]]
-        return out[n:]
+        self.cache.state = [list(row) for row in out[n:self._n_pools]]
+        return out[self._n_pools:]
+
+    @property
+    def _n_pools(self):
+        """The pool operands of a program that writes them: the page
+        pools, a cache row each, then the state pools, a state row
+        each."""
+        return len(self.cache.rows) + len(self.cache.state_rows)
+
+    def _all_pools(self):
+        return self.cache.pools + self.cache.state
+
+    def _slot_of(self, pages):
+        """Where there are state layers an engine call is handed the
+        pair (a stream's pages as ``table_row`` takes them, the stream's
+        slot): -> (pages, the slot as the programs take it, or ())."""
+        if not self.state_runs:
+            return pages, ()
+        pages, slot = pages
+        return pages, (np.int32(slot),)
 
     def _ensure_prefill(self, bucket):
         if bucket in self._prefill:
             return
-        L, P, n = self.n_layers, self.page_size, len(self.cache.rows)
+        L, P, n = len(self._kv_layers), self.page_size, len(self.cache.rows)
         n_pages = bucket // P
 
         def pack(*args):
@@ -1099,21 +1326,43 @@ class DecodeEngine(object):
                 paged = rows.reshape(self.cache.slots, n_pages, P, -1)
                 for t in range(self.ut_steps):
                     at = self.cache.shift(pages, t)
-                    for i, pool in enumerate(row_pools):
-                        row_pools[i] = pool.at[at[self._group[i]]].set(
-                            paged[t * L + i])
+                    for k, i in enumerate(self._kv_layers):
+                        row_pools[i] = row_pools[i].at[
+                            at[self._group[i]]].set(paged[t * L + k])
             return tuple(out)
+
+        def pack_states(*args):
+            # ``pack``, and the final states the prefill kept into row
+            # ``slot`` of the state pools: (*page pools, *state pools,
+            # *kept rows, *kept states a run, *pages, slot)
+            m, runs = self._n_pools, len(self.state_runs)
+            states = m - n
+            rest = args[m + n + states * runs:]
+            out = pack(*args[:n], *args[m:m + n], *rest[:-1])
+            kept = args[m + n:m + n + states * runs]
+            return out + tuple(
+                [jax.lax.dynamic_update_slice(
+                    buf, kept[k * runs + r][:, None].astype(buf.dtype),
+                    (0, rest[-1]) + (0,) * (buf.ndim - 2))
+                 for r, buf in enumerate(row)]
+                for k, row in enumerate(args[n:m]))
 
         toks = jnp.zeros((bucket,), jnp.int32)
         prefill = self._prefill_fn(bucket)
         self._prefill[bucket] = self._compile(
             prefill, self.params, toks, jnp.int32(0), bucket=bucket)
+        states = len(self.cache.state_rows) * len(self.state_runs)
         kept = jax.eval_shape(prefill, self.params, toks,
-                              jnp.int32(0))[1:1 + n]
+                              jnp.int32(0))[1:1 + n + states]
         pages = [jnp.zeros((n_pages,), jnp.int32) for _ in self._trashes()]
+        packer = pack
+        if self.state_runs:
+            packer = pack_states
+            packer.__name__ = 'pack'
+            pages.append(jnp.int32(0))      # the slot
         self._pack[bucket] = self._compile(
-            pack, *self.cache.pools, *kept, *pages,
-            donate=tuple(range(n)), bucket=bucket)
+            packer, *self._all_pools(), *kept, *pages,
+            donate=tuple(range(self._n_pools)), bucket=bucket)
 
     def _ensure_chunk(self, bucket):
         """Chunked-prefill executable for one chunk bucket: a SINGLE
@@ -1134,21 +1383,21 @@ class DecodeEngine(object):
         program's first operand, as every program's."""
         if bucket in self._chunk:
             return
-        n = len(self.cache.rows)
         self._chunk[bucket] = self._compile(
-            self._chunk_fn(bucket), self.params, *self.cache.pools,
+            self._chunk_fn(bucket), self.params, *self._all_pools(),
             jnp.zeros((bucket,), jnp.int32),
             jnp.asarray(self.idle_row),
             jnp.int32(0), jnp.int32(1), *self._idle_step,
-            donate=tuple(range(1, 1 + n)), bucket=bucket)
+            *self._idle_slot,
+            donate=tuple(range(1, 1 + self._n_pools)), bucket=bucket)
 
     def _ensure_step(self):
         if self._step is not None:
             return
         self._step = self._compile(
-            self._step_fn(), self.params, *self.cache.pools,
+            self._step_fn(), self.params, *self._all_pools(),
             *self._idle_step,
-            donate=tuple(range(1, 1 + len(self.cache.rows))))
+            donate=tuple(range(1, 1 + self._n_pools)))
 
     def warmup(self):
         """AOT-compile every prefill bucket, its pack, and the decode
@@ -1171,10 +1420,11 @@ class DecodeEngine(object):
             self._ensure_step()
             for b in self.chunk_buckets:
                 logits = self._pools_out(self._chunk[b](
-                    self.params, *self.cache.pools,
+                    self.params, *self._all_pools(),
                     jnp.zeros((b,), jnp.int32),
                     jnp.asarray(self.idle_row),
-                    jnp.int32(0), jnp.int32(b), *self._idle_step))[0]
+                    jnp.int32(0), jnp.int32(b), *self._idle_step,
+                    *self._idle_slot))[0]
                 jax.block_until_ready(logits)
         else:
             for b in self.buckets:
@@ -1183,14 +1433,17 @@ class DecodeEngine(object):
             for b in self.buckets:
                 logits, *kept = self._prefill[b](
                     self.params, jnp.zeros((b,), jnp.int32),
-                    jnp.int32(0))[:1 + len(self.cache.rows)]
+                    jnp.int32(0))[:1 + len(self.cache.rows)
+                                  + len(self.cache.state_rows)
+                                  * len(self.state_runs)]
                 all_trash = [jnp.full((b // self.page_size,), t, jnp.int32)
                              for t in self._trashes()]
                 self._pools_out(self._pack[b](
-                    *self.cache.pools, *kept, *all_trash))
+                    *self._all_pools(), *kept, *all_trash,
+                    *self._idle_slot))
                 jax.block_until_ready(logits)
         logits = self._pools_out(self._step(
-            self.params, *self.cache.pools, *self._idle_step))[0]
+            self.params, *self._all_pools(), *self._idle_step))[0]
         jax.block_until_ready(logits)
         self._compiles_at_warmup = self.compiles_total
 
@@ -1224,8 +1477,10 @@ class DecodeEngine(object):
             self._ensure_prefill(bucket)
             toks = np.zeros((bucket,), np.int32)
             toks[:t] = prompt
-            n = len(self.cache.rows)
+            n = len(self.cache.rows) + len(self.cache.state_rows) \
+                * len(self.state_runs)
             n_pages = bucket // self.page_size
+            pages, slot = self._slot_of(pages)
             ring = ()
             if self.ring_pages:
                 pages, ring = pages
@@ -1245,7 +1500,7 @@ class DecodeEngine(object):
             logits, *rest = self._prefill[bucket](
                 self.params, toks, np.int32(t - 1))
             self._pools_out(self._pack[bucket](
-                *self.cache.pools, *rest[:n], *page_ids))
+                *self._all_pools(), *rest[:n], *page_ids, *slot))
             return self._fetch((logits,), rest[n:], args,
                                decoded=False)[0]
 
@@ -1310,16 +1565,19 @@ class DecodeEngine(object):
             with _obs.span('decode.prefill_chunk.dispatch'):
                 toks = np.zeros((bucket,), np.int32)
                 toks[:c] = tokens
+                pages, slot = self._slot_of(pages)
                 pt = self.table_row(pages)
                 if self.ring_pages:
                     self._recycled(pos0, pos0 + c)
+                if slot:
+                    args.update(ssm_scan_tokens=c, ssm_from_zero=pos0 == 0)
                 carried, host = (self._idle_step, None) \
                     if step_tokens is None else self._decode_operands(
                         step_tokens, page_tables, ctx_lens, args)
                 logits, nxt, step_logits, ids, ctx, *extra = \
                     self._pools_out(self._chunk[bucket](
-                        self.params, *self.cache.pools, toks, pt,
-                        np.int32(pos0), np.int32(c), *carried))
+                        self.params, *self._all_pools(), toks, pt,
+                        np.int32(pos0), np.int32(c), *carried, *slot))
             with _obs.span('decode.prefill_chunk.fetch'):
                 self._attn_blocks(pos0, bucket, args)
                 if step_tokens is not None:
@@ -1357,7 +1615,7 @@ class DecodeEngine(object):
                 ops, host = self._decode_operands(
                     tokens, page_tables, ctx_lens, args)
                 logits, nxt, ids, ctx, *extra = self._pools_out(
-                    self._step(self.params, *self.cache.pools, *ops))
+                    self._step(self.params, *self._all_pools(), *ops))
             with _obs.span('decode.step.fetch'):
                 self._kv_pages(page_tables, ctx_lens, args)
                 nxt, = self._fetch((nxt,), extra, args, step=True)
@@ -1393,6 +1651,8 @@ class DecodeEngine(object):
             blk, layers = self.block, {}
             for i, g in enumerate(self._group if isinstance(blk, KVBlock)
                                   else ()):
+                if i in self.cache.state_layers:
+                    continue
                 pages = (self.pages_per_stream, self.ring_pages)[g]
                 if chunk_attention_path(
                         jax.default_backend(), blk.n_kv_heads,
@@ -1415,6 +1675,7 @@ class DecodeEngine(object):
 
     # -- a chunk's rows into the pools, a page at a time -----------------
 
+    ssm_state_bytes = 0         # live state bytes, over the decode rows' calls
     kv_write_pages = 0          # pages chunks' rows were cached as
     kv_write_rows = 0           # carried rows cached one at a time
 
@@ -1536,6 +1797,10 @@ class _DecodeMetrics(object):
         self.cached_pages = child(reg.gauge(
             'paddle_tpu_decode_prefix_cached_pages',
             'KV pages currently held by the prefix trie', L))
+        self.state_recomputed = child(reg.counter(
+            'paddle_tpu_decode_state_recomputed_total',
+            'preemptions that threw a stream\'s per-slot state away '
+            '(recomputed from position 0 at readmission)', L))
 
     def close(self):
         for m in self._families:
@@ -1713,6 +1978,11 @@ class DecodeServer(object):
                     cached, eng.page_size, dtype=eng.cache.dtype,
                     n_layers=eng.cache.slots,
                     row_widths=eng.cache.row_widths()),
+                # where some layers keep a state a stream: the slots
+                # that hold one now, and the preemptions that threw one
+                # away (recomputed with the stream's pages)
+                'state_slots_live': active if eng.state_runs else 0,
+                'state_recomputed': int(self._m.state_recomputed.value),
                 'submitted': self._submitted,
                 'completed': self._completed,
                 'dropped': 0,  # admission queues, never sheds
@@ -1794,9 +2064,13 @@ class DecodeServer(object):
         return pages
 
     def _stream_pages(self, st):
-        """A stream's pages as the engine's calls take them."""
-        return (st._pages, st._ring) if self.engine.ring_pages \
+        """A stream's pages as the engine's calls take them, and where
+        some layers keep a state a stream, its slot with them: the
+        stream's row of the state pools is the batch slot it holds from
+        admission to retirement or preemption (no second allocator)."""
+        pages = (st._pages, st._ring) if self.engine.ring_pages \
             else st._pages
+        return (pages, st._slot) if self.engine.state_runs else pages
 
     def _free_ring(self, st):
         if st._ring:
@@ -2007,6 +2281,8 @@ class DecodeServer(object):
         st._prefill_pos = None
         st._ctx_len = 0
         self._m.preempted.inc()
+        if eng.state_runs:
+            self._m.state_recomputed.inc()
         with self._cv:
             self._preempted += 1
             self._slots[st._slot] = None

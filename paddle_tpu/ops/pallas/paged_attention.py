@@ -60,10 +60,12 @@ def supported(n_kv_heads, head_dim, page_size, dtype, group=1):
     that are whole sublane tiles of the pool's dtype (a page is one DMA
     into a tile-aligned slice of the block).  ``group`` query heads over
     each K/V head are laid out a group member at a time, ``Hkv`` rows
-    each, which then have to be whole float32 sublane tiles."""
+    each, which then have to be whole float32 sublane tiles; over ONE
+    K/V head (multi-query attention) the query heads are the rows as
+    they are."""
     return (n_kv_heads * head_dim) % 128 == 0 and \
         page_size % _sublane_rows(dtype) == 0 and \
-        (group == 1 or n_kv_heads % 8 == 0)
+        (group == 1 or n_kv_heads % 8 == 0 or n_kv_heads == 1)
 
 
 def _live_pages(ctx, page, window):
@@ -126,7 +128,9 @@ def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
     if group > 1:
         row = jax.lax.rem(row, kv_heads)
     diag = (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
-    if group == 1:
+    if group == 1 or kv_heads == 1:
+        # (one K/V head under every query head: the rows are the query
+        # heads, up to a whole tile, and every lane is theirs)
         q_rows = q_ref[0]
     else:
         pieces = [jnp.broadcast_to(q_ref[0, g:g + 1], (kv_heads, hd))
@@ -191,6 +195,8 @@ def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
     if group == 1:
         o_ref[0] = jnp.sum(jnp.where(diag, out, 0.0), axis=0,
                            keepdims=True).astype(o_ref.dtype)
+    elif kv_heads == 1:
+        o_ref[0] = out.astype(o_ref.dtype)
     else:
         out = jnp.where(diag, out, 0.0)
         for g in range(group):
@@ -233,14 +239,17 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
         qf = q.astype(jnp.float32)
         if group == 1:
             return qf.reshape(s, 1, hd)
+        if kv_heads == 1:       # the heads as rows, up to a whole tile
+            return jnp.pad(qf, ((0, 0), (0, hp - h), (0, 0)))
         # member g of every group side by side on its K/V head's lanes
         return qf.reshape(s, kv_heads, group, d).transpose(
             0, 2, 1, 3).reshape(s, group, hd)
 
-    row = pl.BlockSpec((1, group, hd), lambda i, pt, ln: (i, 0, 0))
+    rows = hp if kv_heads == 1 and group > 1 else group
+    row = pl.BlockSpec((1, rows, hd), lambda i, pt, ln: (i, 0, 0))
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((s, group, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, rows, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s,),
@@ -268,6 +277,8 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
       queries(), k_pool.reshape(n, page, hd), v_pool.reshape(n, page, hd))
     if group == 1:
         return out.reshape(s, h, d)
+    if kv_heads == 1:
+        return out[:, :h]
     return out.reshape(s, group, kv_heads, d).transpose(
         0, 2, 1, 3).reshape(s, h, d)
 

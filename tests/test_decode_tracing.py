@@ -325,6 +325,60 @@ def test_a_loops_spans_say_what_it_ran(params, ut_steps):
         assert a['cache_bytes_per_position'] == ut_steps * L * 2 * D * 4
 
 
+STATE_ARGS = ('ssm_live_slots', 'ssm_state_bytes', 'ssm_scan_tokens',
+              'ssm_from_zero')
+STATE_COMPILE_ARGS = ('layer_kinds', 'state_rows', 'state_bytes_per_stream',
+                      'state_pool_bytes', 'ssm')
+
+
+@pytest.mark.parametrize('block', ['opt', 'ouro', 'jamba'])
+def test_only_a_block_with_state_layers_says_what_its_states_did(params,
+                                                                 block):
+    """The state layers' arguments on ``decode.step``,
+    ``decode.prefill_chunk`` and ``decode.compile`` under a server: a
+    block without such layers (``OptBlock``, a looped ``OuroBlock``)
+    says none of them, and its ``stats()`` counts no state."""
+    import test_jamba_decode as jam
+    import test_ouro_decode as ouro
+    eng = {'opt': lambda: make_engine(params, prefill_chunk_tokens=PAGE),
+           'ouro': lambda: ouro.engine(2, L, chunk=PAGE),
+           'jamba': lambda: jam.engine(PAGE)}[block]()
+    eng.warmup()
+    rng = np.random.default_rng(0)
+    server = DecodeServer(eng, warmup=False)
+    try:
+        for st in [server.submit(rng.integers(1, jam.V, n), max_new_tokens=6)
+                   for n in (5, 20, 9)]:
+            st.result(timeout=120.0)
+        stats = server.stats()
+    finally:
+        server.close()
+    evs = spans()
+    steps = [e['args'] for e in evs if e['name'] == 'decode.step']
+    chunks = [e['args'] for e in evs if e['name'] == 'decode.prefill_chunk']
+    compiles = [e['args'] for e in evs if e['name'] == 'decode.compile']
+    assert steps and chunks and compiles
+    assert stats['state_slots_live'] == stats['state_recomputed'] == 0
+    if block != 'jamba':
+        for a in steps + chunks:
+            assert not set(STATE_ARGS) & set(a)
+        for a in compiles:
+            assert not set(STATE_COMPILE_ARGS) & set(a)
+        return
+    per_stream = eng.cache.state_bytes_per_stream()
+    for a in steps + [c for c in chunks if c['step_rows']]:
+        assert 1 <= a['ssm_live_slots'] <= 3
+        assert a['ssm_state_bytes'] == a['ssm_live_slots'] * per_stream
+    for a in chunks:
+        assert a['ssm_scan_tokens'] == a['tokens']
+        assert isinstance(a['ssm_from_zero'], bool)
+        assert ('ssm_live_slots' in a) == bool(a['step_rows'])
+    assert sum(a['ssm_from_zero'] for a in chunks) == 3     # a prompt each
+    for a in compiles:
+        assert set(STATE_COMPILE_ARGS) <= set(a)
+        assert a['state_bytes_per_stream'] == per_stream
+
+
 def test_a_chunks_span_counts_the_kernels_blocks(monkeypatch):
     """``attn_blocks`` / ``attn_whole_blocks`` on ``decode.prefill_chunk``
     and in ``stats()`` where the chunk rows take the live-pages kernel
