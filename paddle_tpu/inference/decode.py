@@ -104,7 +104,7 @@ from .. import observability as _obs
 from ..analysis import lockdebug as _lkd
 from ..compile_cache import enable_compile_cache
 from ..transpiler.memory_model import page_pool_bytes
-from .blocks import KVBlock, OptBlock
+from .blocks import OptBlock
 
 __all__ = ['DecodeEngine', 'DecodeServer', 'DecodeStream',
            'extract_params', 'decode_buckets', 'PrefixCache',
@@ -169,6 +169,49 @@ def _in_a_roomy_frame(f):
              % ' = '.join('_%d' % i for i in range(1 << 16)), scope)
         _ROOMY.append(scope['roomy'])
     return _ROOMY[0](f)
+
+
+# what jax says of one compile and its persistent cache: every look-up
+# records the first, a look-up that found the executable the second
+# (jax 0.9.0 ``compiler.compile_or_get_cached``; ``cache_misses`` is
+# recorded only where the new entry passes the thresholds and is written)
+_CACHE_ASKED = '/jax/compilation_cache/compile_requests_use_cache'
+_CACHE_HIT = '/jax/compilation_cache/cache_hits'
+_heard = threading.local()  # .events: of the compile open on this thread
+_listening = []             # non-empty once ``_hear`` is registered
+
+
+def _hear(event, **_kw):
+    events = getattr(_heard, 'events', None)
+    if events is not None:
+        events.add(event)
+
+
+def _staged(jitted, args):
+    """``jitted.lower(*args).compile()`` a stage a span: Python to
+    jaxpr, jaxpr to StableHLO, and the backend, whose span says what the
+    persistent cache did (``cache``: ``'hit'``, a load; ``'miss'``, XLA
+    compiled; ``'off'``, jax looked nothing up).  With metrics off the
+    spans are the shared no-op, and nothing listens."""
+    with _obs.span('decode.compile.trace'):
+        traced = jitted.trace(*args)
+    with _obs.span('decode.compile.lower'):
+        lowered = traced.lower()
+    if not _obs.enabled():
+        return lowered.compile()
+    if not _listening:
+        jax.monitoring.register_event_listener(_hear)
+        _listening.append(_hear)
+    said = {}
+    events = _heard.events = set()
+    try:
+        with _obs.span('decode.compile.backend', args=said):
+            compiled = lowered.compile()
+            said['cache'] = 'hit' if _CACHE_HIT in events else \
+                'miss' if _CACHE_ASKED in events else 'off'
+    finally:
+        _heard.events = None
+    return compiled
 
 
 class _PageGroup(object):
@@ -695,8 +738,8 @@ class DecodeEngine(object):
                     len(cache.state_layers)}
                 if fn.__name__ in ('prefill', 'chunk') else {})
         with _obs.span('decode.compile', args=span_args):
-            compiled = _in_a_roomy_frame(lambda: jax.jit(
-                fn, donate_argnums=donate).lower(*args).compile())
+            compiled = _in_a_roomy_frame(lambda: _staged(
+                jax.jit(fn, donate_argnums=donate), args))
             # whether the pools are updated in place, as the compiler
             # declares it: the donated bytes it aliased to outputs and
             # the scratch the program needs beside its arguments
@@ -1352,8 +1395,10 @@ class DecodeEngine(object):
         self._prefill[bucket] = self._compile(
             prefill, self.params, toks, jnp.int32(0), bucket=bucket)
         states = len(self.cache.state_rows) * len(self.state_runs)
-        kept = jax.eval_shape(prefill, self.params, toks,
-                              jnp.int32(0))[1:1 + n + states]
+        # what the prefill keeps, as the compiled program declares it:
+        # shapes and types only, as a second trace would give them
+        kept = [jax.ShapeDtypeStruct(o.shape, o.dtype) for o in
+                self._prefill[bucket].out_info[1:1 + n + states]]
         pages = [jnp.zeros((n_pages,), jnp.int32) for _ in self._trashes()]
         packer = pack
         if self.state_runs:
@@ -1408,9 +1453,36 @@ class DecodeEngine(object):
         route every write to the trash page, so pool contents survive
         bit-for-bit even on a re-warm with streams resident.
         Afterwards the serving loop calls only precompiled, pre-run
-        executables (compiles_after_warmup counts any miss)."""
+        executables (compiles_after_warmup counts any miss).
+
+        One ``decode.warmup`` span encloses it (``programs``: the
+        executables this call built, ``runs``: the executions it made),
+        a ``decode.compile`` each program and a ``decode.warmup.run``
+        each execution and its wait beneath it."""
         if self._compiles_at_warmup == self.compiles_total:
             return  # already compiled AND warm-executed, nothing new
+        args, built = {'runs': 0}, self.compiles_total
+        with _obs.span('decode.warmup', args=args):
+            self._warm(args)
+            args['programs'] = self.compiles_total - built
+        self._compiles_at_warmup = self.compiles_total
+
+    def _warm(self, said):
+        """Build what is missing, then run every executable once
+        (counted in ``said['runs']``)."""
+        def first_run(program, bucket, run):
+            said['runs'] += 1
+            with _obs.span('decode.warmup.run',
+                           args={'program': program, 'bucket': bucket}):
+                return jax.block_until_ready(run())
+
+        def pack(b, kept):
+            all_trash = [jnp.full((b // self.page_size,), t, jnp.int32)
+                         for t in self._trashes()]
+            self._pools_out(self._pack[b](
+                *self._all_pools(), *kept, *all_trash, *self._idle_slot))
+            return self._all_pools()
+
         if self.chunked:
             # chunked path: all prefill (cold included) runs the chunk
             # executables — the monolithic prefill/pack pair is never
@@ -1419,33 +1491,27 @@ class DecodeEngine(object):
                 self._ensure_chunk(b)
             self._ensure_step()
             for b in self.chunk_buckets:
-                logits = self._pools_out(self._chunk[b](
-                    self.params, *self._all_pools(),
-                    jnp.zeros((b,), jnp.int32),
-                    jnp.asarray(self.idle_row),
-                    jnp.int32(0), jnp.int32(b), *self._idle_step,
-                    *self._idle_slot))[0]
-                jax.block_until_ready(logits)
+                first_run('chunk', b, lambda: self._pools_out(
+                    self._chunk[b](
+                        self.params, *self._all_pools(),
+                        jnp.zeros((b,), jnp.int32),
+                        jnp.asarray(self.idle_row),
+                        jnp.int32(0), jnp.int32(b), *self._idle_step,
+                        *self._idle_slot))[0])
         else:
             for b in self.buckets:
                 self._ensure_prefill(b)
             self._ensure_step()
             for b in self.buckets:
-                logits, *kept = self._prefill[b](
-                    self.params, jnp.zeros((b,), jnp.int32),
-                    jnp.int32(0))[:1 + len(self.cache.rows)
-                                  + len(self.cache.state_rows)
-                                  * len(self.state_runs)]
-                all_trash = [jnp.full((b // self.page_size,), t, jnp.int32)
-                             for t in self._trashes()]
-                self._pools_out(self._pack[b](
-                    *self._all_pools(), *kept, *all_trash,
-                    *self._idle_slot))
-                jax.block_until_ready(logits)
-        logits = self._pools_out(self._step(
-            self.params, *self._all_pools(), *self._idle_step))[0]
-        jax.block_until_ready(logits)
-        self._compiles_at_warmup = self.compiles_total
+                _logits, *kept = first_run(
+                    'prefill', b, lambda: self._prefill[b](
+                        self.params, jnp.zeros((b,), jnp.int32),
+                        jnp.int32(0))[:1 + len(self.cache.rows)
+                                      + len(self.cache.state_rows)
+                                      * len(self.state_runs)])
+                first_run('pack', b, lambda: pack(b, kept))
+        first_run('step', None, lambda: self._pools_out(self._step(
+            self.params, *self._all_pools(), *self._idle_step))[0])
 
     @property
     def compiles_after_warmup(self):
@@ -1542,10 +1608,7 @@ class DecodeEngine(object):
         then returns (last-row logits, next tokens [S] as numpy, the
         decode rows' logits [S, V] left on the device for whoever asks).
         The span's ``tokens`` and ``bucket`` stay the chunk's;
-        ``step_rows`` counts the running slots carried,
-        ``kv_write_pages`` / ``kv_write_rows`` the pages the chunk's
-        rows were cached as and the carried rows cached one at a time
-        (``_kv_writes``), and
+        ``step_rows`` counts the running slots carried, and
         ``fetched_bytes`` what came back to the host: the last row, and
         with rows carried their ids, beside the routing counts; the
         carried three go in as ``step``'s do, and ``host_operands``
@@ -1579,11 +1642,9 @@ class DecodeEngine(object):
                         self.params, *self._all_pools(), toks, pt,
                         np.int32(pos0), np.int32(c), *carried, *slot))
             with _obs.span('decode.prefill_chunk.fetch'):
-                self._attn_blocks(pos0, bucket, args)
                 if step_tokens is not None:
                     args['step_rows'] = self._kv_pages(page_tables,
                                                        ctx_lens, args)
-                self._kv_writes(c, args['step_rows'], args)
                 if step_tokens is None:
                     # no decode rows ran: what is held stays as it is
                     return self._fetch((logits,), extra, args,
@@ -1625,59 +1686,9 @@ class DecodeEngine(object):
     def resident_bytes(self):
         return self.cache.resident_bytes()
 
-    # -- blocks of pages the chunk rows' kernel walks ---------------------
-    # (state and code down here, below every function a program is traced
-    # from: their line numbers are part of what a compiled kernel is keyed
-    # by, and the other blocks' programs keep theirs)
-
-    attn_blocks = 0             # blocks walked, over the engine's life
-    attn_whole_blocks = 0       # those of them every row saw whole
-    _chunk_kernel_layers = None     # built by the first chunk
-
-    def _attn_blocks(self, pos0, rows, span_args):
-        """Where some layers' chunk rows take the live-pages kernel
-        (``chunk_attention_path``, as the op's dispatch asks it): the
-        blocks of pages it walks for ``rows`` rows from ``pos0`` and
-        those of them that every row of their pass sees whole (no mask
-        can bite there; the kernel masks them all the same), summed
-        over these layers by ``chunk_blocks`` (host integers, the
-        kernel's own arithmetic) -> the span's ``attn_blocks``,
-        ``attn_whole_blocks`` and the engine's totals.  Where they
-        gather, nothing."""
-        layers = self._chunk_kernel_layers
-        if layers is None:
-            # {(window, table pages): layers that take the kernel}
-            from ..ops.attention import chunk_attention_path
-            blk, layers = self.block, {}
-            for i, g in enumerate(self._group if isinstance(blk, KVBlock)
-                                  else ()):
-                if i in self.cache.state_layers:
-                    continue
-                pages = (self.pages_per_stream, self.ring_pages)[g]
-                if chunk_attention_path(
-                        jax.default_backend(), blk.n_kv_heads,
-                        blk.head_dim(self.sizes), self.page_size,
-                        self.cache.dtype, blk.n_heads or blk.heads[i],
-                        pages) == 'pallas_paged':
-                    kind = (blk.window_of(i), pages)
-                    layers[kind] = layers.get(kind, 0) + 1
-            self._chunk_kernel_layers = layers
-        if not layers:
-            return
-        from ..ops.pallas.paged_attention import chunk_blocks
-        blocks = whole = 0
-        for (window, pages), n in layers.items():
-            b, w = chunk_blocks(pos0, rows, window, self.page_size, pages)
-            blocks, whole = blocks + n * b, whole + n * w
-        span_args.update(attn_blocks=blocks, attn_whole_blocks=whole)
-        self.attn_blocks += blocks
-        self.attn_whole_blocks += whole
-
     # -- a chunk's rows into the pools, a page at a time -----------------
 
     ssm_state_bytes = 0         # live state bytes, over the decode rows' calls
-    kv_write_pages = 0          # pages chunks' rows were cached as
-    kv_write_rows = 0           # carried rows cached one at a time
 
     def _pages_then(self, n, attend):
         """``attend`` of chunk: of the rows layer i caches, the first
@@ -1716,20 +1727,6 @@ class DecodeEngine(object):
                     r[n:].reshape(-1, P, r.shape[-1]))
             return attend(i, q, [r[:n] for r in rows], pools, at, *tables)
         return by_page
-
-    def _kv_writes(self, tokens, step_rows, span_args):
-        """How a chunk call's rows reached the pools, host integers ->
-        the span's ``kv_write_pages`` (the pages the chunk's ``tokens``
-        were written as) and ``kv_write_rows`` (the ``step_rows``
-        carried rows, written one at a time), each times the cache rows
-        and the cache slots (a layer a recurrence), and the engine's
-        totals."""
-        each = len(self.cache.rows) * self.cache.slots
-        pages = -(-tokens // self.page_size) * each
-        span_args.update(kv_write_pages=pages,
-                         kv_write_rows=step_rows * each)
-        self.kv_write_pages += pages
-        self.kv_write_rows += step_rows * each
 
 
 class _DecodeMetrics(object):
@@ -2030,15 +2027,6 @@ class DecodeServer(object):
                 # where the block runs its layers several times a token:
                 # the passes over a weight layer those calls ran
                 'loop_passes': self.engine.loop_passes,
-                # where chunk rows take the live-pages kernel: the
-                # blocks it walked, and those every row saw whole
-                'attn_blocks': self.engine.attn_blocks,
-                'attn_whole_blocks': self.engine.attn_whole_blocks,
-                # how chunk calls' rows reached the pools: the pages the
-                # chunks' rows were written as, and the carried decode
-                # rows written one at a time (x cache rows x slots)
-                'kv_write_pages': self.engine.kv_write_pages,
-                'kv_write_rows': self.engine.kv_write_rows,
             }
 
     # -- worker side ---------------------------------------------------

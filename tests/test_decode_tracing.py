@@ -298,12 +298,6 @@ def test_a_loops_spans_say_what_it_ran(params, ut_steps):
     carrying = [a for a in chunks if a['step_rows']]
     assert steps and carrying and compiles
     alone = [a for a in chunks if not a['step_rows']]
-    # how the chunks' rows reached the pools: a cache slot a layer a
-    # recurrence, K and V
-    slots = (ut_steps or 1) * L
-    for a in chunks:
-        assert a['kv_write_pages'] == -(-a['tokens'] // PAGE) * 2 * slots
-        assert a['kv_write_rows'] == a['step_rows'] * 2 * slots
     if ut_steps is None:
         for a in steps + chunks:
             assert not set(LOOP_ARGS) & set(a)
@@ -379,126 +373,166 @@ def test_only_a_block_with_state_layers_says_what_its_states_did(params,
         assert a['state_bytes_per_stream'] == per_stream
 
 
-def test_a_chunks_span_counts_the_kernels_blocks(monkeypatch):
-    """``attn_blocks`` / ``attn_whole_blocks`` on ``decode.prefill_chunk``
-    and in ``stats()`` where the chunk rows take the live-pages kernel
-    (the path's answer and the kernel's interpreter put in, as
-    ``test_the_chunk_op_takes_the_kernel_where_the_path_says`` does),
-    summed over the layers by the kernel's own arithmetic (whole: a
-    block that every row of its pass sees entirely); where they gather,
-    neither is there."""
-    import sys
-    import test_laguna_decode as lag    # its tiny engine: two layer kinds
-    from paddle_tpu.inference import blocks
-    from paddle_tpu.ops import attention
-    from paddle_tpu.ops.pallas.paged_attention import (
-        chunk_blocks, chunk_paged_attention)
-    params = lag.make_params(0)
-    prompts = [np.random.default_rng(n).integers(1, lag.V, n)
-               for n in (5, 41, 19)]
+# -- set-up: decode.warmup, the three stages of a compile, the first runs ----
 
-    def run():
-        timeline.reset()
-        server = DecodeServer(lag.make_engine(
-            params, top=64, prefill_chunk_tokens=2 * lag.PAGE),
-            warmup=False)
-        try:
-            streams = [server.submit(p, max_new_tokens=4) for p in prompts]
-            tokens = [list(st.result(timeout=300.0)) for st in streams]
-            return server.engine, tokens, server.stats(), [
-                e['args'] for e in spans()
-                if e['name'] == 'decode.prefill_chunk']
-        finally:
-            server.close()
-
-    _eng, gathered, stats, chunks = run()
-    assert chunks and stats['attn_blocks'] == stats['attn_whole_blocks'] == 0
-    for a in chunks:
-        assert 'attn_blocks' not in a and 'attn_whole_blocks' not in a
-
-    kernels = sys.modules['paddle_tpu.ops.pallas.paged_attention']
-    for mod in (blocks, attention):
-        monkeypatch.setattr(mod, 'chunk_attention_path',
-                            lambda *a: 'pallas_paged')
-    monkeypatch.setattr(
-        kernels, 'chunk_paged_attention',
-        lambda *a, **kw: chunk_paged_attention(*a, interpret=True, **kw))
-    # blocks of two pages, so that a prompt of 41 has some behind it
-    monkeypatch.setattr(kernels, '_CHUNK_BLOCK_POSITIONS', 2 * lag.PAGE)
-    eng, tokens, stats, chunks = run()
-    assert tokens == gathered
-    compiled = [e['args'] for e in spans() if e['name'] == 'decode.compile'
-                and e['args']['program'] == 'chunk']
-    assert compiled and all(
-        a['attention'] == {'full': 'xla_gather+pallas_paged',
-                           'window': 'xla_gather+pallas_paged'}
-        for a in compiled)
-    for e in spans():
-        if e['name'] in ('decode.step', 'decode.prefill_into'):
-            assert 'attn_blocks' not in e['args']
-    for a in chunks:
-        assert 0 <= a['attn_whole_blocks'] < a['attn_blocks']
-    assert stats['attn_blocks'] == sum(a['attn_blocks'] for a in chunks)
-    assert stats['attn_whole_blocks'] == sum(
-        a['attn_whole_blocks'] for a in chunks) > 0
-    # one chunk by hand: 8 rows from position 32, two layers of a kind
-    full = chunk_blocks(32, 8, None, lag.PAGE, eng.pages_per_stream)
-    ring = chunk_blocks(32, 8, lag.WINDOW, lag.PAGE, eng.ring_pages)
-    assert full == (5, 4)
-    assert (2 * (full[0] + ring[0]), 2 * (full[1] + ring[1])) in [
-        (a['attn_blocks'], a['attn_whole_blocks']) for a in chunks]
+STAGES = ['decode.compile.trace', 'decode.compile.lower',
+          'decode.compile.backend']
+SETUP_NAMES = set(STAGES) | {'decode.warmup', 'decode.warmup.run'}
 
 
-def test_a_chunks_span_counts_how_its_rows_reached_the_pools(params):
-    """``kv_write_pages`` / ``kv_write_rows`` on ``decode.prefill_chunk``:
-    the pages the chunk's tokens were cached as and the carried decode
-    rows cached one at a time, each times the cache rows (K and V) and
-    the layers; on a chunk alone, on one that carries, and totalled by
-    ``stats()``; a step's span has neither."""
-    eng = make_engine(params, prefill_chunk_tokens=2 * PAGE)
-    each = 2 * L
-    pages = eng.cache.alloc(4)
-    prompt = np.arange(1, 2 * PAGE + 4)          # 16 + 3 tokens
-    for lo, hi in eng.chunk_spans(len(prompt)):
-        first = eng.prefill_chunk(prompt[lo:hi], pages, lo)
-    alone = [e['args'] for e in spans() if e['name'] == 'decode.prefill_chunk']
-    assert [(a['kv_write_pages'], a['kv_write_rows']) for a in alone] \
-        == [(2 * each, 0), (1 * each, 0)]
-    # a chunk of a page and one token that carries the first stream's row
-    tok = np.zeros(STREAMS, np.int32)
-    ctx = np.zeros(STREAMS, np.int32)
-    pt = np.full((STREAMS, eng.pages_per_stream), eng.cache.trash, np.int32)
-    tok[1], ctx[1], pt[1, :4] = int(np.argmax(first)), len(prompt), pages
-    eng.prefill_chunk(np.arange(1, PAGE + 2), eng.cache.alloc(2), 0,
-                      tok, pt, ctx)
-    carrying = [e['args'] for e in spans()
-                if e['name'] == 'decode.prefill_chunk'][-1]
-    assert carrying['step_rows'] == 1
-    assert (carrying['kv_write_pages'], carrying['kv_write_rows']) \
-        == (2 * each, 1 * each)
-    eng.step(tok, pt, ctx + 1)
-    step, = [e['args'] for e in spans() if e['name'] == 'decode.step']
-    assert 'kv_write_pages' not in step and 'kv_write_rows' not in step
-    assert (eng.kv_write_pages, eng.kv_write_rows) == (5 * each, each)
+def family(evs):
+    """(by id, {id: its children in the order they started}, a function
+    from an event to the names of its ancestors, nearest first)."""
+    by_id = {e['id']: e for e in evs}
+    kids = {}
+    for e in sorted(evs, key=lambda e: e['ts']):
+        kids.setdefault(e['parent'], []).append(e)
+
+    def ancestors(e):
+        out = []
+        while e['parent'] is not None:
+            e = by_id[e['parent']]
+            out.append(e['name'])
+        return out
+    return by_id, kids, ancestors
 
 
-def test_stats_total_the_pages_and_rows_chunks_wrote(params):
-    eng = make_engine(params, prefill_chunk_tokens=PAGE)
-    rng = np.random.default_rng(3)
-    server = DecodeServer(eng, warmup=False)
+def inside(child, parent):
+    return parent['ts'] <= child['ts'] and \
+        child['ts'] + child['dur'] <= parent['ts'] + parent['dur'] + 1e-9
+
+
+def built_by_warmup(chunked):
+    return [('chunk', 8), ('step', None)] if chunked else \
+        [('prefill', 8), ('pack', 8), ('prefill', 16), ('pack', 16),
+         ('step', None)]
+
+
+@pytest.mark.parametrize('chunked', [False, True])
+def test_a_warmups_compiles_have_their_three_stages_in_order(params,
+                                                             chunked):
+    eng = make_engine(params, prefill_chunk_tokens=PAGE if chunked else 0)
+    eng.warmup()
+    evs = spans()
+    by_id, kids, ancestors = family(evs)
+    warm, = [e for e in evs if e['name'] == 'decode.warmup']
+    assert warm['parent'] is None
+    comp = [e for e in evs if e['name'] == 'decode.compile']
+    assert [(e['args']['program'], e['args']['bucket']) for e in comp] \
+        == built_by_warmup(chunked)
+    assert warm['args'] == {'programs': len(comp), 'runs': len(comp)}
+    for c in comp:
+        below = kids[c['id']]
+        assert [k['name'] for k in below] == STAGES
+        assert all(inside(k, c) for k in below)
+        # one after the other: a stage starts where the last one ended
+        for a, b in zip(below, below[1:]):
+            assert a['ts'] + a['dur'] <= b['ts']
+        assert [k['args'] for k in below] == [None, None, {'cache': 'off'}]
+    for e in evs:
+        if e['name'] in ('decode.compile', 'decode.warmup.run'):
+            assert ancestors(e) == ['decode.warmup'] and inside(e, warm)
+        if e['name'] in STAGES:
+            assert ancestors(e) == ['decode.compile', 'decode.warmup']
+    # (the engine placed its weights when it was made, before warm-up)
+    assert {e['name'] for e in evs} == SETUP_NAMES | {'decode.compile',
+                                                      'decode.weights'}
+    # what no child names is the span's own, and never negative
+    assert sum(k['dur'] for k in kids[warm['id']]) <= warm['dur'] + 1e-9
+
+
+@pytest.mark.parametrize('chunked', [False, True])
+def test_one_warm_run_for_each_executable_warmup_runs(params, chunked):
+    eng = make_engine(params, prefill_chunk_tokens=PAGE if chunked else 0)
+    eng.warmup()
+    runs = [e for e in spans() if e['name'] == 'decode.warmup.run']
+    assert [(e['args']['program'], e['args']['bucket']) for e in runs] \
+        == sorted(built_by_warmup(chunked), key=lambda p: p[0] == 'step')
+    assert all(set(e['args']) == {'program', 'bucket'} and e['dur'] > 0
+               for e in runs)
+    # the runs come after every compile, and one at a time
+    last = max(e['ts'] + e['dur'] for e in spans()
+               if e['name'] == 'decode.compile')
+    assert all(last <= e['ts'] for e in runs)
+    for a, b in zip(runs, runs[1:]):
+        assert a['ts'] + a['dur'] <= b['ts']
+
+
+def test_a_bucket_met_after_warmup_has_its_stages_under_its_prefill(params):
+    eng = make_engine(params, top=32)
+    eng.buckets = [8, 16]
+    eng.warmup()
+    timeline.ring().clear()
+    eng.buckets = [8, 16, 32]
+    eng.prefill_into(np.arange(1, 21, dtype=np.int32), eng.cache.alloc(3))
+    evs = spans()
+    _by_id, kids, ancestors = family(evs)
+    comp = [e for e in evs if e['name'] == 'decode.compile']
+    assert len(comp) == 2
+    for c in comp:
+        assert [k['name'] for k in kids[c['id']]] == STAGES
+        assert ancestors(c) == ['decode.prefill_into']
+    assert sorted(e['name'] for e in evs) == sorted(
+        STAGES * 2 + ['decode.compile'] * 2 + ['decode.prefill_into'])
+    # the re-warm builds nothing, and runs everything once more
+    timeline.ring().clear()
+    eng.warmup()
+    warm, = [e for e in spans() if e['name'] == 'decode.warmup']
+    assert warm['args'] == {'programs': 0, 'runs': 7}
+    assert {e['name'] for e in spans()} == {'decode.warmup',
+                                            'decode.warmup.run'}
+
+
+@pytest.mark.parametrize('chunked', [False, True])
+def test_a_second_warmup_with_nothing_new_records_no_span(params, chunked):
+    eng = make_engine(params, prefill_chunk_tokens=PAGE if chunked else 0)
+    eng.warmup()
+    assert spans()
+    timeline.ring().clear()
+    eng.warmup()
+    assert timeline.ring().events() == []
+    assert eng.compiles_after_warmup == 0
+
+
+def test_metrics_disabled_leaves_none_of_the_setup_spans(params):
+    obs.set_enabled(False)
     try:
-        streams = [server.submit(rng.integers(1, V, n), max_new_tokens=5)
-                   for n in (5, 20, 9)]
-        for st in streams:
-            st.result(timeout=120.0)
-        stats = server.stats()
+        eng = make_engine(params, top=32)
+        eng.buckets = [8, 16]
+        eng.warmup()
+        eng.buckets = [8, 16, 32]
+        eng.prefill_into(np.arange(1, 21, dtype=np.int32),
+                         eng.cache.alloc(3))
+        assert eng.compiles_total == 7 and eng.compiles_after_warmup == 2
+        assert timeline.ring().events() == []
     finally:
-        server.close()
-    chunks = [e['args'] for e in spans()
-              if e['name'] == 'decode.prefill_chunk']
-    assert stats['kv_write_pages'] == sum(
-        a['kv_write_pages'] for a in chunks) \
-        == 2 * L * sum(-(-a['tokens'] // PAGE) for a in chunks) > 0
-    assert stats['kv_write_rows'] == sum(
-        a['kv_write_rows'] for a in chunks) \
-        == 2 * L * sum(a['step_rows'] for a in chunks) > 0
+        obs.reload_enabled()
+    timeline.ring().clear()
+    eng.warmup()        # and with them on again, the re-warm is seen
+    assert {e['name'] for e in spans()} == {'decode.warmup',
+                                            'decode.warmup.run'}
+
+
+def test_the_backend_span_says_what_the_cache_did(params, compile_cache):
+    """With a persistent cache, a fresh directory: every program of the
+    first engine is a miss (XLA compiled it and the entry was written),
+    every program of a second engine over the same weights a hit; the
+    other cases of this file, with no cache, read ``'off'``."""
+    said = []
+    for _ in range(2):
+        timeline.ring().clear()
+        make_engine(params, prefill_chunk_tokens=PAGE).warmup()
+        said.append([e['args']['cache'] for e in spans()
+                     if e['name'] == 'decode.compile.backend'])
+    assert said == [['miss', 'miss'], ['hit', 'hit']]
+    assert any(compile_cache.iterdir())
+
+
+# chipbench's reader of these spans, on a ring built by hand: its cases
+# live with the benchmark (chipbench/tests/test_setup_spans.py, which tier
+# 1 does not collect) and are collected here by name
+from chipbench.tests.test_setup_spans import (  # noqa: E402,F401
+    test_a_cut_ring_gives_none_never_a_partial_sum,
+    test_a_ring_without_the_spans_gives_none_and_not_zero,
+    test_sums_by_name_counts_by_cache_and_stops_at_t_open,
+    test_the_setup_spans_line_has_a_row_a_program_once_a_run)
