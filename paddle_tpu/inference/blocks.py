@@ -38,7 +38,8 @@ description supplies the rest, once, as pure functions of the weights (a
   prompt chunk (query j at position ``pos0 + j``) over the stream's
   pages ``pt`` [MPP] of layer i's ``pools`` (one buffer a cache row);
   ``attend_step(p, i, q, pools, pt, ctx_len)`` -> ctx: one token a slot
-  over the slot's pages ``pt`` [S, MPP], ``ctx_len`` [S] positions each;
+  over the slot's pages ``pt`` [S, MPP], ``ctx_len`` [S] positions each
+  (0 for an idle slot, which attends over nothing);
 - ``describe(program, sizes, backend, page_size, dtype)`` -> what the
   program's ``decode.compile`` span says of the block's part in it
   (which attention its shapes take);

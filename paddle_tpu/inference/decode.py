@@ -1227,11 +1227,16 @@ class DecodeEngine(object):
                 tables[self._group[i]], pos0)
         return read
 
-    def _step_read(self, params, pos):
+    def _step_read(self, params, pos, running):
+        # a slot that is not running attends over nothing: a context of
+        # 0 is the idle slot the step kernels skip (its table is all
+        # trash, and its row is not counted)
+        ctx_len = jnp.where(running, pos + 1, 0)
+
         def read(i, q, pools, tables):
             return self.block.attend_step(
                 params, i, q, [pool[i] for pool in pools],
-                tables[self._group[i]], pos + 1)
+                tables[self._group[i]], ctx_len)
         return read
 
     def _next_rows(self, pt, ctx_len, nxt):
@@ -1257,7 +1262,7 @@ class DecodeEngine(object):
             spos, spage, soffset = self._step_rows(step_pt, ctx_len)
             pos, valid, page_ids = self._chunk_rows(
                 bucket, pt, pos0, n_valid)
-            read_step = self._step_read(params, spos)
+            read_step = self._step_read(params, spos, step_pt[:, 0] != trash)
             read_chunk = self._chunk_read(params, pos0)
             step_tables, tables = self._tables(step_pt), self._tables(pt)
 
@@ -1314,7 +1319,8 @@ class DecodeEngine(object):
             x, pools, _kept, extra = self._layers(
                 params, blk.embed(params, tokens, pos), pos,
                 pt[:, 0] != trash,
-                self._write_then(offset, self._step_read(params, pos)),
+                self._write_then(offset, self._step_read(
+                    params, pos, pt[:, 0] != trash)),
                 pools=args[:n], pages=(page_idx, self._tables(pt)),
                 advance=self._advance_rows(pt[:, 0] != trash)
                 if self.state_runs else None)

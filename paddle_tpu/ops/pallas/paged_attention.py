@@ -8,7 +8,9 @@ slot's ``ceil(ctx_len / P)`` live pages straight from the pool as the
 engine holds it, ``[N, P, H*D]``: the pools stay in HBM, the page table
 and the context lengths are scalar-prefetched, and the kernel copies
 blocks of pages to VMEM itself (one DMA a page, the next block in
-flight while this one is multiplied).  One grid step is one slot.  A
+flight while this one is multiplied).  One grid step is one slot, and
+the step of a slot without context is bare: no query copied in, no
+scratch set, no output written (``_held_slots``, ``_skip_idle``).  A
 block is ``[T, H*D]`` with every head's keys side by side on the lanes,
 so all heads go through the MXU at once against a block-diagonal query
 ``[H, H*D]`` (row h holds q_h on head h's lanes): scores ``[H, T]``,
@@ -78,11 +80,45 @@ def _live_pages(ctx, page, window):
     return first, pl.cdiv(ctx, page) - first, lo
 
 
-def _kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+# -- a slot without context costs the step kernels a bare grid step ---------
+
+def _held_slots(ctx_len):
+    """The slot whose query and output blocks each grid step holds: its
+    own where the slot is live, else the live slot's before it (slot
+    0's, before any).  Pallas copies no block whose index did not
+    change, so an idle step moves no query in and no output out.
+    Arithmetic on ``ctx_len`` alone, no sort: one small reduction that
+    the layers of a step share."""
+    at = jnp.arange(ctx_len.shape[0], dtype=jnp.int32)
+    live_before = (ctx_len > 0)[None, :] & (at[None, :] <= at[:, None])
+    return jnp.max(jnp.where(live_before, at[None, :], 0), axis=1)
+
+
+def _skip_idle(body):
+    """``body(slot, pt_ref, len_ref, *refs)`` as the kernel of a call
+    whose third prefetched scalars are ``_held_slots``: the step of a
+    slot without context runs nothing of it (no scratch set, no query
+    laid out, no output written)."""
+    def kernel(pt_ref, len_ref, held_ref, *refs):
+        del held_ref                        # the index maps read it
+        slot = pl.program_id(0)
+        pl.when(len_ref[slot] > 0)(
+            functools.partial(body, slot, pt_ref, len_ref, *refs))
+    return kernel
+
+
+def _zero_idle(out, ctx_len):
+    """``out`` [S, rows, lanes] with zeros in the rows of the slots
+    without context: a skipped step wrote nothing there, so they hold
+    whatever the buffer held."""
+    return jnp.where((ctx_len > 0)[:, None, None], out,
+                     jnp.zeros((), out.dtype))
+
+
+def _kernel(s, pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
             sem, m_scr, l_scr, acc_scr, *, scale, page, ppb, mpp,
             head_dim, kv_heads, group, window, precision):
-    s = pl.program_id(0)
-    ctx = len_ref[s]
+    ctx = len_ref[s]            # of slot ``s``, this grid step's: > 0
     # live pages of this slot: with a window, the pages that hold its
     # ``window`` newest positions, in a table that is a ring
     first, n_pages, lo = _live_pages(ctx, page, window)
@@ -246,12 +282,15 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
             0, 2, 1, 3).reshape(s, group, hd)
 
     rows = hp if kv_heads == 1 and group > 1 else group
-    row = pl.BlockSpec((1, rows, hd), lambda i, pt, ln: (i, 0, 0))
+    row = pl.BlockSpec((1, rows, hd), lambda i, pt, ln, held: (held[i], 0, 0))
+    # (a ring holds the newest of any number of positions)
+    ctx_len = jnp.clip(ctx_len.astype(jnp.int32), 0, mpp * page) \
+        if window is None else jnp.maximum(ctx_len.astype(jnp.int32), 0)
     out = pl.pallas_call(
-        kernel,
+        _skip_idle(kernel),
         out_shape=jax.ShapeDtypeStruct((s, rows, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(s,),
             in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -271,10 +310,9 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_len, scale=None,
         name='paged_attention_live_pages',
         interpret=interpret,
     )(jnp.clip(page_table.astype(jnp.int32), 0, n - 1).reshape(-1),
-      # (a ring holds the newest of any number of positions)
-      jnp.clip(ctx_len.astype(jnp.int32), 0, mpp * page)
-      if window is None else jnp.maximum(ctx_len.astype(jnp.int32), 0),
+      ctx_len, _held_slots(ctx_len),
       queries(), k_pool.reshape(n, page, hd), v_pool.reshape(n, page, hd))
+    out = _zero_idle(out, ctx_len)
     if group == 1:
         return out.reshape(s, h, d)
     if kv_heads == 1:
@@ -503,11 +541,11 @@ def latent_supported(page_size, dtype):
     return page_size % _sublane_rows(dtype) == 0
 
 
-def _latent_kernel(pt_ref, len_ref, q_ref, pool_hbm, o_ref, buf, sem,
+def _latent_kernel(g, pt_ref, len_ref, q_ref, pool_hbm, o_ref, buf, sem,
                    m_scr, l_scr, acc_scr, *, scale, page, ppb, mpp, heads,
                    group, value_dim, precision):
-    g = pl.program_id(0)
-    ctx = len_ref[g]            # positions the group's LAST token sees
+    # positions the LAST token of group ``g``, this grid step's, sees: > 0
+    ctx = len_ref[g]
     n_pages = pl.cdiv(ctx, page)
     n_blocks = pl.cdiv(n_pages, ppb)
     rows = group * heads
@@ -619,17 +657,19 @@ def latent_paged_attention(q, pool, page_table, ctx_len, scale, value_dim,
         heads=heads, group=group, value_dim=value_dim,
         precision=(jax.lax.Precision.HIGHEST if dtype == jnp.float32
                    else None))
+    ctx_len = jnp.clip(ctx_len.astype(jnp.int32), 0, mpp * page)
     out = pl.pallas_call(
-        kernel,
+        _skip_idle(kernel),
         out_shape=jax.ShapeDtypeStruct((groups, rows, value_dim),
                                        jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(groups,),
-            in_specs=[pl.BlockSpec((1, rows, w), lambda i, pt, ln: (i, 0, 0)),
+            in_specs=[pl.BlockSpec((1, rows, w),
+                                   lambda i, pt, ln, held: (held[i], 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, rows, value_dim),
-                                   lambda i, pt, ln: (i, 0, 0)),
+                                   lambda i, pt, ln, held: (held[i], 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, ppb * page, w), dtype),
                 pltpu.SemaphoreType.DMA((2,)),
@@ -642,9 +682,9 @@ def latent_paged_attention(q, pool, page_table, ctx_len, scale, value_dim,
         name='latent_paged_attention_live_pages',
         interpret=interpret,
     )(jnp.clip(page_table.astype(jnp.int32), 0, n - 1).reshape(-1),
-      jnp.clip(ctx_len.astype(jnp.int32), 0, mpp * page),
+      ctx_len, _held_slots(ctx_len),
       q.astype(dtype).reshape(groups, rows, w), pool)
-    return out.reshape(n_tok, heads, value_dim)
+    return _zero_idle(out, ctx_len).reshape(n_tok, heads, value_dim)
 
 
 # -- the chunk kernel's blocks, in the kernel and counted on the host (down

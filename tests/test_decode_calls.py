@@ -428,6 +428,22 @@ def test_an_idle_row_is_zero_trash_zero_on_the_device(model, ring):
     assert not np.asarray(held[2])[idle].any()
 
 
+@pytest.mark.parametrize('model', sorted(MODELS))
+def test_an_idle_slot_attends_over_no_position(model, monkeypatch):
+    """A step's read (a plain step's and a carrying chunk's) hands the
+    block's ``attend_step`` ``pos + 1`` positions for a running slot
+    and 0 for an idle one, the context the live-pages kernels skip: an
+    idle slot used to attend over position 0 of the trash page, a copy
+    of K and V a layer for every slot that held nothing."""
+    _, params, eng, _ = engine(model)
+    monkeypatch.setattr(eng.block, 'attend_step',
+                        lambda p, i, q, pools, pt, ctx_len: ctx_len)
+    read = eng._step_read(params, jnp.asarray([4, 0, 0, 7]),
+                          jnp.asarray([True, False, True, False]))
+    got = read(0, None, [], [None] * (max(eng._group) + 1))
+    assert np.array_equal(np.asarray(got), [5, 0, 1, 0])
+
+
 @pytest.mark.parametrize('chunked', [False, True])
 @pytest.mark.parametrize('model', sorted(MODELS))
 def test_a_replay_by_hand_with_int64_ids_reuses_and_meets_the_reference(

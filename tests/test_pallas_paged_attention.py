@@ -451,3 +451,87 @@ def test_the_chunk_op_takes_the_kernel_where_the_path_says(monkeypatch):
                       {'window': 512})['Out'][0]
     assert calls == [{'scale': None, 'window': 512}]    # the math
     close(np.asarray(got), np.asarray(want), jnp.float32)
+
+
+# -- slots without context: the step kernels skip them, and say zero --------
+
+from jax.experimental.pallas import tpu as pltpu  # noqa: E402
+from paddle_tpu.ops.pallas.paged_attention import (  # noqa: E402
+    latent_paged_attention)
+
+# the TPU's interpreter copies blocks as the chip's pipeline does (none
+# whose index did not change, an output when its index moves on) and
+# starts the output and every buffer as NaN: what a skipped grid step
+# leaves unwritten shows, as it does on a chip and never in
+# ``interpret=True``'s block-by-block copies
+POISONED = pltpu.InterpretParams(uninitialized_memory='nan')
+SLOTS = 6
+IDLE = {'leading': [0, 0, 0, 1, 1, 1], 'trailing': [1, 1, 1, 0, 0, 0],
+        'alternating': [0, 1, 0, 1, 0, 1], 'all_but_one': [0, 0, 0, 0, 1, 0],
+        'all': [0] * SLOTS}
+# (query heads a K/V head, K/V heads, head width, the pools' dtype); the
+# latent kernel: tokens a group
+STEP_KERNELS = {'group1_f32': (1, HKV, DG, jnp.float32),
+                'group6_f32': (6, HKV, DG, jnp.float32),
+                'group9_f32': (9, HKV, DG, jnp.float32),
+                'group6_bf16': (6, HKV, DG, jnp.bfloat16),
+                'one_kv_head_bf16': (5, 1, 128, jnp.bfloat16),
+                'latent_group1': 1, 'latent_group8': 8}
+DEAD = slice(50, 60)        # the pool's pages behind the idle slots' tables
+
+
+def step_kernel(kernel, window):
+    """-> (run(q, pt, ctx) of any number of slots, the query rows a slot,
+    q of ``SLOTS`` slots, the table's columns, three live lengths).  The
+    pools are NaN on the ``DEAD`` pages."""
+    rng = np.random.RandomState(16)
+    mpp = max(RINGS[window][1], 12)
+    if kernel.startswith('latent'):
+        per = STEP_KERNELS[kernel]
+        pool = jnp.asarray(rng.randn(60, P, 192), jnp.bfloat16) \
+            .at[DEAD].set(jnp.nan)
+        q = jnp.asarray(rng.randn(SLOTS * per, 4, 192), jnp.float32)
+
+        def run(q, pt, ctx):
+            return latent_paged_attention(q, pool, pt, ctx, 0.1, 128,
+                                          group=per, interpret=POISONED)
+        return run, per, q, mpp, [8, 77, mpp * P]
+    group, kv_heads, d, dtype = STEP_KERNELS[kernel]
+    k, v = (jnp.asarray(rng.randn(60, P, kv_heads * d), dtype)
+            .at[DEAD].set(jnp.nan) for _ in range(2))
+    q = jnp.asarray(rng.randn(SLOTS, kv_heads * group, d), jnp.float32)
+    kw = {} if window is None else {'window': window}
+
+    def run(q, pt, ctx):
+        return paged_attention(q, k, v, pt, ctx, interpret=POISONED, **kw)
+    # (under a window a context is any length: the ring holds its newest)
+    return run, 1, q, mpp, [P + 1, 77, mpp * P if window is None else 2000]
+
+
+@pytest.mark.parametrize('pattern', sorted(IDLE))
+@pytest.mark.parametrize('kernel,window', [
+    (k, w) for k in sorted(STEP_KERNELS)
+    for w in ([None] if k.startswith('latent') else [None, 512, 5])])
+def test_idle_slots_are_skipped_and_zero(kernel, window, pattern):
+    """Live rows are, bit for bit, the kernel's on that slot alone,
+    whatever its neighbours; a slot without context comes out exactly
+    zero though its step wrote nothing, the output buffer began as NaN
+    and its table points at pages of NaN."""
+    run, per, q, mpp, lengths = step_kernel(kernel, window)
+    rng = np.random.RandomState(17)
+    live = np.array(IDLE[pattern], bool)
+    ctx = np.zeros(SLOTS, np.int32)
+    ctx[live] = (lengths * 2)[:live.sum()]
+    pt = np.stack([rng.permutation(50)[:mpp] if on else
+                   50 + rng.permutation(10)[np.arange(mpp) % 10]
+                   for on in live]).astype(np.int32)
+    got = np.asarray(run(q, jnp.asarray(pt), jnp.asarray(ctx)))
+    assert got.shape[0] == SLOTS * per and got.dtype == np.float32
+    got = got.reshape((SLOTS, per) + got.shape[1:])
+    assert np.all(np.isfinite(got))
+    assert np.array_equal(got[~live], np.zeros_like(got[~live]))
+    for s in np.flatnonzero(live):
+        alone = run(q[s * per:(s + 1) * per], jnp.asarray(pt[s:s + 1]),
+                    jnp.asarray(ctx[s:s + 1]))
+        assert np.array_equal(got[s], np.asarray(alone)), s
+        assert np.any(got[s] != 0)
